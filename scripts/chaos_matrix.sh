@@ -123,7 +123,7 @@
 # bit-identically.
 #
 # Since ISSUE 18 the matrix also covers the RANGED-PREFILL cells
-# (tests/test_ranged_prefill.py): the pipelined disagg handoff — decode
+# (tests/test_ranged_engine.py): the pipelined disagg handoff — decode
 # admission at FIRST-page-landed while the tail streams — must keep the
 # transfer-span decomposition exact with tokens byte-identical, and a
 # corrupt KV chunk injected mid-pipelined-handoff must walk the guard
@@ -142,7 +142,7 @@
 # worlds {2, 4, 8}.
 #
 # Since ISSUE 20 the matrix also covers the SPECULATIVE-SERVING cells
-# (tests/test_spec_serving.py): a corrupted draft token injected
+# (tests/test_spec_soak.py): a corrupted draft token injected
 # mid-round must be REJECTED by the batched verify pass with the token
 # stream byte-identical to a non-speculative run, and the quick
 # speculative soak campaign — self-draft speculation × scheduled draft
@@ -168,7 +168,7 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
-log="$(mktemp /tmp/chaos_matrix.XXXXXX.log)"
+log="$(mktemp "${TMPDIR:-/tmp}/chaos_matrix.XXXXXX.log")"
 trap 'rm -f "$log"' EXIT
 
 files="tests/test_chaos.py tests/test_elastic.py \
@@ -177,8 +177,8 @@ files="tests/test_chaos.py tests/test_elastic.py \
     tests/test_obs.py tests/test_analysis.py tests/test_overload.py \
     tests/test_prefix_cache.py tests/test_disagg.py tests/test_synth.py \
     tests/test_flight_recorder.py tests/test_fleet.py \
-    tests/test_recovery.py tests/test_ranged_prefill.py \
-    tests/test_fp8.py tests/test_spec_serving.py"
+    tests/test_recovery.py tests/test_ranged_engine.py \
+    tests/test_fp8.py tests/test_spec_serving.py tests/test_spec_soak.py"
 marker="chaos"
 lint_args=""
 if [ "${1:-}" = "--quick" ]; then
@@ -188,8 +188,8 @@ if [ "${1:-}" = "--quick" ]; then
         tests/test_prefix_cache.py tests/test_disagg.py \
         tests/test_synth.py tests/test_flight_recorder.py \
         tests/test_fleet.py tests/test_recovery.py \
-        tests/test_ranged_prefill.py tests/test_fp8.py \
-        tests/test_spec_serving.py"
+        tests/test_ranged_engine.py tests/test_fp8.py \
+        tests/test_spec_serving.py tests/test_spec_soak.py"
     marker="chaos and not slow"
     # keep the quick posture bounded: worlds {2,4} (the full {2,4,8}
     # sweep is the default standalone run's job)

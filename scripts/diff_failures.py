@@ -16,7 +16,7 @@ Usage::
     scripts/diff_failures.py <pytest-log> [manifest] [--update]
 
 - ``<pytest-log>``: a ``pytest -q`` capture (run_tier1.sh passes
-  ``/tmp/_t1.log``); failures are the ``FAILED <nodeid>[ - reason]`` lines.
+  its tier-1 log); failures are the ``FAILED <nodeid>[ - reason]`` lines.
 - ``manifest``: defaults to ``tests/known_failures.txt`` next to this repo.
 - ``--update``: rewrite the manifest to exactly this run's failure set
   and PRINT the node ids removed/added relative to the old manifest (a
@@ -27,11 +27,9 @@ Usage::
 Exit codes: 0 = subset (prints the fixed set, if any); 1 = new failures
 (prints them); 2 = usage/IO error.
 
-The manifest describes ONE documented environment (this box's jax line —
-see CHANGES.md baselines). On a healthy install the failure set is empty
-and the subset check is trivially green; on a different degraded
-environment the manifest will not match — regenerate it there with
-``--update`` before relying on the gate.
+The manifest describes the ONE installed toolchain (jax 0.9.0; regenerated
+in PR 23 from the driver's command). Shrink it with ``--update`` after
+fixing failures.
 """
 
 from __future__ import annotations

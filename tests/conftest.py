@@ -8,21 +8,18 @@ deadlock when many interpreted remote DMAs move large payloads concurrently
 box). Keep per-DMA test payloads <= ~8 KiB; correctness coverage does not
 need more, and real-TPU runs are unaffected.
 
-Runtime budget (1-core box, re-measured 2026-08-01): the `-m quick` tier
-is the fast gate (~8 min at 164 tests — it grows with kernel-family
-coverage; the whole-loop speculative integration tests moved to the
-slow tier when the r5 device-side while_loop rewrite tripled their
-interpret-mode cost); the full suite is ~65 min (test_decode ~14 min
-and test_models ~9 min dominate). The floor is
-structural, not shape-driven: every interpreted pallas_call pays ~44 ms
-of host machinery (≈112 io_callbacks + the per-call shared-memory
-setup/cleanup barriers across virtual devices — profiled against
-jax 0.9 interpret_pallas_call), and a model-level train-step test runs
-hundreds of such calls plus a ~35 s trace+XLA-compile of its fwd+bwd
-shard_map program that no persistent cache can hold (callback-bearing
-executables are not cacheable). Model tests therefore use the smallest
-layer count that still covers their property, and serving programs are
-shared across tests via the keyed `jit_shard_map` cache."""
+Runtime budget: the driver runs tier-1 (`-m 'not slow'`) with six xdist
+workers and `--dist loadfile` inside 1470 s, so the wall time is at least
+the slowest FILE. Keep files under ~5 min of test time each (PR 23 split
+test_ranged_prefill.py and test_spec_serving.py for this; the serving soak
+campaigns are the long poles). The floor is structural, not shape-driven:
+every interpreted pallas_call pays ~44 ms of host machinery (io_callbacks
+plus per-call shared-memory setup across virtual devices), and a
+model-level test runs hundreds of such calls plus a ~35 s trace+compile
+that no persistent cache can hold (callback-bearing executables are not
+cacheable). Model tests therefore use the smallest layer count that still
+covers their property, and serving programs are shared across tests via
+the keyed `jit_shard_map` cache."""
 
 import os
 import signal
@@ -67,7 +64,23 @@ def pytest_configure(config):
     )
 
 
+# Heaviest files first (test-seconds of PR 23's run, longest first). Under
+# `--dist loadfile` xdist hands whole files to workers in collection
+# order, i.e. alphabetically: a multi-minute file that sorts late starts
+# late and becomes the wall time (a 430 s soak starting at 630 s made a
+# 615 s-ideal run take 1066 s). Everything not named keeps its order.
+_LONG_POLES = (
+    "test_chunked_prefill.py",
+    "test_ranged_batcher.py", "test_ranged_prefill.py",
+    "test_ranged_engine.py", "test_spec_soak.py", "test_prefix_cache.py",
+    "test_disagg.py", "test_ragged.py", "test_overload.py",
+    "test_chip_smoke.py", "test_recovery.py",
+)
+
+
 def pytest_collection_modifyitems(config, items):
+    rank = {name: i for i, name in enumerate(_LONG_POLES)}
+    items.sort(key=lambda it: rank.get(it.path.name, len(rank)))  # stable
     for item in items:
         # soak implies slow (ISSUE 11): the campaign tier never rides the
         # fast gate, and forgetting the second marker can't break that
@@ -146,9 +159,7 @@ def _resilience_isolation():
     """The resilience health registry is process-global: a watchdog
     quarantine or downgrade recorded by one test would pin later tests'
     op entries to the golden path, silently changing what they cover.
-    Reset around every test — keeping only the environment pins (whether
-    this jax install can build fused kernels doesn't change per test, and
-    re-paying the failing trace hundreds of times would)."""
+    Reset around every test."""
     from triton_dist_tpu import resilience
     from triton_dist_tpu.obs import alerts, blackbox, metrics
 
@@ -161,10 +172,10 @@ def _resilience_isolation():
         alerts.reset()
         blackbox.reset()
 
-    resilience.reset(keep_env=True)
+    resilience.reset()
     _flight_recorder_reset()
     yield
-    resilience.reset(keep_env=True)
+    resilience.reset()
     _flight_recorder_reset()
 
 

@@ -36,6 +36,36 @@ def test_all_gather_methods(mesh8, method, dtype):
 
 
 @pytest.mark.parametrize("method", ["ring_1d", "ring_bidir", "full_mesh_push"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_all_gather_rows_off_the_sublane_tile(mesh4, method, dtype):
+    """258 rows per PE — the flash-decode combine's payload at Llama-8B
+    widths (b*hq + ceil(b*hq/d)) — is not a whole number of 8-row (f32) or
+    16-row (bf16) sublane tiles. On the chip an unpadded slot at the
+    dynamic offset ``me*258`` halted the four-chip decode step (PR 23);
+    ``all_gather`` pads the rows and slices them back. Interpret mode
+    cannot show the halt, so this pins the pad/slice arithmetic against
+    ``lax.all_gather``; ``tests/test_chip_compile.py`` holds the compile."""
+    m, d = 258, 8   # <= ~8 KiB per PE (see above)
+
+    def both(x):
+        return (
+            all_gather(x, axis="tp", method=method),
+            jax.lax.all_gather(x, "tp", tiled=True),
+        )
+
+    fn = jax.jit(
+        jax.shard_map(
+            both, mesh=mesh4, in_specs=P("tp"), out_specs=(P(None), P(None)),
+            check_vma=False,
+        )
+    )
+    x = jax.random.normal(jax.random.PRNGKey(7), (4 * m, d)).astype(dtype)
+    out, want = fn(x)
+    assert out.shape == want.shape == (4 * m, d) and out.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("method", ["ring_1d", "ring_bidir", "full_mesh_push"])
 def test_all_gather_smaller_world(mesh4, method):
     m, d = 8, 128
     x = jax.random.normal(jax.random.PRNGKey(0), (4 * m, d), jnp.float32)

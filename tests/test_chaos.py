@@ -36,13 +36,6 @@ from triton_dist_tpu.resilience import watchdog
 
 pytestmark = pytest.mark.chaos
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="fault injection needs the Mosaic TPU interpreter (jax >= 0.6); "
-    "on this jax line the fused kernels degrade to XLA goldens instead "
-    "(covered by the degradation tests)",
-)
 
 # interpret-mode poll iterations cost a host callback each — keep budgets
 # small; a real lost signal trips within a handful of polls
@@ -305,45 +298,6 @@ def test_watchdog_quarantine_pins_family_to_golden():
     assert health.short_circuited("chaos_quarantine") is None
 
 
-def test_process_global_failure_memoized_at_op_level_only():
-    """A missing-API failure pins an op-level family to its golden path
-    (the env cannot heal mid-process; re-paying the failing trace per
-    serving step is real cost). Topology failures and direct shard-level
-    calls are never pinned."""
-    golden = lambda: 7
-    env_calls = {"n": 0}
-
-    def env_broken():
-        env_calls["n"] += 1
-        raise NotImplementedError("no Mosaic interpreter on this jax")
-
-    entry = resilience.guard_op("chaos_env_op", golden)(env_broken)
-    assert entry() == 7 and entry() == 7
-    assert env_calls["n"] == 1, "op entry must not re-pay the failing trace"
-    assert health.short_circuited("chaos_env_op")
-
-    topo_calls = {"n": 0}
-
-    def topo_broken():
-        topo_calls["n"] += 1
-        raise resilience.UnsupportedTopologyError("axis has no ICI path")
-
-    entry = resilience.guard_op("chaos_topo_op", golden)(topo_broken)
-    assert entry() == 7 and entry() == 7
-    assert topo_calls["n"] == 2, "topology failures are per-mesh, not pinned"
-    assert health.short_circuited("chaos_topo_op") is None
-
-    shard_calls = {"n": 0}
-
-    def shard_broken():
-        shard_calls["n"] += 1
-        raise NotImplementedError("no Mosaic interpreter on this jax")
-
-    assert resilience.guarded_call("chaos_env_shard", shard_broken, golden) == 7
-    assert resilience.guarded_call("chaos_env_shard", shard_broken, golden) == 7
-    assert shard_calls["n"] == 2, "direct shard-level calls always re-attempt"
-
-
 def test_health_registry_snapshot_shape():
     health.record_downgrade("fam_a", "forced", RuntimeError("x"))
     health.record_timeout("fam_b", [{"pe": 1}])
@@ -406,14 +360,12 @@ def _run_cell(mesh, family, plan):
 
 
 # fast representative slice — rides tier-1
-@needs_interpreter
 @pytest.mark.parametrize("fault", ["drop_signal", "straggler"])
 def test_chaos_quick(fault, mesh4):
     _run_cell(mesh4, "all_gather_op", FAULTS[fault])
 
 
 # the full matrix — slow tier; scripts/chaos_matrix.sh runs it standalone
-@needs_interpreter
 @pytest.mark.slow
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 @pytest.mark.parametrize("family", FAMILY_NAMES)
@@ -426,7 +378,6 @@ def test_chaos_matrix(family, fault, mesh4):
         assert outcome == "timeout"
 
 
-@needs_interpreter
 def test_watchdog_armed_clean_run_is_correct(mesh4):
     """An armed watchdog with no fault must not perturb results — bounded
     waits consume semaphores exactly like the blocking waits."""
@@ -439,7 +390,6 @@ def test_watchdog_armed_clean_run_is_correct(mesh4):
     assert health.is_healthy()
 
 
-@needs_interpreter
 def test_poison_and_continue_posture(mesh4):
     """raise_on_timeout=False: the op returns NaN-poisoned output instead
     of raising; the health registry still records the timeout."""
@@ -454,7 +404,6 @@ def test_poison_and_continue_posture(mesh4):
     assert np.isnan(out).any(), "poisoned output must carry NaNs"
 
 
-@needs_interpreter
 def test_fault_plan_site_and_family_filters(mesh4):
     """A plan scoped to a family that never runs must not perturb the one
     that does."""

@@ -1,0 +1,211 @@
+"""The main path's kernels, compiled at Llama-3.1-8B widths for a DESCRIBED
+``v5e:2x2`` — the chip's own compiler, no chip attached.
+
+Interpret mode cannot see what Mosaic refuses (a slice not aligned to the
+tiling, too much VMEM, a kernel that will not partition); these compiles
+can, in a second or two each, so they guard every later PR at no chip
+time. A compile that passes is not a run: ``chip_smoke.py`` is the run.
+
+The topology is described inside a module-scoped fixture, never at import
+(one process at a time may load libtpu; under xdist only the worker that
+is handed this file may do so), and everything built from it is built in
+fixtures or tests. All cases live in this one file for the same reason.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from triton_dist_tpu import config as tdt_config
+
+# Llama-3.1-8B (models/presets.py): the serving shapes of chip_smoke.py
+HIDDEN, FFN, VOCAB = 4096, 14336, 128256
+N_Q, N_KV, HEAD = 32, 8, 128
+SLOTS, S_MAX, PAGE = 8, 2048, 128
+M_TOKENS = 4096  # one prefill admission: 8 slots x bucket 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever libtpu raises here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    loud = tdt_config.get_config().fallback_to_xla
+    tdt_config.update(fallback_to_xla=False)
+    yield t
+    tdt_config.update(fallback_to_xla=loud)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices[:4]), ("tp",))
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_flash_decode_compiles(one_chip):
+    from triton_dist_tpu.ops.flash_decode import flash_decode
+
+    q = _struct((SLOTS, N_Q, HEAD), jnp.bfloat16, one_chip)
+    kv = _struct((SLOTS, N_KV, S_MAX, HEAD), jnp.bfloat16, one_chip)
+    lens = _struct((SLOTS,), jnp.int32, one_chip)
+    text = _compiled_text(
+        functools.partial(flash_decode, interpret=False), q, kv, kv, lens
+    )
+    assert "tpu_custom_call" in text
+
+
+def _paged_args(one_chip):
+    pages_per_seq = S_MAX // PAGE
+    pool = _struct(
+        (SLOTS * pages_per_seq, N_KV, PAGE, HEAD), jnp.bfloat16, one_chip
+    )
+    table = _struct((SLOTS, pages_per_seq), jnp.int32, one_chip)
+    return pool, table
+
+
+def test_paged_flash_decode_compiles(one_chip):
+    from triton_dist_tpu.ops.flash_decode import paged_flash_decode
+
+    pool, table = _paged_args(one_chip)
+    q = _struct((SLOTS, N_Q, HEAD), jnp.bfloat16, one_chip)
+    lens = _struct((SLOTS,), jnp.int32, one_chip)
+    text = _compiled_text(
+        functools.partial(paged_flash_decode, interpret=False),
+        q, pool, pool, lens, table,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [16, 128])
+def test_paged_flash_verify_compiles(one_chip, rows):
+    """The ranged-prefill kernel: ``rows`` query positions per slot (a
+    speculative chunk, a chunked-prefill chunk) against the paged pool."""
+    from triton_dist_tpu.ops.flash_decode import paged_flash_verify
+
+    pool, table = _paged_args(one_chip)
+    q = _struct((SLOTS, rows, N_Q, HEAD), jnp.bfloat16, one_chip)
+    lens = _struct((SLOTS, rows), jnp.int32, one_chip)
+    text = _compiled_text(
+        functools.partial(paged_flash_verify, interpret=False),
+        q, pool, pool, lens, table,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_matmul_compiles(one_chip):
+    from triton_dist_tpu.ops.gemm import matmul
+
+    a = _struct((M_TOKENS, HIDDEN), jnp.bfloat16, one_chip)
+    b = _struct((HIDDEN, FFN), jnp.bfloat16, one_chip)
+    text = _compiled_text(functools.partial(matmul, interpret=False), a, b)
+    assert "tpu_custom_call" in text
+
+
+def _shard_mapped(fn, mesh, in_specs, out_specs):
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
+
+
+@pytest.mark.parametrize(
+    "n_cols",
+    [
+        pytest.param(2 * FFN, id="gate_up"),
+        # 128256 / 4 = 32064 = 64 x 501: the picked column block is not
+        # lane-aligned, which Mosaic refuses unless ag_gemm pads (PR 23)
+        pytest.param(VOCAB, id="lm_head"),
+    ],
+)
+def test_ag_gemm_compiles_on_four(mesh4, n_cols):
+    from triton_dist_tpu.ops.allgather_gemm import ag_gemm
+
+    a = _struct(
+        (M_TOKENS, HIDDEN), jnp.bfloat16, NamedSharding(mesh4, P("tp", None))
+    )
+    b = _struct(
+        (HIDDEN, n_cols), jnp.bfloat16, NamedSharding(mesh4, P(None, "tp"))
+    )
+    fn = _shard_mapped(
+        functools.partial(ag_gemm, axis="tp", interpret=False), mesh4,
+        (P("tp", None), P(None, "tp")), P(None, "tp"),
+    )
+    text = _compiled_text(fn, a, b)
+    assert "tpu_custom_call" in text
+    # the gather is the kernel's ring, not an XLA collective
+    assert "all-gather" not in text
+
+
+def test_gemm_rs_compiles_on_four(mesh4):
+    from triton_dist_tpu.ops.gemm_reduce_scatter import gemm_rs
+
+    a = _struct(
+        (M_TOKENS, FFN), jnp.bfloat16, NamedSharding(mesh4, P(None, "tp"))
+    )
+    b = _struct(
+        (FFN, HIDDEN), jnp.bfloat16, NamedSharding(mesh4, P("tp", None))
+    )
+    fn = _shard_mapped(
+        functools.partial(gemm_rs, axis="tp", interpret=False), mesh4,
+        (P(None, "tp"), P("tp", None)), P("tp", None),
+    )
+    text = _compiled_text(fn, a, b)
+    assert "tpu_custom_call" in text
+    assert "reduce-scatter" not in text
+
+
+def test_paged_flash_decode_distributed_compiles_on_four(mesh4):
+    """The TP=4 decode step's attention: paged flash-decode over each
+    PE's sequence shard, then the ``full_mesh_push`` all-gather of the
+    (out | lse) payload — 8*32 + 2 = 258 rows per PE, not a whole number of
+    sublane tiles. Unpadded, that slot layout compiled and then halted the
+    chip (PR 23); ``all_gather`` pads the rows. The compile is what can be
+    held here: two kernels, and no XLA all-gather standing in for the push."""
+    from triton_dist_tpu.ops.flash_decode import paged_flash_decode_distributed
+
+    pages_per_shard = S_MAX // 4 // PAGE
+    sharded = NamedSharding(mesh4, P("tp"))
+    pool = _struct(
+        (4 * SLOTS * pages_per_shard, N_KV, PAGE, HEAD), jnp.bfloat16, sharded
+    )
+    table = _struct((4 * SLOTS, pages_per_shard), jnp.int32, sharded)
+    lens = _struct((4 * SLOTS,), jnp.int32, sharded)
+    q = _struct((SLOTS, N_Q, HEAD), jnp.bfloat16, NamedSharding(mesh4, P()))
+    assert SLOTS * N_Q + -(-SLOTS * N_Q // HEAD) == 258
+    fn = _shard_mapped(
+        functools.partial(
+            paged_flash_decode_distributed, axis="tp", interpret=False
+        ),
+        mesh4, (P(), P("tp"), P("tp"), P("tp"), P("tp")), P(),
+    )
+    text = _compiled_text(fn, q, pool, pool, lens, table)
+    assert text.count("tpu_custom_call") >= 2
+    assert "all-gather" not in text

@@ -23,27 +23,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu import config as tdt_config
 from triton_dist_tpu.ops.common import chunk_schedule
 from triton_dist_tpu.resilience import FaultPlan
 from triton_dist_tpu.resilience import records as R
 
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-needs_dist = pytest.mark.skipif(
-    not HAS_AXIS_SIZE,
-    reason="fused ring ops use jax.lax.axis_size / jax.shard_map "
-    "(pre-existing seed gap on this jax line; the golden-path degradation "
-    "is covered by tests/test_chaos.py)",
-)
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="chunk-signal fault injection needs the Mosaic TPU interpreter "
-    "(jax >= 0.6)",
-)
 
 
 # ---------------------------------------------------------------------------
@@ -235,7 +221,6 @@ def test_config_chunk_fields_default_legacy():
 # Kernel-level: chunked schedules vs goldens (interpret mode)
 # ---------------------------------------------------------------------------
 
-@needs_dist
 def test_all_gather_chunked_non_divisor(mesh4):
     """The ISSUE's canonical case live: 3 chunks over a 512-row shard —
     non-divisor spans (171/171/170) must still land every row exactly."""
@@ -246,7 +231,6 @@ def test_all_gather_chunked_non_divisor(mesh4):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
-@needs_dist
 def test_all_gather_chunk1_matches_legacy(mesh4):
     """chunks_per_shard=1 is the legacy schedule bit for bit."""
     from triton_dist_tpu.ops.allgather import all_gather_op
@@ -258,7 +242,6 @@ def test_all_gather_chunk1_matches_legacy(mesh4):
     np.testing.assert_array_equal(np.asarray(legacy), np.asarray(x))
 
 
-@needs_dist
 def test_all_gather_bidir_chunked(mesh4):
     from triton_dist_tpu.ops.allgather import all_gather_op
 
@@ -267,7 +250,6 @@ def test_all_gather_bidir_chunked(mesh4):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
-@needs_dist
 @pytest.mark.parametrize("chunks", [2, 3])
 def test_ag_gemm_chunked(mesh4, chunks):
     """Chunk-granular fused AG-GEMM vs the all_gather+dot golden; chunks=3
@@ -303,7 +285,6 @@ def test_ag_gemm_chunked(mesh4, chunks):
     )
 
 
-@needs_dist
 def test_ag_gemm_chunk1_matches_legacy(mesh4):
     """chunks_per_shard=1 reproduces the legacy fused schedule exactly
     (same kernel, bitwise-equal outputs)."""
@@ -319,7 +300,6 @@ def test_ag_gemm_chunk1_matches_legacy(mesh4):
     np.testing.assert_array_equal(np.asarray(legacy), np.asarray(c1))
 
 
-@needs_dist
 def test_gemm_rs_ring_chunked(mesh4):
     from triton_dist_tpu.ops.gemm_reduce_scatter import GemmRSConfig, gemm_rs_op
 
@@ -334,7 +314,6 @@ def test_gemm_rs_ring_chunked(mesh4):
     )
 
 
-@needs_dist
 @pytest.mark.parametrize("chunks", [2, 3])
 def test_reduce_scatter_ring_chunked(mesh4, chunks):
     from triton_dist_tpu.ops.reduce_scatter import (
@@ -377,8 +356,6 @@ def _mesh2():
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_chunk_signal_drop_names_chunk_wait_site(_chaos_config):
     """A dropped per-chunk signal trips the watchdog and the diagnostic
     record names the chunk wait site (kind ``chunk_wait``) — the
@@ -404,8 +381,6 @@ def test_chunk_signal_drop_names_chunk_wait_site(_chaos_config):
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_chunk_signal_dup_never_corrupts(_chaos_config):
     """A duplicated chunk signal must end in a correct result or a loud
     semaphore diagnostic — never silent corruption (the over-credit can

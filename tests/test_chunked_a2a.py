@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 
 from triton_dist_tpu import config as tdt_config
@@ -32,20 +31,7 @@ from triton_dist_tpu import perf_model as pm
 from triton_dist_tpu.resilience import FaultPlan
 from triton_dist_tpu.resilience import records as R
 
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-needs_dist = pytest.mark.skipif(
-    not HAS_AXIS_SIZE,
-    reason="fused a2a/MoE ops use jax.lax.axis_size / jax.shard_map "
-    "(pre-existing seed gap on this jax line; the golden-path degradation "
-    "is covered by tests/test_chaos.py)",
-)
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="chunk-signal fault injection needs the Mosaic TPU interpreter "
-    "(jax >= 0.6)",
-)
 
 
 # ---------------------------------------------------------------------------
@@ -243,7 +229,6 @@ def _a2a_case(key, n, max_m, hidden, uneven=False):
     return tokens, splits
 
 
-@needs_dist
 @pytest.mark.parametrize("chunks", [2, 3])
 def test_fast_all_to_all_chunked(mesh4, chunks):
     """Chunk-granular a2a vs the transpose golden; chunks=3 over max_m=8
@@ -259,7 +244,6 @@ def test_fast_all_to_all_chunked(mesh4, chunks):
     np.testing.assert_array_equal(np.asarray(rsplits), np.asarray(splits).T)
 
 
-@needs_dist
 def test_fast_all_to_all_chunked_uneven_splits(mesh4):
     """Non-divisor chunk counts over UNEVEN per-peer row counts: the slab
     contract ships full padded slabs whatever the valid counts, so the
@@ -275,7 +259,6 @@ def test_fast_all_to_all_chunked_uneven_splits(mesh4):
     np.testing.assert_array_equal(np.asarray(rsplits), np.asarray(splits).T)
 
 
-@needs_dist
 def test_fast_all_to_all_chunk1_matches_legacy(mesh4):
     """chunks_per_shard=1 dispatches to the unchanged legacy kernel — the
     exchange is bit-for-bit the default config's."""
@@ -292,7 +275,6 @@ def test_fast_all_to_all_chunk1_matches_legacy(mesh4):
     np.testing.assert_array_equal(np.asarray(ls), np.asarray(cs))
 
 
-@needs_dist
 def test_ep_layer_chunked_roundtrip(mesh4):
     """EPAll2AllLayer with a chunked transport: dispatch + combine must
     reproduce the legacy layer's output exactly (same slab contract, same
@@ -335,7 +317,6 @@ def test_ep_layer_chunked_roundtrip(mesh4):
     np.testing.assert_array_equal(legacy, chunked)
 
 
-@needs_dist
 def test_ag_group_gemm_overlap_chunked(mesh4):
     """The chunked fused up-projection (ring chunks consumed group by
     group) vs the dense golden — gather_group_blocks=2 forces several
@@ -389,7 +370,6 @@ def test_ag_group_gemm_overlap_chunked(mesh4):
             )
 
 
-@needs_dist
 def test_tp_moe_pipeline_chunked_matches_sequential(mesh4):
     """The full chunked MoE pipeline (dispatch → group-GEMM → combine over
     chunk-granular transfers) vs the sequential composition: same routing,
@@ -435,7 +415,6 @@ def test_tp_moe_pipeline_chunked_matches_sequential(mesh4):
     np.testing.assert_allclose(fused, seq, rtol=1e-5, atol=1e-5)
 
 
-@needs_dist
 def test_tp_moe_pipeline_chunk1_matches_legacy(mesh4):
     """chunks_per_shard=1 routes the whole pipeline through the unchanged
     legacy kernels — bit-for-bit against the default config."""
@@ -488,8 +467,6 @@ def _mesh2():
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_a2a_chunk_signal_drop_names_chunk_wait_site(_chaos_config):
     """A dropped per-chunk a2a signal trips the watchdog and the
     diagnostic record names the chunk wait site (kind ``chunk_wait``) —
@@ -517,8 +494,6 @@ def test_a2a_chunk_signal_drop_names_chunk_wait_site(_chaos_config):
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_a2a_chunk_signal_dup_never_corrupts(_chaos_config):
     """A duplicated a2a chunk signal must end in a correct exchange or a
     loud semaphore diagnostic — never silent corruption (the data-coupled

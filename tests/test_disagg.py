@@ -56,7 +56,7 @@ def _restore_config():
     snap = (cfg.timeout_iters, cfg.fault_plan, cfg.raise_on_timeout,
             cfg.fallback_to_xla, cfg.retry_policy, cfg.elastic,
             cfg.suspect_threshold, cfg.probation_probes, cfg.obs)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     elastic.reset()
     yield
     tdt_config.update(
@@ -65,7 +65,7 @@ def _restore_config():
         suspect_threshold=snap[6], probation_probes=snap[7], obs=snap[8],
     )
     retry.set_clock(None)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     elastic.reset()
 
 
@@ -529,7 +529,7 @@ def test_corrupt_chunk_mid_handoff_attributed_recovery(model):
     prefill (greedy AND seeded-sampled)."""
     cfg, params = model
     for temp_kw in ({}, dict(temperature=0.8, top_k=4)):
-        resilience.reset(keep_env=True)
+        resilience.reset()
         elastic.reset()
         trace = _traffic(n=4, seed=5, prompt_len=("fixed", 5),
                          output_len=("fixed", 3), **temp_kw)

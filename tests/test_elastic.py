@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu import config as tdt_config
@@ -38,12 +37,6 @@ from triton_dist_tpu.resilience import records as R
 
 pytestmark = pytest.mark.chaos
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="live fault injection needs the Mosaic TPU interpreter "
-    "(jax >= 0.6); the host-level arc covers the elastic machinery here",
-)
 
 
 @pytest.fixture(autouse=True)
@@ -535,7 +528,6 @@ def test_disabled_config_takes_preexisting_paths(mesh4, monkeypatch):
 # Live arc (Mosaic TPU interpreter): real fused kernels, real injector
 # ---------------------------------------------------------------------------
 
-@needs_interpreter
 def test_elastic_arc_live(mesh4):
     """ISSUE 2 acceptance: the full arc against the real fused allgather —
     persistent straggler PE times the step out, retries back off and

@@ -29,6 +29,7 @@ tests/test_ragged.py):
   DMAs (local HBM) and NO signal edges.
 """
 
+import importlib
 import dataclasses
 import functools
 
@@ -42,9 +43,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_dist_tpu import config as tdt_config
 from triton_dist_tpu import perf_model as pm
-import triton_dist_tpu.ops.allgather_group_gemm as agg_mod
-import triton_dist_tpu.ops.group_gemm as gg_mod
-import triton_dist_tpu.ops.moe_reduce_rs as rs_mod
+# ops/__init__ re-exports functions that shadow these submodule names,
+# and `import a.b.c as x` binds through the attribute chain
+agg_mod = importlib.import_module("triton_dist_tpu.ops.allgather_group_gemm")
+gg_mod = importlib.import_module("triton_dist_tpu.ops.group_gemm")
+rs_mod = importlib.import_module("triton_dist_tpu.ops.moe_reduce_rs")
 from triton_dist_tpu.ops.group_gemm import (
     GroupGemmConfig,
     group_gemm,
@@ -60,19 +63,7 @@ from triton_dist_tpu.resilience import FaultPlan
 from triton_dist_tpu.resilience import records as R
 from triton_dist_tpu.shmem import device as shmem
 
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-needs_dist = pytest.mark.skipif(
-    not HAS_AXIS_SIZE,
-    reason="fused MoE ops use jax.lax.axis_size / jax.shard_map "
-    "(pre-existing seed gap on this jax line)",
-)
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="the fused kernels need the Mosaic TPU interpreter off-chip "
-    "(jax >= 0.6); host-tier emitter logic is covered above",
-)
 
 
 def _case_ids():
@@ -986,7 +977,6 @@ def _small_panels(monkeypatch):
     monkeypatch.setattr(gg_mod, "_PANEL_ROWS", 4)
 
 
-@needs_interpreter
 @pytest.mark.parametrize("variant", ["fwd", "w8", "ragged", "w8_ragged"])
 def test_emitter_grid_bit_exact_vs_legacy(monkeypatch, _small_panels, variant):
     """The migration contract, grid family: the emitter's generated kernel
@@ -1024,7 +1014,6 @@ def test_emitter_grid_bit_exact_vs_legacy(monkeypatch, _small_panels, variant):
     np.testing.assert_array_equal(emitted, legacy)
 
 
-@needs_interpreter
 @pytest.mark.parametrize("ragged", [False, True])
 def test_emitter_dw_bit_exact_vs_legacy(monkeypatch, _small_panels, ragged):
     """Migration contract, dW family."""
@@ -1081,8 +1070,6 @@ def _overlap_pipeline(mesh, cfg, m_loc=8, topk=2, n_exp=3, h_dim=32,
     )(x, w_up, w_down, ids, tw.astype(jnp.float32)), np.float32)
 
 
-@needs_dist
-@needs_interpreter
 @pytest.mark.parametrize("ragged", [False, True])
 def test_emitter_overlap_bit_exact_vs_legacy(
     monkeypatch, mesh4, _small_panels, ragged,
@@ -1102,8 +1089,6 @@ def test_emitter_overlap_bit_exact_vs_legacy(
     np.testing.assert_array_equal(emitted, legacy)
 
 
-@needs_dist
-@needs_interpreter
 @pytest.mark.parametrize("chunks,ragged", [(1, False), (1, True), (2, False),
                                            (2, True)])
 def test_w8_overlap_kernels_match_sequential(mesh4, _small_panels, chunks,
@@ -1191,8 +1176,6 @@ def _chaos_pipeline(cfg):
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 @pytest.mark.parametrize("site", [1, 2])
 def test_w8_chunk_signal_drop_no_new_edge(_chaos_config, site):
     """Dropping a chunk signal under the w8 RAGGED CHUNKED pipeline
@@ -1219,8 +1202,6 @@ def test_w8_chunk_signal_drop_no_new_edge(_chaos_config, site):
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_w8_chunk_signal_dup_never_corrupts(_chaos_config):
     """A duplicated chunk signal under the w8 ragged chunked pipeline must
     end exact or loud — never silently wrong."""

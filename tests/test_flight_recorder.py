@@ -325,7 +325,7 @@ def _serve_once(tiny1, mesh1, *, obs_cfg, overload=True, slo_ttft=80.0):
     cfg, params = tiny1
     tdt_config.update(obs=obs_cfg)
     obs.reset()
-    health.reset(keep_env=True)
+    health.reset()
     clock = retry.FakeClock()
     with retry.clock_scope(clock):
         eng = ServingEngine(
@@ -389,7 +389,7 @@ def test_alert_fires_before_shed_all_batch(tiny1, mesh1):
     cfg, params = tiny1
     tdt_config.update(obs=obs.ObsConfig(alerts=obs.AlertConfig()))
     obs.reset()
-    health.reset(keep_env=True)
+    health.reset()
     clock = retry.FakeClock()
     with retry.clock_scope(clock):
         eng = ServingEngine(

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh
 
 from triton_dist_tpu import config as tdt_config
@@ -62,19 +61,7 @@ from triton_dist_tpu.serving import (
 )
 from triton_dist_tpu.serving import overload as ov
 
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-needs_dist = pytest.mark.skipif(
-    not HAS_AXIS_SIZE,
-    reason="fused MoE ops use jax.lax.axis_size / jax.shard_map "
-    "(pre-existing seed gap on this jax line)",
-)
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="the quantized-cache kernels need the Mosaic TPU interpreter "
-    "off-chip (jax >= 0.6); host-tier fp8 logic is covered above",
-)
 
 
 @pytest.fixture(autouse=True)
@@ -405,7 +392,6 @@ def _rand_case(key, b, hq, h_kv, s, d, dtype=jnp.float32):
     return q, k, v, kv_lens
 
 
-@needs_interpreter
 @pytest.mark.parametrize("soft_cap", [0.0, 20.0])
 @pytest.mark.parametrize("d", [128, 96])
 def test_flash_decode_fp8_parity(soft_cap, d):
@@ -428,7 +414,6 @@ def test_flash_decode_fp8_parity(soft_cap, d):
     )
 
 
-@needs_interpreter
 def test_flash_verify_fp8_parity():
     """Multi-position verify over the fp8 cache: each verified position i
     attends its own prefix ``lens[:, i]`` — the ranged-verify contract;
@@ -457,7 +442,6 @@ def test_flash_verify_fp8_parity():
         )
 
 
-@needs_interpreter
 def test_paged_flash_decode_fp8_parity():
     """fp8 page pools (the paged × fp8 cell of the serving cache matrix):
     shuffled pages + block-table indirection, per-position scale pools."""
@@ -501,7 +485,6 @@ def _paginate(k, v, page_size, key=None, n_extra_pages=0):
     return kp, vp, bt
 
 
-@needs_dist
 def test_flash_decode_fp8_distributed():
     """SP decode over a sequence-sharded fp8 cache merges to the f32
     distributed answer within quantization error (per-shard fp8 partials,

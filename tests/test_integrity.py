@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from triton_dist_tpu import config as tdt_config
@@ -47,12 +46,6 @@ from triton_dist_tpu.resilience import records as R
 from triton_dist_tpu.resilience.guard import guarded_call
 from triton_dist_tpu.resilience.records import DistTimeoutError
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="live payload injection needs the Mosaic TPU interpreter "
-    "(jax >= 0.6); the host-tier ladder/containment cells still run",
-)
 
 TIMEOUT_ITERS = 300
 
@@ -365,7 +358,7 @@ def test_one_detection_one_strike():
     assert health.counters()[("one_strike", health.INTEGRITY)] == 1
 
 
-def test_timeout_mid_ladder_takes_guard_taxonomy():
+def test_timeout_mid_ladder_takes_guard_classification():
     """A watchdog trip on a RETRY attempt of the corruption ladder gets
     the same treatment as a first-attempt trip: loud raise + family
     quarantine pin (not an unhandled escape past the guard)."""
@@ -590,7 +583,7 @@ def test_serving_poison_quarantine_survivors_byte_identical(tiny1, _mesh1):
     # poison slot 0's logits on decode call #3 — the uid occupying slot 0
     # becomes the quarantined request (injection wraps the jitted step's
     # host callable; everything downstream is the production path)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(integrity=IntegrityConfig())
     eng2 = _engine(cfg, params, _mesh1)
     orig = eng2._batcher._step
@@ -640,7 +633,7 @@ def test_serving_step_integrity_error_rebuilds_and_replays(tiny1, _mesh1,
         eng.submit(r)
     golden = eng.run_until_idle()
 
-    resilience.reset(keep_env=True)
+    resilience.reset()
     calls = {"n": 0}
     real_step = ContinuousBatcher.step
 
@@ -674,7 +667,7 @@ def test_serving_stop_drain_races_persistent_straggler(tiny4, _mesh4,
     from triton_dist_tpu.serving import Finished
 
     cfg, params = tiny4
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(elastic=True, suspect_threshold=1, probation_probes=1)
 
     recs = [{"pe": pe, "kind": "barrier_all", "site": 0, "status": "timeout",
@@ -717,7 +710,6 @@ def _mesh2():
 
 
 @pytest.mark.chaos
-@needs_interpreter
 @pytest.mark.parametrize("kind", F.PAYLOAD_KINDS)
 def test_canary_detects_payload_corruption_chunked_allgather(kind):
     """ISSUE 8 acceptance (kernel tier): each payload kind injected into
@@ -755,7 +747,6 @@ def test_canary_detects_payload_corruption_chunked_allgather(kind):
 
 
 @pytest.mark.chaos
-@needs_interpreter
 def test_canary_happy_path_bit_exact():
     """Acceptance: integrity checks armed, NO fault plan — the chunked
     kernels' outputs stay bit-exact vs the unarmored run (detection is

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_dist_tpu import config as tdt_config
@@ -33,18 +32,6 @@ from triton_dist_tpu.obs import telemetry as T
 from triton_dist_tpu.resilience import FaultPlan, guarded_call, health, retry
 from triton_dist_tpu.resilience import records as R
 
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-needs_dist = pytest.mark.skipif(
-    not HAS_AXIS_SIZE,
-    reason="fused ring ops use jax.lax.axis_size / jax.shard_map "
-    "(pre-existing seed gap on this jax line)",
-)
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="live wait telemetry needs the Mosaic TPU interpreter "
-    "(jax >= 0.6); the telemetry decode/aggregation units run everywhere",
-)
 
 TIMEOUT_ITERS = 300
 DELAY_ITERS = 500
@@ -698,8 +685,6 @@ def _mesh2():
     return Mesh(np.array(jax.devices()[:2]), ("tp",))
 
 
-@needs_interpreter
-@needs_dist
 def test_wait_stats_armed_bit_exact_and_attributed():
     """The acceptance contract: obs armed (wait_stats on top of the
     watchdog) is observation-only — results bit-exact to the fully
@@ -728,8 +713,6 @@ def test_wait_stats_armed_bit_exact_and_attributed():
         assert s["total_spins"] >= 0 and s["max_spins"] <= 10_000
 
 
-@needs_interpreter
-@needs_dist
 def test_wait_stats_without_watchdog_is_inert():
     """wait_stats without timeout_iters must add nothing (the chunk
     signal discipline: no watchdog, no bounded waits, no telemetry)."""
@@ -744,8 +727,6 @@ def test_wait_stats_without_watchdog_is_inert():
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_straggler_shifts_victim_wait_site_spin_histogram():
     """End-to-end attribution (the ISSUE 9 acceptance cell): a straggler
     PE injected via FaultPlan delays its entry into the chunked ring

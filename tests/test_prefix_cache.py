@@ -543,7 +543,7 @@ def test_poisoned_shared_page_strikes_every_reader(tiny4b, mesh1):
         ]
 
     def run(poison_uid=None):
-        resilience.reset(keep_env=True)
+        resilience.reset()
         eng = _engine(cfg, params, mesh1, ServingPrefixCacheConfig())
         if poison_uid is not None:
             tdt_config.update(integrity=IntegrityConfig())

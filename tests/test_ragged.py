@@ -22,18 +22,20 @@ Three tiers, matching the repo's environment matrix (tests/test_chunked*):
   result exact, exactly like the padded schedule; never corruption.
 """
 
+import importlib
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_dist_tpu import config as tdt_config
 from triton_dist_tpu import perf_model as pm
-import triton_dist_tpu.ops.group_gemm as gg_mod
+# ops/__init__ re-exports functions that shadow these submodule names,
+# and `import a.b.c as x` binds through the attribute chain
+gg_mod = importlib.import_module("triton_dist_tpu.ops.group_gemm")
 from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
 from triton_dist_tpu.ops.moe_utils import (
     moe_align_block_size,
@@ -45,19 +47,7 @@ from triton_dist_tpu.ops.moe_utils import (
 from triton_dist_tpu.resilience import FaultPlan
 from triton_dist_tpu.resilience import records as R
 
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-needs_dist = pytest.mark.skipif(
-    not HAS_AXIS_SIZE,
-    reason="fused MoE ops use jax.lax.axis_size / jax.shard_map "
-    "(pre-existing seed gap on this jax line)",
-)
 
-HAS_TPU_INTERPRETER = hasattr(pltpu, "InterpretParams")
-needs_interpreter = pytest.mark.skipif(
-    not HAS_TPU_INTERPRETER,
-    reason="the fused kernels need the Mosaic TPU interpreter off-chip "
-    "(jax >= 0.6); host-tier ragged logic is covered above",
-)
 
 
 def _case_ids():
@@ -278,7 +268,6 @@ def _small_panels(monkeypatch):
     monkeypatch.setattr(gg_mod, "_PANEL_ROWS", 4)
 
 
-@needs_interpreter
 def test_group_gemm_ragged_vs_ragged_dot(_small_panels):
     """Ragged kernel vs the jax.lax.ragged_dot golden over the PACKED live
     rows, at non-divisor counts (zero-row expert, single-row tail); dead
@@ -304,7 +293,6 @@ def test_group_gemm_ragged_vs_ragged_dot(_small_panels):
     assert np.all(np.asarray(out)[~live] == 0)
 
 
-@needs_interpreter
 def test_group_gemm_ragged_false_bit_exact(_small_panels):
     """ragged=False dispatches to the byte-identical legacy kernels:
     forward, w8 and dw agree BIT-EXACTLY with the default config, with or
@@ -346,7 +334,6 @@ def test_group_gemm_ragged_false_bit_exact(_small_panels):
     )
 
 
-@needs_interpreter
 def test_group_gemm_ragged_live_rows_bit_exact(_small_panels):
     """Ragged changes WHICH rows are computed, never their math: per-row
     K-reduction order is untouched, so live rows match the padded kernel
@@ -378,7 +365,6 @@ def test_group_gemm_ragged_live_rows_bit_exact(_small_panels):
     np.testing.assert_array_equal(got8[live], ref8[live])
 
 
-@needs_interpreter
 def test_group_gemm_dw_ragged_masks_junk(_small_panels):
     """dw zeroes masked rows BEFORE AᵀG: poison every pad row with huge
     junk — the ragged dW must still match the live-rows golden exactly
@@ -415,8 +401,6 @@ def test_group_gemm_dw_ragged_masks_junk(_small_panels):
     assert np.all(got[1] == 0)  # the zero-row expert stays exactly zero
 
 
-@needs_dist
-@needs_interpreter
 @pytest.mark.parametrize("chunks", [1, 2])
 def test_ag_group_gemm_overlap_ragged(mesh4, chunks, _small_panels):
     """The ragged fused up-projection (legacy and chunked schedules) vs
@@ -475,8 +459,6 @@ def test_ag_group_gemm_overlap_ragged(mesh4, chunks, _small_panels):
         )
 
 
-@needs_dist
-@needs_interpreter
 def test_tp_moe_ragged_matches_padded(mesh4, _small_panels):
     """Full fused pipeline, ragged vs padded: same routing, same math —
     forward AND gradients (the backward's grouped GEMMs and dw consume
@@ -528,8 +510,6 @@ def test_tp_moe_ragged_matches_padded(mesh4, _small_panels):
         )
 
 
-@needs_dist
-@needs_interpreter
 def test_tp_moe_ragged_chunked_composition(mesh4, _small_panels):
     """ragged × chunks_per_shard through the whole overlapped pipeline
     (m_loc=256 engages the combine-side chunk schedule) vs the padded
@@ -568,8 +548,6 @@ def test_tp_moe_ragged_chunked_composition(mesh4, _small_panels):
     np.testing.assert_allclose(fused, seq, rtol=1e-5, atol=1e-5)
 
 
-@needs_dist
-@needs_interpreter
 def test_tp_moe_ragged_dot_sentinel(mesh4):
     """The jax.lax.ragged_dot sentinel candidate (backend="ragged_dot")
     runs the pipeline through the sequential composition and matches the
@@ -598,8 +576,6 @@ def test_tp_moe_ragged_dot_sentinel(mesh4):
     )
 
 
-@needs_dist
-@needs_interpreter
 def test_ep_moe_ragged_matches_padded(mesh4, _small_panels):
     """EP layer end-to-end: the ragged receiver alignment (virtual
     padding expert skipped outright) reproduces the padded output."""
@@ -682,8 +658,6 @@ def _chaos_pipeline(cfg):
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 @pytest.mark.parametrize("site", [1, 2])
 def test_ragged_chunk_signal_drop_no_new_edge(_chaos_config, site):
     """Dropping a chunk signal under the RAGGED chunked pipeline behaves
@@ -714,8 +688,6 @@ def test_ragged_chunk_signal_drop_no_new_edge(_chaos_config, site):
 
 
 @pytest.mark.chaos
-@needs_interpreter
-@needs_dist
 def test_ragged_chunk_signal_dup_never_corrupts(_chaos_config):
     """A duplicated chunk signal under the ragged chunked pipeline must
     end exact or loud (semaphore diagnostic / watchdog) — never silently

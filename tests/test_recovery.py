@@ -67,7 +67,7 @@ def _restore_config():
     snap = (cfg.timeout_iters, cfg.fault_plan, cfg.raise_on_timeout,
             cfg.fallback_to_xla, cfg.retry_policy, cfg.elastic,
             cfg.suspect_threshold, cfg.probation_probes, cfg.obs)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     elastic.reset()
     yield
     tdt_config.update(
@@ -77,7 +77,7 @@ def _restore_config():
     )
     retry.set_clock(None)
     obs.reset()
-    resilience.reset(keep_env=True)
+    resilience.reset()
     elastic.reset()
 
 

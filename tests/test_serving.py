@@ -593,7 +593,7 @@ def test_serving_elastic_arc(tiny4, mesh4):
     golden_eng, golden = _serve_tiny4(tiny4, mesh4)
     assert golden_eng.rebuilds == 0 and len(golden) == 5
 
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(elastic=True, suspect_threshold=1, probation_probes=1)
     eng, done = _serve_tiny4(tiny4, mesh4, fault_at=3,
                              fault_recs=_recs([0, 2, 3]))
@@ -620,7 +620,7 @@ def test_serving_arc_unattributable_timeout_keeps_full_world(tiny4, mesh4):
     """Every PE tripping (fabric-wide) must not quarantine anyone: the
     engine rebuilds on the FULL world and service continues losslessly."""
     golden_eng, golden = _serve_tiny4(tiny4, mesh4)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(elastic=True, suspect_threshold=1)
     eng, done = _serve_tiny4(tiny4, mesh4, fault_at=3,
                              fault_recs=_recs([0, 1, 2, 3]))
@@ -635,7 +635,7 @@ def test_serving_arc_unattributable_timeout_keeps_full_world(tiny4, mesh4):
 def test_serving_engine_escalates_after_max_failures(tiny4, mesh4):
     """A timeout storm the rebuild/replay loop cannot absorb must
     escalate loudly, not spin forever."""
-    resilience.reset(keep_env=True)
+    resilience.reset()
     with pytest.raises(RuntimeError, match="consecutive step timeouts"):
         _serve_tiny4(tiny4, mesh4, fault_at=tuple(range(1, 20)),
                      fault_recs=_recs([0, 2, 3]), max_failures=2)

@@ -4,9 +4,7 @@ This is ``tests/test_overlap_structure.py::_spy_comm`` promoted into a
 first-class recording mode: the ``shmem/device.py`` primitive surface is
 replaced by shims that RECORD instead of issuing hardware ops, and the
 kernel body runs once per rank as plain eager Python — no Pallas trace, no
-interpreter, no devices — so it works on any jax line (this box's
-jax 0.4.37 cannot even construct ``TPUCompilerParams(has_side_effects=)``,
-let alone interpret a fused kernel).
+interpreter, no devices.
 
 How a capture runs (``capture_world``):
 
@@ -58,7 +56,7 @@ DMA_START = "dma_start"  # local async copy issued (credits its sem slot)
 DMA_WAIT = "dma_wait"    # local async copy waited (consumes 1)
 CHUNKED = "chunked_put"  # marker: a chunked put family was emitted
 # NOTE: barrier_all has no event kind of its own — the capture shim emits
-# its dissemination rounds as targeted SIGNAL + bounded WAIT pairs on a
+# its rounds as targeted SIGNALs + one bounded WAIT per round on a
 # shared "<barrier>" slot, which is faithful to the hardware (one barrier
 # semaphore counter per PE, credits conserved across rounds — see the
 # cross-invocation caveat on shmem.barrier_all) and lets the credit model
@@ -394,16 +392,19 @@ def capture_shims(state: _CaptureState, op_modules: list):
         if n == 1:
             return
         scope = watchdog.active()
-        # mirror the real dissemination barrier: one signal + one bounded
-        # wait (site-numbered, KIND_BARRIER) per round, on a synthetic
-        # per-launch slot shared by all ranks
+        # mirror the real all-pairs barrier (shmem.barrier_rounds): per
+        # round, one signal to each peer in the round's offset range and
+        # one bounded wait (site-numbered, KIND_BARRIER) consuming as many
+        # credits, on a synthetic per-launch slot shared by all ranks
         me = rank
         slot = ("<barrier>", ())
-        for r in range(max(1, math.ceil(math.log2(n)))):
-            partner = (me + (1 << r)) % n
-            state.record(Event(SIGNAL, slot=slot, value=1, dst=partner))
+        for lo, hi in shmem.barrier_rounds(n):
+            for off in range(lo, hi):
+                state.record(
+                    Event(SIGNAL, slot=slot, value=1, dst=(me + off) % n)
+                )
             state.record(Event(
-                WAIT, slot=slot, value=1, kind=S.KIND_BARRIER,
+                WAIT, slot=slot, value=hi - lo, kind=S.KIND_BARRIER,
                 site=scope.next_wait_site(),
             ))
 
@@ -570,16 +571,6 @@ def capture_shims(state: _CaptureState, op_modules: list):
         patch(pl, "when", when)
         patch(pltpu, "make_async_copy", make_async_copy)
         patch(pltpu, "emit_pipeline", emit_pipeline)
-        if not hasattr(pltpu, "MemorySpace"):
-            # jax lines before CompilerParams/MemorySpace: the fused MoE
-            # entries name pltpu.MemorySpace.HBM in their BlockSpecs, which
-            # the capture launcher ignores anyway — shim the namespace so
-            # the entry's spec-building code runs (restored to absent)
-            import types
-
-            patch(pltpu, "MemorySpace", types.SimpleNamespace(
-                HBM="hbm", ANY="any", SMEM="smem", VMEM="vmem"
-            ))
         for mod in op_modules:
             if hasattr(mod, "dist_pallas_call"):
                 patch(mod, "dist_pallas_call", dist_pallas_call)

@@ -28,10 +28,12 @@ plumbing); data parallelism is an outer mesh axis that only the gradient
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding
 
 from triton_dist_tpu.ops.allgather_gemm import AGGemmConfig
 from triton_dist_tpu.ops.gemm_reduce_scatter import GemmRSConfig
@@ -74,14 +76,15 @@ class TransformerConfig:
         return self.q_dim + 2 * self.kv_dim
 
 
-def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
-    """Unsharded parameter pytree; pair with :func:`param_specs` +
-    ``jax.device_put`` to lay it out over the mesh."""
+def _init_tree(key: jax.Array, cfg: TransformerConfig) -> dict:
     n_mats = cfg.n_layers * 4 + 2
     keys = iter(jax.random.split(key, n_mats))
 
     def w(shape, scale):
         return (jax.random.normal(next(keys), shape) * scale).astype(cfg.dtype)
+
+    def ones(shape):
+        return jnp.ones(shape, cfg.dtype)
 
     h, f = cfg.hidden, cfg.ffn
     g = cfg.n_q_heads // cfg.n_kv_heads
@@ -89,7 +92,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     for _ in range(cfg.n_layers):
         layers.append(
             dict(
-                attn_norm=jnp.ones((h,), cfg.dtype),
+                attn_norm=ones((h,)),
                 # QKV stored KV-GROUP-MAJOR: [H, n_kv_heads, (g+2)*d] — each
                 # group's g query heads, its K head, its V head, contiguous.
                 # Column-sharding a flat [H, q|k|v] concat would hand one PE
@@ -98,7 +101,7 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
                 wqkv=w((h, cfg.n_kv_heads, (g + 2) * cfg.head_dim), h**-0.5),
                 # wo rows in the same group-major q-head order
                 wo=w((cfg.q_dim, h), cfg.q_dim**-0.5),
-                mlp_norm=jnp.ones((h,), cfg.dtype),
+                mlp_norm=ones((h,)),
                 # gate/up interleaved PER FFN UNIT: [H, F, 2] — sharding F
                 # gives every PE matched gate+up columns
                 w_gate_up=w((h, f, 2), h**-0.5),
@@ -108,9 +111,41 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     return dict(
         embed=w((cfg.vocab, h), 0.02),
         layers=layers,
-        final_norm=jnp.ones((h,), cfg.dtype),
+        final_norm=ones((h,)),
         lm_head=w((h, cfg.vocab), h**-0.5),
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _init_program(cfg: TransformerConfig, mesh: Mesh | None):
+    """The whole-tree initializer as ONE jitted program per (shapes, mesh):
+    with a mesh its outputs carry the :func:`param_specs` shardings, so
+    every leaf is born in its final layout."""
+    shardings = None if mesh is None else jax.tree.map(
+        lambda spec: NamedSharding(mesh, spec), param_specs(cfg),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+    return jax.jit(
+        functools.partial(_init_tree, cfg=cfg), out_shardings=shardings
+    )
+
+
+def init_params(
+    key: jax.Array, cfg: TransformerConfig, mesh: Mesh | None = None
+) -> dict:
+    """Parameter pytree, built by one jitted program. With ``mesh`` every
+    leaf comes out in its :func:`param_specs` sharding (serving-sized
+    models: nothing larger than a shard ever sits on one device — an 8B
+    tree built eagerly would sit whole, in f32, on the default device
+    before the first ``device_put``). Without it the tree is unsharded on
+    the default device — pair with :func:`param_specs` + ``device_put``
+    (tests, tiny configs). Same values for the same key either way."""
+    # the program depends on shapes, dtype and axis only: drop the kernel
+    # configs so the cache key is hashable and shared across them
+    shapes = dataclasses.replace(
+        cfg, ag_config=None, rs_config=None, interpret=None
+    )
+    return _init_program(shapes, mesh)(key)
 
 
 def param_specs(cfg: TransformerConfig) -> dict:

@@ -26,6 +26,7 @@ import functools
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
@@ -511,6 +512,20 @@ def _all_gather_fused(x: jax.Array, *, axis: str = "tp", method: str = "auto", i
             x.size * x.dtype.itemsize, n, devices
         )
     m = x.shape[0]
+    # every PE's slot starts at me*m rows of the HBM output: keep m a
+    # whole number of (8 x 32-bit) sublane tiles, or the dynamic slot
+    # offset is misaligned to the tiled layout (the flash-decode combine's
+    # payload is b*hq + ceil(b*hq/d) rows — 258 at Llama-8B widths)
+    tile = 8 * max(1, 4 // x.dtype.itemsize)
+    if m % tile:
+        m_pad = -(-m // tile) * tile
+        out = _all_gather_fused(
+            jnp.pad(x, ((0, m_pad - m),) + ((0, 0),) * (x.ndim - 1)),
+            axis=axis, method=method, interpret=interpret, devices=devices,
+            chunks_per_shard=chunks_per_shard,
+        )
+        out = out.reshape(n, m_pad, *x.shape[1:])[:, :m]
+        return out.reshape((n * orig_shape[0],) + tuple(orig_shape[1:]))
     out_shape = (n * m, *x.shape[1:])
     n_steps = max(1, n - 1)
     chunks = max(1, int(chunks_per_shard))

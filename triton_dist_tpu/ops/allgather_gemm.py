@@ -322,10 +322,7 @@ def ag_gemm(
     Golden: ``jax.lax.all_gather(a, axis, tiled=True) @ b`` — served
     automatically when the fused kernel cannot run in this environment
     (resilience layer, docs/resilience.md; the same guard every other op
-    family carries — its absence here was why a jax line without the
-    CompilerParams surface could not trace the TP transformer forward, so
-    prefill admission and the serving engine's MXU-rate path failed
-    instead of degrading).
+    family carries).
     """
     from triton_dist_tpu import resilience
 
@@ -432,6 +429,23 @@ def _ag_gemm_fused(
         return (out, ag) if gather_output else out
     bm = _pick_block(m_loc, cfg.block_m)
     bn = _pick_block(n_loc, cfg.block_n)
+    if cfg.block_n % 128 == 0 and bn != n_loc and bn % 128 and n_loc % 512:
+        # the config asked for lane-aligned column blocks but the divisor
+        # search fell below a lane: Mosaic refuses a proper slice that is
+        # not a multiple of 128 ("Slice shape along dimension 1 must be
+        # aligned to tiling (128)" — Llama-3's vocab shard at TP=4,
+        # 32064 = 64 x 501, picks bn=64). Pad B's columns to the next
+        # multiple of 512 — one weight-shard copy per call, <2% extra MXU
+        # work — and slice the product back.
+        pad = -n_loc % 512
+        res = _ag_gemm_fused(
+            a, jnp.pad(b, ((0, 0), (0, pad))), axis=axis, config=config,
+            gather_output=gather_output, out_dtype=out_dtype,
+            interpret=interpret,
+        )
+        if gather_output:
+            return res[0][:, :n_loc], res[1]
+        return res[:, :n_loc]
     if n == 1:
         # World-1 degenerates to a plain MXU matmul: routing A through the
         # gather workspace would cost an extra HBM round-trip of the whole

@@ -51,6 +51,7 @@ from triton_dist_tpu.resilience.integrity import (
 from triton_dist_tpu.resilience.guard import (
     UnsupportedTopologyError,
     fallbackable,
+    golden_path,
     guard_op,
     guarded_call,
 )
@@ -70,16 +71,13 @@ from triton_dist_tpu.resilience.retry import (
 )
 
 
-def reset(*, keep_env: bool = False) -> None:
+def reset() -> None:
     """Clear all process-global resilience state — health statistics and
     pins, elastic peer states, and fault-plan trigger counts — between
-    tests or benchmark phases. ``keep_env=True`` preserves the
-    environment pins (a jax install that cannot build fused kernels is
-    still the same install afterwards), which is the per-test isolation
-    posture ``tests/conftest.py`` uses."""
+    tests or benchmark phases."""
     from triton_dist_tpu.resilience import faults as _faults
 
-    health.reset(keep_env=keep_env)
+    health.reset()
     elastic.reset()
     _faults.reset_triggers()
 
@@ -103,6 +101,7 @@ __all__ = [
     "fallbackable",
     "family_code_for",
     "family_name_for",
+    "golden_path",
     "guard_op",
     "guarded_call",
     "health",

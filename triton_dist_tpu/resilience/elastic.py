@@ -658,7 +658,7 @@ def probe_world(mesh, axis: str = "tp") -> bool:
         return True
     except DistTimeoutError:
         return False
-    except Exception as exc:  # noqa: BLE001 — guard taxonomy decides
+    except Exception as exc:  # noqa: BLE001 — guard classification decides
         if not _guard.fallbackable(exc):
             raise
         _probe_golden(mesh, axis)

@@ -4,7 +4,7 @@ Every fused distributed op in this framework has a mathematically identical
 golden path built from ``jax.lax`` collectives (the same goldens the test
 suite asserts against). :func:`guarded_call` runs the fused path and, when
 it fails for an ENVIRONMENTAL reason — a Mosaic compile failure, an
-unsupported topology, a jax API the installed version lacks — records the
+unsupported topology — records the
 downgrade in :mod:`triton_dist_tpu.resilience.health` and returns the
 golden result instead, so a serving step degrades to a correct slow path
 rather than taking the process down (the collective-fallback discipline
@@ -20,11 +20,18 @@ What does NOT fall back:
 - anything raised by the fallback itself.
 
 Set ``config.update(fallback_to_xla=False)`` to make every failure loud
-(CI posture); the default is to degrade (serving posture).
+(the posture of CI and of everything that measures: ``chip_smoke.py``,
+``bench.py``, ``scripts/``); the default is to degrade (serving posture).
+
+:func:`golden_path` is the EXPLICIT way to run the goldens: inside the
+scope every guarded entry serves its XLA twin directly — no failure, no
+downgrade record — so a fused run can be compared with its unfused twin
+through the same entry points (``chip_smoke.py --chips 4``).
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import re
 import threading
@@ -60,16 +67,6 @@ _COMPILE_MARKERS = re.compile(
     r"|every candidate config failed",
     re.IGNORECASE,
 )
-# Missing-API failures from running against a jax outside the tested range
-# (pyproject allows jax>=0.4.35; the fused kernels need the Mosaic
-# interpreter / CompilerParams surface of newer lines).
-_API_MARKERS = re.compile(
-    r"module '?jax|'?jax\.[a-z_.]+'? has no attribute|InterpretParams"
-    r"|shard_map|CompilerParams",
-    re.IGNORECASE,
-)
-
-
 def _timeout_in_chain(exc: BaseException) -> bool:
     """A DistTimeoutError anywhere in the cause chain (e.g. wrapped by the
     autotuner's terminal RuntimeError)."""
@@ -87,8 +84,6 @@ def fallbackable(exc: BaseException) -> bool:
     if isinstance(exc, NotImplementedError):  # incl. UnsupportedTopologyError
         return True
     mod = type(exc).__module__ or ""
-    if isinstance(exc, (AttributeError, TypeError)) and _API_MARKERS.search(str(exc)):
-        return True
     if mod.startswith(("jaxlib", "jax.")) or mod == "jax":
         # compile/lowering-layer failures only; a genuine runtime/device
         # fault must stay loud (see _COMPILE_MARKERS note)
@@ -98,19 +93,27 @@ def fallbackable(exc: BaseException) -> bool:
     return False
 
 
-def _process_global(exc: BaseException) -> bool:
-    """Is this failure inherent to the PROCESS environment (a jax API the
-    install lacks), as opposed to this particular shape/topology/config?
-    Only the former is safe to memoize: an UnsupportedTopologyError for one
-    mesh axis says nothing about the next, but a missing Mosaic interpreter
-    cannot heal mid-process."""
-    if isinstance(exc, UnsupportedTopologyError):
-        return False
-    if isinstance(exc, NotImplementedError):
-        return True
-    return isinstance(exc, (AttributeError, TypeError)) and bool(
-        _API_MARKERS.search(str(exc))
-    )
+_golden_depth = 0
+
+
+def golden_active() -> bool:
+    """Inside a :func:`golden_path` scope (part of ``jit_shard_map``'s
+    program key: a cached fused program must not serve a golden run)."""
+    return _golden_depth > 0
+
+
+@contextlib.contextmanager
+def golden_path():
+    """Serve every guarded entry's XLA-collective golden EXPLICITLY while
+    the scope is open (tracing happens at first call, so keep the scope
+    open around the calls, not only around construction). An entry with
+    no golden (quantized caches) raises rather than run fused."""
+    global _golden_depth
+    _golden_depth += 1
+    try:
+        yield
+    finally:
+        _golden_depth -= 1
 
 
 def guarded_call(
@@ -134,10 +137,10 @@ def guarded_call(
     identical XLA program and the tuner would persist a meaningless
     "best" config. Direct shard-level calls (a user's own ``shard_map``)
     have no outer guard and keep their fallback."""
-    return _guarded(family, primary, fallback, args, kwargs, pin_global=False)
+    return _guarded(family, primary, fallback, args, kwargs)
 
 
-def _guarded(family, primary, fallback, args, kwargs, *, pin_global):
+def _guarded(family, primary, fallback, args, kwargs):
     from triton_dist_tpu import obs as _obs
 
     # observability (ISSUE 9): one span per OUTERMOST guarded entry,
@@ -147,13 +150,12 @@ def _guarded(family, primary, fallback, args, kwargs, *, pin_global):
     # timeline reader cares about; disarmed this is one attribute read.
     if _guard_depth() > 0 or not _obs.span_enabled():
         return _guarded_impl(family, primary, fallback, args, kwargs,
-                             pin_global=pin_global, sp=_obs.NULL_SPAN)
+                             sp=_obs.NULL_SPAN)
     with _obs.span(f"op:{family}", cat="op") as sp:
-        return _guarded_impl(family, primary, fallback, args, kwargs,
-                             pin_global=pin_global, sp=sp)
+        return _guarded_impl(family, primary, fallback, args, kwargs, sp=sp)
 
 
-def _guarded_impl(family, primary, fallback, args, kwargs, *, pin_global, sp):
+def _guarded_impl(family, primary, fallback, args, kwargs, *, sp):
     from triton_dist_tpu import config as tdt_config
     from triton_dist_tpu.resilience import integrity as _integrity
 
@@ -163,6 +165,13 @@ def _guarded_impl(family, primary, fallback, args, kwargs, *, pin_global, sp):
     # raised inside the fused path (jit_shard_map) take the same ladder.
     checking = _guard_depth() == 0 and _integrity.output_checks_enabled()
 
+    if golden_active():
+        if fallback is None:
+            raise NotImplementedError(
+                f"{family} has no golden path to serve under golden_path()"
+            )
+        sp.set("rung", "golden_explicit")
+        return fallback(*args, **kwargs)
     if fallback is None or not tdt_config.get_config().fallback_to_xla:
         # no golden rung / loud CI posture: detection still runs, loudly
         sp.set("rung", "fused")
@@ -173,10 +182,9 @@ def _guarded_impl(family, primary, fallback, args, kwargs, *, pin_global, sp):
     if _guard_depth() > 0:
         return primary(*args, **kwargs)
     if health.short_circuited(family) is not None:
-        # pinned to the golden path: a process-global env failure already
-        # proved the fused path cannot build (no point re-paying the failing
-        # trace per call), or a watchdog trip left the family's collective
-        # semaphore state undefined (quarantine; see docs/resilience.md).
+        # pinned to the golden path: a watchdog trip left the family's
+        # collective semaphore state undefined (quarantine; see
+        # docs/resilience.md).
         # Recorded once at pin time — not per call, to keep the event deque
         # and counters meaningful.
         sp.set("rung", "golden_pinned")
@@ -223,7 +231,7 @@ def _guarded_impl(family, primary, fallback, args, kwargs, *, pin_global, sp):
                     raise
                 # a NON-integrity failure surfaced mid-ladder (e.g. a
                 # watchdog trip on a retry attempt): hand it to the SAME
-                # taxonomy a first-attempt failure gets — timeouts
+                # classification a first-attempt failure gets — timeouts
                 # quarantine-pin the family and stay loud, environmental
                 # failures degrade to the golden path
                 exc = ladder_exc
@@ -251,16 +259,6 @@ def _guarded_impl(family, primary, fallback, args, kwargs, *, pin_global, sp):
             # exception "currently being handled" is still the original
             # IntegrityError — a bare raise would resurrect the wrong one
             raise exc
-        if pin_global and _process_global(exc):
-            # memoize ONLY at the op-entry level (the serving/bench surface,
-            # where re-paying a failing trace per step is real cost) and
-            # ONLY for process-global failures; direct shard-level calls
-            # keep re-attempting the fused path — a debug session that
-            # patches the environment mid-process should see it recover
-            health.short_circuit(
-                family, f"environment cannot build fused kernels: {exc}",
-                kind=health.PIN_ENV,
-            )
         health.record_downgrade(
             family,
             reason="fused path failed; served golden XLA collective path",
@@ -283,7 +281,7 @@ def guard_op(family: str, golden: Callable[..., Any] | None):
     def deco(fused: Callable[..., Any]) -> Callable[..., Any]:
         @functools.wraps(fused)
         def entry(*args: Any, **kwargs: Any) -> Any:
-            return _guarded(family, fused, golden, args, kwargs, pin_global=True)
+            return _guarded(family, fused, golden, args, kwargs)
 
         entry.__wrapped_fused__ = fused
         entry.__golden__ = golden

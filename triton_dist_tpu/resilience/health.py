@@ -131,7 +131,6 @@ FLIP_KINDS = (DOWNGRADE, TIMEOUT, PE_QUARANTINE, INTEGRITY, SKIP_STEP,
               HANDOFF_FALLBACK, POOL_COLLAPSE, REPLICA_FAILOVER)
 
 # short-circuit pin kinds (why a family is pinned to its golden path)
-PIN_ENV = "env"               # process-global environment failure
 PIN_QUARANTINE = "quarantine"  # watchdog trip: device semaphore residue
 
 
@@ -155,14 +154,11 @@ _total_dropped = 0
 # (per-(family, kind) counters are never dropped; only event DETAIL is)
 _dropped_by_kind: dict[str, int] = {}
 # families guarded_call serves straight from the golden path without
-# retrying the fused one: {family: (reason, pin_kind)}. Two ways in — a
-# process-global environmental failure (PIN_ENV: the install cannot build
-# fused kernels; retrying re-pays a failing trace per call), or a watchdog
-# quarantine (PIN_QUARANTINE: after a timeout the family's collective
-# semaphore state is undefined; reusing it could silently corrupt the next
-# launch). The kind matters to reset(): env pins describe the process and
-# survive a keep_env reset; quarantine pins describe device state and are
-# released by the elastic layer in interpret mode (elastic.py).
+# retrying the fused one: {family: (reason, pin_kind)}. The way in is a
+# watchdog quarantine (PIN_QUARANTINE: after a timeout the family's
+# collective semaphore state is undefined; reusing it could silently
+# corrupt the next launch); the elastic layer releases these in interpret
+# mode (elastic.py).
 _short_circuit: dict[str, tuple[str, str]] = {}
 
 
@@ -620,32 +616,24 @@ def clear_short_circuit(family: str) -> None:
 def clear_timeout_quarantines() -> None:
     """Release every PIN_QUARANTINE pin (interpret-mode recovery: the
     elastic layer excised or re-admitted the culprit PE and simulated
-    semaphores are rebuilt per launch). Env pins always survive."""
+    semaphores are rebuilt per launch)."""
     with _lock:
         for f in [f for f, (_, k) in _short_circuit.items()
                   if k == PIN_QUARANTINE]:
             del _short_circuit[f]
 
 
-def reset(*, keep_short_circuit: bool = False, keep_env: bool = False) -> None:
+def reset(*, keep_short_circuit: bool = False) -> None:
     """Clear the statistics. ``keep_short_circuit=True`` preserves ALL
     golden-path pins — use it when resetting between phases of one process
     (bench): clearing a Python dict does not clean a quarantined family's
     device semaphore, so re-enabling its fused kernel would risk exactly
-    the silent corruption the quarantine exists to prevent.
-    ``keep_env=True`` preserves only the PIN_ENV pins (a jax install that
-    cannot build fused kernels is still the same install after the reset)
-    while releasing quarantine pins — the per-test isolation posture."""
+    the silent corruption the quarantine exists to prevent."""
     global _total_dropped
     with _lock:
         _events.clear()
         _counters.clear()
         if not keep_short_circuit:
-            if keep_env:
-                for f in [f for f, (_, k) in _short_circuit.items()
-                          if k != PIN_ENV]:
-                    del _short_circuit[f]
-            else:
-                _short_circuit.clear()
+            _short_circuit.clear()
         _total_dropped = 0
         _dropped_by_kind.clear()

@@ -1,13 +1,13 @@
 """Retry with deterministic exponential backoff for transient failures.
 
 The guard layer (guard.py) answers "can this failure EVER succeed here?" —
-a Mosaic compile failure or a missing jax API is deterministic, and the
+a Mosaic compile failure is deterministic, and the
 golden XLA path is the cure. This module answers the other question: "was
 this failure TRANSIENT?" A watchdog trip (:class:`DistTimeoutError`) is a
 timing event — a late peer, comm jitter, one lost signal — and production
 fleets absorb those with a bounded retry before declaring anything sick.
 
-Classification reuses the existing taxonomy (docs/resilience.md):
+Classification reuses the existing classification (docs/resilience.md):
 
 - **transient** — a ``DistTimeoutError`` anywhere in the cause chain.
   Retried under the policy; each failed attempt feeds the elastic layer's
@@ -38,7 +38,7 @@ from typing import Any, Callable
 from triton_dist_tpu.resilience import health
 from triton_dist_tpu.resilience.records import DistTimeoutError
 
-# failure classes (the retry-relevant projection of the guard taxonomy)
+# failure classes (the retry-relevant projection of the guard classification)
 TRANSIENT = "transient"
 DETERMINISTIC = "deterministic"
 # detected data corruption (IntegrityError in the chain, ISSUE 8):

@@ -33,7 +33,7 @@ from __future__ import annotations
 
 KIND_SIGNAL = 1   # shmem.signal_wait_until
 KIND_WAIT = 2     # shmem.wait (dl.wait parity)
-KIND_BARRIER = 3  # a dissemination-barrier round in shmem.barrier_all
+KIND_BARRIER = 3  # one round of shmem.barrier_all
 KIND_CHUNK = 4    # shmem.wait_chunk: a per-chunk arrival wait of a chunked
                   # put (the sub-shard granularity of the ring pipelines)
 KIND_INTEGRITY = 5  # shmem.wait_chunk canary: the landed chunk's payload

@@ -881,7 +881,7 @@ def _run_speculative_campaign(spec: SoakSpec) -> CampaignResult:
     cfgsnap = tdt_config.get_config()
     saved = (cfgsnap.elastic, cfgsnap.suspect_threshold,
              cfgsnap.probation_probes)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(
         elastic=True, suspect_threshold=max(1, spec.n_timeouts),
         probation_probes=1,
@@ -938,7 +938,7 @@ def _run_speculative_campaign(spec: SoakSpec) -> CampaignResult:
         }
         # wipe the reference run's (empty, but structurally possible)
         # health residue so the judged run's accounting stands alone
-        resilience.reset(keep_env=True)
+        resilience.reset()
 
         trace = generate_trace(traffic)
         schedule = _spec_fault_schedule(spec)
@@ -991,7 +991,7 @@ def _run_speculative_campaign(spec: SoakSpec) -> CampaignResult:
             elastic=saved[0], suspect_threshold=saved[1],
             probation_probes=saved[2],
         )
-        resilience.reset(keep_env=True)
+        resilience.reset()
 
 
 @contextlib.contextmanager
@@ -1161,7 +1161,7 @@ def _run_disagg_campaign(spec: SoakSpec) -> CampaignResult:
     cfgsnap = tdt_config.get_config()
     saved = (cfgsnap.elastic, cfgsnap.suspect_threshold,
              cfgsnap.probation_probes, cfgsnap.fault_plan)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(
         elastic=True, suspect_threshold=max(1, spec.n_timeouts),
         probation_probes=1,
@@ -1269,7 +1269,7 @@ def _run_disagg_campaign(spec: SoakSpec) -> CampaignResult:
             elastic=saved[0], suspect_threshold=saved[1],
             probation_probes=saved[2], fault_plan=saved[3],
         )
-        resilience.reset(keep_env=True)
+        resilience.reset()
 
 
 @contextlib.contextmanager
@@ -1608,7 +1608,7 @@ def _run_fleet_campaign(spec: SoakSpec) -> CampaignResult:
         )
     cfgsnap = tdt_config.get_config()
     saved = (cfgsnap.elastic, cfgsnap.fault_plan)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     recovery = spec.fleet_recovery
     tdt_config.update(
         elastic=bool(recovery),
@@ -1735,7 +1735,7 @@ def _run_fleet_campaign(spec: SoakSpec) -> CampaignResult:
         return result
     finally:
         tdt_config.update(elastic=saved[0], fault_plan=saved[1])
-        resilience.reset(keep_env=True)
+        resilience.reset()
 
 
 def run_campaign(spec: SoakSpec, *, model=None) -> CampaignResult:
@@ -1779,7 +1779,7 @@ def run_campaign(spec: SoakSpec, *, model=None) -> CampaignResult:
     cfgsnap = tdt_config.get_config()
     saved = (cfgsnap.elastic, cfgsnap.suspect_threshold,
              cfgsnap.probation_probes)
-    resilience.reset(keep_env=True)
+    resilience.reset()
     tdt_config.update(
         elastic=True, suspect_threshold=spec.n_timeouts, probation_probes=1
     )
@@ -1892,4 +1892,4 @@ def run_campaign(spec: SoakSpec, *, model=None) -> CampaignResult:
             elastic=saved[0], suspect_threshold=saved[1],
             probation_probes=saved[2],
         )
-        resilience.reset(keep_env=True)
+        resilience.reset()
