@@ -300,6 +300,13 @@ def test_disagg_byte_identical_to_unified_greedy(model):
     for uid in done:
         assert isinstance(done[uid], Finished)
         assert done[uid].tokens == done_u[uid].tokens, uid
+        # per-token stamps cross the pools: the first is the prefill
+        # pool's (the client's TTFT), the rest the decode pool's
+        fin = done[uid]
+        assert len(fin.t_tokens) == len(fin.tokens)
+        assert list(fin.t_tokens) == sorted(fin.t_tokens)
+        assert fin.t_tokens[0] == fin.t_first_token
+        assert fin.t_tokens[-1] == fin.t_finished
     snap = eng.snapshot()
     assert snap["requests"]["handoffs"] == len(
         [u for u in done if len(done[u].tokens) > 1]

@@ -41,6 +41,7 @@ from triton_dist_tpu.ops.flash_decode import (
     flash_decode_distributed,
     paged_flash_decode_distributed,
 )
+from triton_dist_tpu.obs.tracer import span as _span
 from triton_dist_tpu.utils import axis_size as _axis_size
 
 
@@ -932,6 +933,7 @@ class ContinuousBatcher:
         self.slot_out: list[list] = [[] for _ in range(b)]
         self.queue: list[Request] = []
         self.finished: list[tuple[Any, list]] = []
+        self.rounds = 0     # decode rounds run (the `round` of their span)
         # poisoned requests (ISSUE 8): slots whose logit row went
         # non-finite under an armed config.integrity — evicted, never
         # finished; drained by the serving engine for typed rejection
@@ -1082,54 +1084,56 @@ class ContinuousBatcher:
         bucket = 1
         while bucket < S:
             bucket *= 2
-        tokens = np.zeros((self.cfg.batch, bucket), np.int32)
-        tokens[i, :S] = req.prompt[lo:hi]
-        pos0 = np.full(self.cfg.batch, self.s_max, np.int32)  # parked rows
-        pos0[i] = lo
-        if self._px is not None and self._px_dirty:
-            # the paged scatter and attention read the device table: an
-            # acquire/publish that just repointed this slot's row must
-            # land first
-            self._push_px_table()
-        logits, self.cache = self._ranged_prog(bucket)(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos0)
-        )
-        self.prefill_tokens_total += S
-        self.prefill_work_total += bucket * hi
-        if self._px is not None:
-            # publish-on-completion, batch form: every prompt page fully
-            # covered by [0, hi) enters the trie now (its last position's
-            # KV just landed) — the same gate the decode loop applies one
-            # page at a time
-            pg = self._px.page
-            while True:
-                g = self._px.next_publish(i)
-                if (g + 1) * pg > hi or (g + 1) * pg > L:
-                    break
-                if self._px.publish(i, g, req.prompt[g * pg:(g + 1) * pg]):
-                    self._px_dirty = True
-        if hi < L:
-            return  # mid-prompt chunk: no token to sample yet
-        from triton_dist_tpu.resilience import integrity as _integrity
-
-        last_i = np.asarray(logits[i, S - 1], np.float32)
-        if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
-            # poisoned at admission: quarantine before a token exists
-            self._poison_slot(i, "non-finite prefill logits")
-            return
-        t0 = req.sample(last_i, self.slot_rng[i])
-        self.slot_fed[i] = L
-        self.slot_out[i] = [t0]
-        self.tok[i] = t0
-        self.pos[i] = L
-        if len(self.slot_out[i]) >= req.max_new_tokens or (
-            req.eos_id is not None and t0 == req.eos_id
-        ):
-            self.finished.append((req.uid, self.slot_out[i]))
-            self.slot_req[i] = None
+        with _span("tdt.batcher.ranged_pass", uid=str(req.uid), slot=i,
+                   lo=lo, hi=hi, bucket=bucket):
+            tokens = np.zeros((self.cfg.batch, bucket), np.int32)
+            tokens[i, :S] = req.prompt[lo:hi]
+            pos0 = np.full(self.cfg.batch, self.s_max, np.int32)  # parked rows
+            pos0[i] = lo
+            if self._px is not None and self._px_dirty:
+                # the paged scatter and attention read the device table: an
+                # acquire/publish that just repointed this slot's row must
+                # land first
+                self._push_px_table()
+            logits, self.cache = self._ranged_prog(bucket)(
+                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(pos0)
+            )
+            self.prefill_tokens_total += S
+            self.prefill_work_total += bucket * hi
             if self._px is not None:
-                self._px.release(i)
-                self._px_dirty = True
+                # publish-on-completion, batch form: every prompt page fully
+                # covered by [0, hi) enters the trie now (its last position's
+                # KV just landed) — the same gate the decode loop applies one
+                # page at a time
+                pg = self._px.page
+                while True:
+                    g = self._px.next_publish(i)
+                    if (g + 1) * pg > hi or (g + 1) * pg > L:
+                        break
+                    if self._px.publish(i, g, req.prompt[g * pg:(g + 1) * pg]):
+                        self._px_dirty = True
+            if hi < L:
+                return  # mid-prompt chunk: no token to sample yet
+            from triton_dist_tpu.resilience import integrity as _integrity
+
+            last_i = np.asarray(logits[i, S - 1], np.float32)
+            if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
+                # poisoned at admission: quarantine before a token exists
+                self._poison_slot(i, "non-finite prefill logits")
+                return
+            t0 = req.sample(last_i, self.slot_rng[i])
+            self.slot_fed[i] = L
+            self.slot_out[i] = [t0]
+            self.tok[i] = t0
+            self.pos[i] = L
+            if len(self.slot_out[i]) >= req.max_new_tokens or (
+                req.eos_id is not None and t0 == req.eos_id
+            ):
+                self.finished.append((req.uid, self.slot_out[i]))
+                self.slot_req[i] = None
+                if self._px is not None:
+                    self._px.release(i)
+                    self._px_dirty = True
 
     def _admit_ranged(self, i: int, req: Request, lo: int) -> None:
         """Ranged admission: feed prompt positions ``[lo, L)`` — the
@@ -1147,108 +1151,127 @@ class ContinuousBatcher:
             return
         self._ranged_pass(i, req, lo, len(req.prompt))
 
+    def _prefill_inputs(self, i: int, req: Request, bucket: int) -> tuple:
+        """Slot ``i``'s prompt padded to its bucket, the slot mask and the
+        pick row, uploaded."""
+        L = len(req.prompt)
+        with _span("tdt.batcher.admit_prefill.build"):
+            prompt = np.zeros((self.cfg.batch, bucket), np.int32)
+            prompt[i, :L] = req.prompt
+            # pad positions write junk KV beyond L-1, but decode overwrites
+            # each position before kv_lens ever exposes it; the first
+            # generated token comes from position L-1's logits (pick)
+            pick = np.zeros(self.cfg.batch, np.int32)
+            pick[i] = L - 1
+            return (
+                jnp.asarray(prompt),
+                jnp.asarray(np.arange(self.cfg.batch) == i),
+                jnp.asarray(pick),
+            )
+
     def _admit_prefill(self, i: int, req: Request) -> None:
         """MXU-rate admission: one masked full-forward pass writes the
         whole prompt's KV and yields the first generated token."""
         L = len(req.prompt)
         bucket = self._bucket(L)
-        prompt = np.zeros((self.cfg.batch, bucket), np.int32)
-        prompt[i, :L] = req.prompt
-        # pad positions write junk KV beyond L-1, but decode overwrites
-        # each position before kv_lens ever exposes it; the first
-        # generated token comes from position L-1's logits (pick)
-        pick = np.zeros(self.cfg.batch, np.int32)
-        pick[i] = L - 1
-        self.cache, last = self._prefill_prog(bucket)(
-            self.params, self.cache, jnp.asarray(prompt),
-            jnp.asarray(np.arange(self.cfg.batch) == i),
-            jnp.asarray(pick),
-        )
-        self.prefill_tokens_total += L
-        self.prefill_work_total += bucket * bucket
-        from triton_dist_tpu.resilience import integrity as _integrity
+        with _span("tdt.batcher.admit_prefill", uid=str(req.uid), slot=i,
+                   prompt_len=L, bucket=bucket):
+            args = self._prefill_inputs(i, req, bucket)
+            with _span("tdt.batcher.admit_prefill.dispatch"):
+                prog = self._prefill_prog(bucket)
+                self.cache, last = prog(self.params, self.cache, *args)
+            self.prefill_tokens_total += L
+            self.prefill_work_total += bucket * bucket
+            from triton_dist_tpu.resilience import integrity as _integrity
 
-        last_i = np.asarray(last[i], np.float32)
-        if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
-            # poisoned at admission: quarantine before a token exists
-            self._poison_slot(i, "non-finite prefill logits")
-            return
-        t0 = req.sample(last_i, self.slot_rng[i])
-        self.slot_fed[i] = L
-        self.slot_out[i] = [t0]
-        self.tok[i] = t0
-        self.pos[i] = L
-        if len(self.slot_out[i]) >= req.max_new_tokens or (
-            req.eos_id is not None and t0 == req.eos_id
-        ):
-            self.finished.append((req.uid, self.slot_out[i]))
-            self.slot_req[i] = None
+            with _span("tdt.batcher.admit_prefill.pull"):
+                last_i = np.asarray(last[i], np.float32)
+            if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
+                # poisoned at admission: quarantine before a token exists
+                self._poison_slot(i, "non-finite prefill logits")
+                return
+            t0 = req.sample(last_i, self.slot_rng[i])
+            self.slot_fed[i] = L
+            self.slot_out[i] = [t0]
+            self.tok[i] = t0
+            self.pos[i] = L
+            if len(self.slot_out[i]) >= req.max_new_tokens or (
+                req.eos_id is not None and t0 == req.eos_id
+            ):
+                self.finished.append((req.uid, self.slot_out[i]))
+                self.slot_req[i] = None
 
     def _admit(self) -> None:
-        # _admit_prefill can free the slot it just filled (max_new_tokens=1
-        # or instant EOS), so one linear pass would leave that slot empty
-        # until the next step even with queued work — re-pass until a full
-        # sweep admits nothing
-        admitted = True
-        while admitted and self.queue:
-            admitted = False
-            for i, r in enumerate(self.slot_req):
-                if r is None and self.queue:
-                    req = self.queue.pop(0)
-                    admitted = True
-                    self.slot_req[i] = req
-                    self.slot_out[i] = []
-                    # a live generator (prefix replay) continues sampling
-                    # mid-stream; otherwise each admission re-derives the
-                    # slot RNG from the request seed (the documented
-                    # neighbor-independent sampling guarantee)
-                    self.slot_rng[i] = (
-                        req.rng if req.rng is not None
-                        else np.random.default_rng(req.seed)
-                    )
-                    if self.prefill and len(req.prompt) > 1:
-                        if self._px is not None:
-                            # px × fast prefill (ISSUE 18): the trie hit's
-                            # pages are the ranged pass's already-landed
-                            # prior — only the divergent suffix runs. The
-                            # MISS path rides the same ranged entry from
-                            # lo=0, so hit ≡ miss bit for bit (range
-                            # composition), and both ≡ the token-fed px
-                            # engine (decode-chain equivalence).
+        if not self.queue:
+            return
+        with _span("tdt.batcher.admit", queued=len(self.queue)) as sp:
+            # _admit_prefill can free the slot it just filled (max_new_tokens=1
+            # or instant EOS), so one linear pass would leave that slot empty
+            # until the next step even with queued work — re-pass until a full
+            # sweep admits nothing
+            n_admitted = 0
+            admitted = True
+            while admitted and self.queue:
+                admitted = False
+                for i, r in enumerate(self.slot_req):
+                    if r is None and self.queue:
+                        req = self.queue.pop(0)
+                        admitted = True
+                        n_admitted += 1
+                        self.slot_req[i] = req
+                        self.slot_out[i] = []
+                        # a live generator (prefix replay) continues sampling
+                        # mid-stream; otherwise each admission re-derives the
+                        # slot RNG from the request seed (the documented
+                        # neighbor-independent sampling guarantee)
+                        self.slot_rng[i] = (
+                            req.rng if req.rng is not None
+                            else np.random.default_rng(req.seed)
+                        )
+                        if self.prefill and len(req.prompt) > 1:
+                            if self._px is not None:
+                                # px × fast prefill (ISSUE 18): the trie hit's
+                                # pages are the ranged pass's already-landed
+                                # prior — only the divergent suffix runs. The
+                                # MISS path rides the same ranged entry from
+                                # lo=0, so hit ≡ miss bit for bit (range
+                                # composition), and both ≡ the token-fed px
+                                # engine (decode-chain equivalence).
+                                n_hit = self._px.acquire(
+                                    i, req.prompt, req.max_new_tokens
+                                )
+                                self._px_dirty = True
+                                self._admit_ranged(i, req, n_hit)
+                            elif (self.prefill_chunk_tokens is not None
+                                  and len(req.prompt)
+                                  > self.prefill_chunk_tokens):
+                                # chunked-prefill scheduling: park the slot;
+                                # bounded ranged chunks land between decode
+                                # steps. Shorter prompts keep the legacy
+                                # bucket prefill byte for byte (the
+                                # armed-but-untriggered pin).
+                                self._admit_ranged(i, req, 0)
+                            else:
+                                self._admit_prefill(i, req)
+                        elif self._px is not None:
+                            # longest-prefix match (ISSUE 12): every fully
+                            # shared page is skipped — the slot starts its
+                            # feed at the first token whose KV the trie does
+                            # not already hold; the divergent page onward is
+                            # freshly claimed (CoW), so shared pages are
+                            # never written
                             n_hit = self._px.acquire(
                                 i, req.prompt, req.max_new_tokens
                             )
                             self._px_dirty = True
-                            self._admit_ranged(i, req, n_hit)
-                        elif (self.prefill_chunk_tokens is not None
-                              and len(req.prompt)
-                              > self.prefill_chunk_tokens):
-                            # chunked-prefill scheduling: park the slot;
-                            # bounded ranged chunks land between decode
-                            # steps. Shorter prompts keep the legacy
-                            # bucket prefill byte for byte (the
-                            # armed-but-untriggered pin).
-                            self._admit_ranged(i, req, 0)
+                            self.pos[i] = n_hit
+                            self.tok[i] = req.prompt[n_hit]
+                            self.slot_fed[i] = n_hit + 1
                         else:
-                            self._admit_prefill(i, req)
-                    elif self._px is not None:
-                        # longest-prefix match (ISSUE 12): every fully
-                        # shared page is skipped — the slot starts its
-                        # feed at the first token whose KV the trie does
-                        # not already hold; the divergent page onward is
-                        # freshly claimed (CoW), so shared pages are
-                        # never written
-                        n_hit = self._px.acquire(
-                            i, req.prompt, req.max_new_tokens
-                        )
-                        self._px_dirty = True
-                        self.pos[i] = n_hit
-                        self.tok[i] = req.prompt[n_hit]
-                        self.slot_fed[i] = n_hit + 1
-                    else:
-                        self.pos[i] = 0
-                        self.tok[i] = req.prompt[0]
-                        self.slot_fed[i] = 1
+                            self.pos[i] = 0
+                            self.tok[i] = req.prompt[0]
+                            self.slot_fed[i] = 1
+            sp.set("admitted", n_admitted)
 
     @property
     def idle(self) -> bool:
@@ -1410,42 +1433,57 @@ class ContinuousBatcher:
         serving batcher replaces this with a draft+verify round —
         serving/speculative.py — and falls back here when no slot is in
         a speculation-eligible state)."""
-        if self._px is not None and self._px_dirty:
-            self._push_px_table()
-        logits, self.cache = self._step(
-            self.params, self.cache,
-            jnp.asarray(self.tok), jnp.asarray(self.pos),
-        )
-        # per-request poison detection (ISSUE 8): one [b]-bool transfer
-        # when config.integrity arms the output checks — a non-finite
-        # logit row quarantines exactly that slot's request below
-        from triton_dist_tpu.resilience import integrity as _integrity
+        self.rounds += 1
+        with _span("tdt.batcher.decode_round", round=self.rounds) as sp:
+            with _span("tdt.batcher.decode_round.upload"):
+                if self._px is not None and self._px_dirty:
+                    self._push_px_table()
+                tok_d, pos_d = jnp.asarray(self.tok), jnp.asarray(self.pos)
+            with _span("tdt.batcher.decode_round.dispatch"):
+                logits, self.cache = self._step(
+                    self.params, self.cache, tok_d, pos_d,
+                )
+            # per-request poison detection (ISSUE 8): one [b]-bool
+            # transfer when config.integrity arms the output checks — a
+            # non-finite logit row quarantines exactly that slot's request
+            # below
+            from triton_dist_tpu.resilience import integrity as _integrity
 
-        row_ok = (
-            np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
-            if _integrity.output_checks_enabled() else None
-        )
-        # greedy slots need only the [b]-int argmax; the full [b, vocab]
-        # row transfer (~vocab x 4 bytes/slot over a possibly-remote link)
-        # is paid only when some active request actually samples
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        logits_h = (
-            np.asarray(logits, np.float32)
+            with _span("tdt.batcher.decode_round.pull"):
+                row_ok = (
+                    np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+                    if _integrity.output_checks_enabled() else None
+                )
+                # greedy slots need only the [b]-int argmax; the full
+                # [b, vocab] row transfer (~vocab x 4 bytes/slot over a
+                # possibly-remote link) is paid only when some active
+                # request actually samples
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            logits_h = None
             if any(
                 r is not None and r.temperature > 0.0
                 and self.slot_fed[i] >= len(r.prompt)  # past prompt feed
                 for i, r in enumerate(self.slot_req)
-            )
-            else None
-        )
+            ):
+                with _span("tdt.batcher.decode_round.sample"):
+                    logits_h = np.asarray(logits, np.float32)
+            self._take_round(sp, nxt, logits_h, row_ok)
+
+    def _take_round(self, sp, nxt, logits_h, row_ok) -> None:
+        """The host half of a decode round: every live slot takes its
+        token (or feeds its next prompt token), finished requests leave
+        their slots, and the round's span gets its counts."""
+        live = feeding = tokens = finished = 0
         for i, req in enumerate(self.slot_req):
             if req is None:
                 continue  # idle slot decoded a dummy token; ignore
+            live += 1
             if i in self._chunk:
                 # parked mid-chunk: the slot's decode row was a dummy
                 # (pos = s_max — no PE owns it, nothing was written) and
                 # its garbage logits carry no health signal; its position
                 # advances via the ranged chunks, not here
+                feeding += 1
                 continue
             if row_ok is not None and not row_ok[i]:
                 # poison quarantine: THIS request is evicted and typed-
@@ -1458,17 +1496,20 @@ class ContinuousBatcher:
                 # ignored, the next input is the given token
                 self.tok[i] = req.prompt[self.slot_fed[i]]
                 self.slot_fed[i] += 1
+                feeding += 1
             else:
                 t = (
                     int(nxt[i]) if req.temperature <= 0.0
                     else req.sample(logits_h[i], self.slot_rng[i])
                 )
                 self.slot_out[i].append(t)
+                tokens += 1
                 self.tok[i] = t
                 done = len(self.slot_out[i]) >= req.max_new_tokens or (
                     req.eos_id is not None and t == req.eos_id
                 )
                 if done:
+                    finished += 1
                     self.finished.append((req.uid, self.slot_out[i]))
                     self.slot_req[i] = None
                     if self._px is not None:
@@ -1478,6 +1519,10 @@ class ContinuousBatcher:
             self.pos[i] += 1
             if self._px is not None:
                 self._publish_step(i, req)
+        sp.set("live", live)
+        sp.set("feeding", feeding)
+        sp.set("tokens", tokens)
+        sp.set("finished", finished)
 
     def run(self, max_steps: int = 100000) -> list[tuple[Any, list]]:
         """Drive until every queued request finishes; returns

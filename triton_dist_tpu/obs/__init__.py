@@ -10,8 +10,11 @@ Six pieces (docs/observability.md for the full contract):
   which ladder rung actually ran — fused / retry / golden fallback /
   integrity), ``jit_shard_map`` dispatch (trace vs cached call), autotune
   sweeps (candidates + crowned config), and the serving engine's
-  per-request lifecycle. Ring-buffered and dependency-free like
-  ``resilience/health.py``; a FakeClock makes exports byte-identical.
+  per-request lifecycle. Ring-buffered like ``resilience/health.py``; a
+  FakeClock makes exports byte-identical. ``span`` is also a
+  ``jax.profiler.TraceAnnotation`` whenever a profiler session runs,
+  armed or not: the serving loop's ``tdt.engine.*`` / ``tdt.batcher.*``
+  spans land in the device trace itself that way.
 - :mod:`telemetry` — the device tier: with
   ``config.update(obs=ObsConfig(wait_stats=True))`` on top of an armed
   watchdog, every bounded wait site writes its observed spin count into a

@@ -3,9 +3,9 @@ layer).
 
 Design rules, shared with ``resilience/health.py``:
 
-- **Dependency-free and bounded** — a ring buffer of finished spans plus
-  per-name streaming duration histograms behind one lock. The ring bound
-  is ``ObsConfig.max_spans``; evictions are COUNTED and surfaced
+- **Bounded** — a ring buffer of finished spans plus per-name streaming
+  duration histograms behind one lock. The ring bound is
+  ``ObsConfig.max_spans``; evictions are COUNTED and surfaced
   (``dropped_spans`` — no silent caps), and the per-name stats are
   streaming, so percentiles survive any number of evictions.
 - **Deterministic** — every timestamp comes from the injectable
@@ -18,6 +18,14 @@ Design rules, shared with ``resilience/health.py``:
 - **Zero overhead disarmed** — every entry point checks
   ``config.obs`` first; ``None`` (the default) traces nothing and adds
   one attribute read per call site.
+- **One switch for the device trace** — :func:`span` is also a
+  ``jax.profiler.TraceAnnotation`` whenever a profiler session runs
+  (``jax.profiler.start_trace``, ``utils.group_profile``, a profiler
+  server), whatever ``config.obs`` says: the span then lies in the host
+  plane of the ``.xplane.pb``, on the same clock as the device ops, with
+  its attributes as the event's stats. No session: one read of the
+  profiler's own flag. The serving loop's ``tdt.*`` spans
+  (docs/observability.md, "Spans in the device trace") come this way.
 
 Nesting is tracked per thread: :func:`span` is a context manager whose
 depth places it under its parent in the exported timeline, and
@@ -28,11 +36,18 @@ holding a handle).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import threading
 from typing import Any
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+from triton_dist_tpu import config as _tdt_config
+from triton_dist_tpu.resilience import retry as _retry
+
+# True while a profiler session records host events (one atomic read)
+_profiling = _TraceAnnotation.is_enabled
 
 # --- minimal streaming log-binned histogram (ms) ---------------------------
 # Self-contained on purpose: serving/metrics.py has a richer twin, but
@@ -133,9 +148,7 @@ _tls = threading.local()
 
 
 def _cfg():
-    from triton_dist_tpu import config as tdt_config
-
-    return tdt_config.get_config().obs
+    return _tdt_config.get_config().obs
 
 
 def span_enabled() -> bool:
@@ -144,8 +157,6 @@ def span_enabled() -> bool:
 
 
 def _clock_now() -> float:
-    from triton_dist_tpu.resilience import retry as _retry
-
     return _retry.get_clock().monotonic()
 
 
@@ -177,24 +188,56 @@ def _finish(sp: Span) -> None:
             _dropped += n_evict
 
 
-@contextlib.contextmanager
-def span(name: str, cat: str = "host", **attrs: Any):
-    """Open a nested span on the resilience clock. Yields the
-    :class:`Span` (or :data:`NULL_SPAN` when obs is disarmed) so the body
-    can attach attributes — e.g. which guard-ladder rung actually ran."""
-    if not span_enabled():
-        yield NULL_SPAN
-        return
-    stack = _open_stack()
-    sp = Span(name=name, cat=cat, t_start=_clock_now(), attrs=dict(attrs),
-              depth=len(stack))
-    stack.append(sp)
-    try:
-        yield sp
-    finally:
-        stack.pop()
-        sp.t_end = _clock_now()
-        _finish(sp)
+class _OpenSpan:
+    """One ``with span(...)`` block: a profiler annotation while a
+    profiler session runs, a ring :class:`Span` while ``config.obs`` arms
+    it, both when both listen. ``set`` reaches both."""
+
+    __slots__ = ("name", "cat", "attrs", "_ann", "_sp")
+
+    def __init__(self, name: str, cat: str, attrs: dict):
+        self.name, self.cat, self.attrs = name, cat, attrs
+        self._ann = self._sp = None
+
+    def __enter__(self):
+        if _profiling():
+            self._ann = _TraceAnnotation(self.name, **self.attrs)
+            self._ann.__enter__()
+        if span_enabled():
+            stack = _open_stack()
+            self._sp = Span(name=self.name, cat=self.cat,
+                            t_start=_clock_now(), attrs=self.attrs,
+                            depth=len(stack))
+            stack.append(self._sp)
+        elif self._ann is None:
+            return NULL_SPAN
+        return self
+
+    def set(self, key: str, value: Any) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**{key: value})
+        if self._sp is not None:
+            self._sp.attrs[key] = value
+
+    def __exit__(self, *exc) -> bool:
+        sp = self._sp
+        if sp is not None:
+            _open_stack().pop()
+            sp.t_end = _clock_now()
+            _finish(sp)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def span(name: str, cat: str = "host", **attrs: Any) -> _OpenSpan:
+    """A nested span as a context manager: in the device trace whenever a
+    profiler session runs, in the ring (on the resilience clock) when
+    ``config.obs`` arms it. ``with`` yields a handle whose ``set(key,
+    value)`` attaches an attribute known only inside the body (which
+    guard-ladder rung ran, how many tokens a round made) to both;
+    :data:`NULL_SPAN` when nothing listens."""
+    return _OpenSpan(name, cat, attrs)
 
 
 def record_span(name: str, t_start: float, t_end: float, *,

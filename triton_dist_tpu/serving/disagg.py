@@ -556,7 +556,8 @@ class DisaggServingEngine:
         ):
             # complete at prefill: the first token was the whole answer
             self.metrics.count("prefill_completed")
-            self._finalize(uid, list(res.tokens), res.t_finished)
+            self._finalize(uid, list(res.tokens), res.t_finished,
+                           res.t_tokens)
             return
         # the KV handoff: stream the prompt's page chain to the decode
         # pool through the guard ladder; admission gates on t_landed
@@ -641,10 +642,15 @@ class DisaggServingEngine:
         # token the prefill pool already served; the two must agree) is
         # pinned in tests — a runtime assertion here would mask the
         # fault-injection soaks that deliberately corrupt handoff state
+        t_tokens = res.t_tokens
         if st.t_first is None:
             st.t_first = res.t_first_token
+        elif t_tokens:
+            # the client saw its first token when the prefill pool made
+            # it; the decode pool's copy of it came later
+            t_tokens = (st.t_first,) + t_tokens[1:]
         st.resumed += res.resumed
-        self._finalize(uid, list(res.tokens), res.t_finished)
+        self._finalize(uid, list(res.tokens), res.t_finished, t_tokens)
 
     def _count_terminal(self, terminal: str, priority: str) -> None:
         """One coordinator-tier terminal: the private tally AND its
@@ -654,7 +660,8 @@ class DisaggServingEngine:
         _mx.counter("serving_requests_total", engine=self.family,
                     terminal=terminal, priority=priority)
 
-    def _finalize(self, uid: Any, tokens: list, now: float) -> None:
+    def _finalize(self, uid: Any, tokens: list, now: float,
+                  t_tokens: tuple) -> None:
         st = self._states.pop(uid)
         prio = st.priority if self.metrics.classes else None
         ttft_ms = (st.t_first - st.t_enqueue) * 1e3
@@ -701,7 +708,7 @@ class DisaggServingEngine:
         fin = Finished(
             uid=uid, tokens=tokens, t_enqueue=st.t_enqueue,
             t_admitted=st.t_prefill_admitted, t_first_token=st.t_first,
-            t_finished=now, resumed=st.resumed,
+            t_finished=now, resumed=st.resumed, t_tokens=t_tokens,
         )
         self.results[uid] = fin
         self._record_phase_spans(st, fin)

@@ -265,6 +265,11 @@ class Finished:
     t_first_token: float | None
     t_finished: float
     resumed: int
+    # engine-clock time at which each token of ``tokens`` became visible
+    # to the engine (one stamp a step, so a speculative round's tokens
+    # share one); stamps of a replay prefix are kept. Without a replay
+    # the first equals ``t_first_token``; the last equals ``t_finished``
+    t_tokens: tuple = ()
 
     @property
     def ttft_ms(self) -> float:
@@ -284,6 +289,8 @@ class _ReqState:
     first_recorded: bool = False     # original-TTFT sample already taken
     awaiting_first: bool = True      # no token seen since (re)admission
     tokens: list = dataclasses.field(default_factory=list)  # replay prefix
+    # when each token so far was seen (replay prefix + the live slot's)
+    t_tokens: list = dataclasses.field(default_factory=list)
     resumed: int = 0
     priority: str = "interactive"    # overload class (ISSUE 11)
     deadline: float | None = None    # absolute engine-clock deadline
@@ -379,6 +386,9 @@ class ServingEngine:
         # per-step deltas feeding the controller's pressure window
         self._step_arrived = 0
         self._step_finished = 0
+        # running tallies, read as deltas by the tdt.engine.observe span
+        self._n_first_tokens = 0
+        self._n_finished = 0
         self._step_slo_ok = 0
         self._step_slo_scored = 0
         self.mesh = self._target_mesh()
@@ -647,42 +657,56 @@ class ServingEngine:
                 "deadline expired in queue",
             )
 
-    def _admit(self, now: float) -> None:
+    def _admit(self, now: float) -> int:
         ctrl = self._overload
         if ctrl is not None:
             self._shed_expired(now)
+        admitted = 0
         while self._batcher.n_free_slots > 0 and self._pending:
             st = self._pop_admission()
             st.t_admitted = now
             self.metrics.count("admitted")
             self._batcher.submit(st.req)
+            admitted += 1
+        return admitted
 
     # -- the step loop --------------------------------------------------
 
     def _step_once(self) -> bool:
         """Admit + one batcher step. False when there is nothing to do."""
-        self._admit(self.clock.monotonic())
-        if self._batcher.idle:
-            return False
-        try:
-            self._batcher.step()
-        except Exception as exc:  # noqa: BLE001 — classified below
-            from triton_dist_tpu.resilience import integrity as _integrity
+        b = self._batcher
+        with _obs.span("tdt.engine.step", pending=len(self._pending),
+                       in_flight=b.n_active + len(b.queue)):
+            with _obs.span("tdt.engine.admit") as sp:
+                sp.set("admitted", self._admit(self.clock.monotonic()))
+            if b.idle:
+                return False
+            try:
+                b.step()
+            except Exception as exc:  # noqa: BLE001 — classified below
+                from triton_dist_tpu.resilience import integrity as _integrity
 
-            if _retry.timeout_in_chain(exc) is not None:
-                self._on_step_timeout(exc)
-                return True
-            if _integrity.integrity_in_chain(exc) is not None:
-                # whole-step corruption detected BELOW the logits (a
-                # canary / output guard tripped inside the jitted step):
-                # same containment as a timeout — attribute, rebuild, and
-                # prefix-replay every in-flight request (no token of the
-                # poisoned step was ever consumed); the per-REQUEST
-                # quarantine path is the batcher's logit check, not this
-                self._on_step_integrity(exc)
-                return True
-            raise
-        self._failures = 0
+                if _retry.timeout_in_chain(exc) is not None:
+                    self._on_step_timeout(exc)
+                    return True
+                if _integrity.integrity_in_chain(exc) is not None:
+                    # whole-step corruption detected BELOW the logits (a
+                    # canary / output guard tripped inside the jitted
+                    # step): same containment as a timeout — attribute,
+                    # rebuild, and prefix-replay every in-flight request
+                    # (no token of the poisoned step was ever consumed);
+                    # the per-REQUEST quarantine path is the batcher's
+                    # logit check, not this
+                    self._on_step_integrity(exc)
+                    return True
+                raise
+            self._failures = 0
+            self._charge_virtual_time()
+            self._observe_step()
+            return True
+
+    def _charge_virtual_time(self) -> None:
+        """What the step just run costs on a virtual engine clock."""
         if self.serving.virtual_step_s:
             if self.serving.speculative is not None:
                 # speculative step-count accounting (ISSUE 20): a
@@ -706,17 +730,23 @@ class ServingEngine:
             self._prefill_work_seen = total
             if delta > 0:
                 self.clock.sleep(delta * self.serving.virtual_prefill_work_s)
-        self._observe(self.clock.monotonic())
-        # alerts evaluate AFTER this step's finishes were scored and
-        # BEFORE the ladder observes them (ISSUE 15): the burn-rate rule
-        # sees the misses on the step they happen, the ladder needs the
-        # pressure window to integrate them — so a goodput burn alert
-        # FIRES before the ladder can reach shed_all_batch (pinned in
-        # tests/test_flight_recorder.py: alerts lead degradation)
-        self._alerts_step()
-        self._overload_step()
-        self._maybe_probe()
-        return True
+
+    def _observe_step(self) -> None:
+        with _obs.span("tdt.engine.observe") as sp:
+            n_first, n_finished = self._n_first_tokens, self._n_finished
+            self._observe(self.clock.monotonic())
+            # alerts evaluate AFTER this step's finishes were scored and
+            # BEFORE the ladder observes them (ISSUE 15): the burn-rate
+            # rule sees the misses on the step they happen, the ladder
+            # needs the pressure window to integrate them — so a goodput
+            # burn alert FIRES before the ladder can reach shed_all_batch
+            # (pinned in tests/test_flight_recorder.py: alerts lead
+            # degradation)
+            self._alerts_step()
+            self._overload_step()
+            self._maybe_probe()
+            sp.set("first_tokens", self._n_first_tokens - n_first)
+            sp.set("finished", self._n_finished - n_finished)
 
     # -- burn-rate alerts (ISSUE 15) ------------------------------------
 
@@ -940,14 +970,24 @@ class ServingEngine:
             if r is None:
                 continue
             st = self._states[r.uid]
-            if st.awaiting_first and b.slot_out[i]:
-                self._record_first(st, now)
+            if b.slot_out[i]:
+                if st.awaiting_first:
+                    self._record_first(st, now)
+                self._stamp_tokens(st, len(b.slot_out[i]), now)
         for uid, toks, reason in b.drain_poisoned():
             self._finalize_poisoned(uid, toks, reason, now)
         for uid, reason in b.drain_struck():
             self._restart_struck(uid, reason, now)
         for uid, toks in b.drain_finished():
             self._finalize(uid, toks, now)
+
+    @staticmethod
+    def _stamp_tokens(st: _ReqState, n_live: int, now: float) -> None:
+        """Give ``now`` to every token of ``st`` not stamped yet: its
+        replay prefix plus the ``n_live`` tokens of its current slot."""
+        n_new = len(st.tokens) + n_live - len(st.t_tokens)
+        if n_new > 0:
+            st.t_tokens.extend([now] * n_new)
 
     def _restart_struck(self, uid: Any, reason: str, now: float) -> None:
         """Prefix-strike fan-out (ISSUE 12): this in-flight request was
@@ -960,6 +1000,7 @@ class ServingEngine:
         event, like every other disruption."""
         st = self._states[uid]
         st.tokens = []
+        st.t_tokens = []
         st.resumed += 1
         st.awaiting_first = True
         if not st.first_recorded:
@@ -975,6 +1016,7 @@ class ServingEngine:
     def _record_first(self, st: _ReqState, now: float) -> None:
         st.awaiting_first = False
         st.t_first = now
+        self._n_first_tokens += 1
         ttft_ms = (now - st.t_enqueue) * 1e3
         prio = st.priority if self._overload is not None else None
         if st.resumed:
@@ -997,6 +1039,7 @@ class ServingEngine:
             # finished within its admission step (instant EOS / prefill
             # one-shot): the first token was never observed mid-slot
             self._record_first(st, now)
+        self._stamp_tokens(st, len(toks), now)
         tokens = st.tokens + list(toks)
         ttft_ms = (st.t_first - st.t_enqueue) * 1e3
         e2e_ms = (now - st.t_enqueue) * 1e3
@@ -1041,6 +1084,7 @@ class ServingEngine:
             # finish, on the engine clock (evaluated in _alerts_step)
             ae.observe_request(now, slo_ok=goodput_ok, ttft_ms=ttft_ms)
         self._step_finished += 1
+        self._n_finished += 1
         if self.metrics.slo is not None or st.deadline is not None:
             self._step_slo_scored += 1
             if goodput_ok:
@@ -1053,6 +1097,7 @@ class ServingEngine:
             uid=uid, tokens=tokens, t_enqueue=st.t_enqueue,
             t_admitted=st.t_admitted, t_first_token=st.t_first,
             t_finished=now, resumed=st.resumed,
+            t_tokens=tuple(st.t_tokens),
         )
         self._record_phase_spans(self.results[uid], n_tokens=len(tokens))
 
@@ -1161,61 +1206,64 @@ class ServingEngine:
         either way (a timed-out donating step consumed it), so replay —
         prompt + tokens-so-far re-entering as a fresh prompt — is the
         re-materialization path; no generated token is lost."""
-        old = self._batcher
-        now = self.clock.monotonic()
-        rebuild_t0 = now
-        # completed work survives first (the drain_finished contract);
-        # poisoned evictions are final too — they must not re-enter replay
-        for uid, toks, poison_reason in old.drain_poisoned():
-            self._finalize_poisoned(uid, toks, poison_reason, now)
-        for uid, toks in old.drain_finished():
-            self._finalize(uid, toks, now)
-        # struck readers restart into the NEW batcher below; px counters
-        # accumulate at the engine so a rebuild never zeroes the hit-rate
-        struck = old.drain_struck()
-        self._fold_px(old.prefix_cache_stats())
-        self._fold_spec(old)
-        active, queued = old.export_in_flight()
-        target = self._target_mesh()
-        self.rebuilds += 1
-        self.metrics.count("rebuilds")
-        _mx.counter("serving_rebuilds_total", engine=self.family)
-        health.record_serving_rebuild(
-            self.family, world=int(target.devices.size),
-            reason=f"{reason}; {len(active)} in-flight replayed, "
-                   f"{len(queued)} re-queued",
-        )
-        self.mesh = target
-        self._batcher = self._build(target)
-        for req, toks, rng in active:
-            st = self._states[req.uid]
-            st.tokens.extend(toks)
-            st.resumed += 1
-            st.awaiting_first = True
-            st.t_first = st.t_first if st.first_recorded else None
-            self.metrics.count("resumed")
-            # prefix replay: everything generated so far becomes prompt;
-            # the live RNG continues a sampled stream mid-draw
-            self._batcher.submit(dataclasses.replace(
-                st.req,
-                prompt=list(st.req.prompt) + st.tokens,
-                max_new_tokens=st.req.max_new_tokens - len(st.tokens),
-                rng=rng,
-            ))
-        for req in queued:
-            # admitted but never started (possibly already a replay):
-            # resubmit verbatim
-            self._batcher.submit(req)
-        for uid, strike_reason in struck:
-            self._restart_struck(uid, strike_reason, now)
-        # the rebuild/replay arc as one engine-track span (ISSUE 9) —
-        # engine-clock timestamps, so FakeClock runs export identically
-        _obs.record_span(
-            "serving:rebuild", rebuild_t0, self.clock.monotonic(),
-            cat="serving", track=f"{self._obs_tag}engine", reason=reason,
-            world=int(target.devices.size), replayed=len(active),
-            requeued=len(queued),
-        )
+        with _obs.span("tdt.engine.rebuild", reason=reason) as sp:
+            old = self._batcher
+            now = self.clock.monotonic()
+            rebuild_t0 = now
+            # completed work survives first (the drain_finished contract);
+            # poisoned evictions are final too — they must not re-enter replay
+            for uid, toks, poison_reason in old.drain_poisoned():
+                self._finalize_poisoned(uid, toks, poison_reason, now)
+            for uid, toks in old.drain_finished():
+                self._finalize(uid, toks, now)
+            # struck readers restart into the NEW batcher below; px counters
+            # accumulate at the engine so a rebuild never zeroes the hit-rate
+            struck = old.drain_struck()
+            self._fold_px(old.prefix_cache_stats())
+            self._fold_spec(old)
+            active, queued = old.export_in_flight()
+            target = self._target_mesh()
+            self.rebuilds += 1
+            self.metrics.count("rebuilds")
+            _mx.counter("serving_rebuilds_total", engine=self.family)
+            health.record_serving_rebuild(
+                self.family, world=int(target.devices.size),
+                reason=f"{reason}; {len(active)} in-flight replayed, "
+                       f"{len(queued)} re-queued",
+            )
+            self.mesh = target
+            self._batcher = self._build(target)
+            for req, toks, rng in active:
+                st = self._states[req.uid]
+                st.tokens.extend(toks)
+                self._stamp_tokens(st, 0, now)
+                st.resumed += 1
+                st.awaiting_first = True
+                st.t_first = st.t_first if st.first_recorded else None
+                self.metrics.count("resumed")
+                # prefix replay: everything generated so far becomes prompt;
+                # the live RNG continues a sampled stream mid-draw
+                self._batcher.submit(dataclasses.replace(
+                    st.req,
+                    prompt=list(st.req.prompt) + st.tokens,
+                    max_new_tokens=st.req.max_new_tokens - len(st.tokens),
+                    rng=rng,
+                ))
+            for req in queued:
+                # admitted but never started (possibly already a replay):
+                # resubmit verbatim
+                self._batcher.submit(req)
+            for uid, strike_reason in struck:
+                self._restart_struck(uid, strike_reason, now)
+            # the rebuild/replay arc as one engine-track span (ISSUE 9) —
+            # engine-clock timestamps, so FakeClock runs export identically
+            _obs.record_span(
+                "serving:rebuild", rebuild_t0, self.clock.monotonic(),
+                cat="serving", track=f"{self._obs_tag}engine", reason=reason,
+                world=int(target.devices.size), replayed=len(active),
+                requeued=len(queued),
+            )
+            sp.set("replayed", len(active))
 
     def _maybe_probe(self) -> None:
         if self.full_mesh.devices.ndim != 1 or not elastic.enabled():
@@ -1253,27 +1301,58 @@ class ServingEngine:
             heap.append((a.t_s, seq, a, 0))
             seq += 1
         heapq.heapify(heap)
-        steps = 0
-        while True:
-            now = self.clock.monotonic()
-            if self._stopping and heap:
-                for _, _, a, attempt in heap:
-                    uid = a.request.uid
-                    if (self._overload is not None and attempt > 0
-                            and uid is not None):
-                        # an already-offered request awaiting its backoff:
-                        # cancellation makes its Rejected terminal — the
-                        # never-a-silent-drop invariant survives stop()
-                        self._record_terminal_rejected(Rejected(
-                            uid, "cancelled by stop() while awaiting "
-                            "resubmit", len(self._pending),
-                            getattr(a, "priority", "interactive"),
-                        ))
-                    else:
-                        self.metrics.count("cancelled")
-                heap.clear()
+        with _obs.span("tdt.engine.serve", offered=len(heap)):
+            steps = 0
+            while True:
+                now = self.clock.monotonic()
+                if self._stopping and heap:
+                    for _, _, a, attempt in heap:
+                        uid = a.request.uid
+                        if (self._overload is not None and attempt > 0
+                                and uid is not None):
+                            # an already-offered request awaiting its backoff:
+                            # cancellation makes its Rejected terminal — the
+                            # never-a-silent-drop invariant survives stop()
+                            self._record_terminal_rejected(Rejected(
+                                uid, "cancelled by stop() while awaiting "
+                                "resubmit", len(self._pending),
+                                getattr(a, "priority", "interactive"),
+                            ))
+                        else:
+                            self.metrics.count("cancelled")
+                    heap.clear()
+                if heap and heap[0][0] <= now:
+                    seq = self._ingest(heap, seq, now)
+                if self._step_once():
+                    steps += 1
+                    if steps >= max_steps:
+                        raise RuntimeError(
+                            f"serve(max_steps={max_steps}) exhausted with "
+                            f"work still in flight; finished results are "
+                            f"intact in self.results"
+                        )
+                    continue
+                if heap:
+                    dt = heap[0][0] - self.clock.monotonic()
+                    if dt > 0:
+                        with _obs.span("tdt.engine.sleep",
+                                       dt_us=int(dt * 1e6)):
+                            self.clock.sleep(dt)
+                    continue
+                return dict(self.results)
+
+    def _ingest(self, heap: list, seq: int, now: float) -> int:
+        """Submit every arrival due by ``now``; returns the next ``seq``.
+        ``late_us`` is how long after its due time the loop popped an
+        entry (it looks at the heap only between steps)."""
+        n = late_sum = late_max = 0
+        with _obs.span("tdt.engine.ingest") as sp:
             while heap and heap[0][0] <= now:
-                _, _, a, attempt = heapq.heappop(heap)
+                t_due, _, a, attempt = heapq.heappop(heap)
+                n += 1
+                late = int((now - t_due) * 1e6)
+                late_sum += late
+                late_max = max(late_max, late)
                 # arrival_t is ALWAYS the originally-offered time (a.t_s),
                 # resubmits included: TTFT/e2e accrue from when the client
                 # first asked, and the deadline budget anchors there too —
@@ -1297,21 +1376,10 @@ class ServingEngine:
                             attempt + 1,
                         ))
                         seq += 1
-            if self._step_once():
-                steps += 1
-                if steps >= max_steps:
-                    raise RuntimeError(
-                        f"serve(max_steps={max_steps}) exhausted with work "
-                        f"still in flight; finished results are intact in "
-                        f"self.results"
-                    )
-                continue
-            if heap:
-                dt = heap[0][0] - self.clock.monotonic()
-                if dt > 0:
-                    self.clock.sleep(dt)
-                continue
-            return dict(self.results)
+            sp.set("n", n)
+            sp.set("late_us_sum", late_sum)
+            sp.set("late_us_max", late_max)
+        return seq
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> dict:
         """Serve what is already queued/in flight (no new traffic)."""

@@ -91,6 +91,7 @@ from triton_dist_tpu.models.decode import (
     specs_for,
 )
 from triton_dist_tpu.models.speculative import accept_lengths
+from triton_dist_tpu.obs.tracer import span as _span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -407,9 +408,12 @@ class SpeculativeBatcher(ContinuousBatcher):
             self.last_accepts = {}
             self.last_step_units = 1.0
             return
-        self._spec_round(spec, k)
+        self.rounds += 1
+        with _span("tdt.batcher.decode_round", round=self.rounds,
+                   kind="spec") as sp:
+            self._spec_round(spec, k, sp)
 
-    def _spec_round(self, spec: list[int], k: int) -> None:
+    def _spec_round(self, spec: list[int], k: int, sp) -> None:
         sd = self.spec_decode
         b = self.cfg.batch
         catchup_cols = 0
@@ -517,9 +521,11 @@ class SpeculativeBatcher(ContinuousBatcher):
         # -- per-slot consume --------------------------------------------
         self.last_accepts = {}
         acc_round = off_round = 0
+        live = tokens = finished = 0   # for the round's span
         for i, req in enumerate(self.slot_req):
             if req is None or i in self._chunk:
                 continue
+            live += 1
             n_cols = S if i in spec_set else 1
             if fin is not None and not fin[i, :n_cols].all():
                 self._poison_slot(i, "non-finite logits")
@@ -552,6 +558,7 @@ class SpeculativeBatcher(ContinuousBatcher):
                 if len(self.slot_out[i]) >= req.max_new_tokens or (
                     req.eos_id is not None and t == req.eos_id
                 ):
+                    finished += 1
                     self.finished.append((req.uid, self.slot_out[i]))
                     self.slot_req[i] = None
                     if self._px is not None:
@@ -561,11 +568,12 @@ class SpeculativeBatcher(ContinuousBatcher):
                 self.pos[i] += 1
                 if self._px is not None:
                     self._publish_step(i, req)
+            n_done = len(self.slot_out[i]) - n_before
+            tokens += n_done
             if i in spec_set:
                 # accounting is over COMMITTED tokens: EOS/max_new can
                 # cut the emitted run short, and counting uncommitted
                 # accepts would overstate α into the adaptive loop
-                n_done = len(self.slot_out[i]) - n_before
                 a_done = min(a, n_done)
                 self.last_accepts[i] = a_done
                 acc_round += a_done
@@ -585,6 +593,11 @@ class SpeculativeBatcher(ContinuousBatcher):
             + sd.draft_cost_factor * catchup_cols
         )
         self._note_round(acc_round, off_round)
+        sp.set("live", live)
+        sp.set("offered", off_round)
+        sp.set("accepted", acc_round)
+        sp.set("tokens", tokens)
+        sp.set("finished", finished)
 
     def _accept(self, i, req, drafts_i, preds_i, logits_h, q_list, k):
         """Per-slot acceptance: returns ``(emitted_tokens,

@@ -343,6 +343,12 @@ def group_profile(
 
         with group_profile("decode") as run_dir: ...
 
+    The session this opens is the one switch for the serving loop's own
+    spans: every ``obs.span`` reached while it runs (``tdt.engine.*``,
+    ``tdt.batcher.*`` — docs/observability.md, "Spans in the device
+    trace") lands in the host plane of the ``.xplane.pb`` itself, on the
+    device ops' clock, with its counts as the event's stats.
+
     When the obs layer is armed (``config.obs``, ISSUE 9) the exit path
     additionally drops ``obs_trace.json`` — the span/wait-telemetry
     chrome trace — into the same directory, so XProf planes and host
