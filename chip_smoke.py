@@ -270,6 +270,8 @@ def reference_logits(cfg, params, tokens, prompt_lens, n_new: int):
     import jax
     import jax.numpy as jnp
 
+    from triton_dist_tpu.models.tp_transformer import unpack_gate_up
+
     c = cfg
     g, d = c.n_q_heads // c.n_kv_heads, c.head_dim
 
@@ -314,9 +316,8 @@ def reference_logits(cfg, params, tokens, prompt_lens, n_new: int):
             ).reshape(b, t, c.q_dim)
             x = x + mm(a, p["wo"])
             h = norm(x, p["mlp_norm"])
-            gu = mm(h, p["w_gate_up"].reshape(c.hidden, -1))
-            gu = gu.reshape(b, t, c.ffn, 2)
-            x = x + mm(jax.nn.silu(gu[..., 0]) * gu[..., 1], p["w_down"])
+            w_gate, w_up = unpack_gate_up(p["w_gate_up"], c)
+            x = x + mm(jax.nn.silu(mm(h, w_gate)) * mm(h, w_up), p["w_down"])
         # only the positions that predict a served token reach the vocab
         idx = last[:, None] + jnp.arange(n_new, dtype=jnp.int32)[None, :]
         xs = jnp.take_along_axis(x, idx[:, :, None], axis=1)

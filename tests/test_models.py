@@ -20,6 +20,7 @@ from triton_dist_tpu.models.tp_transformer import (
     _causal_gqa_attention,
     rmsnorm,
     rope,
+    unpack_gate_up,
 )
 from triton_dist_tpu.ops.allgather_gemm import AGGemmConfig
 from triton_dist_tpu.ops.gemm_reduce_scatter import GemmRSConfig
@@ -55,8 +56,9 @@ def _ref_forward(tokens, params, cfg):
         attn = _causal_gqa_attention(q, k, v, cfg)
         x = x + attn.reshape(b * s, cfg.q_dim) @ p["wo"]
         h = rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-        gu = (h @ p["w_gate_up"].reshape(cfg.hidden, -1)).reshape(b * s, -1, 2)
-        gate, up = gu[..., 0], gu[..., 1]
+        # two plain GEMMs over the unpacked halves: independent of how the
+        # model pairs the stored columns
+        gate, up = (h @ w for w in unpack_gate_up(p["w_gate_up"], cfg))
         x = x + (jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up) @ p["w_down"]
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x @ params["lm_head"]

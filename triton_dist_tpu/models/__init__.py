@@ -47,10 +47,12 @@ from triton_dist_tpu.models.tp_transformer import (
     moe_param_specs,
     moe_quantized_param_specs,
     opt_state_specs,
+    pack_gate_up,
     param_specs,
     quantize_moe_serving_params,
     specs_for,
     train_step,
+    unpack_gate_up,
 )
 
 __all__ = [
@@ -84,8 +86,10 @@ __all__ = [
     "moe_param_specs",
     "moe_quantized_param_specs",
     "opt_state_specs",
+    "pack_gate_up",
     "param_specs",
     "quantize_moe_serving_params",
     "specs_for",
     "train_step",
+    "unpack_gate_up",
 ]

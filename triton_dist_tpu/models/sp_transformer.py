@@ -23,6 +23,7 @@ from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig,
     rmsnorm,
     rope,
+    unpack_gate_up,
 )
 from triton_dist_tpu.ops.grads import ring_attention_grad
 from triton_dist_tpu.ops.ring_attention import (
@@ -83,8 +84,8 @@ class SPTransformer:
         x = x + attn.reshape(b, s_loc, c.q_dim) @ p["wo"]
 
         h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
-        gu = (h @ p["w_gate_up"].reshape(c.hidden, -1)).reshape(b, s_loc, -1, 2)
-        act = jax.nn.silu(gu[..., 0].astype(jnp.float32)).astype(x.dtype) * gu[..., 1]
+        gate, up = unpack_gate_up(h @ p["w_gate_up"], c)
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
         return x + act @ p["w_down"]
 
     def __call__(self, tokens_loc: jax.Array, params: dict) -> jax.Array:

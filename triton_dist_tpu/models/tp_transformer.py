@@ -102,9 +102,9 @@ def _init_tree(key: jax.Array, cfg: TransformerConfig) -> dict:
                 # wo rows in the same group-major q-head order
                 wo=w((cfg.q_dim, h), cfg.q_dim**-0.5),
                 mlp_norm=ones((h,)),
-                # gate/up interleaved PER FFN UNIT: [H, F, 2] — sharding F
-                # gives every PE matched gate+up columns
-                w_gate_up=w((h, f, 2), h**-0.5),
+                # gate|up as ONE plain matrix, paired per column block
+                # (pack_gate_up): its GEMM reads the leaf as stored
+                w_gate_up=w((h, 2 * f), h**-0.5),
                 w_down=w((f, h), f**-0.5),
             )
         )
@@ -157,7 +157,7 @@ def param_specs(cfg: TransformerConfig) -> dict:
         wqkv=P(None, t, None),       # kv groups sharded
         wo=P(t, None),               # row-parallel
         mlp_norm=P(None),
-        w_gate_up=P(None, t, None),  # ffn units sharded
+        w_gate_up=P(None, t),        # ffn units sharded, gate+up matched
         w_down=P(t, None),           # row-parallel
     )
     return dict(
@@ -166,6 +166,46 @@ def param_specs(cfg: TransformerConfig) -> dict:
         final_norm=P(None),
         lm_head=P(None, t),    # vocab-parallel
     )
+
+
+def _gate_up_block(cfg: TransformerConfig) -> int:
+    """Width B of the column blocks gate and up alternate in: one lane
+    tile (the activation's split then cuts whole tiles) where every TP
+    degree up to 8 keeps whole tiles a PE, else 1 (toy widths; llama-7b's
+    11008 and qwen2's 29568, which pay a lane-strided split instead)."""
+    return 128 if cfg.ffn % (8 * 128) == 0 else 1
+
+
+def pack_gate_up(
+    w_gate: jax.Array, w_up: jax.Array, cfg: TransformerConfig
+) -> jax.Array:
+    """``[H, F]`` gate and up -> the stored ``w_gate_up [H, 2F]``: the
+    order ``[H, F/B, 2, B]``, flattened. A column shard ``[H, 2F/n]``
+    (``param_specs``) then holds matched gate and up units wherever
+    ``(F/n) % B == 0``, whatever the TP degree, and packing a shard packs
+    its slice of the whole."""
+    blk = _gate_up_block(cfg)
+    h = w_gate.shape[0]
+    pair = [w.reshape(h, -1, blk) for w in (w_gate, w_up)]
+    return jnp.concatenate(pair, axis=-1).reshape(h, -1)
+
+
+def unpack_gate_up(
+    x: jax.Array, cfg: TransformerConfig
+) -> tuple[jax.Array, jax.Array]:
+    """Inverse of :func:`pack_gate_up` over the last axis: ``(gate, up)``
+    of a stored weight (or a column shard of it), and of the activation
+    ``h @ w_gate_up`` — the only thing the model ever splits."""
+    blk = _gate_up_block(cfg)
+    if x.shape[-1] % (2 * blk):
+        raise ValueError(
+            f"{x.shape[-1]} gate/up columns are not whole pairs of "
+            f"{blk}-column blocks: ffn={cfg.ffn} is sharded too finely"
+        )
+    lead = x.shape[:-1]
+    pair = x.reshape(*lead, -1, 2 * blk)
+    return (pair[..., :blk].reshape(*lead, -1),
+            pair[..., blk:].reshape(*lead, -1))
 
 
 def rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -254,11 +294,8 @@ class TPTransformer:
     def _mlp(self, x: jax.Array, p: dict) -> jax.Array:
         """Dense SwiGLU MLP half of the block (overridden by the MoE model)."""
         c = self.cfg
-        b, s = c.batch, c.seq
         h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
-        gu = self._col(h, p["w_gate_up"].reshape(c.hidden, -1))
-        gu = gu.reshape(b * s, -1, 2)              # [m, F/n, 2]
-        gate, up = gu[..., 0], gu[..., 1]
+        gate, up = unpack_gate_up(self._col(h, p["w_gate_up"]), c)  # [m, F/n]
         act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
         return self._row(act, p["w_down"])
 
