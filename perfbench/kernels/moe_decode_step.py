@@ -1,0 +1,27 @@
+"""What one decode step of the latent-attention / gated-expert model
+needs. Bytes: every weight outside the routed banks (attention, the dense
+layer, routers, shared experts, head: read once a step), the experts the
+step's tokens were routed to and no others, and the live latent rows.
+Operations: two per weight outside the banks and slot, and the routed
+experts' per assignment."""
+
+
+def bank_bytes(run) -> float:
+    """The routed banks held: what a step does NOT have to read whole."""
+    c, kern = run.config, run.kernel("expert_gemm")
+    held = (c.get("experts_held") or [0, c["n_routed_experts"]])[1]
+    return kern.expert_layers(run) * held * kern.expert_bytes(run)
+
+
+def bytes_per_step(run, steps: int) -> float:
+    return (run.weight_bytes - bank_bytes(run)
+            + run.kernel("expert_gemm").bytes_per_step(run, steps)
+            + run.kernel("mla_decode").bytes_per_step(run, steps))
+
+
+def flops_per_step(run, steps: int) -> float:
+    width = 2 if run.sizes["dtype"] in ("bfloat16", "float16") else 4
+    dense = 2.0 * (run.weight_bytes - bank_bytes(run)) / width
+    return (dense * run.config["engine"]["slots"]
+            + run.kernel("expert_gemm").flops_per_step(run, steps)
+            + run.kernel("mla_decode").flops_per_step(run, steps))

@@ -121,6 +121,61 @@ def test_paged_flash_verify_compiles(one_chip, rows):
     assert "tpu_custom_call" in text
 
 
+def test_mla_paged_decode_compiles(one_chip):
+    """The absorbed latent-attention decode at the published widths of the
+    benchmark's latent-attention configuration: 16 slots, 32 heads, rows of
+    640 (512 latent + 64 rotated key + 64 pad), values of 512, 5 layers."""
+    from triton_dist_tpu.ops.mla_decode import mla_paged_decode
+
+    slots, heads, row, d_v, layers = 16, 32, 640, 512, 5
+    pages_per_seq = S_MAX // PAGE
+    pool = _struct((layers, slots * pages_per_seq, PAGE, row), jnp.bfloat16,
+                   one_chip)
+    table = _struct((slots, pages_per_seq), jnp.int32, one_chip)
+    q = _struct((slots, heads, row), jnp.bfloat16, one_chip)
+    lens = _struct((slots,), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda q, pool, lens, table: mla_paged_decode(
+            q, pool, 3, lens, table, d_v=d_v, scale=192 ** -0.5,
+            interpret=False),
+        q, pool, lens, table,
+    )
+    assert "tpu_custom_call" in text and "mla_paged_decode" in text
+
+
+@pytest.mark.parametrize("rows,block_m", [(16, 16), (4096, 128)])
+def test_gated_expert_group_gemms_compile(one_chip, rows, block_m):
+    """The routed experts' two grouped GEMMs at the same configuration's
+    widths (256 experts of 2048 x 768, top-8): a decode step's 16 rows in
+    16-row blocks over the tight alignment, a prefill's 4096 rows in
+    128-row blocks; one whole expert matrix a tile."""
+    from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
+    from triton_dist_tpu.utils import round_up
+
+    hidden, fe, n_exp, topk = 2048, 768, 256, 8
+    t = rows * topk
+    t_pad = round_up(t + min(n_exp, t) * (block_m - 1), block_m)
+    ids = _struct((t_pad // block_m,), jnp.int32, one_chip)
+
+    def gemms(a, w_up, w_down, ids, valid):
+        up = GroupGemmConfig(block_m=block_m, block_n=fe, block_k=hidden,
+                             ragged=True)
+        down = GroupGemmConfig(block_m=block_m, block_n=hidden, block_k=fe,
+                               ragged=True)
+        gu = group_gemm(a, w_up, ids, valid_rows=valid, config=up,
+                        interpret=False)
+        return group_gemm(gu[:, :fe] * gu[:, fe:], w_down, ids,
+                          valid_rows=valid, config=down, interpret=False)
+
+    text = _compiled_text(
+        gemms,
+        _struct((t_pad, hidden), jnp.bfloat16, one_chip),
+        _struct((n_exp, hidden, 2 * fe), jnp.bfloat16, one_chip),
+        _struct((n_exp, fe, hidden), jnp.bfloat16, one_chip), ids, ids,
+    )
+    assert text.count("tpu_custom_call") >= 2 and "group_gemm" in text
+
+
 def test_matmul_compiles(one_chip):
     from triton_dist_tpu.ops.gemm import matmul
 

@@ -28,7 +28,7 @@ def test_preset_tp_validation_trips():
 
 
 def test_moe_preset_class():
-    cfg = presets.preset("mixtral-8x7b")
+    cfg = presets.preset("moe-gelu-8x")
     assert isinstance(cfg, MoETransformerConfig)
     assert (cfg.n_experts, cfg.topk) == (8, 2)
 
@@ -50,14 +50,14 @@ def test_ep_preset_variants():
     same for CLI callers; dense presets reject EP."""
     from triton_dist_tpu.models import EPMoETransformerConfig
 
-    flat = presets.preset("mixtral-8x7b:ep")
+    flat = presets.preset("moe-gelu-8x:ep")
     assert isinstance(flat, EPMoETransformerConfig) and flat.ep_outer is None
-    hier = presets.preset("mixtral-8x7b:ep-hier")
+    hier = presets.preset("moe-gelu-8x:ep-hier")
     assert isinstance(hier, EPMoETransformerConfig)
     assert hier.ep_outer == "dcn"
-    kw = presets.preset("mixtral-8x7b", ep=True)
+    kw = presets.preset("moe-gelu-8x", ep=True)
     assert isinstance(kw, EPMoETransformerConfig) and kw.ep_outer is None
-    kw2 = presets.preset("mixtral-8x7b", ep_outer="dp")
+    kw2 = presets.preset("moe-gelu-8x", ep_outer="dp")
     assert kw2.ep_outer == "dp"
     with pytest.raises(ValueError, match="dense"):
         presets.preset("llama-3.1-8b", ep=True)

@@ -236,7 +236,7 @@ def test_shape_sweep_table():
     assert table["llama-3.1-70b"]["ag_gemm"] == (8192, 8192, 28672)
     assert table["llama-3.1-70b"]["gemm_rs"] == (8192, 28672, 8192)
     assert table["qwen2-72b"]["ag_gemm"] == (8192, 8192, 29568)
-    assert table["mixtral-8x7b"]["moe"] == (8192, 4096, 14336, 8, 2)
+    assert table["moe-gelu-8x"]["moe"] == (8192, 4096, 14336, 8, 2)
     assert "moe" not in table["llama-3.1-8b"]
     assert set(table) == set(presets.PRESETS)
 

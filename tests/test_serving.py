@@ -197,7 +197,7 @@ def test_traffic_trace_byte_identical_replay():
 
 def test_traffic_preset_mix_admissible():
     s_max = 64
-    spec = preset_mix("mixtral-8x7b", s_max=s_max, rate_rps=3.0,
+    spec = preset_mix("moe-gelu-8x", s_max=s_max, rate_rps=3.0,
                       n_requests=50, seed=4, vocab=128)
     assert spec.vocab == 128  # override for shrunk serving heads
     assert (traffic_mod.max_length(spec.prompt_len)

@@ -92,7 +92,7 @@ def moe_align_block_size_host(
     ``ops.moe_utils.moe_align_block_size``."""
     topk_ids = np.ascontiguousarray(topk_ids, np.int32)
     t = topk_ids.shape[0]
-    t_pad = -(-(t + n_experts * (block_m - 1)) // block_m) * block_m
+    t_pad = -(-(t + min(n_experts, t) * (block_m - 1)) // block_m) * block_m
     lib = _load()
     if lib is not None:
         sorted_ids = np.empty(t_pad, np.int32)

@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, ClassVar
 
 import numpy as np
 
@@ -246,6 +246,10 @@ class PagedKVCacheSpec:
     # every pre-cache caller built, byte for byte.
     extra_pages: int = 0
 
+    # what the pool holds: k and v of every kv head ("kv"), or one latent
+    # row a token (LatentPagedCacheSpec); a config names its family's kind
+    kind: ClassVar[str] = "kv"
+
     def _geometry(self, cfg, n: int, n_o: int = 1) -> tuple[int, int]:
         s_shard = _shard_of(self.s_max, n)
         if s_shard % self.page_size != 0:
@@ -265,15 +269,13 @@ class PagedKVCacheSpec:
         # inner sequence shard
         return pages_per_seq, (cfg.batch // n_o) * pages_per_seq
 
-    def init(self, cfg: TransformerConfig, n: int, n_o: int = 1) -> dict:
+    def _table(self, cfg, n: int, n_o: int = 1) -> tuple[int, jax.Array]:
+        """``(pages in the whole pool, block table [w, b_att, pages a
+        sequence])``: what every cache KIND shares."""
         pages_per_seq, n_pages = self._geometry(cfg, n, n_o)
         n_pages += self.extra_pages
         b_att = cfg.batch // n_o   # per-outer-group batch slice
         w = n_o * n                # total PEs
-        shape = (
-            cfg.n_layers, w * n_pages, cfg.n_kv_heads, self.page_size,
-            cfg.head_dim,
-        )
         if self.static_table:
             bt = jnp.broadcast_to(
                 (
@@ -285,11 +287,19 @@ class PagedKVCacheSpec:
             )
         else:
             bt = jnp.zeros((w, b_att, pages_per_seq), jnp.int32)
+        return w * n_pages, bt
+
+    def init(self, cfg: TransformerConfig, n: int, n_o: int = 1) -> dict:
+        n_pool, bt = self._table(cfg, n, n_o)
+        shape = (
+            cfg.n_layers, n_pool, cfg.n_kv_heads, self.page_size,
+            cfg.head_dim,
+        )
         return dict(
             k=jnp.zeros(shape, cfg.dtype),
             v=jnp.zeros(shape, cfg.dtype),
             block_table=bt,
-            n_alloc=jnp.zeros((w,), jnp.int32),
+            n_alloc=jnp.zeros((bt.shape[0],), jnp.int32),
         )
 
     def specs(self, cfg: TransformerConfig) -> dict:
@@ -407,6 +417,57 @@ class PagedKVCacheSpec:
         return attn, cache
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentPagedCacheSpec(PagedKVCacheSpec):
+    """The paged cache's second KIND: one latent row a token and layer
+    (``models/mla_moe.py``: normed kv latent | rotated shared key | pad,
+    shared by every head) in ONE pool ``lat [n_layers, n_pages, page,
+    row]`` instead of ``k`` and ``v`` pools of ``n_kv_heads x head_dim``.
+    Block table, static page ranges, ``extra_pages`` and the
+    drop-out-of-range write discipline are the k/v kind's, unchanged; the
+    model writes rows at prefill and at each step and reads them through
+    ``ops/mla_decode.py``. Static tables only (the batcher's)."""
+
+    kind: ClassVar[str] = "latent"
+
+    def init(self, cfg, n: int, n_o: int = 1) -> dict:
+        if not self.static_table:
+            raise NotImplementedError(
+                "the latent cache kind needs static_table=True")
+        if n != 1 or n_o != 1:
+            raise NotImplementedError(
+                f"the latent cache kind lives on a one-device shard "
+                f"(got {n_o} x {n} devices): a latent row is not sharded "
+                f"over sequence or heads yet")
+        n_pool, bt = self._table(cfg, n, n_o)
+        lat = jnp.zeros(
+            (cfg.n_layers, n_pool, self.page_size, cfg.latent_row),
+            cfg.dtype)
+        return dict(lat=lat, block_table=bt,
+                    n_alloc=jnp.zeros((bt.shape[0],), jnp.int32))
+
+    def specs(self, cfg) -> dict:
+        kv = PagedKVCacheSpec.specs(self, cfg)
+        return dict(lat=P(None, cfg.axis, None, None),
+                    block_table=kv["block_table"], n_alloc=kv["n_alloc"])
+
+    def _refuse(self, what: str):
+        raise NotImplementedError(
+            f"{what} is not built for the latent cache kind "
+            f"(LatentPagedCacheSpec): it reads k/v pools")
+
+    def update_and_attend(self, *a, **kw):
+        self._refuse("the k/v decode step")
+
+    def update_multi_and_attend(self, *a, **kw):
+        self._refuse("speculative verify / ranged prefill")
+
+
+# a config's ``cache_kind`` -> the paged cache its family's passes use
+PAGED_CACHE_KINDS = {
+    spec.kind: spec for spec in (PagedKVCacheSpec, LatentPagedCacheSpec)}
+
+
 def _decode_mlp(c, x, p, me, n, n_o, interpret):
     """Decode-shaped MLP residual on ``m`` replicated rows (``m`` =
     per-group batch for decode, batch × chunk for the speculative verify
@@ -508,6 +569,10 @@ def decode_step(
     outer-sharded), the EP MLP's two-phase dispatch spans the whole mesh,
     and the returned logits are re-gathered to the replicated ``[b,
     vocab]`` layout — the host scheduling loop is deployment-agnostic."""
+    if cfg.own_passes:
+        # a layer-plan family: (logits, cache, its pass_counters)
+        return cfg.decode_step(
+            params, cache, tokens, pos, spec=spec, interpret=interpret)
     n_o, my_o = _outer_dims(cfg)
     if cfg.batch % n_o:
         raise ValueError(
@@ -768,6 +833,21 @@ class Request:
         return int(rng.choice(len(probs), p=probs))
 
 
+@dataclasses.dataclass
+class _Ahead:
+    """A decode step sent before the round it belongs to (lookahead): its
+    inputs and outputs on the device, the cache epoch it left, and the
+    ``tok`` / ``pos`` the host must hold for the step to be that round's."""
+
+    tok_d: Any
+    pos_d: Any
+    logits: Any
+    stats: Any
+    epoch: int
+    tok: Any = None
+    pos: Any = None
+
+
 class StepsExhaustedError(RuntimeError):
     """``ContinuousBatcher.run`` spent its step budget with work still in
     flight. Completed generations are NOT lost (ISSUE 6 satellite): the
@@ -820,8 +900,20 @@ class ContinuousBatcher:
         prefill_chunk_tokens: int | None = None,
         interpret: Any = None,
         prefix_cache: Any = None,
+        lookahead: bool = False,
     ):
         self.cfg, self.mesh, self.s_max = cfg, mesh, s_max
+        # lookahead (ROADMAP A6, "read back one step late"): a round whose
+        # tokens cannot change the schedule dispatches the NEXT step, fed on
+        # the device, before it pulls its own tokens, so the host's half of
+        # a round runs under the device's next step. Off: the round as it
+        # was, op for op.
+        self.lookahead = bool(lookahead)
+        self._ahead: _Ahead | None = None
+        self._pass_stats = None  # the last program's counters (device)
+        self._epoch = 0          # bumped by every new cache or params
+        self.rounds_ahead = 0    # rounds whose step the round before sent
+        self.ahead_discarded = 0
         n = mesh.shape[cfg.axis]
         n_o = _mesh_outer(cfg, mesh)
         self._n_o = n_o
@@ -835,6 +927,24 @@ class ContinuousBatcher:
         self._px = None
         self._px_dirty = False
         self.struck: list[tuple[Any, str]] = []
+        # what the model's family declares: counters its programs return
+        # beside their logits, and the kind of paged cache they read
+        self._counters = cfg.pass_counters
+        if cfg.cache_kind == "latent":
+            refused = [
+                name for name, on in (
+                    ("prefix_cache", prefix_cache is not None),
+                    ("prefill_chunk_tokens (ranged prefill)",
+                     prefill_chunk_tokens is not None),
+                    ("fd_config", fd_config is not None),
+                    ("a contiguous cache (no page_size)", not page_size),
+                    ("a mesh wider than one device", n * n_o != 1),
+                ) if on
+            ]
+            if refused:
+                raise NotImplementedError(
+                    f"the latent cache kind (LatentPagedCacheSpec) does not "
+                    f"support: {', '.join(refused)}")
         if prefix_cache is not None:
             prefix_cache.validate()
             if not page_size:
@@ -889,7 +999,7 @@ class ContinuousBatcher:
         # charge model bills (ISSUE 18)
         self.prefill_work_total = 0
         self.spec = (
-            PagedKVCacheSpec(
+            PAGED_CACHE_KINDS[cfg.cache_kind](
                 s_max, page_size, static_table=True,
                 extra_pages=1 if prefix_cache is not None else 0,
             )
@@ -913,16 +1023,16 @@ class ContinuousBatcher:
         # instance)
         from triton_dist_tpu.ops.common import jit_shard_map
 
-        self._step = jit_shard_map(
+        self._step = self._keep_stats(jit_shard_map(
             step, mesh,
             (
                 specs_for(cfg, params), self.spec.specs(cfg), P(None),
                 P(None),
             ),
-            (P(None, None), self.spec.specs(cfg)),
+            (P(None, None), self.spec.specs(cfg)) + self._stats_specs(),
             key=("batcher_step", cfg, self.spec, fd_config, str(interpret)),
             donate_argnums=(1,),
-        )
+        ))
         b = cfg.batch
         self.pos = np.zeros(b, np.int32)        # next write position per slot
         self.tok = np.zeros(b, np.int32)        # next input token per slot
@@ -945,6 +1055,114 @@ class ContinuousBatcher:
                 pps_local=(s_max // n) // page_size, n_pes=n,
             )
             self._px_dirty = True   # park every row on scratch before step 1
+
+    def _stats_specs(self) -> tuple:
+        """Out-spec of the counters a program returns after its usual
+        outputs (``cfg.pass_counters``); nothing where it declares none."""
+        return (P(None),) if self._counters else ()
+
+    def _keep_stats(self, prog):
+        """A program that declares counters returns them last: keep them
+        aside (``_pass_stats``, still on the device) so that every caller
+        sees the usual pair. No counters: the program itself."""
+        if not self._counters:
+            return prog
+
+        def call(*args):
+            *out, self._pass_stats = prog(*args)
+            return out
+
+        return call
+
+    @property
+    def cache(self):
+        return self._cache
+
+    @cache.setter
+    def cache(self, tree) -> None:
+        # a step sent ahead is good only for the cache it was given
+        self._cache, self._epoch = tree, self._epoch + 1
+
+    def _pull_next(self, sp, logits, tok_d=None, pos_d=None) -> np.ndarray:
+        """The round's one pull: each slot's best token; the step's
+        counters, where it declares any, ride the same transfer (one int32
+        vector) and land on the round's span. Given the round's inputs as
+        they are on the device, and where :meth:`_ahead_mask` allows it,
+        the next round's step goes out BEFORE this blocks."""
+        if not self._counters:
+            packed = jnp.argmax(logits, axis=-1)
+        else:
+            packed = _argmax_with(logits, self._pass_stats)
+        live = None if tok_d is None else self._ahead_mask()
+        if live is not None:
+            packed.copy_to_host_async()   # queued before the step ahead
+            self._send_ahead(packed, tok_d, pos_d, live)
+        packed = np.asarray(packed, np.int32)
+        if live is not None:
+            # what the host will hold after this round if it went as the
+            # mask foresaw: the next round checks before it believes
+            self._ahead.tok = np.where(live, packed[: len(live)], self.tok)
+            self._ahead.pos = self.pos + live
+            sp.set("ahead", 1)
+        if self._counters:
+            self._set_counters(sp, packed[len(self.tok):])
+        return packed[: len(self.tok)]
+
+    def _ahead_mask(self) -> np.ndarray | None:
+        """The live slots, if the next step may go out before this round's
+        tokens are seen: every live slot takes its own best token and one
+        more after it (greedy, past its prompt, no stop token, not at its
+        last token), and nothing else reads the logits or moves pages."""
+        from triton_dist_tpu.resilience import integrity as _integrity
+
+        if (not self.lookahead or self._px is not None or self._chunk
+                or _integrity.output_checks_enabled()):
+            return None
+        live = np.zeros(len(self.tok), bool)
+        for i, req in enumerate(self.slot_req):
+            if req is None:
+                continue
+            if (req.temperature > 0.0 or req.eos_id is not None
+                    or self.slot_fed[i] < len(req.prompt)
+                    or len(self.slot_out[i]) + 2 > req.max_new_tokens):
+                return None
+            live[i] = True
+        return live if live.any() else None
+
+    def _send_ahead(self, packed, tok_d, pos_d, live) -> None:
+        tok_d, pos_d = _advance_on(self.mesh)(packed, tok_d, pos_d, live)
+        logits, self.cache = self._step(self.params, self.cache, tok_d, pos_d)
+        self._ahead = _Ahead(tok_d, pos_d, logits, self._pass_stats,
+                             self._epoch)
+
+    def _round_inputs(self):
+        """``(tok, pos, logits)`` of the round about to run: its inputs on
+        the device and, where the round before sent this step ahead and
+        neither a slot nor the cache nor the weights moved since, the
+        logits it already has (else ``None``: the step is still to run; a
+        step sent in vain wrote only rows that this one writes again)."""
+        a, self._ahead = self._ahead, None
+        if a is not None:
+            if (a.epoch == self._epoch and np.array_equal(a.tok, self.tok)
+                    and np.array_equal(a.pos, self.pos)):
+                self.rounds_ahead += 1
+                self._pass_stats = a.stats
+                return a.tok_d, a.pos_d, a.logits
+            self.ahead_discarded += 1
+        if self.lookahead:
+            # placed as the advance program places its outputs, so that the
+            # step and the advance each see ONE type of input
+            rep = NamedSharding(self.mesh, P(None))
+            return (jax.device_put(self.tok, rep),
+                    jax.device_put(self.pos, rep), None)
+        return jnp.asarray(self.tok), jnp.asarray(self.pos), None
+
+    def _pull_last(self, sp, last, i: int) -> np.ndarray:
+        """An admission's pull: slot ``i``'s logit row (and the pass's
+        counters, where it declares any, onto the admission's span)."""
+        if self._counters:
+            self._set_counters(sp, np.asarray(self._pass_stats))
+        return np.asarray(last[i], np.float32)
 
     @property
     def params(self) -> dict:
@@ -982,7 +1200,9 @@ class ContinuousBatcher:
                     relaid, nbytes = relaid + 1, nbytes + w.nbytes
             sp.set("relaid", relaid)
             sp.set("bytes", nbytes)
-        self._params = tree
+            for name, value in cfg.param_bytes(tree).items():
+                sp.set(name, value)
+        self._params, self._epoch = tree, self._epoch + 1
 
     def validate_request(self, req: Request) -> None:
         """Admissibility checks (shared with the serving engine, which
@@ -1023,16 +1243,16 @@ class ContinuousBatcher:
 
         from triton_dist_tpu.ops.common import jit_shard_map
 
-        prog = jit_shard_map(
+        prog = self._keep_stats(jit_shard_map(
             fn, mesh,
             (
                 specs_for(cfg, self.params), spec.specs(cfg), P(None, None),
                 P(None), P(None),
             ),
-            (spec.specs(cfg), P(None, None)),
+            (spec.specs(cfg), P(None, None)) + self._stats_specs(),
             key=("batcher_prefill", cfg, spec, s_max, bucket),
             donate_argnums=(1,),  # see self._step: the old cache is dead
-        )
+        ))
         self._prefill_progs[bucket] = prog
         return prog
 
@@ -1212,17 +1432,17 @@ class ContinuousBatcher:
         L = len(req.prompt)
         bucket = self._bucket(L)
         with _span("tdt.batcher.admit_prefill", uid=str(req.uid), slot=i,
-                   prompt_len=L, bucket=bucket):
+                   prompt_len=L, bucket=bucket) as sp:
             args = self._prefill_inputs(i, req, bucket)
             with _span("tdt.batcher.admit_prefill.dispatch"):
-                prog = self._prefill_prog(bucket)
-                self.cache, last = prog(self.params, self.cache, *args)
+                self.cache, last = self._prefill_prog(bucket)(
+                    self.params, self.cache, *args)
             self.prefill_tokens_total += L
             self.prefill_work_total += bucket * bucket
             from triton_dist_tpu.resilience import integrity as _integrity
 
             with _span("tdt.batcher.admit_prefill.pull"):
-                last_i = np.asarray(last[i], np.float32)
+                last_i = self._pull_last(sp, last, i)
             if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
                 # poisoned at admission: quarantine before a token exists
                 self._poison_slot(i, "non-finite prefill logits")
@@ -1475,11 +1695,12 @@ class ContinuousBatcher:
             with _span("tdt.batcher.decode_round.upload"):
                 if self._px is not None and self._px_dirty:
                     self._push_px_table()
-                tok_d, pos_d = jnp.asarray(self.tok), jnp.asarray(self.pos)
+                tok_d, pos_d, logits = self._round_inputs()
             with _span("tdt.batcher.decode_round.dispatch"):
-                logits, self.cache = self._step(
-                    self.params, self.cache, tok_d, pos_d,
-                )
+                if logits is None:
+                    logits, self.cache = self._step(
+                        self.params, self.cache, tok_d, pos_d,
+                    )
             # per-request poison detection (ISSUE 8): one [b]-bool
             # transfer when config.integrity arms the output checks — a
             # non-finite logit row quarantines exactly that slot's request
@@ -1495,7 +1716,7 @@ class ContinuousBatcher:
                 # [b, vocab] row transfer (~vocab x 4 bytes/slot over a
                 # possibly-remote link) is paid only when some active
                 # request actually samples
-                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+                nxt = self._pull_next(sp, logits, tok_d, pos_d)
             logits_h = None
             if any(
                 r is not None and r.temperature > 0.0
@@ -1505,6 +1726,10 @@ class ContinuousBatcher:
                 with _span("tdt.batcher.decode_round.sample"):
                     logits_h = np.asarray(logits, np.float32)
             self._take_round(sp, nxt, logits_h, row_ok)
+
+    def _set_counters(self, sp, values) -> None:
+        for name, value in zip(self._counters, values):
+            sp.set(name, int(value))
 
     def _take_round(self, sp, nxt, logits_h, row_ok) -> None:
         """The host half of a decode round: every live slot takes its
@@ -1582,6 +1807,28 @@ class ContinuousBatcher:
         return self.drain_finished()
 
 
+@functools.lru_cache(maxsize=None)
+def _advance_on(mesh: Mesh):
+    """A round's inputs from the round before, on the device: a live slot
+    takes its best token and the next position, an idle one stays.
+    Shardings pinned (replicated over ``mesh``): the program's own outputs
+    and a fresh upload are one signature, so it compiles once."""
+    rep = NamedSharding(mesh, P(None))
+
+    def advance(packed, tok, pos, live):
+        return (jnp.where(live, packed[: tok.shape[0]], tok),
+                pos + live.astype(pos.dtype))
+
+    return jax.jit(advance, in_shardings=(rep,) * 4, out_shardings=(rep, rep))
+
+
+@jax.jit
+def _argmax_with(logits, stats):
+    """``[argmax of each row | stats]`` as one int32 vector."""
+    return jnp.concatenate(
+        [jnp.argmax(logits, axis=-1).astype(jnp.int32), stats])
+
+
 def _prompt_shard(prompt, b, length, cfg):
     """This PE's contiguous slice of the b-major flattened prompt — the
     model's token sharding (shared by generate's prefill and the
@@ -1632,6 +1879,12 @@ def prefill_cache(
         EPMoETransformer, TPMoETransformer, TPTransformer,
     )
 
+    if cfg.own_passes:
+        # a layer-plan family: (cache, last, its pass_counters), the head
+        # on the picked rows only
+        return cfg.prefill_cache(
+            params, cache, prompt_loc, spec, s_max,
+            slot_mask=slot_mask, pick=pick, interpret=cfg.interpret)
     paged = isinstance(spec, PagedKVCacheSpec)
     if paged and not spec.static_table:
         raise ValueError(

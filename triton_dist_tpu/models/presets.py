@@ -2,15 +2,22 @@
 ready-to-run configs (≙ the perf-test suite's shape list,
 reference ``python/triton_dist/test/nvidia/test_ag_gemm.py:149-156``:
 M=8192 with N/K drawn from LLaMA-7B / 3.1-8B / 3.1-70B / 3.1-405B,
-Mistral-7B, Qwen2-72B; the MoE tests use Mixtral-8x7B shapes).
+Mistral-7B, Qwen2-72B; the MoE tests use 8 experts, top-2, at 4096 x
+14336).
 
-All numbers are the public architecture shapes of the open-weight models.
+The dense numbers are the public architecture shapes of the open-weight
+models. ``moe-gelu-8x`` is NOT a published model: its experts are the
+one-projection gelu stand-in of ``MoETransformerConfig`` at the dense
+``ffn`` width (8 experts, top-2, softmax routing), kept for the fused
+AG-GroupGEMM / MoE-Reduce-RS pipelines and their tests. A published
+gated-expert model (SwiGLU experts at their own width, shared expert,
+sigmoid routing, latent attention) is ``models/mla_moe.MLAMoEConfig``.
 Presets carry GLOBAL dimensions; sharding is derived by ``param_specs`` /
 ``moe_param_specs`` from the mesh, so the same preset runs at any TP
 degree that divides its head/ffn counts (``validate_tp`` checks).
 
     cfg = presets.preset("llama-3.1-8b", batch=1, seq=8192)
-    cfg = presets.preset("mixtral-8x7b", tp_check=8)
+    cfg = presets.preset("moe-gelu-8x", tp_check=8)
 """
 
 from __future__ import annotations
@@ -36,7 +43,7 @@ _DENSE = {
     "qwen2-72b": (8192, 29568, 64, 8, 128, 152064),
 }
 _MOE = {
-    "mixtral-8x7b": (4096, 14336, 32, 8, 128, 32000, 8, 2),
+    "moe-gelu-8x": (4096, 14336, 32, 8, 128, 32000, 8, 2),
 }
 
 PRESETS = tuple(sorted((*_DENSE, *_MOE)))
@@ -49,8 +56,9 @@ def validate_tp(cfg: TransformerConfig, tp: int) -> None:
         raise ValueError(
             f"tp={tp} does not divide n_kv_heads={cfg.n_kv_heads}"
         )
-    # dense and expert MLPs share `ffn` (MoETransformerConfig adds expert
-    # COUNT, not a distinct width), so one check covers both
+    # the gelu stand-in's experts share the dense `ffn` (MoETransformerConfig
+    # adds expert COUNT, not a distinct width: MLAMoEConfig.expert_ffn is
+    # the gated expert's own), so one check covers both
     if cfg.ffn % tp:
         raise ValueError(f"tp={tp} does not divide ffn={cfg.ffn}")
 
@@ -78,7 +86,7 @@ def preset(
     one; ``ep_outer="dcn"`` further selects the hierarchical two-phase
     dispatch over an (outer, inner) mesh (≙ the reference's multi-node
     EPAll2AllLayer). A name suffix spells the same thing for CLI
-    callers: ``"mixtral-8x7b:ep"`` / ``"mixtral-8x7b:ep-hier"``."""
+    callers: ``"moe-gelu-8x:ep"`` / ``"moe-gelu-8x:ep-hier"``."""
     if name.endswith(":ep-hier"):
         name, ep, ep_outer = name[: -len(":ep-hier")], True, ep_outer or "dcn"
     elif name.endswith(":ep"):

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +62,23 @@ class TransformerConfig:
     ag_config: AGGemmConfig | None = None
     rs_config: GemmRSConfig | None = None
     interpret: Any = None
+
+    # What a family answers for itself. These are the answers of the
+    # one-kind families this module and models/decode.py serve; a family
+    # that walks a layer plan of its own (models/mla_moe.py) sets
+    # ``own_passes`` and brings ``param_specs()``, ``decode_step(...)`` and
+    # ``prefill_cache(...)`` as methods, which the shared code calls.
+    own_passes: ClassVar[bool] = False
+    # which kind of paged cache its passes read and write
+    # (models/decode.py ``PAGED_CACHE_KINDS``)
+    cache_kind: ClassVar[str] = "kv"
+    # names of the int32 counters a pass returns after its usual outputs
+    pass_counters: ClassVar[tuple[str, ...]] = ()
+
+    def param_bytes(self, params: dict) -> dict:
+        """Byte counts of ``params`` worth a counter on
+        ``tdt.batcher.take_params``, by name."""
+        return {}
 
     @property
     def q_dim(self) -> int:
@@ -575,6 +592,8 @@ def specs_for(cfg: TransformerConfig, params: dict | None = None) -> dict:
     actual `params` when they might be serving-quantized
     (:func:`quantize_moe_serving_params` adds scale entries the spec tree
     must mirror)."""
+    if cfg.own_passes:
+        return cfg.param_specs()
     quantized = params is not None and params["layers"] and (
         "w_up_scale" in params["layers"][0]
     )

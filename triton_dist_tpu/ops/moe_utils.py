@@ -12,7 +12,7 @@ device-side necessity, not a design feature; the C++ host-side equivalent
 for native tooling is part of the csrc/ build — see csrc/ when present.)
 
 All shapes are static: the padded row count is the worst case
-``T + E*(block_m-1)`` rounded up, with sentinel rows marked by token id
+``T + min(E, T)*(block_m-1)`` rounded up, with sentinel rows marked by token id
 ``T`` (gathers clamp, epilogues mask).
 """
 
@@ -27,16 +27,38 @@ from triton_dist_tpu.utils import round_up
 
 
 def select_experts(
-    logits: jax.Array, topk: int
+    logits: jax.Array,
+    topk: int,
+    *,
+    scoring: str = "softmax",
+    bias: jax.Array | None = None,
+    scale: float = 1.0,
 ) -> tuple[jax.Array, jax.Array]:
-    """Softmax + top-k routing (≙ ``select_experts``, moe_reduce_rs.py:180).
+    """Top-k routing (≙ ``select_experts``, moe_reduce_rs.py:180).
 
-    logits: ``[tokens, E]``. Returns ``(weights [tokens, topk] — softmax
-    scores renormalized over the chosen experts, ids [tokens, topk] int32)``.
+    logits: ``[tokens, E]``. Returns ``(weights [tokens, topk], ids
+    [tokens, topk] int32)``. ``scoring="softmax"`` (default): softmax
+    scores, renormalized over the chosen experts. ``scoring="sigmoid"``:
+    each expert scored on its own. ``bias [E]`` enters the CHOICE only
+    (top-k of ``score + bias``, the aux-loss-free balancing term); the
+    weights are the unbiased scores of the chosen experts, normalized over
+    them, times ``scale``.
     """
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, ids = jax.lax.top_k(probs, topk)
+    x = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(x, axis=-1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(x)
+    else:
+        raise ValueError(f"unknown scoring {scoring!r} (softmax, sigmoid)")
+    if bias is None:
+        weights, ids = jax.lax.top_k(scores, topk)
+    else:
+        _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), topk)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, ids.astype(jnp.int32)
 
 
@@ -86,9 +108,15 @@ def moe_align_block_size(
     are IDENTICAL to the legacy form: ragged changes what is computed, not
     where rows live, which is what lets every downstream consumer (gather,
     scatter, backward, the rank-major overlap layout) work unchanged.
+
+    The padded rows are sized for the experts that CAN be hit: ``T``
+    assignments touch at most ``min(E, T)`` experts, each of which pads by
+    at most ``block_m - 1`` rows. With more experts than assignments
+    (decode: 128 assignments over 256 experts) that halves the blocks the
+    grouped GEMM walks; for ``E <= T`` it is ``T + E*(block_m-1)``.
     """
     t = topk_ids.shape[0]
-    t_pad = round_up(t + n_experts * (block_m - 1), block_m)
+    t_pad = round_up(t + min(n_experts, t) * (block_m - 1), block_m)
     counts = jnp.bincount(topk_ids, length=n_experts)
     padded_counts = ((counts + block_m - 1) // block_m) * block_m
     seg_starts = jnp.concatenate(

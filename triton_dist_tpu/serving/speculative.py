@@ -175,6 +175,15 @@ class SpeculativeBatcher(ContinuousBatcher):
 
     def __init__(self, cfg, params, mesh, *, s_max, spec_decode, **kw):
         px_cfg = kw.get("prefix_cache")
+        if cfg.cache_kind == "latent":
+            raise NotImplementedError(
+                "speculative decoding is not built for the latent cache "
+                "kind (LatentPagedCacheSpec): its verify step reads k/v pools")
+        if kw.get("lookahead"):
+            raise NotImplementedError(
+                "lookahead sends the plain step ahead of its round; a "
+                "draft+verify round decides its inputs from the tokens it "
+                "pulls: pass one or the other")
         super().__init__(cfg, params, mesh, s_max=s_max, **kw)
         sd = spec_decode.validate()
         self.spec_decode = sd
