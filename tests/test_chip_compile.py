@@ -14,6 +14,7 @@ fixtures or tests. All cases live in this one file for the same reason.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -264,3 +265,59 @@ def test_paged_flash_decode_distributed_compiles_on_four(mesh4):
     text = _compiled_text(fn, q, pool, pool, lens, table)
     assert text.count("tpu_custom_call") >= 2
     assert "all-gather" not in text
+
+
+@pytest.mark.parametrize("rows", [0, 16], ids=["step", "verify_chunk"])
+def test_kv_pool_is_written_and_read_in_place(topo, rows):
+    """One layer of the paged k/v kind at serving widths, cache donated:
+    the chip's compiler keeps the stacked pool in the layout it arrives
+    in (no ``copy`` of the pool's shape, no pool-sized temporary) and
+    aliases it in and out. With a scatter window of ``[h_kv, d]`` it
+    re-lays the whole pool around every scatter, which is what
+    ``_write_rows`` indexes the head for. ``rows`` > 0: the verify /
+    ranged-prefill twin."""
+    from triton_dist_tpu.models.decode import PagedKVCacheSpec
+    from triton_dist_tpu.models.tp_transformer import TransformerConfig
+
+    n_layers = 4   # 4 x 33.5 MB a tensor: more than fast memory can hide
+    cfg = TransformerConfig(
+        vocab=VOCAB, hidden=HIDDEN, ffn=FFN, n_layers=n_layers, n_q_heads=N_Q,
+        n_kv_heads=N_KV, head_dim=HEAD, batch=SLOTS, dtype=jnp.bfloat16)
+    spec = PagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    lead = (SLOTS, rows) if rows else (SLOTS,)
+
+    def layer(cache, k_new, v_new, q, pos):
+        attend = (
+            spec.update_multi_and_attend if rows else spec.update_and_attend)
+        return attend(
+            cfg, cache, 1, k_new, v_new, q, pos, jax.lax.axis_index("tp"), 1,
+            None, False)
+
+    cs = spec.specs(cfg)
+    fn = jax.jit(
+        jax.shard_map(
+            layer, mesh=mesh, in_specs=(cs, P(), P(), P(), P()),
+            out_specs=(P(), cs), check_vma=False),
+        donate_argnums=(0,))
+    rep = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(lambda: spec.init(cfg, 1, 1))
+    cache = jax.tree.map(
+        lambda x, s: _struct(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, cs)
+    compiled = fn.lower(
+        cache,
+        _struct(lead + (N_KV, HEAD), jnp.bfloat16, rep),
+        _struct(lead + (N_KV, HEAD), jnp.bfloat16, rep),
+        _struct(lead + (N_Q, HEAD), jnp.bfloat16, rep),
+        _struct((SLOTS,), jnp.int32, rep),
+    ).compile()
+    text = compiled.as_text()
+    pool = "bf16[%d,%d,%d,%d,%d]" % shapes["k"].shape
+    pool_bytes = 2 * np.prod(shapes["k"].shape)
+    assert "tpu_custom_call" in text
+    assert not re.findall(re.escape(pool) + r"\S* copy\(", text)
+    assert pool + "{4,3,2,1,0" in text and pool + "{4,2,3,1,0" not in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // n_layers

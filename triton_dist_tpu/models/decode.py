@@ -101,25 +101,27 @@ def _local_lens(pos_b, me, s_shard):
     return jnp.clip(pos_b + 1 - me * s_shard, 0, s_shard).astype(jnp.int32)
 
 
-def _mask_store_and_lens(
-    cfg, cache, li, upd_k, upd_v, pos_b, me, s_shard, gate_batch=True
-):
-    """Owner-gated cache write + per-PE valid lengths. ``pos_b`` is
-    per-sequence ``[b]`` (ragged decode; the lockstep path broadcasts a
-    scalar). ``gate_batch=True`` gates ownership per sequence along the
-    leading batch dim (the CONTIGUOUS layout); the paged pool is
-    page-leading, gates its scatter INDICES instead (non-owner rows go
-    out of range and drop), and passes ``gate_batch=False`` with
-    fully-gated updates."""
-    if gate_batch:
-        owner_b = pos_b // s_shard                   # [b]
-        sel = (me == owner_b).reshape((-1,) + (1,) * (upd_k.ndim - 1))
-        upd_k = jnp.where(sel, upd_k, cache["k"][li])
-        upd_v = jnp.where(sel, upd_v, cache["v"][li])
-    cache = dict(
-        cache, k=cache["k"].at[li].set(upd_k), v=cache["v"].at[li].set(upd_v)
-    )
-    return upd_k, upd_v, cache, _local_lens(pos_b, me, s_shard)
+def _write_rows(cache, li, lead, slot, rows):
+    """Scatter ``rows [..., h_kv, d]`` into layer ``li`` of a stacked
+    cache at ``(lead[...], :, slot[...])``, in ONE op and in place: the
+    page pool ``[n_layers, n_pool, h_kv, page, d]`` (``lead`` = page ids)
+    or the contiguous ``[n_layers, b, h_kv, s, d]`` (``lead`` = the
+    sequence). An index out of range drops its row: how a non-owner PE is
+    gated (page ``n_pool``, offset ``s_shard``). Every leading dimension
+    is indexed, the head too, so that the scatter's window is one ``[d]``
+    row: with a window of ``[h_kv, d]`` XLA re-lays the WHOLE cache,
+    window dimensions minor-most, before every scatter and back after."""
+    heads = jnp.arange(cache.shape[2])
+    return cache.at[li, lead[..., None], heads, slot[..., None]].set(
+        rows.astype(cache.dtype), mode="drop")
+
+
+def _pool_pages(pool):
+    """The stacked page pool ``[n_layers, n_pool, ...]`` as ONE run of
+    pages ``[n_layers * n_pool, ...]``: only the leading dimensions merge
+    (the tiled trailing two are untouched), so this is a view, not a
+    copy, and layer ``li``'s page ``p`` is page ``li * n_pool + p``."""
+    return pool.reshape((-1,) + pool.shape[2:])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,19 +163,19 @@ class KVCacheSpec:
         sequence shard, then SP flash-decode partials merge by
         log-sum-exp. ``pos_b [b]`` may be ragged (continuous batching)."""
         s_shard = _shard_of(self.s_max, n)
-        off_b = pos_b % s_shard                          # [b]
+        # ownership gates the scatter INDICES: a non-owner's offset goes
+        # out of range and its row drops (the paged pool's discipline)
+        own_b = me == pos_b // s_shard                   # [b]
+        safe_off = jnp.where(own_b, pos_b % s_shard, s_shard)
         bidx = jnp.arange(cfg.batch)
-        upd_k = cache["k"][li].at[bidx, :, off_b, :].set(
-            k_new.astype(cache["k"].dtype)
-        )
-        upd_v = cache["v"][li].at[bidx, :, off_b, :].set(
-            v_new.astype(cache["v"].dtype)
-        )
-        k_sh, v_sh, cache, local_lens = _mask_store_and_lens(
-            cfg, cache, li, upd_k, upd_v, pos_b, me, s_shard
+        cache = dict(
+            cache,
+            k=_write_rows(cache["k"], li, bidx, safe_off, k_new),
+            v=_write_rows(cache["v"], li, bidx, safe_off, v_new),
         )
         attn = flash_decode_distributed(
-            q.astype(k_sh.dtype), k_sh, v_sh, local_lens,
+            q.astype(cache["k"].dtype), cache["k"][li], cache["v"][li],
+            _local_lens(pos_b, me, s_shard),
             axis=cfg.axis, config=fd_config, interpret=interpret,
         )
         return attn, cache
@@ -193,7 +195,6 @@ class KVCacheSpec:
 
         S = k_new.shape[1]
         s_shard = _shard_of(self.s_max, n)
-        kc, vc = cache["k"][li], cache["v"][li]
         # ONE scatter for all (sequence, chunk-position) pairs: ownership
         # gates the INDICES — non-owner entries go out of range and drop
         # (the paged pool's discipline) — so the append costs one pass,
@@ -204,17 +205,15 @@ class KVCacheSpec:
         bmat = jnp.broadcast_to(
             jnp.arange(cfg.batch)[:, None], safe_off.shape
         )
-        kc = kc.at[bmat, :, safe_off, :].set(
-            k_new.astype(kc.dtype), mode="drop"
+        cache = dict(
+            cache,
+            k=_write_rows(cache["k"], li, bmat, safe_off, k_new),
+            v=_write_rows(cache["v"], li, bmat, safe_off, v_new),
         )
-        vc = vc.at[bmat, :, safe_off, :].set(
-            v_new.astype(vc.dtype), mode="drop"
-        )
-        cache = dict(cache, k=cache["k"].at[li].set(kc), v=cache["v"].at[li].set(vc))
         # row i attends global positions < pos0 + i + 1: the ranged entry
         # derives the per-(sequence, chunk-row) local prefix from pos0
         attn = flash_ranged_prefill_distributed(
-            q.astype(kc.dtype), kc, vc, pos0,
+            q.astype(cache["k"].dtype), cache["k"][li], cache["v"][li], pos0,
             axis=cfg.axis, config=fd_config, interpret=interpret,
         )
         return attn, cache
@@ -227,7 +226,13 @@ class PagedKVCacheSpec:
     flash_decode.py:136,203 — vLLM-style). Pages are allocated at RUNTIME
     from a per-PE counter the first time a position lands in a new logical
     page, and the block-table indirection steers the kernel's page fetches
-    via scalar prefetch (ops/flash_decode.paged_flash_decode)."""
+    via scalar prefetch (ops/flash_decode.paged_flash_decode).
+
+    The pool stays where it lies (docs/serving.md "The pool's
+    discipline"): one stacked array a tensor, written by one index-gated
+    scatter a layer (``_write_rows``) and read by the kernels as the view
+    ``_pool_pages`` with the table shifted to the layer's pages; no layer
+    is sliced out or put back."""
 
     s_max: int
     page_size: int
@@ -353,19 +358,19 @@ class PagedKVCacheSpec:
         own_b = me == pos_b // s_shard                   # [b]
         n_pool = cache["k"].shape[1]
         safe_ids = jnp.where(own_b, page_ids, n_pool)    # OOB → dropped
-        upd_k = cache["k"][li].at[safe_ids, :, slot_b].set(
-            k_new.astype(cache["k"].dtype), mode="drop"
+        cache = dict(
+            cache,
+            k=_write_rows(cache["k"], li, safe_ids, slot_b, k_new),
+            v=_write_rows(cache["v"], li, safe_ids, slot_b, v_new),
         )
-        upd_v = cache["v"][li].at[safe_ids, :, slot_b].set(
-            v_new.astype(cache["v"].dtype), mode="drop"
-        )
-        k_sh, v_sh, cache, local_lens = _mask_store_and_lens(
-            cfg, cache, li, upd_k, upd_v, pos_b, me, s_shard,
-            gate_batch=False,
-        )
+        # the kernel reads its pages out of the WHOLE pool: the table is
+        # shifted to this layer's run of pages
         attn = paged_flash_decode_distributed(
-            q.astype(k_sh.dtype), k_sh, v_sh, local_lens,
-            cache["block_table"][0], axis=cfg.axis, interpret=interpret,
+            q.astype(cache["k"].dtype),
+            _pool_pages(cache["k"]), _pool_pages(cache["v"]),
+            _local_lens(pos_b, me, s_shard),
+            cache["block_table"][0] + li * n_pool,
+            axis=cfg.axis, interpret=interpret,
         )
         return attn, cache
 
@@ -401,18 +406,15 @@ class PagedKVCacheSpec:
         n_pool = cache["k"].shape[1]
         safe_ids = jnp.where(own, page_ids, n_pool)        # OOB → dropped
         slot = off_mat % self.page_size
-        kc = cache["k"][li].at[safe_ids, :, slot].set(
-            k_new.astype(cache["k"].dtype), mode="drop"
-        )
-        vc = cache["v"][li].at[safe_ids, :, slot].set(
-            v_new.astype(cache["v"].dtype), mode="drop"
-        )
         cache = dict(
-            cache, k=cache["k"].at[li].set(kc), v=cache["v"].at[li].set(vc)
+            cache,
+            k=_write_rows(cache["k"], li, safe_ids, slot, k_new),
+            v=_write_rows(cache["v"], li, safe_ids, slot, v_new),
         )
         attn = paged_flash_ranged_prefill_distributed(
-            q.astype(kc.dtype), kc, vc, pos0, bt,
-            axis=cfg.axis, interpret=interpret,
+            q.astype(cache["k"].dtype),
+            _pool_pages(cache["k"]), _pool_pages(cache["v"]),
+            pos0, bt + li * n_pool, axis=cfg.axis, interpret=interpret,
         )
         return attn, cache
 
