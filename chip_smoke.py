@@ -56,9 +56,9 @@ FULL = dict(
     s_max=2048, page=128, slots=8, n_req=8, prompt_lo=128, prompt_hi=512,
     new_tokens=32, layers={1: 16, 4: 32},
 )
-TOY = dict(
-    s_max=64, page=8, slots=4, n_req=4, prompt_lo=8, prompt_hi=16,
-    new_tokens=4, layers={1: 2, 4: 2},
+TOY = dict(   # the least that walks every phase: the answers cross one round
+    s_max=32, page=8, slots=2, n_req=2, prompt_lo=6, prompt_hi=8,
+    new_tokens=2, layers={1: 1, 4: 1},
 )
 
 

@@ -67,7 +67,7 @@
 # scripts/chaos_soak.py / `pytest -m soak` (soak implies slow).
 #
 # Since ISSUE 13 the matrix also covers the DISAGGREGATED-SERVING cells
-# (tests/test_disagg.py): a corrupted/dropped KV chunk mid-handoff must
+# (tests/test_disagg.py, tests/test_disagg_soak.py): a corrupted/dropped KV chunk mid-handoff must
 # walk the guard ladder (bounded re-send → whole-sequence re-stream →
 # decode-local cold re-prefill) with the culprit PE struck and the
 # request finishing byte-identically to unified cold prefill; a
@@ -79,7 +79,7 @@
 # kv_stream kernel family (ops/kv_stream.py) at worlds {2, 4, 8}.
 #
 # Since ISSUE 12 the matrix also covers the PREFIX-CACHE cells
-# (tests/test_prefix_cache.py): a poisoned SHARED prefix page must
+# (tests/test_prefix_cache_chaos.py): a poisoned SHARED prefix page must
 # strike every reader of the chain (evicted for a cold re-prefill,
 # byte-identical regeneration, no request lost), and the quick
 # shared-prefix soak campaign composes the strike with the straggler /
@@ -112,7 +112,7 @@
 # replays bit-identically (the full set rides scripts/chaos_soak.py).
 #
 # Since ISSUE 17 the matrix also covers the RECOVERY-PLANE cells
-# (tests/test_recovery.py): the elastic-ON fleet with per-replica
+# (tests/test_recovery.py, tests/test_recovery_soak.py): the elastic-ON fleet with per-replica
 # ElasticScope namespaces must keep strikes inside their replica
 # (pe{N}@r{i} health families only), regrow a quarantined decode pool
 # by probation mid-serve, un-collapse a collapsed prefill pool after a
@@ -123,7 +123,7 @@
 # bit-identically.
 #
 # Since ISSUE 18 the matrix also covers the RANGED-PREFILL cells
-# (tests/test_ranged_engine.py): the pipelined disagg handoff — decode
+# (tests/test_pipelined_admission.py, tests/test_disagg_soak.py): the pipelined disagg handoff — decode
 # admission at FIRST-page-landed while the tail streams — must keep the
 # transfer-span decomposition exact with tokens byte-identical, and a
 # corrupt KV chunk injected mid-pipelined-handoff must walk the guard
@@ -175,9 +175,11 @@ files="tests/test_chaos.py tests/test_elastic.py \
     tests/test_chunked.py tests/test_chunked_a2a.py tests/test_ragged.py \
     tests/test_emitter.py tests/test_serving.py tests/test_integrity.py \
     tests/test_obs.py tests/test_analysis.py tests/test_overload.py \
-    tests/test_prefix_cache.py tests/test_disagg.py tests/test_synth.py \
+    tests/test_prefix_cache_chaos.py tests/test_prefix_cache_soak.py \
+    tests/test_disagg.py tests/test_disagg_soak.py tests/test_synth.py \
     tests/test_flight_recorder.py tests/test_fleet.py \
-    tests/test_recovery.py tests/test_ranged_engine.py \
+    tests/test_recovery.py tests/test_recovery_soak.py \
+    tests/test_pipelined_admission.py \
     tests/test_fp8.py tests/test_spec_serving.py tests/test_spec_soak.py"
 marker="chaos"
 lint_args=""
@@ -185,10 +187,12 @@ if [ "${1:-}" = "--quick" ]; then
     shift
     files="tests/test_integrity.py tests/test_serving.py \
         tests/test_elastic.py tests/test_overload.py \
-        tests/test_prefix_cache.py tests/test_disagg.py \
+        tests/test_prefix_cache_chaos.py tests/test_prefix_cache_soak.py \
+        tests/test_disagg.py tests/test_disagg_soak.py \
         tests/test_synth.py tests/test_flight_recorder.py \
         tests/test_fleet.py tests/test_recovery.py \
-        tests/test_ranged_engine.py tests/test_fp8.py \
+        tests/test_recovery_soak.py \
+        tests/test_pipelined_admission.py tests/test_fp8.py \
         tests/test_spec_serving.py tests/test_spec_soak.py"
     marker="chaos and not slow"
     # keep the quick posture bounded: worlds {2,4} (the full {2,4,8}

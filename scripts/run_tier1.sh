@@ -91,16 +91,6 @@ timeout -k 10 300 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee "$chaos_log"
 chaos_rc=${PIPESTATUS[0]}
 
-perf_rc=0
-if [ "${TDT_PERF_GATE:-0}" = "1" ]; then
-    # opt-in perf stage (ISSUE 3 satellite): ring-op bench ratios vs the
-    # BASELINE.json floors; fails without a chip (see scripts/perf_gate.sh)
-    echo
-    echo "== perf gate (opt-in: TDT_PERF_GATE=1) =="
-    scripts/perf_gate.sh
-    perf_rc=$?
-fi
-
 echo
 echo "== tier-1 summary =="
 printf '  tier-1:      rc=%s  %s passed / %s failed / %s skipped\n' \
@@ -127,7 +117,7 @@ if [ "$t1_rc" -ne 0 ]; then
         fi
     fi
 fi
-if [ "$t1_ok" -ne 0 ] || [ "$chaos_rc" -ne 0 ] || [ "$perf_rc" -ne 0 ] \
+if [ "$t1_ok" -ne 0 ] || [ "$chaos_rc" -ne 0 ] \
     || [ "$diff_rc" -ne 0 ] || [ "$lint_rc" -ne 0 ]; then
     echo "tier-1 gate: FAIL"
     exit 1

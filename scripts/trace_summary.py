@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Summarize an obs chrome trace for chip logs (ISSUE 9 satellite).
 
-Reads the artifact ``bench.py --obs-trace PATH`` / ``obs.export_chrome_trace``
+Reads the artifact ``obs.export_chrome_trace(PATH)``
 writes (a Perfetto-loadable chrome trace whose span events carry op-entry
 ladder rungs and whose instant events on the ``device wait telemetry``
 process carry per-(family, site, kind) spin histograms) and prints two

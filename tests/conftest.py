@@ -10,16 +10,33 @@ need more, and real-TPU runs are unaffected.
 
 Runtime budget: the driver runs tier-1 (`-m 'not slow'`) with six xdist
 workers and `--dist loadfile` inside 1470 s, so the wall time is at least
-the slowest FILE. Keep files under ~5 min of test time each (PR 23 split
-test_ranged_prefill.py and test_spec_serving.py for this; the serving soak
-campaigns are the long poles). The floor is structural, not shape-driven:
-every interpreted pallas_call pays ~44 ms of host machinery (io_callbacks
-plus per-call shared-memory setup across virtual devices), and a
-model-level test runs hundreds of such calls plus a ~35 s trace+compile
-that no persistent cache can hold (callback-bearing executables are not
-cacheable). Model tests therefore use the smallest layer count that still
-covers their property, and serving programs are shared across tests via
-the keyed `jit_shard_map` cache."""
+the slowest FILE, and a run cut by the limit counts only as far as it got.
+What a cell costs is interpreted STEPS: every interpreted pallas_call pays
+~44 ms of host machinery (io_callbacks plus per-call shared-memory setup
+across virtual devices), so a decode step of a two-layer model on the
+4-device mesh is seconds, while a program's trace and compile are about a
+tenth of the test that builds it (callback-bearing executables are not
+cacheable on disk; the keyed `jit_shard_map` cache shares them inside a
+process). The rules (PR 30 brought the suite to them):
+
+- one geometry a file: the tests of a file share one `cfg` / mesh /
+  `s_max` through module-scoped fixtures, so each program is built once,
+  and a reference run that several tests compare against (a token-fed
+  serve, a whole-range pass, a golden arc, a green campaign) is a
+  module-scoped fixture too, run once;
+- the smallest layer count and the shortest answers that still cover the
+  property: two layers where a cache bit has to come out of attention, one
+  where tokens are compared; answers just long enough to cross a decode
+  round (and, on the 4-device mesh, onto the second PE's rows);
+- the smallest campaign that still fires every fault it asserts on
+  (`resilience/soak.py` fails a campaign whose scheduled fault never
+  fired, so a campaign cut too far fails loudly, not vacuously); a replay
+  cell reruns the green cell's spec, not a third campaign; the long sets
+  live under `-m soak`;
+- no file over a fifth of the run's wall time: split it along its tiers
+  (or, where the tier is one parametrised test, a file a cell:
+  ranged_model_tier.py), and keep `_LONG_POLES` in the order of the last
+  run's record."""
 
 import os
 import signal
@@ -64,17 +81,31 @@ def pytest_configure(config):
     )
 
 
-# Heaviest files first (test-seconds of PR 23's run, longest first). Under
-# `--dist loadfile` xdist hands whole files to workers in collection
-# order, i.e. alphabetically: a multi-minute file that sorts late starts
-# late and becomes the wall time (a 430 s soak starting at 630 s made a
-# 615 s-ideal run take 1066 s). Everything not named keeps its order.
+# Heaviest files first: every file over 1/80 of the summed test-seconds
+# (twice as fine as the 1/40 a long pole starts at: a 100 s file that
+# sorts late in the alphabet starts late too) in PR 30's run of the
+# driver's command on its own tree before its last (six workers, 4522 s
+# summed, 871 s wall; the last read 4115 s and 763 s in the same order
+# but for neighbours), longest first. Under `--dist loadfile` xdist hands whole files
+# to workers in collection order, i.e. alphabetically: a multi-minute
+# file that sorts late starts late and becomes the wall time. Everything
+# not named keeps its order. Re-read the order from a run's `--junitxml`
+# when a file grows (tests/test_docs_refs.py holds the names to files
+# that exist).
 _LONG_POLES = (
-    "test_chunked_prefill.py",
-    "test_ranged_batcher.py", "test_ranged_prefill.py",
-    "test_ranged_engine.py", "test_spec_soak.py", "test_prefix_cache.py",
-    "test_disagg.py", "test_ragged.py", "test_overload.py",
-    "test_chip_smoke.py", "test_recovery.py",
+    "test_spec_soak.py", "test_ranged_engine.py", "test_emitter.py",
+    "test_mla_moe.py", "test_disagg.py", "test_ranged_batcher.py",
+    "test_serving.py", "test_prefill_work.py", "test_prefix_cache_soak.py",
+    "test_prefix_cache.py", "test_disagg_soak.py", "test_ranged_prefill.py",
+    "test_chunked_prefill.py", "test_flash_decode.py",
+    "test_ranged_kernel.py", "test_overload.py", "test_moe_pipeline.py",
+    "test_prefix_cache_chaos.py", "test_spec_serving.py",
+    "test_ragged_pipeline.py", "test_ranged_contiguous.py",
+    "test_flight_recorder.py", "test_recovery.py", "test_fp8.py",
+    "test_lookahead.py", "test_gemm_rs.py", "test_chip_smoke.py",
+    "test_ranged_paged.py", "test_ring_attention.py",
+    "test_gate_up_layout.py", "test_moe.py", "test_ag_gemm.py",
+    "test_fleet.py", "test_ragged.py",
 )
 
 

@@ -1,7 +1,14 @@
 """Shared model, prompts and drivers of the ranged-prefill test files
 (test_ranged_prefill / test_ranged_batcher / test_chunked_prefill /
 test_ranged_engine — one file until PR 23 split it so six xdist workers
-balance under ``--dist loadfile``)."""
+balance under ``--dist loadfile``).
+
+What these files cost is interpreted steps, not compiles (a step on the
+4-device mesh is seconds, a program's trace and compile a tenth of its
+test): the model tier keeps two layers, because a one-layer cache holds
+no bit that attention produced; the batcher and serving tiers compare
+TOKENS, which one layer's attention already decides, and serve answers
+just long enough to cross a decode round on the second PE."""
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +43,13 @@ def _model_cfg(**over):
 def model():
     cfg = _model_cfg()
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(scope="module")
+def model1():
+    """The batcher and serving tiers' model: one layer (see above)."""
+    cfg = _model_cfg(n_layers=1)
+    return cfg, init_params(jax.random.PRNGKey(2), cfg)
 
 
 @pytest.fixture(scope="module")
@@ -77,8 +91,19 @@ def _bt_run(model, mesh, reqs, **kw):
     return out, bt
 
 
-def _mk(uid, prompt, **kw):
-    return Request(list(prompt), max_new_tokens=6, uid=uid, **kw)
+def _mk(uid, prompt, new=4, **kw):
+    # 8-token prompts fill the first PE's rows of the 4-device mesh: the
+    # answer's rows land on the second
+    return Request(list(prompt), max_new_tokens=new, uid=uid, **kw)
+
+
+@pytest.fixture(scope="module")
+def tok_fed(model1, mesh4, bt_prompts):
+    """The token-fed contiguous batcher's answers to p1 ("a") and p2 ("c"):
+    the reference of the batcher tier's byte-identity class, served once a
+    file."""
+    p1, p2 = bt_prompts
+    return _bt_run(model1, mesh4, [_mk("a", p1), _mk("c", p2)])[0]
 
 
 def _serve(model, mesh, reqs, serving=None, **kw):

@@ -383,7 +383,7 @@ def test_tp_moe_pipeline_chunked_matches_sequential(mesh4):
 
     n, m_loc, topk, n_exp, h_dim, f_dim = 4, 256, 1, 2, 16, 32
     m_tot = n * m_loc
-    cfg = GroupGemmConfig(block_m=4, block_n=32, block_k=16,
+    cfg = GroupGemmConfig(block_m=32, block_n=32, block_k=16,
                           chunks_per_shard=2)
     kx, ku, kd, kl = jax.random.split(jax.random.PRNGKey(35), 4)
     x = jax.random.normal(kx, (m_tot, h_dim), jnp.float32)

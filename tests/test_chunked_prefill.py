@@ -11,57 +11,46 @@ from jax.sharding import Mesh
 from triton_dist_tpu.models import init_params
 from triton_dist_tpu.models.decode import ContinuousBatcher
 from triton_dist_tpu.models.prefix_cache import PrefixCacheConfig
-from ranged_helpers import BT_SMAX, _bt_run, _mk, _model_cfg, bt_prompts, model
+from ranged_helpers import (
+    BT_SMAX, _bt_run, _mk, _model_cfg, bt_prompts, model1, tok_fed,
+)
 
 
-def test_chunked_composes_with_paged_and_px(mesh4, model, bt_prompts):
+def test_chunked_composes_with_paged_and_px(
+        mesh4, model1, bt_prompts, tok_fed):
     """Chunked admission over the paged cache, and chunked × prefix-cache
     together, stay in the byte-identity class."""
     p1, p2 = bt_prompts
-    c_tok, _ = _bt_run(model, mesh4, [_mk("a", p1), _mk("c", p2)])
     cp_on, _ = _bt_run(
-        model, mesh4, [_mk("a", p1)], prefill=True, prefill_chunk_tokens=3,
+        model1, mesh4, [_mk("a", p1)], prefill=True, prefill_chunk_tokens=3,
         page_size=4,
     )
-    assert cp_on["a"] == c_tok["a"]
+    assert cp_on["a"] == tok_fed["a"]
     reqs = lambda: [_mk("a", p1), _mk("b", p1), _mk("c", p2)]
     o_pxt, _ = _bt_run(
-        model, mesh4, reqs(), page_size=4, prefix_cache=PrefixCacheConfig()
+        model1, mesh4, reqs(), page_size=4, prefix_cache=PrefixCacheConfig()
     )
     cpx_on, _ = _bt_run(
-        model, mesh4, reqs(), page_size=4,
+        model1, mesh4, reqs(), page_size=4,
         prefix_cache=PrefixCacheConfig(), prefill=True,
         prefill_chunk_tokens=2,
     )
     assert cpx_on == o_pxt
 
 
-def test_chunked_armed_untriggered_byte_identity(mesh4, model, bt_prompts):
-    """prefill_chunk_tokens >= every prompt length: armed but never
-    triggered must be byte-identical to the disarmed prefill batcher
-    (including the work counter — no chunk pass ever ran)."""
-    p1, _ = bt_prompts
-    u_on, bt_u = _bt_run(
-        model, mesh4, [_mk("a", p1)], prefill=True, prefill_chunk_tokens=16
-    )
-    u_off, bt_d = _bt_run(model, mesh4, [_mk("a", p1)], prefill=True)
-    assert u_on == u_off
-    assert bt_u.prefill_work_total == bt_d.prefill_work_total
-
-
-def test_chunked_interleaves_decode(mesh4, model, bt_prompts):
+def test_chunked_interleaves_decode(mesh4, model1, bt_prompts, tok_fed):
     """A long prompt chunking at ct=2 while a neighbor slot decodes:
     the neighbor makes progress during the chunk steps (the scheduling
     point of the whole feature) and the long request's tokens still
     equal the token-fed reference."""
-    cfg, params = model
+    cfg, params = model1
     p1, p2 = bt_prompts
-    c_tok, _ = _bt_run(model, mesh4, [_mk("a", p1), _mk("c", p2)])
     bt = ContinuousBatcher(
         cfg, params, mesh4, s_max=BT_SMAX, prefill=True,
         prefill_chunk_tokens=2,
     )
-    bt.submit(_mk("short", p1[:2]))
+    # an answer long enough to still be decoding while "long" chunks
+    bt.submit(_mk("short", p1[:2], new=6))
     bt.step()
     bt.submit(_mk("long", p1))
     neighbor_progress = []
@@ -76,7 +65,7 @@ def test_chunked_interleaves_decode(mesh4, model, bt_prompts):
             neighbor_progress.append(after > before)
     done = dict(bt.drain_finished())
     assert sorted(done) == ["long", "short"]
-    assert done["long"] == c_tok["a"]
+    assert done["long"] == tok_fed["a"]
     assert any(neighbor_progress), "neighbor never decoded during chunking"
 
 
@@ -94,5 +83,3 @@ def test_chunk_tokens_validation():
             cfg, params, mesh, s_max=BT_SMAX, prefill=True,
             prefill_chunk_tokens=0,
         )
-
-

@@ -14,8 +14,8 @@ Tier structure mirrors tests/test_serving.py:
 - **chaos tier** (``pytest.mark.chaos``, rides ``chaos_matrix.sh``):
   corrupt/dropped KV chunks mid-handoff walking the full guard ladder
   with attributed strikes, the prefill-pool shrink-mid-stream arc, the
-  pool-collapse-to-unified arc, and the quick disagg soak campaign with
-  bit-identical seeded replay.
+  pool-collapse-to-unified arc; the quick disagg soak campaign with
+  bit-identical seeded replay is test_disagg_soak.py.
 """
 
 from __future__ import annotations
@@ -668,41 +668,3 @@ def test_prefill_pool_collapse_degrades_to_unified(model):
         uid = eng.submit(Request([1, 2, 3], max_new_tokens=2, uid="post"))
         eng.run_until_idle()
     assert isinstance(eng.results["post"], Finished)
-
-
-@pytest.mark.chaos
-def test_disagg_soak_campaign_quick_and_replay():
-    """The chaos-matrix disagg soak cell: one seeded two-pool campaign
-    (burst traffic × corrupt KV chunks mid-handoff × prefill straggler)
-    passes every invariant and replays bit-identically from its seed."""
-    from triton_dist_tpu.resilience import soak
-
-    spec = soak.SoakSpec.disagg(seed=1)
-    res = soak.run_campaign(spec)
-    assert res.ok, (res.failures, res.error)
-    again = soak.run_campaign(spec)
-    assert again.fingerprint == res.fingerprint
-
-
-@pytest.mark.chaos
-def test_disagg_soak_collapse_campaign():
-    """The scheduled-pool-collapse composition (every third seed): the
-    campaign must actually collapse and still satisfy every invariant."""
-    from triton_dist_tpu.resilience import soak
-
-    spec = soak.SoakSpec.disagg(seed=0)
-    assert spec.collapse_at_step > 0
-    res = soak.run_campaign(spec)
-    assert res.ok, (res.failures, res.error)
-    assert res.snapshot["engine"]["collapsed"]
-
-
-@pytest.mark.soak
-def test_disagg_soak_campaign_set():
-    """The full ISSUE 13 disagg set (5 seeds — what scripts/chaos_soak.py
-    runs); soak marker ⇒ slow, never rides tier-1."""
-    from triton_dist_tpu.resilience import soak
-
-    for seed in range(200, 205):
-        res = soak.run_campaign(soak.SoakSpec.disagg(seed=seed))
-        assert res.ok, (seed, res.failures, res.error)

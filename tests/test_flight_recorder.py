@@ -17,9 +17,8 @@ Tier structure (the test_overload.py convention):
   quick seeded soak campaign under the armed flight recorder — exactly
   one bundle per health-flipping event (no duplicates, no misses), with
   real flips so the invariant is not vacuous;
-- **CLI tier**: scripts/postmortem.py renders bundles deterministically,
-  scripts/trace_summary.py --incidents folds them into its tables, and
-  scripts/bench_trend.py gates per-metric history regressions.
+- **CLI tier**: scripts/postmortem.py renders bundles deterministically
+  and scripts/trace_summary.py --incidents folds them into its tables.
 """
 
 import filecmp
@@ -541,7 +540,7 @@ def test_quick_soak_one_bundle_per_flip():
     if the census and the health flip counters disagree — assert the
     campaign is green AND actually flipped (not vacuous)."""
     result = soak.run_campaign(soak.SoakSpec(
-        seed=1, n_requests=10, max_queue=4, fault_window=20,
+        seed=1, n_requests=6, max_queue=4, fault_window=20,
     ))
     assert result.ok, result.failures
     flips = sum(
@@ -620,41 +619,3 @@ def test_trace_summary_folds_incidents(tmp_path, capsys):
     assert "pe1:quarantined" in out.lower()
 
 
-def test_bench_trend_gates_regressions(tmp_path, capsys):
-    bt = _load_script("bench_trend")
-
-    def bench_file(name, rows):
-        p = tmp_path / name
-        p.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-        return str(p)
-
-    hist = bench_file("BENCH_h1.json.log", [
-        {"metric": "gemm_tflops", "value": 100.0, "unit": "TFLOPS",
-         "vs_baseline": 1.0},
-        {"metric": "decode_us", "value": 200.0, "unit": "us"},
-    ])
-    # within tolerance: higher-better down 1%, lower-better up 2% -> pass
-    fresh_ok = bench_file("fresh_ok.log", [
-        {"metric": "gemm_tflops", "value": 99.0, "unit": "TFLOPS"},
-        {"metric": "decode_us", "value": 204.0, "unit": "us"},
-        {"metric": "brand_new", "value": 1.0, "unit": "x"},
-    ])
-    assert bt.main([fresh_ok, "--history", hist,
-                    "--baseline", str(tmp_path / "missing.json")]) == 0
-    out = capsys.readouterr().out
-    assert "0 regressed" in out and "1 new" in out
-    # beyond tolerance in BOTH directions -> nonzero exit, named rows
-    fresh_bad = bench_file("fresh_bad.log", [
-        {"metric": "gemm_tflops", "value": 90.0, "unit": "TFLOPS"},
-        {"metric": "decode_us", "value": 230.0, "unit": "us"},
-    ])
-    assert bt.main([fresh_bad, "--history", hist]) == 1
-    out = capsys.readouterr().out
-    assert out.count("REGRESSED") == 2
-    # a driver artifact (tail-embedded lines) parses too
-    artifact = tmp_path / "BENCH_r99.json"
-    artifact.write_text(json.dumps({
-        "tail": '{"metric": "gemm_tflops", "value": 101.0, '
-                '"unit": "TFLOPS"}\nnoise\n',
-    }))
-    assert bt.main([str(artifact), "--history", hist]) == 0

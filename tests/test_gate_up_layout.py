@@ -174,8 +174,10 @@ def _old_layout(params: dict, cfg) -> dict:
 
 
 def _serve(batcher) -> dict:
+    """Two prompts of one prefill bucket, answers that cross one decode
+    round: what shows whose weights a batcher serves with."""
     rng = np.random.default_rng(3)
-    for i, (n_prompt, n_new) in enumerate([(3, 4), (5, 3), (2, 5)]):
+    for i, (n_prompt, n_new) in enumerate([(3, 2), (4, 2)]):
         batcher.submit(Request(
             [int(t) for t in rng.integers(0, 32, n_prompt)], n_new, uid=i))
     return dict(batcher.run(max_steps=100))
@@ -199,7 +201,7 @@ def _intakes() -> list:
 @pytest.mark.parametrize("n", [1, 2])
 @pytest.mark.parametrize("ffn", WIDTHS)
 def test_door_relays_the_old_layout_once(ring, ffn, n):
-    cfg = _cfg(ffn)
+    cfg = _cfg(ffn, n_layers=1)     # a leaf is re-laid a layer: one shows it
     mesh = _mesh(n)
     born = init_params(jax.random.PRNGKey(0), cfg)
     old = _old_layout(born, cfg)
@@ -221,6 +223,6 @@ def test_door_relays_the_old_layout_once(ring, ffn, n):
         for p, q in zip(b.params["layers"], born["layers"]):
             np.testing.assert_array_equal(p["w_gate_up"], q["w_gate_up"])
     want = _serve(from_born)
-    assert len(want) == 3
+    assert len(want) == 2
     assert _serve(from_old) == want
     assert _serve(assigned) == want

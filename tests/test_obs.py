@@ -649,34 +649,6 @@ def test_serving_engine_phase_span_stats():
                for v in osnap["serving"].values())
 
 
-def test_bench_serving_info_lines_carry_phase_breakdown():
-    from triton_dist_tpu.serving import bench as sbench
-
-    row = {
-        "rate_rps": 2.0,
-        "n_finished": 1,
-        "snapshot": {
-            "latency_ms": {
-                "ttft": {"p50": 1.0, "p99": 2.0},
-                "e2e": {"p50": 3.0, "p99": 4.0},
-            },
-            "load": {"queue_depth": {"p99": 0.0}},
-            "tokens": {"per_s": 5.0},
-            "slo": None,
-            "span_ms": {
-                "serving:queued": {"count": 1, "p50_ms": 0.5, "p99_ms": 0.6},
-                "serving:decode": {"count": 1, "p50_ms": 7.0, "p99_ms": 8.0},
-                "serving:prefill": {"count": 0, "p50_ms": 0.0,
-                                    "p99_ms": 0.0},
-            },
-        },
-    }
-    names = {n: v for n, v, _ in sbench.info_lines([row])}
-    assert names["serving_queued_p50_ms_lam2"] == 0.5
-    assert names["serving_decode_p99_ms_lam2"] == 8.0
-    assert "serving_prefill_p50_ms_lam2" not in names  # empty phase skipped
-
-
 # ---------------------------------------------------------------------------
 # Kernel tier (Mosaic interpreter): live wait telemetry
 # ---------------------------------------------------------------------------

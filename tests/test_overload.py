@@ -569,15 +569,24 @@ def test_no_lost_request_under_compound_overload(tiny1, mesh1):
 # Chaos tier: the seeded soak (quick cells; the 20-campaign run is soak)
 # ---------------------------------------------------------------------------
 
+# the smallest campaign that still trips both scheduled faults (a campaign
+# that outlives neither fails its own "never fired" invariant): what a
+# campaign costs is its requests, and a third of them decide nothing more
+_QUICK = dict(seed=0, n_requests=8, n_timeouts=1, n_corruptions=1,
+              fault_window=20)
+
+
+@pytest.fixture(scope="module")
+def quick_campaign():
+    return soak.run_campaign(soak.SoakSpec(**_QUICK))
+
+
 @pytest.mark.chaos
-def test_quick_soak_campaign_green():
+def test_quick_soak_campaign_green(quick_campaign):
     """One multi-fault campaign (flash crowd × persistent straggler ×
     payload corruption) through the production engine: every invariant
     holds (no lost request, no deadlock, accounting balanced)."""
-    res = soak.run_campaign(soak.SoakSpec(
-        seed=0, n_requests=12, n_timeouts=1, n_corruptions=1,
-        fault_window=20,
-    ))
+    res = quick_campaign
     assert res.error is None, res.error
     assert res.ok, res.failures
     assert res.rebuilds >= 2, "straggler + corruption arcs both rebuilt"
@@ -585,10 +594,9 @@ def test_quick_soak_campaign_green():
 
 
 @pytest.mark.chaos
-def test_soak_replay_bit_identical():
-    spec = soak.SoakSpec(seed=7, n_requests=12, n_timeouts=1,
-                         n_corruptions=1, fault_window=20)
-    a, b = soak.run_campaign(spec), soak.run_campaign(spec)
+def test_soak_replay_bit_identical(quick_campaign):
+    """The same spec run again: the first run is the green cell's."""
+    a, b = quick_campaign, soak.run_campaign(soak.SoakSpec(**_QUICK))
     assert a.ok and b.ok, (a.failures, b.failures)
     assert a.fingerprint == b.fingerprint
     assert a.terminals == b.terminals

@@ -13,24 +13,24 @@ Tier structure (the test_serving.py convention):
   invariant (every page owned exactly once; every shared page refcounted
   exactly once per reader) asserted after every mutation;
 - **engine tier** (world-1 mesh, real batcher steps): sharing
-  byte-identity, the metrics surface, the multi-PE table (mesh4);
+  byte-identity, the metrics surface; the multi-PE table (mesh4) is in
+  test_prefix_cache_chaos.py;
 - **chaos tier** (``pytest.mark.chaos``, chaos_matrix.sh): the strike
-  fan-out cell and the quick shared-prefix soak campaign.
+  fan-out cell (test_prefix_cache_chaos.py) and the quick shared-prefix
+  soak campaign (test_prefix_cache_soak.py).
 """
 
 import dataclasses
 import json
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
 
 from triton_dist_tpu import config as tdt_config
-from triton_dist_tpu import resilience
 from triton_dist_tpu.models import init_params
-from triton_dist_tpu.models.decode import ContinuousBatcher, Request
+from triton_dist_tpu.models.decode import ContinuousBatcher
 from triton_dist_tpu.models.prefix_cache import (
     PagePrefixCache,
     PrefixCacheConfig,
@@ -39,10 +39,7 @@ from triton_dist_tpu.models.tp_transformer import TransformerConfig
 from triton_dist_tpu.ops.allgather_gemm import AGGemmConfig
 from triton_dist_tpu.ops.gemm_reduce_scatter import GemmRSConfig
 from triton_dist_tpu.resilience import retry
-from triton_dist_tpu.resilience.integrity import IntegrityConfig
 from triton_dist_tpu.serving import (
-    Finished,
-    Poisoned,
     PrefixCacheConfig as ServingPrefixCacheConfig,
     ServingConfig,
     ServingEngine,
@@ -51,7 +48,6 @@ from triton_dist_tpu.serving import (
     shared_prefix_mix,
     trace_fingerprint,
 )
-from triton_dist_tpu.serving import bench as sbench
 
 
 @pytest.fixture(autouse=True)
@@ -77,13 +73,6 @@ def _cfg(**over):
 @pytest.fixture(scope="module")
 def tiny1():
     cfg = _cfg()
-    return cfg, init_params(jax.random.PRNGKey(0), cfg)
-
-
-@pytest.fixture(scope="module")
-def tiny4b():
-    # batch=4 slots so three readers can share one producer's chain
-    cfg = _cfg(batch=4)
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
 
 
@@ -328,29 +317,6 @@ def test_shared_prefix_mix_zipf_and_admissible():
                     prefix_share=0.0).validate()
 
 
-def test_bench_info_lines_carry_px_columns():
-    snap = {
-        "requests": {}, "tokens": {"per_s": 1.0, "goodput_per_s": 1.0},
-        "latency_ms": {k: {"p50": 1.0, "p99": 2.0} for k in
-                       ("ttft", "e2e")},
-        "load": {"queue_depth": {"p99": 0.0}},
-        "slo": None,
-        "prefix_cache": {"hit_rate": 0.9, "prefill_tokens_saved": 123,
-                         "pages_shared": 7},
-    }
-    lines = sbench.info_lines(
-        [{"rate_rps": 4.0, "snapshot": snap, "n_finished": 1}], tag="_px_on"
-    )
-    names = [n for n, _, _ in lines]
-    assert "serving_px_hit_rate_lam4_px_on" in names
-    assert "serving_px_tokens_saved_lam4_px_on" in names
-    assert "serving_px_pages_shared_lam4_px_on" in names
-    for name, value, unit in lines:
-        assert "vs_baseline" not in json.dumps(
-            {"metric": name, "value": value, "unit": unit}
-        )
-
-
 # ---------------------------------------------------------------------------
 # Engine tier: byte-identity + metrics surface (world-1 mesh)
 # ---------------------------------------------------------------------------
@@ -440,41 +406,6 @@ def test_ttft_collapses_under_sharing(tiny1, mesh1):
     assert warm_p50 * 2 <= cold_p50, (cold_p50, warm_p50)
 
 
-def test_multi_pe_chain_spans_pes(tiny4b):
-    """World-4: a shared chain's pages live on DIFFERENT PEs (global page
-    g on PE g // pps_local) and the per-PE table rows stay consistent —
-    tokens byte-identical to the cold run."""
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 devices")
-    cfg, params = tiny4b
-    cfg = dataclasses.replace(cfg, n_kv_heads=4)
-    params = init_params(jax.random.PRNGKey(2), cfg)
-    mesh = Mesh(np.array(jax.devices()[:4]), ("tp",))
-    prefix = list(range(10, 22))             # 3 pages: PEs 0, 0, 1 @ s_max 32
-    reqs = lambda: [  # noqa: E731
-        Request(prefix + [1, 2], max_new_tokens=3, uid="p"),
-        Request(prefix + [3], max_new_tokens=4, uid="c"),
-    ]
-    b0 = ContinuousBatcher(cfg, params, mesh, s_max=32, page_size=4)
-    for r in reqs():
-        b0.submit(r)
-    cold = dict(b0.run(max_steps=200))
-    b1 = ContinuousBatcher(cfg, params, mesh, s_max=32, page_size=4,
-                           prefix_cache=PrefixCacheConfig())
-    p, c = reqs()
-    b1.submit(p)
-    warm = dict(b1.run(max_steps=200))
-    b1.submit(c)
-    warm.update(b1.run(max_steps=200))
-    assert warm == cold
-    px = b1.prefix_cache
-    assert px.stats()["hits"] == 1
-    # pages_per_shard = (32/4)/4 = 2: global pages 0,1 on PE0, page 2 on
-    # PE1 — the chain really spans PEs
-    assert px.pps_local == 2 and px.stats()["prefill_tokens_saved"] == 12
-    px.audit()
-
-
 def test_engine_px_counters_survive_rebuild(tiny1, mesh1, monkeypatch):
     """A mid-serve rebuild (step timeout) starts a FRESH trie, but the
     engine accumulates the counters — the hit-rate the snapshot reports
@@ -517,97 +448,3 @@ def test_engine_px_counters_survive_rebuild(tiny1, mesh1, monkeypatch):
     assert snap["prefix_cache"]["lookups"] >= lookups_clean, (
         "counters accumulate across the rebuild (replays re-admit)"
     )
-
-
-# ---------------------------------------------------------------------------
-# Chaos tier: poisoned shared page strikes every reader
-# ---------------------------------------------------------------------------
-
-@pytest.mark.chaos
-def test_poisoned_shared_page_strikes_every_reader(tiny4b, mesh1):
-    """ISSUE 12 acceptance (quarantine fan-out): a poisoned slot whose
-    chain is SHARED strikes every reader — each is evicted, the chain is
-    detached from the trie, and every struck reader re-prefills cold and
-    regenerates its stream byte-identically (greedy and seeded-sampled);
-    the unrelated neighbor is untouched."""
-    cfg, params = tiny4b
-    prefix = list(range(10, 22))             # 3 shared pages at page 4
-
-    def reqs():
-        return [
-            Request(prefix + [1, 2], max_new_tokens=3, uid="prod"),
-            Request(prefix + [3], max_new_tokens=6, uid="rA"),
-            Request(prefix + [4, 5], max_new_tokens=6, uid="rB",
-                    temperature=0.8, top_k=6, seed=9),
-            Request(prefix + [6], max_new_tokens=5, uid="rC"),
-        ]
-
-    def run(poison_uid=None):
-        resilience.reset()
-        eng = _engine(cfg, params, mesh1, ServingPrefixCacheConfig())
-        if poison_uid is not None:
-            tdt_config.update(integrity=IntegrityConfig())
-            orig = eng._batcher._step
-            calls = {"n": 0}
-
-            def poisoned_step(params_, cache, tok, pos):
-                logits, cache = orig(params_, cache, tok, pos)
-                calls["n"] += 1
-                if calls["n"] == 20:         # readers mid-decode
-                    slot = next(
-                        i for i, r in enumerate(eng._batcher.slot_req)
-                        if r is not None and r.uid == poison_uid
-                    )
-                    logits = logits.at[slot].set(jnp.nan)
-                return logits, cache
-
-            eng._batcher._step = poisoned_step
-        p, a, b, c = reqs()
-        eng.submit(p, arrival_t=0.0)
-        done = eng.run_until_idle()          # producer publishes the chain
-        for r in (a, b, c):
-            eng.submit(r)
-        done.update(eng.run_until_idle())
-        tdt_config.update(integrity=None)
-        return done, eng.snapshot()
-
-    golden, _ = run()
-    assert all(isinstance(r, Finished) for r in golden.values())
-    done, snap = run(poison_uid="rA")
-    assert {u for u, r in done.items() if isinstance(r, Poisoned)} == {"rA"}
-    for uid in ("prod", "rB", "rC"):
-        assert done[uid].tokens == golden[uid].tokens, uid
-    assert done["rB"].resumed == 1 and done["rC"].resumed == 1, (
-        "both readers were struck and restarted"
-    )
-    assert snap["requests"]["prefix_struck"] == 2
-    px = snap["prefix_cache"]
-    assert px["struck_pages"] >= 3 and px["readers_struck"] == 2
-    from triton_dist_tpu.resilience import health
-
-    assert health.counters()[
-        ("continuous_batcher", health.PREFIX_STRIKE)
-    ] == 2
-    assert not health.is_healthy(), "the POISONED event flips health"
-
-
-@pytest.mark.chaos
-def test_quick_shared_prefix_soak_campaign_green():
-    """One shared-prefix soak campaign (burst traffic over Zipf shared
-    prefixes × straggler × corruption × a poisoned shared page): every
-    invariant holds and the seed replays bit-identically — the ISSUE 12
-    composition cell (full set: scripts/chaos_soak.py)."""
-    if len(jax.devices()) < 4:
-        pytest.skip("needs 4 devices")
-    from triton_dist_tpu.resilience import soak
-
-    spec = soak.SoakSpec.shared_prefix(seed=101)
-    a = soak.run_campaign(spec)
-    assert a.error is None, a.error
-    assert a.ok, a.failures
-    assert a.snapshot["requests"].get("poisoned", 0) >= 1
-    assert a.snapshot["requests"].get("prefix_struck", 0) >= 1, (
-        "the poison landed on a multi-reader chain (deferred injection)"
-    )
-    b = soak.run_campaign(spec)
-    assert b.fingerprint == a.fingerprint and b.terminals == a.terminals

@@ -33,12 +33,6 @@ def test_moe_preset_class():
     assert (cfg.n_experts, cfg.topk) == (8, 2)
 
 
-def test_bench_gemm_shapes_match_reference_table():
-    shapes = presets.bench_gemm_shapes("llama-3.1-8b")
-    assert shapes["ag_gemm_up"] == (8192, 4096, 14336)
-    assert shapes["gemm_rs_down"] == (8192, 14336, 4096)
-
-
 def test_unknown_preset_raises():
     with pytest.raises(KeyError):
         presets.preset("nope-13b")

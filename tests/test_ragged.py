@@ -6,16 +6,16 @@ Three tiers, matching the repo's environment matrix (tests/test_chunked*):
   ``(expert_id, valid_rows)`` map invariants, the padding-tax perf-model
   terms and the ``suggest_ragged`` pruning hook, the tune-space ordering
   contract (every ragged candidate strictly after its padded twin,
-  composed with the PR 3/4 chunk invariant), the slowest-rank autotune
-  aggregation (VERDICT r5 missing #3), and the ``bench.py --shapes``
-  model table (VERDICT r5 next-round #7).
+  composed with the PR 3/4 chunk invariant) and the slowest-rank
+  autotune aggregation.
 - **kernel-level** (needs the Mosaic TPU interpreter — this jax line
   cannot build or simulate the fused kernels, the pre-existing seed gap):
   ragged vs the ``jax.lax.ragged_dot`` golden at non-divisor expert
   counts (zero-row expert, single-row tail), ``ragged=False`` ≡ legacy
   bit-exact for forward / w8 / dw and both overlapped pipeline kernels,
-  the dw in-kernel row masking, and the ragged × chunks_per_shard
-  composition through the overlapped pipeline.
+  the dw in-kernel row masking; the whole-pipeline cells (ragged vs
+  padded, ragged × chunks_per_shard, the sentinel, EP) are
+  test_ragged_pipeline.py.
 - **chaos**: ragged tail blocks must not add a droppable signal edge — a
   dropped/duplicated chunk signal under the ragged chunked pipeline
   either trips the watchdogged ``chunk_wait`` diagnostic or leaves the
@@ -46,8 +46,6 @@ from triton_dist_tpu.ops.moe_utils import (
 )
 from triton_dist_tpu.resilience import FaultPlan
 from triton_dist_tpu.resilience import records as R
-
-
 
 
 def _case_ids():
@@ -224,21 +222,6 @@ def test_slowest_rank_best():
     # order preference: a later candidate must win by the margin
     assert _slowest_rank_best([[1.0, 0.99], [1.0, 0.99]]) == 0
     assert _slowest_rank_best([[1.0, 0.90], [1.0, 0.90]]) == 1
-
-
-def test_shape_sweep_table():
-    """The bench --shapes table carries the reference perf suite's model
-    list (M=8192 against the open-model projections) with the MoE
-    pipeline shape on MoE presets only."""
-    from triton_dist_tpu.models import presets
-
-    table = presets.shape_sweep()
-    assert table["llama-3.1-70b"]["ag_gemm"] == (8192, 8192, 28672)
-    assert table["llama-3.1-70b"]["gemm_rs"] == (8192, 28672, 8192)
-    assert table["qwen2-72b"]["ag_gemm"] == (8192, 8192, 29568)
-    assert table["moe-gelu-8x"]["moe"] == (8192, 4096, 14336, 8, 2)
-    assert "moe" not in table["llama-3.1-8b"]
-    assert set(table) == set(presets.PRESETS)
 
 
 def test_group_gemm_ragged_requires_valid_rows():
@@ -457,160 +440,6 @@ def test_ag_group_gemm_overlap_ragged(mesh4, chunks, _small_panels):
         np.testing.assert_array_equal(
             np.asarray(run(off, True)[0]), np.asarray(run(base, False)[0])
         )
-
-
-def test_tp_moe_ragged_matches_padded(mesh4, _small_panels):
-    """Full fused pipeline, ragged vs padded: same routing, same math —
-    forward AND gradients (the backward's grouped GEMMs and dw consume
-    the same map)."""
-    from triton_dist_tpu.ops.grads import tp_moe_mlp_grad
-
-    n, m_loc, topk, n_exp, h_dim, f_dim = 4, 8, 2, 3, 32, 64
-    m_tot = n * m_loc
-    kx, ku, kd, kl = jax.random.split(jax.random.PRNGKey(31), 4)
-    x = jax.random.normal(kx, (m_tot, h_dim), jnp.float32)
-    w_up = jax.random.normal(ku, (n_exp, h_dim, f_dim)) / 8
-    w_down = jax.random.normal(kd, (n_exp, f_dim, h_dim)) / 8
-    tw, ids = select_experts(
-        jax.random.normal(kl, (m_tot, n_exp), jnp.float32), topk
-    )
-    specs = (
-        P("tp", None), P(None, None, "tp"), P(None, "tp", None),
-        P("tp", None), P("tp", None),
-    )
-
-    def run(cfg):
-        def fn(x, wu, wd, ids, tw):
-            def loss(x, wu, wd):
-                out = tp_moe_mlp_grad(
-                    x, wu, wd, ids, tw, "tp", jax.nn.gelu, cfg, None, True
-                )
-                return jnp.sum(out.astype(jnp.float32)), out
-
-            (l, out), grads = jax.value_and_grad(
-                loss, argnums=(0, 1, 2), has_aux=True
-            )(x, wu, wd)
-            return out, *grads
-
-        return jax.jit(
-            jax.shard_map(
-                fn, mesh=mesh4, in_specs=specs,
-                out_specs=(P("tp", None), P("tp", None),
-                           P(None, None, "tp"), P(None, "tp", None)),
-                check_vma=False,
-            )
-        )(x, w_up, w_down, ids, tw.astype(jnp.float32))
-
-    ragged = run(GroupGemmConfig(4, 32, 32, ragged=True))
-    padded = run(GroupGemmConfig(4, 32, 32))
-    for r, p in zip(ragged, padded):
-        np.testing.assert_allclose(
-            np.asarray(r, np.float32), np.asarray(p, np.float32),
-            rtol=1e-5, atol=1e-5,
-        )
-
-
-def test_tp_moe_ragged_chunked_composition(mesh4, _small_panels):
-    """ragged × chunks_per_shard through the whole overlapped pipeline
-    (m_loc=256 engages the combine-side chunk schedule) vs the padded
-    sequential composition."""
-    from triton_dist_tpu.ops.grads import tp_moe_mlp_grad
-
-    n, m_loc, topk, n_exp, h_dim, f_dim = 4, 256, 1, 2, 16, 32
-    m_tot = n * m_loc
-    kx, ku, kd, kl = jax.random.split(jax.random.PRNGKey(35), 4)
-    x = jax.random.normal(kx, (m_tot, h_dim), jnp.float32)
-    w_up = jax.random.normal(ku, (n_exp, h_dim, f_dim)) / 8
-    w_down = jax.random.normal(kd, (n_exp, f_dim, h_dim)) / 8
-    tw, ids = select_experts(
-        jax.random.normal(kl, (m_tot, n_exp), jnp.float32), topk
-    )
-    specs = (
-        P("tp", None), P(None, None, "tp"), P(None, "tp", None),
-        P("tp", None), P("tp", None),
-    )
-
-    def run(overlap, cfg):
-        return jax.jit(
-            jax.shard_map(
-                lambda x, wu, wd, i, t: tp_moe_mlp_grad(
-                    x, wu, wd, i, t, "tp", jax.nn.gelu, cfg, None, overlap
-                ),
-                mesh=mesh4, in_specs=specs, out_specs=P("tp", None),
-                check_vma=False,
-            )
-        )(x, w_up, w_down, ids, tw.astype(jnp.float32))
-
-    fused = np.asarray(run(
-        True, GroupGemmConfig(4, 32, 16, chunks_per_shard=2, ragged=True)
-    ), np.float32)
-    seq = np.asarray(run(False, GroupGemmConfig(4, 32, 16)), np.float32)
-    np.testing.assert_allclose(fused, seq, rtol=1e-5, atol=1e-5)
-
-
-def test_tp_moe_ragged_dot_sentinel(mesh4):
-    """The jax.lax.ragged_dot sentinel candidate (backend="ragged_dot")
-    runs the pipeline through the sequential composition and matches the
-    fused default."""
-    from triton_dist_tpu.ops.grads import tp_moe_mlp_op
-
-    m_tot, h_dim, f_dim, n_exp, topk = 16, 32, 64, 3, 2
-    kx, ku, kd, kl = jax.random.split(jax.random.PRNGKey(41), 4)
-    x = jax.random.normal(kx, (m_tot, h_dim), jnp.float32)
-    w_up = jax.random.normal(ku, (n_exp, h_dim, f_dim)) / 8
-    w_down = jax.random.normal(kd, (n_exp, f_dim, h_dim)) / 8
-    tw, ids = select_experts(
-        jax.random.normal(kl, (m_tot, n_exp), jnp.float32), topk
-    )
-    base = tp_moe_mlp_op(
-        x, w_up, w_down, ids, tw, mesh4,
-        config=GroupGemmConfig(4, 32, 32), overlap=True,
-    )
-    sent = tp_moe_mlp_op(
-        x, w_up, w_down, ids, tw, mesh4,
-        config=GroupGemmConfig(4, 32, 32, backend="ragged_dot"),
-        overlap=True,
-    )
-    np.testing.assert_allclose(
-        np.asarray(base), np.asarray(sent), rtol=1e-5, atol=1e-5
-    )
-
-
-def test_ep_moe_ragged_matches_padded(mesh4, _small_panels):
-    """EP layer end-to-end: the ragged receiver alignment (virtual
-    padding expert skipped outright) reproduces the padded output."""
-    from triton_dist_tpu.layers.ep_moe_mlp import EPMoEMLP
-
-    n, m_loc, hidden, ffn, n_exp, topk, max_m = 4, 8, 16, 32, 8, 2, 16
-    kx, ki, kw, ku, kd = jax.random.split(jax.random.PRNGKey(51), 5)
-    x = jax.random.normal(kx, (n * m_loc, hidden), jnp.float32)
-    ids = jax.random.randint(ki, (n * m_loc, topk), 0, n_exp, jnp.int32)
-    tw = jax.nn.softmax(
-        jax.random.normal(kw, (n * m_loc, topk), jnp.float32), axis=-1
-    )
-    w_up = jax.random.normal(ku, (n_exp, hidden, ffn)) / 8
-    w_down = jax.random.normal(kd, (n_exp, ffn, hidden)) / 8
-
-    def run(cfg):
-        layer = EPMoEMLP(
-            n_experts=n_exp, topk=topk, max_m=max_m, axis="tp",
-            gg_config=cfg,
-        )
-        return jax.jit(
-            jax.shard_map(
-                lambda x, wu, wd, i, t: layer(x, wu, wd, i, t),
-                mesh=mesh4,
-                in_specs=(P("tp", None), P("tp", None, None),
-                          P("tp", None, None), P("tp", None), P("tp", None)),
-                out_specs=P("tp", None), check_vma=False,
-            )
-        )(x, w_up, w_down, ids, tw)
-
-    padded = np.asarray(run(GroupGemmConfig(4, 32, 16)), np.float32)
-    ragged = np.asarray(
-        run(GroupGemmConfig(4, 32, 16, ragged=True)), np.float32
-    )
-    np.testing.assert_allclose(ragged, padded, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------

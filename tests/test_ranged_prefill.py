@@ -8,7 +8,9 @@ then driven through its three doors.
 - **model tier**: ``verify_step`` range composition reproduces
   ``prefill_cache``'s cache AND last logits bit-for-bit (contiguous XLA,
   contiguous kernel, paged static cells), and equals the token-by-token
-  ``decode_step`` chain; bulk prefill is bucket-invariant.
+  ``decode_step`` chain (ranged_model_tier.py, one test file a cell:
+  test_ranged_contiguous / _kernel / _paged); bulk prefill is
+  bucket-invariant (here).
 - **batcher tier**: prefix-cache admission under ``prefill=True`` and
   chunked-prefill scheduling (``prefill_chunk_tokens``) are byte-identical
   to token-fed admission, greedy AND seeded-sampled; armed-but-untriggered
@@ -28,20 +30,16 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from jax.sharding import PartitionSpec as P
 
 
 from triton_dist_tpu.models.decode import (
     KVCacheSpec,
-    PagedKVCacheSpec,
     _prompt_shard,
-    decode_step,
     prefill_cache,
     specs_for,
 )
 
-from triton_dist_tpu.models.speculative import verify_step
 
 from triton_dist_tpu.ops.common import jit_shard_map
 from triton_dist_tpu.ops.flash_decode import (
@@ -170,185 +168,9 @@ def test_paged_ranged_ops_composition_softcap_d96(mesh1):
 
 
 # ---------------------------------------------------------------------------
-# Model tier: ranged composition ≡ whole-prompt prefill ≡ decode chain
+# Model tier: bulk prefill is bucket-invariant (the rest of the tier is
+# ranged_model_tier.py, one file a cell)
 # ---------------------------------------------------------------------------
-
-CELLS = [
-    ("contiguous/xla", lambda: KVCacheSpec(S_MAX), None),
-    (
-        "contiguous/kernel",
-        lambda: KVCacheSpec(S_MAX),
-        FlashDecodeConfig(block_s=4),
-    ),
-    (
-        "paged/static",
-        lambda: PagedKVCacheSpec(S_MAX, 4, static_table=True),
-        None,
-    ),
-]
-
-
-def _run_prefill(mesh, cfg, params_d, pspecs, spec, prompt):
-    cache = _put(mesh, spec.init(cfg, 4, 1), spec.specs(cfg))
-
-    def fn(params, cache, prompt):
-        pcfg = dataclasses.replace(cfg, seq=L, batch=B)
-        return prefill_cache(
-            pcfg, params, cache, _prompt_shard(prompt, B, L, cfg), spec, S_MAX
-        )
-
-    prog = jit_shard_map(
-        fn, mesh, (pspecs, spec.specs(cfg), P(None, None)),
-        (spec.specs(cfg), P(None, None)), key=("rp_prefill", spec),
-    )
-    return prog(params_d, cache, prompt)
-
-
-def _run_ranged(mesh, cfg, params_d, pspecs, spec, prompt, splits, fd):
-    cache = _put(mesh, spec.init(cfg, 4, 1), spec.specs(cfg))
-
-    def fn(params, cache, tokens, pos0):
-        return verify_step(
-            dataclasses.replace(cfg, seq=tokens.shape[1]), params, cache,
-            tokens, pos0, spec=spec, fd_config=fd,
-        )
-
-    last = None
-    lo = 0
-    for hi in splits:
-        prog = jit_shard_map(
-            fn, mesh,
-            (pspecs, spec.specs(cfg), P(None, None), P(None)),
-            (P(None, None, None), spec.specs(cfg)),
-            key=("rp_ranged", spec, hi - lo, fd),
-        )
-        logits, cache = prog(
-            params_d, cache, prompt[:, lo:hi],
-            jnp.full((B,), lo, jnp.int32),
-        )
-        last = logits[:, -1]
-        lo = hi
-    return cache, last
-
-
-def _cache_bits(spec, cache):
-    """The comparable KV bits: landed positions < L (contiguous), or the
-    pool pages the block table names for positions < L (paged)."""
-    k, v = np.asarray(cache["k"]), np.asarray(cache["v"])
-    if "block_table" in cache:
-        bt = np.asarray(cache["block_table"][0])
-        pages = bt[:, : L // 4].reshape(-1)
-        return k[:, pages], v[:, pages]
-    return k[:, :, :, :L], v[:, :, :, :L]
-
-
-@pytest.mark.parametrize(
-    "cell", CELLS, ids=[c[0].replace("/", "-") for c in CELLS]
-)
-@pytest.mark.parametrize("splits", [[3, L], [2, 5, L]], ids=str)
-def test_ranged_composition_matches_prefill(mesh4, model, prompt, cell, splits):
-    """Composing consecutive ranged passes over [0, L) is BIT-IDENTICAL
-    to one whole-range pass — cache AND final logits, on the contiguous
-    XLA, contiguous kernel, and paged static cells (the forward is
-    row-independent, so the split point cannot change any landed bit) —
-    and reproduces the bulk masked prefill's cache numerically (the bulk
-    pass is a different attention program — dense padded rectangle vs
-    the verify family — so cross-PROGRAM agreement is allclose; token
-    byte-identity across programs is pinned at the batcher tier, where
-    the sampler consumes the logits)."""
-    cfg, params = model
-    name, mkspec, fd = cell
-    spec = mkspec()
-    pspecs = specs_for(cfg, params)
-    params_d = _put(mesh4, params, pspecs)
-    cache_w, last_w = _run_ranged(
-        mesh4, cfg, params_d, pspecs, spec, prompt, [L], fd
-    )
-    cache_r, last_r = _run_ranged(
-        mesh4, cfg, params_d, pspecs, spec, prompt, splits, fd
-    )
-    np.testing.assert_array_equal(
-        np.asarray(cache_r["k"]), np.asarray(cache_w["k"])
-    )
-    np.testing.assert_array_equal(
-        np.asarray(cache_r["v"]), np.asarray(cache_w["v"])
-    )
-    np.testing.assert_array_equal(np.asarray(last_r), np.asarray(last_w))
-    cache_p, _ = _run_prefill(mesh4, cfg, params_d, pspecs, spec, prompt)
-    kp, vp = _cache_bits(spec, cache_p)
-    kr, vr = _cache_bits(spec, cache_r)
-    np.testing.assert_allclose(kr, kp, rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(vr, vp, rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize(
-    "cell", CELLS, ids=[c[0].replace("/", "-") for c in CELLS]
-)
-def test_ranged_matches_decode_chain(mesh4, model, prompt, cell):
-    """One whole-prompt ranged pass equals the token-by-token decode_step
-    chain bit-for-bit (cache and final logits) — the ranged forward IS
-    the decode forward, batched over positions."""
-    cfg, params = model
-    name, mkspec, fd = cell
-    spec = mkspec()
-    pspecs = specs_for(cfg, params)
-    params_d = _put(mesh4, params, pspecs)
-
-    cache0 = _put(mesh4, spec.init(cfg, 4, 1), spec.specs(cfg))
-
-    def chain(params, cache, prompt):
-        def body(cache, i):
-            logits, cache = decode_step(
-                cfg, params, cache, prompt[:, i], i, spec=spec, fd_config=fd
-            )
-            return cache, logits
-
-        cache2, logits = jax.lax.scan(body, cache, jnp.arange(L))
-        return logits[-1], cache2
-
-    prog = jit_shard_map(
-        chain, mesh4, (pspecs, spec.specs(cfg), P(None, None)),
-        (P(None, None), spec.specs(cfg)), key=("rp_chain", spec, fd),
-    )
-    last_a, cache_a = prog(params_d, cache0, prompt)
-    cache_b, last_b = _run_ranged(
-        mesh4, cfg, params_d, pspecs, spec, prompt, [L], fd
-    )
-    np.testing.assert_array_equal(
-        np.asarray(cache_a["k"]), np.asarray(cache_b["k"])
-    )
-    np.testing.assert_array_equal(
-        np.asarray(cache_a["v"]), np.asarray(cache_b["v"])
-    )
-    np.testing.assert_array_equal(np.asarray(last_a), np.asarray(last_b))
-
-
-def test_ranged_softcap_self_composition(mesh4, model, prompt):
-    """soft_cap lives in FlashDecodeConfig (the bulk prefill has no cap
-    knob), so the cap≠0 composition pin is SELF-referential: [L] vs
-    [3, L] under a capped kernel config must be bit-identical."""
-    cfg, params = model
-    spec = KVCacheSpec(S_MAX)
-    fd = FlashDecodeConfig(block_s=4, soft_cap=15.0)
-    pspecs = specs_for(cfg, params)
-    params_d = _put(mesh4, params, pspecs)
-    cache_a, last_a = _run_ranged(
-        mesh4, cfg, params_d, pspecs, spec, prompt, [L], fd
-    )
-    cache_b, last_b = _run_ranged(
-        mesh4, cfg, params_d, pspecs, spec, prompt, [3, L], fd
-    )
-    np.testing.assert_array_equal(
-        np.asarray(cache_a["k"]), np.asarray(cache_b["k"])
-    )
-    np.testing.assert_array_equal(np.asarray(last_a), np.asarray(last_b))
-    # and the cap actually bites: uncapped last logits differ
-    _, last_u = _run_ranged(
-        mesh4, cfg, params_d, pspecs, spec, prompt, [L],
-        FlashDecodeConfig(block_s=4),
-    )
-    assert not np.array_equal(np.asarray(last_a), np.asarray(last_u))
-
 
 def test_prefill_bucket_invariance(mesh4, model, prompt):
     """Bulk prefill of an 8-token prompt at bucket 8 vs bucket 16 is
@@ -389,5 +211,3 @@ def test_prefill_bucket_invariance(mesh4, model, prompt):
         np.asarray(c8["k"])[:, :, :, :L], np.asarray(c16["k"])[:, :, :, :L]
     )
     np.testing.assert_array_equal(np.asarray(l8), np.asarray(l16))
-
-

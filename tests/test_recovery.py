@@ -17,11 +17,12 @@ Tier structure mirrors tests/test_disagg.py / tests/test_fleet.py:
   unified), a collapsed topology un-collapses after a clean probation
   window and serves two-pool again, a dead replica resurrects (probe
   rounds -> fresh engine -> cold trie) and then serves again, the
-  armed-but-untriggered byte-identity pins, and the quick recovery
+  armed-but-untriggered byte-identity pins; the quick recovery
   soak campaign (``resilience/soak.py SoakSpec.fleet_recovery_spec``)
-  with bit-identical seeded replay;
-- **soak tier** (``pytest.mark.soak``, implies slow): the full
-  recovery campaign set scripts/chaos_soak.py runs.
+  with bit-identical seeded replay is test_recovery_soak.py;
+- **soak tier** (``pytest.mark.soak``, implies slow; also
+  test_recovery_soak.py): the full recovery campaign set
+  scripts/chaos_soak.py runs.
 """
 
 from __future__ import annotations
@@ -658,44 +659,3 @@ def test_armed_untriggered_fleet_byte_identical(model, mesh4):
     disarmed = run()
     armed = run(elastic_scope=True, resurrect=ResurrectConfig())
     assert armed == disarmed
-
-
-# ---------------------------------------------------------------------------
-# Chaos + soak tiers: the recovery soak campaign
-# ---------------------------------------------------------------------------
-
-@pytest.mark.chaos
-def test_recovery_soak_campaign_quick_and_replay():
-    """The chaos-matrix recovery cell: the elastic-ON fleet campaign
-    (decode straggler regrow × prefill-storm collapse/un-collapse ×
-    windowed replica kill/resurrect) passes every invariant — strikes
-    provably scoped, the dead replica back AND serving — and replays
-    bit-identically from its seed."""
-    from triton_dist_tpu.resilience import soak
-
-    spec = soak.SoakSpec.fleet_recovery_spec(seed=0)
-    res = soak.run_campaign(spec)
-    assert res.ok, (res.failures, res.error)
-    hc = res.health.get("counters", {})
-    assert hc.get("serving_fleet:replica_readmit", 0) >= 1
-    assert hc.get("serving_pool_decode:pool_regrow", 0) >= 1
-    assert hc.get("serving_disagg:pool_uncollapse", 0) >= 1
-    assert res.snapshot["engine"]["dead"] == []
-    assert res.snapshot["fleet"]["resurrections"] >= 1
-    # every PE health family in the campaign is scope-qualified
-    pe_fams = [key.rsplit(":", 1)[0] for key in hc
-               if key.startswith("pe") and key[2:3].isdigit()]
-    assert pe_fams and all("@" in fam for fam in pe_fams), pe_fams
-    again = soak.run_campaign(spec)
-    assert again.fingerprint == res.fingerprint
-
-
-@pytest.mark.soak
-def test_recovery_soak_campaign_set():
-    """The full ISSUE 17 recovery set (3 seeds — what
-    scripts/chaos_soak.py runs); soak marker ⇒ slow, never tier-1."""
-    from triton_dist_tpu.resilience import soak
-
-    for seed in range(3):
-        res = soak.run_campaign(soak.SoakSpec.fleet_recovery_spec(seed=seed))
-        assert res.ok, (seed, res.failures, res.error)

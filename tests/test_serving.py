@@ -50,7 +50,6 @@ from triton_dist_tpu.serving import (
     preset_mix,
     trace_fingerprint,
 )
-from triton_dist_tpu.serving import bench as sbench
 from triton_dist_tpu.serving import traffic as traffic_mod
 
 
@@ -256,28 +255,6 @@ def test_steps_exhausted_error_contract():
     assert err.pending_uids == ("s1", "s2")
     assert err.finished_uids == ("done1",)
     assert "drain_finished" in str(err) and "max_steps=7" in str(err)
-
-
-def test_bench_info_lines_shape():
-    """The bench_serving emission contract: info lines only — no
-    vs_baseline anywhere, so scripts/perf_gate.sh (which only collects
-    vs_baseline-bearing lines) structurally cannot gate them."""
-    m = ServingMetrics(slo=SLOTargets(ttft_ms=100.0))
-    m.observe_finished(ttft_ms=10.0, e2e_ms=20.0, tpot_ms=5.0, n_tokens=3)
-    m.observe_step(queue_depth=2, occupied=1, slots=2)
-    snap = m.snapshot()
-    snap["tokens"]["per_s"] = 1.5
-    snap["tokens"]["goodput_per_s"] = 1.5  # the engine-added twin
-    rows = [{"rate_rps": 2.5, "snapshot": snap, "n_finished": 1}]
-    lines = sbench.info_lines(rows, tag="_t")
-    names = [n for n, _, _ in lines]
-    assert f"serving_ttft_p50_ms_lam2.5_t" in names
-    assert f"serving_slo_attainment_lam2.5_t" in names
-    assert f"serving_goodput_per_s_lam2.5_t" in names
-    assert len(set(names)) == len(names)
-    for name, value, unit in lines:
-        payload = json.dumps({"metric": name, "value": value, "unit": unit})
-        assert "vs_baseline" not in payload
 
 
 # ---------------------------------------------------------------------------
@@ -582,15 +559,24 @@ def _serve_tiny4(tiny4, mesh4, *, fault_at=None, fault_recs=None,
     return eng, done
 
 
+@pytest.fixture(scope="module")
+def golden_tiny4(tiny4, mesh4):
+    """The uninterrupted serve both arcs are compared with, run once."""
+    try:
+        return _serve_tiny4(tiny4, mesh4)
+    finally:
+        retry.set_clock(None)
+
+
 @pytest.mark.chaos
-def test_serving_elastic_arc(tiny4, mesh4):
+def test_serving_elastic_arc(tiny4, mesh4, golden_tiny4):
     """ISSUE 6 acceptance: persistent-straggler step timeout mid-serving →
     PE quarantined → the engine shrinks to the serviceable world (2: the
     3-survivor count is model-invalid) and keeps serving with every
     in-flight request prefix-replayed → probation re-admits → the world
     regrows to 4 mid-serving → every submitted request finishes exactly
     once with tokens byte-identical to the uninterrupted run."""
-    golden_eng, golden = _serve_tiny4(tiny4, mesh4)
+    golden_eng, golden = golden_tiny4
     assert golden_eng.rebuilds == 0 and len(golden) == 5
 
     resilience.reset()
@@ -616,10 +602,11 @@ def test_serving_elastic_arc(tiny4, mesh4):
 
 
 @pytest.mark.chaos
-def test_serving_arc_unattributable_timeout_keeps_full_world(tiny4, mesh4):
+def test_serving_arc_unattributable_timeout_keeps_full_world(
+        tiny4, mesh4, golden_tiny4):
     """Every PE tripping (fabric-wide) must not quarantine anyone: the
     engine rebuilds on the FULL world and service continues losslessly."""
-    golden_eng, golden = _serve_tiny4(tiny4, mesh4)
+    _, golden = golden_tiny4
     resilience.reset()
     tdt_config.update(elastic=True, suspect_threshold=1)
     eng, done = _serve_tiny4(tiny4, mesh4, fault_at=3,

@@ -15,7 +15,7 @@ def test_quick_speculative_soak_green():
     quarantine → shrink → replay → regrow arc, every injected draft
     corruption is rejected by the verify pass, and the streams match a
     clean plain reference byte for byte (check_spec_invariants)."""
-    res = soak.run_campaign(soak.SoakSpec.speculative(seed=600))
+    res = soak.run_campaign(soak.SoakSpec.speculative(seed=600, n_requests=6))
     assert res.error is None, res.error
     assert res.ok, res.failures
     assert res.rebuilds >= 1, "the straggler arc rebuilt mid-speculation"

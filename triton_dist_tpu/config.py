@@ -83,7 +83,7 @@ class Config:
     # reasons (Mosaic compile failure, unsupported topology), recording
     # the downgrade in resilience.health — the SERVING posture, and the
     # default. False = every failure is loud: the posture of CI and of
-    # everything that measures (chip_smoke.py, bench.py, scripts/).
+    # everything that measures (chip_smoke.py, perfbench/, scripts/).
     # Env: TDT_FALLBACK_TO_XLA.
     fallback_to_xla: bool = bool(int(os.environ.get("TDT_FALLBACK_TO_XLA", "1")))
     # --- elastic degraded mode (docs/resilience.md) --------------------
@@ -126,7 +126,7 @@ class Config:
     # wait-telemetry buffer recording every bounded wait site's observed
     # spin count (success path included; rides the diag-output plumbing,
     # NO new signal edges). Exported via obs.export_chrome_trace() /
-    # obs.snapshot() / bench.py --obs-trace. None (default) = no spans,
+    # obs.snapshot(). None (default) = no spans,
     # zero new kernel outputs, bit-exact op results.
     obs: object = None
 

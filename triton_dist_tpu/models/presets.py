@@ -121,38 +121,3 @@ def preset(
         validate_tp(cfg, tp_check)
     return cfg
 
-
-def shape_sweep(m: int = 8192) -> "dict[str, dict[str, tuple]]":
-    """The ``bench.py --shapes`` problem table (VERDICT r5 next-round #7 ≙
-    the reference perf suite's model sweep, test_ag_gemm.py:149-156):
-    per preset, the fused-GEMM (M, K, N) problems — column-parallel
-    up-proj for ag_gemm, row-parallel down-proj for gemm_rs — plus, for
-    MoE presets, the full MoE-pipeline shape ``(M, hidden, ffn, E,
-    topk)``. Per-op perf becomes a curve over the open-model table
-    instead of a single 8B-shaped point."""
-    table: dict[str, dict[str, tuple]] = {}
-    for name in PRESETS:
-        cfg = preset(name)
-        entry: dict[str, tuple] = {
-            "ag_gemm": (m, cfg.hidden, cfg.ffn),
-            "gemm_rs": (m, cfg.ffn, cfg.hidden),
-        }
-        if name in _MOE:
-            entry["moe"] = (
-                m, cfg.hidden, cfg.ffn, cfg.n_experts, cfg.topk
-            )
-        table[name] = entry
-    return table
-
-
-def bench_gemm_shapes(name: str, m: int = 8192) -> dict[str, tuple[int, int, int]]:
-    """The reference benchmark's (M, K, N) problem list for one model:
-    column-parallel up-proj (AG-GEMM side) and row-parallel down-proj
-    (GEMM-RS side) — the two fused-GEMM shapes its perf suite sweeps."""
-    cfg = preset(name)
-    return {
-        "ag_gemm_up": (m, cfg.hidden, cfg.ffn),
-        "gemm_rs_down": (m, cfg.ffn, cfg.hidden),
-        "ag_gemm_qkv": (m, cfg.hidden, (cfg.q_dim + 2 * cfg.kv_dim)),
-        "gemm_rs_o": (m, cfg.q_dim, cfg.hidden),
-    }

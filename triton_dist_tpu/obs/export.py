@@ -25,7 +25,7 @@ Two surfaces:
 
 The top-level snapshot key set is THE versioned schema
 (:data:`SNAPSHOT_SCHEMA` / :data:`SNAPSHOT_SECTIONS`): every section an
-``obs.snapshot()`` / ``bench.py --health-json`` artifact may carry is
+``obs.snapshot()`` artifact may carry is
 registered here with its contract, :func:`validate_snapshot` refuses
 unknown keys at snapshot time, and serving-engine snapshots are held to
 the :data:`ENGINE_SECTIONS` registry by the schema test

@@ -21,7 +21,7 @@ What does NOT fall back:
 
 Set ``config.update(fallback_to_xla=False)`` to make every failure loud
 (the posture of CI and of everything that measures: ``chip_smoke.py``,
-``bench.py``, ``scripts/``); the default is to degrade (serving posture).
+``perfbench/``, ``scripts/``); the default is to degrade (serving posture).
 
 :func:`golden_path` is the EXPLICIT way to run the goldens: inside the
 scope every guarded entry serves its XLA twin directly — no failure, no

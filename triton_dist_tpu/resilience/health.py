@@ -570,7 +570,7 @@ def snapshot() -> dict:
             "short_circuited": {f: r for f, (r, _) in _short_circuit.items()},
             # no silent caps (ISSUE 9 satellite): the bounded deque's
             # evictions are counted AND attributed by kind — emitted via
-            # bench.py --health-json with the rest of the snapshot
+            # obs.snapshot() with the rest of the snapshot
             "dropped_events": _total_dropped,
             "dropped_by_kind": dict(sorted(_dropped_by_kind.items())),
             "last_events": [

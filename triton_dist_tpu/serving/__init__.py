@@ -79,9 +79,6 @@ byte for byte.
   (``obs/alerts.py``; armed via ``ObsConfig(alerts=...)``), and every
   health-flipping event freezes a post-mortem bundle
   (``obs/blackbox.py``) — all None-disarmed, byte-identical off.
-- :mod:`bench` — the ``bench.py bench_serving`` offered-load sweep and
-  overload A/B (virtual clock; ``emit_info`` lines only, never
-  perf-gated).
 
 Everything runs on an injectable clock (``resilience/retry.py``'s module
 clock by default), so whole serve runs — latency percentiles included —

@@ -10,11 +10,11 @@ Design constraints (ISSUE 6):
 - **Deterministic** — nothing here reads a wall clock. Every timestamp
   comes from the caller (the engine's injectable clock), so two serving
   runs with the same traffic seed and a ``FakeClock`` produce *identical*
-  snapshots — asserted in tests and by ``bench.py bench_serving``.
-- **Never gated** — bench emission goes through ``emit_info``-style lines
-  (no ``vs_baseline`` key), so ``scripts/perf_gate.sh`` structurally
-  cannot gate on them (its parser only collects vs_baseline-bearing
-  lines).
+  snapshots — asserted in tests (``tests/test_serving.py``).
+- **Counts, not speeds** — on a ``FakeClock`` every time here is a count
+  of engine steps, never a device time: nothing gates on it, and what
+  the chip does is measured by ``perfbench/run.py`` (``PERF.md``), which
+  reads the same snapshot surface under the real clock.
 
 Percentiles are read from the bins: ``percentile(p)`` returns the upper
 edge of the first bin whose cumulative count reaches ``p`` — a
