@@ -298,8 +298,7 @@ def reference_logits(cfg, params, tokens, prompt_lens, n_new: int):
         x = params["embed"][tokens].astype(jnp.float32)          # [b, T, H]
         for p in params["layers"]:
             h = norm(x, p["attn_norm"])
-            # wqkv is kv-group-major: [H, n_kv, (g q heads | k | v) * d]
-            qkv = mm(h, p["wqkv"].reshape(c.hidden, -1)).astype(c.dtype)
+            qkv = mm(h, p["wqkv"]).astype(c.dtype)   # kv-group-major columns
             qkv = qkv.reshape(b, t, c.n_kv_heads, g + 2, d)
             q = rope(qkv[..., :g, :].reshape(b, t, c.n_q_heads, d), pos)
             k = rope(qkv[..., g, :], pos)

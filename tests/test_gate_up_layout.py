@@ -1,7 +1,8 @@
-"""The gate/up weight is stored as the plain ``[H, 2F]`` matrix its GEMM
-contracts (``pack_gate_up``): no user reshapes, transposes or copies it,
-and the batcher re-lays the old public ``[H, F, 2]`` layout once, at the
-door (``ContinuousBatcher.params``)."""
+"""A weight is stored as the plain matrix its GEMM contracts (``w_gate_up``
+``[H, 2F]``, ``pack_gate_up``; ``wqkv`` ``[H, n_kv*(g+2)*d]``): no user
+reshapes, transposes or copies it, and the batcher re-lays the old public
+3-D layouts (``[H, F, 2]``, ``[H, n_kv, (g+2)*d]``) once, at the door
+(``ContinuousBatcher.params``)."""
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,7 @@ from triton_dist_tpu.models.decode import (
     ContinuousBatcher,
     KVCacheSpec,
     Request,
+    prefill_cache_ranged,
 )
 from triton_dist_tpu.obs import ObsConfig
 from triton_dist_tpu.ops.allgather_gemm import AGGemmConfig
@@ -76,17 +78,21 @@ def _leaf_consumers(jaxpr, var) -> list:
     return found
 
 
-def _decode_case(n):
+def _decode_case(n, step=decode_step, tok_shape=(2,)):
     cfg = _cfg(1024)
     spec = KVCacheSpec(16)
     cache = spec.init(cfg, n, 1)
 
     def fn(params, cache, tok):
-        return decode_step(cfg, params, cache, tok, jnp.int32(3), spec=spec)
+        return step(cfg, params, cache, tok, jnp.int32(3), spec=spec)
 
-    in_specs = (param_specs(cfg), spec.specs(cfg), P(None))
+    in_specs = (param_specs(cfg), spec.specs(cfg), P(*[None] * len(tok_shape)))
     out_specs = (P(None, None), spec.specs(cfg))
-    return cfg, fn, in_specs, out_specs, (cache, jnp.zeros(2, jnp.int32))
+    return cfg, fn, in_specs, out_specs, (cache, jnp.zeros(tok_shape, jnp.int32))
+
+
+def _ranged_case(n):
+    return _decode_case(n, prefill_cache_ranged, (2, 4))
 
 
 def _tp_case(n):
@@ -111,11 +117,17 @@ def _sp_case(n):
     return cfg, fn, (rep, P(None, "tp", None)), P(None, "tp", None), (x,)
 
 
-@pytest.mark.parametrize("case", [_decode_case, _tp_case, _sp_case],
-                         ids=["decode_step", "tp_block", "sp_block"])
-def test_weight_is_read_by_its_contraction_alone(case):
+_CASES = {"decode_step": _decode_case, "tp_block": _tp_case,
+          "sp_block": _sp_case, "prefill_cache_ranged": _ranged_case}
+
+
+@pytest.mark.parametrize("case,leaf", [
+    *[(c, "w_gate_up") for c in ("decode_step", "tp_block", "sp_block")],
+    *[(c, "wqkv") for c in _CASES],
+])
+def test_weight_is_read_by_its_contraction_alone(case, leaf):
     mesh = _mesh(2)
-    cfg, fn, in_specs, out_specs, rest = case(mesh.size)
+    cfg, fn, in_specs, out_specs, rest = _CASES[case](mesh.size)
     params = init_params(jax.random.PRNGKey(0), cfg)
     mapped = jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
@@ -123,11 +135,11 @@ def test_weight_is_read_by_its_contraction_alone(case):
     closed = jax.make_jaxpr(mapped)(params, *rest)
     leaves, _ = jax.tree.flatten_with_path((params, *rest))
     at = [i for i, (path, _) in enumerate(leaves)
-          if any(getattr(k, "key", None) == "w_gate_up" for k in path)]
+          if any(getattr(k, "key", None) == leaf for k in path)]
     assert at
     used = [_leaf_consumers(closed.jaxpr, closed.jaxpr.invars[i]) for i in at]
     used = [u for u in used if u]       # a block reads one layer's leaf
-    assert used, "no layer's w_gate_up reached an equation"
+    assert used, f"no layer's {leaf} reached an equation"
     for u in used:
         assert len(u) == 1 and u[0] in ("dot_general", "pallas_call"), u
 
@@ -164,12 +176,19 @@ def test_shards_that_split_a_block_raise():
 
 # -- the door ------------------------------------------------------------------
 
-def _old_layout(params: dict, cfg) -> dict:
-    """The public layout before PR 27: gate/up interleaved per unit."""
-    layers = [
-        dict(p, w_gate_up=jnp.stack(unpack_gate_up(p["w_gate_up"], cfg), -1))
-        for p in params["layers"]
-    ]
+# leaf -> (the public layout before it was stored as its GEMM reads it
+# (PR 27, PR 31), its two counters on ``tdt.batcher.take_params``)
+_OLD = {
+    "w_gate_up": (lambda w, cfg: jnp.stack(unpack_gate_up(w, cfg), -1),
+                  ("relaid", "bytes")),
+    "wqkv": (lambda w, cfg: w.reshape(cfg.hidden, cfg.n_kv_heads, -1),
+             ("relaid_wqkv", "bytes_wqkv")),
+}
+
+
+def _old_layout(params: dict, cfg, leaf: str) -> dict:
+    layers = [dict(p, **{leaf: _OLD[leaf][0](p[leaf], cfg)})
+              for p in params["layers"]]
     return dict(params, layers=layers)
 
 
@@ -193,20 +212,26 @@ def ring():
     obs.reset()
 
 
-def _intakes() -> list:
-    return [(s.attrs["relaid"], s.attrs["bytes"]) for s in obs.spans()
+def _intakes(leaf: str) -> list:
+    return [tuple(s.attrs[k] for k in _OLD[leaf][1]) for s in obs.spans()
             if s.name == "tdt.batcher.take_params"]
 
 
+def _buffers(x) -> list:
+    return [s.data.unsafe_buffer_pointer() for s in x.addressable_shards]
+
+
 @pytest.mark.parametrize("n", [1, 2])
-@pytest.mark.parametrize("ffn", WIDTHS)
-def test_door_relays_the_old_layout_once(ring, ffn, n):
+@pytest.mark.parametrize(
+    "leaf,ffn", [*[("w_gate_up", f) for f in WIDTHS], ("wqkv", 64)])
+def test_door_relays_the_old_layout_once(ring, leaf, ffn, n):
     cfg = _cfg(ffn, n_layers=1)     # a leaf is re-laid a layer: one shows it
     mesh = _mesh(n)
-    born = init_params(jax.random.PRNGKey(0), cfg)
-    old = _old_layout(born, cfg)
-    assert old["layers"][0]["w_gate_up"].shape == (cfg.hidden, ffn, 2)
-    leaf_bytes = cfg.hidden * 2 * ffn * 4
+    born = init_params(jax.random.PRNGKey(0), cfg, mesh)
+    old = _old_layout(born, cfg, leaf)
+    stored = born["layers"][0][leaf]
+    assert stored.ndim == 2 and old["layers"][0][leaf].ndim == 3
+    leaf_bytes = stored.nbytes
 
     def batcher(tree):
         return ContinuousBatcher(cfg, tree, mesh, s_max=16, prefill=True)
@@ -215,13 +240,18 @@ def test_door_relays_the_old_layout_once(ring, ffn, n):
     # the reseed path: other weights first, the real ones assigned after
     assigned = batcher(init_params(jax.random.PRNGKey(9), cfg))
     assigned.params = old
-    assert _intakes() == [
+    assert _intakes(leaf) == [
         (cfg.n_layers, cfg.n_layers * leaf_bytes), (0, 0), (0, 0),
         (cfg.n_layers, cfg.n_layers * leaf_bytes),
     ]
+    other, = set(_OLD) - {leaf}     # the leaf that arrived as stored
+    assert _intakes(other) == [(0, 0)] * 4
+    # a tree born in the stored layout passes untouched: the same buffers
+    assert _buffers(from_born.params["layers"][0][leaf]) == _buffers(stored)
     for b in (from_old, assigned):
         for p, q in zip(b.params["layers"], born["layers"]):
-            np.testing.assert_array_equal(p["w_gate_up"], q["w_gate_up"])
+            assert p[leaf].sharding.is_equivalent_to(q[leaf].sharding, 2)
+            np.testing.assert_array_equal(p[leaf], q[leaf])
     want = _serve(from_born)
     assert len(want) == 2
     assert _serve(from_old) == want

@@ -45,7 +45,7 @@ def _ref_forward(tokens, params, cfg):
     for p in params["layers"]:
         h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
         # kv-group-major qkv layout (see init_params)
-        qkv = (h @ p["wqkv"].reshape(cfg.hidden, -1)).reshape(
+        qkv = (h @ p["wqkv"]).reshape(
             b, s, cfg.n_kv_heads, g + 2, d
         )
         q = qkv[..., :g, :].reshape(b, s, cfg.n_q_heads, d)
@@ -179,7 +179,7 @@ def _moe_ref_forward(tokens, params, cfg):
     p = params["layers"][0]
     b, s, g, d = cfg.batch, cfg.seq, cfg.n_q_heads // cfg.n_kv_heads, cfg.head_dim
     h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-    qkv = (h @ p["wqkv"].reshape(cfg.hidden, -1)).reshape(b, s, cfg.n_kv_heads, g + 2, d)
+    qkv = (h @ p["wqkv"]).reshape(b, s, cfg.n_kv_heads, g + 2, d)
     q = qkv[..., :g, :].reshape(b, s, cfg.n_q_heads, d)
     k, v = qkv[..., g, :], qkv[..., g + 1, :]
     pos = jnp.arange(s, dtype=jnp.int32)
@@ -449,7 +449,7 @@ def _moe_dense_forward(tokens, params, cfg):
     p = params["layers"][0]
     b, s, g, d = cfg.batch, cfg.seq, cfg.n_q_heads // cfg.n_kv_heads, cfg.head_dim
     h = rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-    qkv = (h @ p["wqkv"].reshape(cfg.hidden, -1)).reshape(b, s, cfg.n_kv_heads, g + 2, d)
+    qkv = (h @ p["wqkv"]).reshape(b, s, cfg.n_kv_heads, g + 2, d)
     q = qkv[..., :g, :].reshape(b, s, cfg.n_q_heads, d)
     k, v = qkv[..., g, :], qkv[..., g + 1, :]
     pos = jnp.arange(s, dtype=jnp.int32)

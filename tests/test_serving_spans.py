@@ -41,7 +41,8 @@ TABLE = {
     "tdt.engine.admit": ("tdt.engine.step", {"admitted"}),
     "tdt.engine.observe": ("tdt.engine.step", {"first_tokens", "finished"}),
     "tdt.engine.rebuild": (None, {"reason", "replayed"}),
-    "tdt.batcher.take_params": ("tdt.engine.rebuild", {"relaid", "bytes"}),
+    "tdt.batcher.take_params": ("tdt.engine.rebuild", {
+        "relaid", "bytes", "relaid_wqkv", "bytes_wqkv"}),
     "tdt.batcher.admit": ("tdt.engine.step", {"queued", "admitted"}),
     "tdt.batcher.admit_prefill": (
         "tdt.batcher.admit", {"uid", "slot", "prompt_len", "bucket"}),

@@ -603,7 +603,7 @@ def decode_step(
     for li, p in enumerate(params["layers"]):
         # --- attention (SP flash decode over the sharded cache) ---
         h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv_loc = h @ p["wqkv"].reshape(c.hidden, -1)      # [b, qkv/n] local
+        qkv_loc = h @ p["wqkv"]                            # [b, qkv/n] local
         # head-complete qkv: PE-major concat == kv-group-major (the groups
         # are sharded contiguously), so a tiled all_gather restores the
         # global group order
@@ -1175,33 +1175,37 @@ class ContinuousBatcher:
         """The ONE place the batcher takes parameters (construction, and a
         later ``batcher.params = tree`` under the same compiled programs):
         every leaf placed in its ``specs_for`` sharding, and a ``w_gate_up``
-        that arrives in the old public ``[H, F, 2]`` layout re-laid ONCE
-        into the stored one (``pack_gate_up``), shard by shard, a layer at
-        a time. A tree born in the program's layout passes untouched."""
+        or ``wqkv`` that arrives in its old public 3-D layout (``[H, F, 2]``,
+        ``[H, n_kv, (g+2)*d]``) re-laid ONCE into the stored matrix, shard
+        by shard, a layer at a time. A tree born in the program's layout
+        passes untouched."""
         cfg, mesh = self.cfg, self.mesh
-
-        def _relay_gate_up(w):  # each PE packs its own units: no traffic
-            return pack_gate_up(w[..., 0], w[..., 1], cfg)
-
-        relay = jax.jit(jax.shard_map(
-            _relay_gate_up, mesh=mesh, in_specs=P(None, cfg.axis, None),
-            out_specs=P(None, cfg.axis),
-        ))
+        # SHIMS: only perfbench's adapter still builds the 3-D leaves; they
+        # go when it builds the stored layout (ROADMAP C14). Each PE
+        # re-lays its own units / kv groups: no traffic
+        shims = (
+            ("w_gate_up", "relaid", "bytes",
+             lambda w: pack_gate_up(w[..., 0], w[..., 1], cfg)),
+            ("wqkv", "relaid_wqkv", "bytes_wqkv",
+             lambda w: w.reshape(cfg.hidden, -1)),
+        )
         with _span("tdt.batcher.take_params") as sp:
             tree = jax.tree.map(
                 lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
                 tree, specs_for(cfg, tree),
             )
-            relaid = nbytes = 0
-            for p in tree["layers"]:
-                w = p.get("w_gate_up")      # the MoE trees carry none
-                # SHIM: only perfbench's adapter still builds [H, F, 2];
-                # goes when it calls pack_gate_up (ROADMAP C14)
-                if w is not None and w.ndim == 3:
-                    p["w_gate_up"] = relay(w)
-                    relaid, nbytes = relaid + 1, nbytes + w.nbytes
-            sp.set("relaid", relaid)
-            sp.set("bytes", nbytes)
+            for name, n_relaid, n_bytes, fn in shims:
+                relay = jax.jit(jax.shard_map(
+                    fn, mesh=mesh, in_specs=P(None, cfg.axis, None),
+                    out_specs=P(None, cfg.axis),
+                ))
+                # the MoE trees carry no w_gate_up
+                old = [p for p in tree["layers"]
+                       if name in p and p[name].ndim == 3]
+                sp.set(n_relaid, len(old))
+                sp.set(n_bytes, sum(p[name].nbytes for p in old))
+                for p in old:
+                    p[name] = relay(p[name])
             for name, value in cfg.param_bytes(tree).items():
                 sp.set(name, value)
         self._params, self._epoch = tree, self._epoch + 1
@@ -2045,7 +2049,7 @@ def prefill_cache_ranged(
     x = params["embed"][tokens.reshape(-1)]                # [m, H] b-major
     for li, p in enumerate(params["layers"]):
         h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv_loc = h @ p["wqkv"].reshape(c.hidden, -1)      # [m, qkv/n]
+        qkv_loc = h @ p["wqkv"]                            # [m, qkv/n]
         qkv = jax.lax.all_gather(qkv_loc, c.axis, axis=1, tiled=True)
         qkv = qkv.reshape(m, c.n_kv_heads, g + 2, d)
         q = qkv[:, :, :g, :].reshape(m, 1, c.n_q_heads, d)

@@ -61,7 +61,7 @@ class SPTransformer:
         d = c.head_dim
 
         h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv = (h @ p["wqkv"].reshape(c.hidden, -1)).reshape(
+        qkv = (h @ p["wqkv"]).reshape(
             b, s_loc, c.n_kv_heads, g + 2, d
         )
         # GLOBAL positions for this shard's rows

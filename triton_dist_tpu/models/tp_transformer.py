@@ -94,6 +94,12 @@ class TransformerConfig:
 
 
 def _init_tree(key: jax.Array, cfg: TransformerConfig) -> dict:
+    """The parameter tree in its STORED layout. The rule for every weight
+    leaf: it is stored as the 2-D matrix its contraction reads, tiled as
+    the chip tiles a 2-D array. A reshape of a stored weight inside a step
+    is a copy of that weight, made again at every use (``w_gate_up`` as
+    ``[H, F, 2]``: a 75.2 ms decode step, 27.3 without, ledger PR 27;
+    ``wqkv`` as ``[H, n_kv, (g+2)*d]``: 13.57 ms, 11.82 without, PR 31)."""
     n_mats = cfg.n_layers * 4 + 2
     keys = iter(jax.random.split(key, n_mats))
 
@@ -110,12 +116,13 @@ def _init_tree(key: jax.Array, cfg: TransformerConfig) -> dict:
         layers.append(
             dict(
                 attn_norm=ones((h,)),
-                # QKV stored KV-GROUP-MAJOR: [H, n_kv_heads, (g+2)*d] — each
-                # group's g query heads, its K head, its V head, contiguous.
-                # Column-sharding a flat [H, q|k|v] concat would hand one PE
-                # only K columns; group-major makes every tp shard a whole
-                # set of attention groups (Megatron's interleaved QKV).
-                wqkv=w((h, cfg.n_kv_heads, (g + 2) * cfg.head_dim), h**-0.5),
+                # QKV as ONE plain matrix [H, n_kv_heads*(g+2)*d], columns
+                # KV-GROUP-MAJOR: each group's g query heads, its K head,
+                # its V head, contiguous, so a column shard is whole
+                # attention groups (Megatron's interleaved QKV; a flat
+                # q|k|v concat would hand one PE only K columns). 2-D
+                # because its GEMM reads the leaf as stored.
+                wqkv=w((h, cfg.n_kv_heads * (g + 2) * cfg.head_dim), h**-0.5),
                 # wo rows in the same group-major q-head order
                 wo=w((cfg.q_dim, h), cfg.q_dim**-0.5),
                 mlp_norm=ones((h,)),
@@ -167,11 +174,14 @@ def init_params(
 
 def param_specs(cfg: TransformerConfig) -> dict:
     """PartitionSpecs matching :func:`init_params`: column-parallel weights
-    shard dim 1, row-parallel weights shard dim 0, norms/embed replicate."""
+    shard dim 1, row-parallel weights shard dim 0, norms/embed replicate.
+    Every weight is the plain matrix its GEMM contracts (see
+    :func:`_init_tree`), so a column shard is a contiguous run of columns:
+    whole kv groups of ``wqkv``, matched gate/up blocks of ``w_gate_up``."""
     t = cfg.axis
     layer = dict(
         attn_norm=P(None),
-        wqkv=P(None, t, None),       # kv groups sharded
+        wqkv=P(None, t),             # whole kv groups a shard
         wo=P(t, None),               # row-parallel
         mlp_norm=P(None),
         w_gate_up=P(None, t),        # ffn units sharded, gate+up matched
@@ -291,7 +301,7 @@ class TPTransformer:
 
         # --- attention ---
         h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv = self._col(h, p["wqkv"].reshape(c.hidden, -1))
+        qkv = self._col(h, p["wqkv"])
         qkv = qkv.reshape(b, s, hkv_loc, g + 2, d)  # local kv groups
         q = qkv[..., :g, :].reshape(b, s, hq_loc, d)
         k = qkv[..., g, :]
