@@ -1,0 +1,11 @@
+"""Distinct HELD experts a decode step's tokens were routed to, per
+expert layer (``experts_hit`` counts the chip's share of the bank only):
+the step reads that many experts' weights. The arithmetic is
+``moe.experts_hit_per_layer``'s."""
+from harness import cells
+
+UNIT = "experts"
+
+
+def read(run):
+    return cells.load_module("metrics", "moe.experts_hit_per_layer").read(run)

@@ -1,0 +1,21 @@
+"""The window-attention / gated-expert decode step's share of its
+roofline: the least time the chip could take for the bytes and operations
+the step needs (kernels/window_moe_decode_step.py: the weights outside
+the banks, the held experts actually hit, the rows the window and the
+full layers can see; HBM bounds it at 32 slots), over the device time a
+step takes."""
+from harness import roofline
+
+UNIT = "%"
+
+
+def read(run):
+    ev = run.modules("decode_step")
+    kern = run.kernel("window_moe_decode_step")
+    if (not len(ev) or not run.kernel("expert_gemm").rounds(run)
+            or not run.kernel("window_decode").rounds(run)):
+        return None
+    floor, _ = roofline.floor_s(
+        kern.flops_per_step(run, len(ev)), kern.bytes_per_step(run, len(ev)),
+        run.peaks)
+    return 100.0 * floor / (ev.total_s() / len(ev))

@@ -93,7 +93,8 @@ def pytest_configure(config):
 # when a file grows (tests/test_docs_refs.py holds the names to files
 # that exist).
 _LONG_POLES = (
-    "test_spec_soak.py", "test_ranged_engine.py", "test_emitter.py",
+    "test_spec_soak.py", "test_ranged_engine.py", "test_window_moe.py",
+    "test_emitter.py",
     "test_mla_moe.py", "test_disagg.py", "test_ranged_batcher.py",
     "test_serving.py", "test_prefill_work.py", "test_prefix_cache_soak.py",
     "test_prefix_cache.py", "test_disagg_soak.py", "test_ranged_prefill.py",

@@ -177,6 +177,61 @@ def test_gated_expert_group_gemms_compile(one_chip, rows, block_m):
     assert text.count("tpu_custom_call") >= 2 and "group_gemm" in text
 
 
+def test_window_paged_flash_decode_compiles(one_chip):
+    """The window form of the paged decode at the published widths of the
+    benchmark's window-attention configuration: 32 slots, 64 q / 8 kv
+    heads of 128, window 128 over a ring of 2 pages a slot, four window
+    layers' pools as one run of pages."""
+    from triton_dist_tpu.ops.flash_decode import paged_flash_decode
+
+    slots, heads, ring, layers = 32, 64, 2, 4
+    pool = _struct((layers * slots * ring, N_KV, PAGE, HEAD), jnp.bfloat16,
+                   one_chip)
+    table = _struct((slots, ring), jnp.int32, one_chip)
+    q = _struct((slots, heads, HEAD), jnp.bfloat16, one_chip)
+    lens = _struct((slots,), jnp.int32, one_chip)
+    text = _compiled_text(
+        functools.partial(paged_flash_decode, window=128, interpret=False),
+        q, pool, pool, lens, table,
+    )
+    assert "tpu_custom_call" in text and "paged_flash_decode_w128" in text
+
+
+@pytest.mark.parametrize("rows,block_m", [(32, 16), (8192, 128)])
+def test_held_expert_group_gemms_compile(one_chip, rows, block_m):
+    """The routed experts' two grouped GEMMs where a chip holds 16 of 128
+    experts of 6144 x 2048 (top-8; every assignment keeps a row): one
+    whole expert matrix is 25 MB, so a tile is the widest slice of it
+    that fits (``gated_experts._tile_n``), the contraction never split."""
+    from triton_dist_tpu.models.gated_experts import _tile_n
+    from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
+    from triton_dist_tpu.utils import round_up
+
+    hidden, fe, held, topk = 6144, 2048, 16, 8
+    t = rows * topk
+    t_pad = round_up(t + min(held, t) * (block_m - 1), block_m)
+    ids = _struct((t_pad // block_m,), jnp.int32, one_chip)
+    assert (_tile_n(hidden, fe, 2), _tile_n(fe, hidden, 2)) == (256, 768)
+
+    def gemms(a, w_up, w_down, ids, valid):
+        up = GroupGemmConfig(block_m=block_m, block_n=_tile_n(hidden, fe, 2),
+                             block_k=hidden, ragged=True)
+        down = GroupGemmConfig(block_m=block_m, block_n=_tile_n(fe, hidden, 2),
+                               block_k=fe, ragged=True)
+        gu = group_gemm(a, w_up, ids, valid_rows=valid, config=up,
+                        interpret=False)
+        return group_gemm(gu[:, :fe] * gu[:, fe:], w_down, ids,
+                          valid_rows=valid, config=down, interpret=False)
+
+    text = _compiled_text(
+        gemms,
+        _struct((t_pad, hidden), jnp.bfloat16, one_chip),
+        _struct((held, hidden, 2 * fe), jnp.bfloat16, one_chip),
+        _struct((held, fe, hidden), jnp.bfloat16, one_chip), ids, ids,
+    )
+    assert text.count("tpu_custom_call") >= 2 and "group_gemm" in text
+
+
 def test_matmul_compiles(one_chip):
     from triton_dist_tpu.ops.gemm import matmul
 

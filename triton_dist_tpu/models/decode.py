@@ -41,6 +41,7 @@ from triton_dist_tpu.models.tp_transformer import (
 from triton_dist_tpu.ops.flash_decode import (
     FlashDecodeConfig,
     flash_decode_distributed,
+    paged_flash_decode,
     paged_flash_decode_distributed,
 )
 from triton_dist_tpu.obs.tracer import span as _span
@@ -251,8 +252,9 @@ class PagedKVCacheSpec:
     # every pre-cache caller built, byte for byte.
     extra_pages: int = 0
 
-    # what the pool holds: k and v of every kv head ("kv"), or one latent
-    # row a token (LatentPagedCacheSpec); a config names its family's kind
+    # what the pool holds: k and v of every kv head ("kv"), one latent row
+    # a token (LatentPagedCacheSpec), or k/v pools of two page lifetimes
+    # (WindowPagedKVCacheSpec); a config names its family's kind
     kind: ClassVar[str] = "kv"
 
     def _geometry(self, cfg, n: int, n_o: int = 1) -> tuple[int, int]:
@@ -465,9 +467,169 @@ class LatentPagedCacheSpec(PagedKVCacheSpec):
         self._refuse("speculative verify / ranged prefill")
 
 
+@dataclasses.dataclass(frozen=True)
+class WindowPagedKVCacheSpec(PagedKVCacheSpec):
+    """The paged cache's third KIND: k/v pools of TWO page lifetimes in
+    one allocator, for a model whose plan names window and full attention
+    layers (``models/window_moe.py``; ``cfg.layer_types``, ``cfg.window``).
+
+    - full layers: ``k_full, v_full [n_full_layers, n_pool, h_kv, page,
+      d]`` over ``s_max / page`` pages a slot, ``block_table``, as the k/v
+      kind's.
+    - window layers: ``k_win, v_win [n_window_layers, n_ring_pool, ...]``
+      over a RING of ``ceil(window / page) + 1`` pages a slot (never more
+      than ``s_max / page``), ``block_table_win [., b, ring]``: position
+      ``p`` lives in the slot's ring page ``(p // page) % ring`` and is
+      overwritten ``ring * page`` positions later, when no window layer
+      can see it any more.
+
+    Same discipline as the k/v kind: static tables, one stacked array a
+    tensor and kind, one index-gated scatter a layer (an index out of
+    range drops its row), the kernels read the pool where it lies through
+    a table shifted to the layer's pages. The model hands rows in
+    (:meth:`write_and_attend` a step, :meth:`write_prompt` at prefill).
+
+    A ring holds no position twice and forgets: what needs the WHOLE
+    sequence in pages that stay refuses this kind by name
+    (:func:`refuse_ring`): the prefix cache and a trie hit, ranged or
+    chunked prefill, speculative verify, the disaggregated handoff, and
+    any mesh wider than one device."""
+
+    kind: ClassVar[str] = "kv_window"
+
+    def ring(self, cfg) -> int:
+        """Pages of a slot's ring in each window layer."""
+        return min(-(-cfg.window // self.page_size) + 1,
+                   self.s_max // self.page_size)
+
+    def init(self, cfg, n: int, n_o: int = 1) -> dict:
+        if not self.static_table:
+            raise NotImplementedError(
+                "the kv_window cache kind needs static_table=True")
+        if n != 1 or n_o != 1:
+            raise NotImplementedError(
+                f"the kv_window cache kind lives on a one-device shard "
+                f"(got {n_o} x {n} devices): a ring is not sharded over "
+                f"sequence or heads yet")
+        if self.extra_pages:
+            refuse_ring("the prefix cache (extra_pages: its scratch page)")
+        n_pool, bt = self._table(cfg, n, n_o)
+        ring, b = self.ring(cfg), cfg.batch
+        bt_win = (jnp.arange(b, dtype=jnp.int32)[:, None] * ring
+                  + jnp.arange(ring, dtype=jnp.int32)[None, :])[None]
+        kinds = cfg.layer_types
+        pool = lambda layers, pages: jnp.zeros(
+            (layers, pages, cfg.n_kv_heads, self.page_size, cfg.head_dim),
+            cfg.dtype)
+        n_full, n_win = kinds.count("full"), kinds.count("window")
+        return dict(
+            k_full=pool(n_full, n_pool), v_full=pool(n_full, n_pool),
+            k_win=pool(n_win, b * ring), v_win=pool(n_win, b * ring),
+            block_table=bt, block_table_win=bt_win,
+            n_alloc=jnp.zeros((bt.shape[0],), jnp.int32),
+        )
+
+    def specs(self, cfg) -> dict:
+        kv = PagedKVCacheSpec.specs(self, cfg)
+        return dict(
+            k_full=kv["k"], v_full=kv["v"], k_win=kv["k"], v_win=kv["v"],
+            block_table=kv["block_table"],
+            block_table_win=kv["block_table"], n_alloc=kv["n_alloc"])
+
+    def _pool_of(self, kind: str) -> tuple[str, str, str]:
+        """``(k pool, v pool, table)`` names of an attention kind."""
+        return (("k_win", "v_win", "block_table_win") if kind == "window"
+                else ("k_full", "v_full", "block_table"))
+
+    def write_and_attend(self, cfg, cache, kind: str, ki: int, k_new, v_new,
+                         q, pos_b, interpret):
+        """One step of the ``ki``-th layer of its attention ``kind``: each
+        slot's new row lands in its page (a full layer's page of the
+        position, a window layer's ring page), then the kernel reads
+        ``[0, pos]`` or the window's ``[pos - window + 1, pos]`` out of
+        the kind's whole pool. ``(attn [b, hq, d] f32, cache)``."""
+        kn, vn, tn = self._pool_of(kind)
+        bt = cache[tn][0]                                  # [b, pages a slot]
+        n_pool = cache[kn].shape[1]
+        col = pos_b // self.page_size
+        if kind == "window":
+            col = col % bt.shape[1]
+        page_ids = bt[jnp.arange(bt.shape[0]),
+                      jnp.minimum(col, bt.shape[1] - 1)]
+        # a slot at s_max owns no page: its write drops
+        safe_ids = jnp.where(pos_b < self.s_max, page_ids, n_pool)
+        slot = pos_b % self.page_size
+        cache = dict(
+            cache,
+            **{kn: _write_rows(cache[kn], ki, safe_ids, slot, k_new),
+               vn: _write_rows(cache[vn], ki, safe_ids, slot, v_new)})
+        attn = paged_flash_decode(
+            q.astype(cache[kn].dtype),
+            _pool_pages(cache[kn]), _pool_pages(cache[vn]),
+            jnp.clip(pos_b + 1, 0, self.s_max), bt + ki * n_pool,
+            window=cfg.window if kind == "window" else None,
+            interpret=interpret,
+        )
+        return attn, cache
+
+    def write_prompt(self, cfg, cache, kind: str, ki: int, k, v, lens,
+                     slot_mask=None):
+        """Prefill's rows ``k, v [b, L, h_kv, d]`` of the ``ki``-th layer
+        of its kind, as whole pages: a full layer's positions ``[0, L)``
+        into the slot's page range; a window layer's LAST ``ring * page``
+        true positions (``lens [b]``: padding past a prompt's end would
+        overwrite what its window still sees) at their ring addresses.
+        ``slot_mask`` gates the scatter INDICES."""
+        kn, vn, tn = self._pool_of(kind)
+        b, L = k.shape[:2]
+        ps = self.page_size
+        bt = cache[tn][0]
+        if kind == "window":
+            span = bt.shape[1] * ps
+            # ring address j holds the last position p < len with
+            # p % span == j (none yet: any row, the mask never reads it)
+            last = lens[:, None] - 1
+            src = last - (last - jnp.arange(span, dtype=jnp.int32)) % span
+            src = jnp.clip(src, 0, L - 1)[:, :, None, None]
+            k, v = (jnp.take_along_axis(x, src, axis=1) for x in (k, v))
+            ids = bt
+        else:
+            n_pages = -(-L // ps)
+            if n_pages * ps != L:
+                pad = ((0, 0), (0, n_pages * ps - L), (0, 0), (0, 0))
+                k, v = jnp.pad(k, pad), jnp.pad(v, pad)
+            ids = bt[:, :n_pages]
+        n_pool = cache[kn].shape[1]
+        if slot_mask is not None:
+            ids = jnp.where(slot_mask[:, None], ids, n_pool)   # OOB -> dropped
+        as_pages = lambda x: jnp.swapaxes(
+            x.reshape(b, -1, ps, *x.shape[2:]), 2, 3
+        ).reshape(-1, x.shape[2], ps, x.shape[3])
+        put = lambda pool, x: pool.at[ki, ids.reshape(-1)].set(
+            as_pages(x).astype(pool.dtype), mode="drop")
+        return dict(cache, **{kn: put(cache[kn], k), vn: put(cache[vn], v)})
+
+    def update_and_attend(self, *a, **kw):
+        raise NotImplementedError(
+            "the dense family's decode step reads ONE k/v pool: a "
+            "kv_window model walks its own plan (cfg.decode_step)")
+
+    def update_multi_and_attend(self, *a, **kw):
+        refuse_ring("speculative verify / ranged prefill")
+
+
+def refuse_ring(what: str):
+    raise NotImplementedError(
+        f"{what} is not built for the kv_window cache kind "
+        f"(WindowPagedKVCacheSpec): a window layer's pages are a ring that "
+        f"forgets, and it needs every position of the sequence in pages "
+        f"that stay")
+
+
 # a config's ``cache_kind`` -> the paged cache its family's passes use
 PAGED_CACHE_KINDS = {
-    spec.kind: spec for spec in (PagedKVCacheSpec, LatentPagedCacheSpec)}
+    spec.kind: spec for spec in (
+        PagedKVCacheSpec, LatentPagedCacheSpec, WindowPagedKVCacheSpec)}
 
 
 def _decode_mlp(c, x, p, me, n, n_o, interpret):
@@ -932,7 +1094,9 @@ class ContinuousBatcher:
         # what the model's family declares: counters its programs return
         # beside their logits, and the kind of paged cache they read
         self._counters = cfg.pass_counters
-        if cfg.cache_kind == "latent":
+        if cfg.cache_kind != "kv":
+            # the kinds a layer-plan family brings: one-device, paged,
+            # whole-prompt admission
             refused = [
                 name for name, on in (
                     ("prefix_cache", prefix_cache is not None),
@@ -945,8 +1109,9 @@ class ContinuousBatcher:
             ]
             if refused:
                 raise NotImplementedError(
-                    f"the latent cache kind (LatentPagedCacheSpec) does not "
-                    f"support: {', '.join(refused)}")
+                    f"the {cfg.cache_kind} cache kind "
+                    f"({PAGED_CACHE_KINDS[cfg.cache_kind].__name__}) does "
+                    f"not support: {', '.join(refused)}")
         if prefix_cache is not None:
             prefix_cache.validate()
             if not page_size:

@@ -1,0 +1,181 @@
+"""The gated-expert MLP that the layer-plan families share
+(``models/mla_moe.py``: latent attention; ``models/window_moe.py``: window
+and full GQA): ONE place for the router, the routed experts through the
+grouped GEMM, the shared expert, the leading dense SwiGLU and the routing
+counters. A family's config ``c`` brings ``hidden``, ``topk``,
+``routed_scaling``, ``expert_ffn``, ``n_shared_experts`` and ``held``
+(first expert, count held here); where the norm and the residual go is
+the family's own.
+
+- ``s = sigmoid(x W_r)``; chosen = top-k of ``s + b``;
+  ``w = s[chosen] / sum(s[chosen]) * routed_scaling``;
+  ``y = sum_k w_k E_k(x) + E_shared(x)``, each ``E`` a SwiGLU. No token is
+  dropped, there is no capacity. The routed part runs as two grouped GEMMs
+  (``ops/group_gemm.py``) over the assignments sorted by expert
+  (``ops/moe_utils.moe_align_block_size``), at decode and at prefill
+  alike: only experts that were hit are read.
+- ``held = (first, count)`` is the chip's share of the bank: the router
+  still scores every expert, the layer computes the part of the result its
+  own experts give, and nothing stands in for the others. The share that
+  holds the bank's first expert adds the shared expert (one share of a
+  layer does).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+
+from triton_dist_tpu.models.tp_transformer import unpack_gate_up
+from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
+from triton_dist_tpu.ops.moe_utils import (
+    gather_sorted_rows, moe_align_block_size, scatter_add_unsorted,
+    select_experts,
+)
+from triton_dist_tpu.utils import axis_size as _axis_size
+
+# counters a pass returns, summed over its expert layers (docs/observability.md)
+MOE_STATS = ("experts_hit", "assignments", "expert_load_max")
+# scope names that survive into the device trace's op names
+EXPERT_SCOPE = "moe_experts"
+# rows per grouped-GEMM block: small at decode, where a step's assignments
+# spread over more experts than there are rows (chip, PR 28: 16-row blocks
+# over the min(E, T) alignment 1.376 ms a layer, 32-row 1.410, 8-row 1.363)
+DECODE_BLOCK_M = 16
+PREFILL_BLOCK_M = 128
+# the most one tile of an expert's matrix may take of VMEM (it is double
+# buffered; the scoped limit is 16 MiB)
+EXPERT_TILE_BYTES = 4 * 2**20
+
+
+def expert_bytes(params: dict) -> int:
+    """Bytes of the routed expert banks in a parameter tree."""
+    return sum(p[k].nbytes for p in params["layers"]
+               for k in ("we_gate_up", "we_down") if k in p)
+
+
+def require_one_shard(cfg, family: str) -> None:
+    n = _axis_size(cfg.axis)
+    if n != 1:
+        raise NotImplementedError(
+            f"the {family} model serves on a one-device shard: axis "
+            f"{cfg.axis!r} has {n} devices and the expert exchange across "
+            f"chips is not built")
+
+
+def swiglu(x, w_gate_up, w_down):
+    """SwiGLU with gate | up stored as contiguous halves."""
+    gu = x @ w_gate_up
+    f = gu.shape[-1] // 2
+    act = jax.nn.silu(gu[:, :f].astype(jnp.float32)).astype(x.dtype) * gu[:, f:]
+    return act @ w_down
+
+
+def dense_mlp(c, h, p):
+    gate, up = unpack_gate_up(h @ p["w_gate_up"], c)
+    act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+    return act @ p["w_down"]
+
+
+def route(c, h, p):
+    """``(weights [m, topk] f32, ids [m, topk] int32)`` over the WHOLE
+    bank, whatever share of it is held here."""
+    logits = h.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    return select_experts(
+        logits, c.topk, scoring="sigmoid", bias=p["router_bias"],
+        scale=c.routed_scaling,
+    )
+
+
+def _tile_n(k_dim: int, n_dim: int, itemsize: int) -> int:
+    """Columns of one B tile ``[k_dim, .]`` of an expert's matrix: all
+    ``n_dim`` of them where that fits ``EXPERT_TILE_BYTES`` (one tile = one
+    expert's whole matrix), else halved until it does. The contraction is
+    never split: consecutive blocks of one expert then re-use the tile in
+    place, so an expert's weights stream once per GEMM however many blocks
+    it fills (and a share's dead blocks, :func:`_align_share`, re-use the
+    last held expert's tile where it lies)."""
+    bn = n_dim
+    while k_dim * bn * itemsize > EXPERT_TILE_BYTES and bn % 256 == 0:
+        bn //= 2
+    return bn
+
+
+def _align_share(local, here, n_held: int, block_m: int):
+    """The alignment of a SHARE's assignments: those to experts held
+    elsewhere sort LAST, as a group of their own, into blocks of no valid
+    row under the last held expert's name: the ragged kernels spend no MXU
+    time on them, write zeros, and fetch no tile (the name is the block
+    before's). Only their grid steps are left, until the rows that landed
+    are all the rows there are (ROADMAP B1)."""
+    al = moe_align_block_size(
+        jnp.where(here, local, n_held).reshape(-1), n_held + 1, block_m,
+        ragged=True)
+    away = al.expert_ids == n_held
+    return dataclasses.replace(
+        al, expert_ids=jnp.minimum(al.expert_ids, n_held - 1),
+        valid_rows=jnp.where(away, 0, al.valid_rows))
+
+
+def routing_stats(local_ids, here, n_held: int) -> jax.Array:
+    """``[experts hit, assignments, largest count on one expert]`` int32 of
+    one layer's routing, over the experts held here."""
+    counts = jnp.zeros((n_held,), jnp.int32).at[local_ids.reshape(-1)].add(
+        here.reshape(-1).astype(jnp.int32))
+    return jnp.stack([jnp.sum(counts > 0), jnp.sum(counts),
+                      jnp.max(counts)]).astype(jnp.int32)
+
+
+def add_stats(stats, st):
+    """A pass's counters with one more layer's: hit and assignments add
+    over layers; the load is the largest seen."""
+    return jnp.stack([stats[0] + st[0], stats[1] + st[1],
+                      jnp.maximum(stats[2], st[2])])
+
+
+def moe_mlp(c, h, p, block_m: int, interpret=None):
+    """Routed experts (the share held here) + the shared expert on rows
+    ``h [m, H]``: ``(y [m, H], stats int32[3])``."""
+    m = h.shape[0]
+    first, n_held = c.held
+    w, ids = route(c, h, p)
+    local = ids - first
+    here = (local >= 0) & (local < n_held)
+    # an assignment to an expert held elsewhere keeps its row (shapes are
+    # static) with weight 0: its part of the result is that other chip's
+    # to add
+    local = jnp.where(here, local, 0)
+    w = jnp.where(here, w, 0.0)
+    if n_held == c.n_experts:
+        al = moe_align_block_size(
+            local.reshape(-1), n_held, block_m, ragged=True)
+    else:
+        al = _align_share(local, here, n_held, block_m)
+    # one B tile = one expert's whole gate (or up, or down) matrix where
+    # VMEM has the room (_tile_n): an expert's weights stream once per
+    # GEMM however many blocks it fills
+    fe = c.expert_ffn
+    size = p["we_gate_up"].dtype.itemsize
+    gg_up = GroupGemmConfig(
+        block_m=block_m, block_n=_tile_n(c.hidden, fe, size),
+        block_k=c.hidden, ragged=True)
+    gg_down = GroupGemmConfig(
+        block_m=block_m, block_n=_tile_n(fe, c.hidden, size), block_k=fe,
+        ragged=True)
+    with jax.named_scope(EXPERT_SCOPE):
+        a = gather_sorted_rows(h, al, c.topk)
+        gu = group_gemm(a, p["we_gate_up"], al.expert_ids,
+                        valid_rows=al.valid_rows, config=gg_up,
+                        interpret=interpret)
+        act = (jax.nn.silu(gu[:, :fe].astype(jnp.float32)).astype(h.dtype)
+               * gu[:, fe:])
+        y = group_gemm(act, p["we_down"], al.expert_ids,
+                       valid_rows=al.valid_rows, config=gg_down,
+                       interpret=interpret)
+        out = scatter_add_unsorted(y, al, w, m)             # f32
+    if first == 0 and c.n_shared_experts:
+        out = out + swiglu(h, p["ws_gate_up"], p["ws_down"]).astype(
+            jnp.float32)
+    return out.astype(h.dtype), routing_stats(local, here, n_held)

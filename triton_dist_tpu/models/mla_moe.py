@@ -3,11 +3,15 @@ DeepSeek-V3 family's block, served through the same batcher, block table
 and spans as the dense decoder.
 
 A model here is a LAYER PLAN (:func:`layer_plan`): the kind of each layer,
-in what varies between layers. This family attends the same way (latent
-attention) over the same cache kind (``cache_kind = "latent"``) in every
-layer, so its plan names the MLP: ``dense`` (SwiGLU) in the first
-``first_k_dense`` layers, ``moe`` (router + routed experts + shared
-expert) after. Parameters, their specs, prefill and the decode step all
+in what varies between layers. A plan can name two things a layer, the
+ATTENTION kind and the MLP kind (``models/window_moe.py`` names both:
+window or full attention, dense or expert MLP). THIS family attends the
+same way (latent attention) over the same cache kind (``cache_kind =
+"latent"``) in every layer, so its plan names the one thing that varies
+here, the MLP: ``dense`` (SwiGLU) in the first ``first_k_dense`` layers,
+``moe`` (router + routed experts + shared expert) after; the gated-expert
+MLP itself is ``models/gated_experts.py``, shared by both plan families.
+Parameters, their specs, prefill and the decode step all
 walk the plan, so a layer is no longer "the" layer. The config answers for
 its family (``own_passes``: ``param_specs`` / ``decode_step`` /
 ``prefill_cache`` / ``pass_counters`` / ``param_bytes``), so the shared
@@ -50,27 +54,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from triton_dist_tpu.models.tp_transformer import (
-    TransformerConfig, rmsnorm, unpack_gate_up,
+from triton_dist_tpu.models.gated_experts import (  # noqa: F401  (the
+    # family's names for what both plan families share)
+    DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, dense_mlp,
+    expert_bytes, moe_mlp, require_one_shard, route, routing_stats,
 )
-from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
+from triton_dist_tpu.models.tp_transformer import TransformerConfig, rmsnorm
 from triton_dist_tpu.ops.mla_decode import latent_row, mla_paged_decode
-from triton_dist_tpu.ops.moe_utils import (
-    gather_sorted_rows, moe_align_block_size, scatter_add_unsorted,
-    select_experts,
-)
-from triton_dist_tpu.utils import axis_size as _axis_size
-
-# counters a pass returns, summed over its expert layers (docs/observability.md)
-MOE_STATS = ("experts_hit", "assignments", "expert_load_max")
-# scope names that survive into the device trace's op names
-EXPERT_SCOPE = "moe_experts"
-# rows per grouped-GEMM block: small at decode, where a step's assignments
-# spread over more experts than there are rows (chip, PR 28: 16-row blocks
-# over the min(E, T) alignment 1.376 ms a layer, 32-row 1.410, 8-row 1.363)
-DECODE_BLOCK_M = 16
-PREFILL_BLOCK_M = 128
-
 
 @dataclasses.dataclass(frozen=True)
 class MLAMoEConfig(TransformerConfig):
@@ -216,12 +206,6 @@ def init_mla_moe_params(key: jax.Array, cfg: MLAMoEConfig) -> dict:
     )
 
 
-def expert_bytes(params: dict) -> int:
-    """Bytes of the routed expert banks in a parameter tree."""
-    return sum(p[k].nbytes for p in params["layers"]
-               for k in ("we_gate_up", "we_down") if k in p)
-
-
 # -- the block's pieces --------------------------------------------------------
 
 def rope_pairs(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -303,96 +287,12 @@ def mla_attend_absorbed(
     return o.reshape(b, c.n_q_heads * c.v_head_dim)
 
 
-def _swiglu(x, w_gate_up, w_down):
-    """SwiGLU with gate | up stored as contiguous halves."""
-    gu = x @ w_gate_up
-    f = gu.shape[-1] // 2
-    act = jax.nn.silu(gu[:, :f].astype(jnp.float32)).astype(x.dtype) * gu[:, f:]
-    return act @ w_down
-
-
-def dense_mlp(c: MLAMoEConfig, h, p):
-    gate, up = unpack_gate_up(h @ p["w_gate_up"], c)
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
-    return act @ p["w_down"]
-
-
-def route(c: MLAMoEConfig, h, p):
-    """``(weights [m, topk] f32, ids [m, topk] int32)`` over the WHOLE
-    bank, whatever share of it is held here."""
-    logits = h.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    return select_experts(
-        logits, c.topk, scoring="sigmoid", bias=p["router_bias"],
-        scale=c.routed_scaling,
-    )
-
-
-def routing_stats(local_ids, here, n_held: int) -> jax.Array:
-    """``[experts hit, assignments, largest count on one expert]`` int32 of
-    one layer's routing, over the experts held here."""
-    counts = jnp.zeros((n_held,), jnp.int32).at[local_ids.reshape(-1)].add(
-        here.reshape(-1).astype(jnp.int32))
-    return jnp.stack([jnp.sum(counts > 0), jnp.sum(counts),
-                      jnp.max(counts)]).astype(jnp.int32)
-
-
-def moe_mlp(c: MLAMoEConfig, h, p, block_m: int, interpret=None):
-    """Routed experts (the share held here) + the shared expert on rows
-    ``h [m, H]``: ``(y [m, H], stats int32[3])``."""
-    m = h.shape[0]
-    first, n_held = c.held
-    w, ids = route(c, h, p)
-    local = ids - first
-    here = (local >= 0) & (local < n_held)
-    # an assignment to an expert held elsewhere keeps its row (shapes are
-    # static) under a held expert with weight 0: its part of the result is
-    # that other chip's to add
-    local = jnp.where(here, local, 0)
-    w = jnp.where(here, w, 0.0)
-    al = moe_align_block_size(
-        local.reshape(-1), n_held, block_m, ragged=True)
-    # one B tile = one expert's whole gate (or up, or down) matrix: an
-    # expert's weights stream once per GEMM however many blocks it fills
-    fe = c.expert_ffn
-    gg_up = GroupGemmConfig(
-        block_m=block_m, block_n=fe, block_k=c.hidden, ragged=True)
-    gg_down = GroupGemmConfig(
-        block_m=block_m, block_n=c.hidden, block_k=fe, ragged=True)
-    with jax.named_scope(EXPERT_SCOPE):
-        a = gather_sorted_rows(h, al, c.topk)
-        gu = group_gemm(a, p["we_gate_up"], al.expert_ids,
-                        valid_rows=al.valid_rows, config=gg_up,
-                        interpret=interpret)
-        act = (jax.nn.silu(gu[:, :fe].astype(jnp.float32)).astype(h.dtype)
-               * gu[:, fe:])
-        y = group_gemm(act, p["we_down"], al.expert_ids,
-                       valid_rows=al.valid_rows, config=gg_down,
-                       interpret=interpret)
-        out = scatter_add_unsorted(y, al, w, m)             # f32
-    if first == 0 and c.n_shared_experts:
-        out = out + _swiglu(h, p["ws_gate_up"], p["ws_down"]).astype(
-            jnp.float32)
-    return out.astype(h.dtype), routing_stats(local, here, n_held)
-
-
 def _mlp(c, kind: str, x, p, block_m, interpret, stats):
     h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
     if kind == "dense":
         return x + dense_mlp(c, h, p), stats
     y, st = moe_mlp(c, h, p, block_m, interpret)
-    # hit and assignments add over layers; the load is the largest seen
-    stats = jnp.stack([stats[0] + st[0], stats[1] + st[1],
-                       jnp.maximum(stats[2], st[2])])
-    return x + y, stats
-
-
-def _require_one_shard(cfg) -> None:
-    n = _axis_size(cfg.axis)
-    if n != 1:
-        raise NotImplementedError(
-            f"the latent-attention / gated-expert model serves on a "
-            f"one-device shard: axis {cfg.axis!r} has {n} devices and the "
-            f"expert exchange across chips is not built")
+    return x + y, add_stats(stats, st)
 
 
 # -- the passes ------------------------------------------------------------------
@@ -434,7 +334,7 @@ def prefill_cache(cfg: MLAMoEConfig, params, cache, prompt, spec, s_max,
     the scatter INDICES, the paged discipline), and the head applied to
     the picked row of each slot only. Returns ``(cache, last [b, V],
     stats int32[3])``."""
-    _require_one_shard(cfg)
+    require_one_shard(cfg, "latent-attention / gated-expert")
     c = cfg
     b, L = c.batch, c.seq
     ps = spec.page_size
@@ -465,7 +365,7 @@ def decode_step(cfg: MLAMoEConfig, params, cache, tokens, pos, *, spec,
     """One ragged decode step (inside shard_map, one-device shard):
     ``(logits [b, V], cache, stats int32[3])``. Each slot's new latent row
     lands in its page first; the absorbed attention then reads the pool."""
-    _require_one_shard(cfg)
+    require_one_shard(cfg, "latent-attention / gated-expert")
     c = cfg
     b = c.batch
     ps, s_max = spec.page_size, spec.s_max

@@ -194,7 +194,7 @@ def _pad_head_dim(x, d_pad: int):
 
 def _online_softmax_step(
     q, k_b, v_b, ks_row, vs_row, chunk_start, kv_len, scale,
-    m_prev, l_prev, acc_prev, soft_cap=0.0,
+    m_prev, l_prev, acc_prev, soft_cap=0.0, kv_lo=None,
 ):
     """One KV-chunk update of one head's online-softmax carry; the single
     source of the decode math for the per-head AND fused-heads kernels.
@@ -210,7 +210,8 @@ def _online_softmax_step(
     Python float — the branch resolves at trace time) squashes the scaled
     scores through ``soft_cap * tanh(s / soft_cap)`` BEFORE the length
     mask, after any int8 dequant scale — the reference's logit soft-cap,
-    in the one place all five kernel paths share."""
+    in the one place all five kernel paths share. ``kv_lo`` (window
+    layers; None adds no op) also masks the positions below it."""
     if ks_row is not None:
         k_b = k_b.astype(jnp.bfloat16)
         v_b = v_b.astype(jnp.bfloat16)
@@ -222,6 +223,8 @@ def _online_softmax_step(
         s = soft_cap * jnp.tanh(s / soft_cap)
     span = chunk_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(span < kv_len, s, NEG_INF)
+    if kv_lo is not None:
+        s = jnp.where(span >= kv_lo, s, NEG_INF)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     # all-masked rows keep m_new == -inf: subtract a clamped copy so the
     # update is exp(-inf) = 0, not exp(-inf - -inf) = NaN. The verify
@@ -375,14 +378,15 @@ def flash_decode(
     )
 
 
-def _xla_decode(q, k, v, kv_lens, *, return_lse, soft_cap=0.0):
+def _xla_decode(q, k, v, kv_lens, *, return_lse, soft_cap=0.0, kv_lo=None):
     """XLA-native GQA decode (``FlashDecodeConfig(block_s=0)``): a masked
     softmax attention XLA fuses into one HBM-bound loop. f32 score/prob
     math matches the Pallas kernel's accumulation precision; the (out, lse)
     contract is identical, so the SP combine consumes either path. Takes
     any head dim natively (no tile padding) — the CPU golden for the
     kernels' non-power-of-2 head-dim padding; ``soft_cap`` applies the
-    same pre-mask logit squash as :func:`_online_softmax_step`."""
+    same pre-mask logit squash as :func:`_online_softmax_step`; ``kv_lo
+    [b]`` (window layers) also masks the positions below it."""
     b, hq, d = q.shape
     _, h_kv, s_len, _ = k.shape
     g = hq // h_kv
@@ -394,6 +398,9 @@ def _xla_decode(q, k, v, kv_lens, *, return_lse, soft_cap=0.0):
         s = soft_cap * jnp.tanh(s / soft_cap)
     span = jnp.arange(s_len, dtype=jnp.int32)
     s = jnp.where(span[None, None, None, :] < kv_lens[:, None, None, None], s, NEG_INF)
+    if kv_lo is not None:
+        s = jnp.where(
+            span[None, None, None, :] >= kv_lo[:, None, None, None], s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     m_safe = jnp.maximum(m, -1e30)  # kv_len==0 rows: avoid inf-inf
     p = jnp.exp(s - m_safe)
@@ -830,6 +837,36 @@ def _xla_paged_decode(q, k_pages, v_pages, kv_lens, block_table, *,
         q, _paged_to_contiguous(k_pages, block_table),
         _paged_to_contiguous(v_pages, block_table),
         kv_lens, return_lse=return_lse, soft_cap=soft_cap,
+    )
+
+
+def _window_pages(window: int, page_size: int, max_pages: int) -> int:
+    """Pages that can hold ``window`` consecutive positions (one more
+    than the window's own where it starts inside a page), never more
+    than the table is wide."""
+    return min(cdiv(window, page_size) + 1, max_pages)
+
+
+def _xla_paged_window_decode(q, k_pages, v_pages, kv_lens, block_table, *,
+                             window, return_lse=False, soft_cap=0.0):
+    """Golden slow path of the WINDOW form (the CPU tests' twin; on the
+    chip a fallback is a health flip): gather the pages that hold ``[kv_len
+    - window, kv_len)`` through the table (column = logical page modulo
+    the table's width) into a contiguous span that starts at the window's
+    first page, and mask by position within it."""
+    page = k_pages.shape[2]
+    max_pages = block_table.shape[1]
+    lo = jnp.maximum(kv_lens - window, 0)                      # [b]
+    first = lo // page
+    logical = first[:, None] + jnp.arange(
+        _window_pages(window, page, max_pages))[None, :]
+    table = jnp.take_along_axis(
+        block_table.astype(jnp.int32), logical % max_pages, axis=1)
+    base = first * page
+    return _xla_decode(
+        q, _paged_to_contiguous(k_pages, table),
+        _paged_to_contiguous(v_pages, table), kv_lens - base,
+        return_lse=return_lse, soft_cap=soft_cap, kv_lo=lo - base,
     )
 
 
@@ -1415,7 +1452,7 @@ def _paged_flash_decode_kernel(
     kv_lens_ref, block_table_ref, q_ref, *rest,
     n_steps: int, pages_per_step: int, page_size: int,
     scale: float, h_kv: int, chunk_dim: int, quant: bool = False,
-    soft_cap: float = 0.0,
+    soft_cap: float = 0.0, window: int | None = None,
 ):
     """Paged decode over ``pages_per_step`` pages concatenated into one
     [g, P·page] span per step (r5 chip finding: the span, not the page
@@ -1428,7 +1465,9 @@ def _paged_flash_decode_kernel(
     flash_decode.py:136,203). ``quant``: int8 page pools — 2P extra
     scale-page slots follow the data slots, concatenated into per-
     position scale rows exactly as :func:`flash_decode_quant` folds
-    them (payload DMAs at half the bytes)."""
+    them (payload DMAs at half the bytes). ``window``: the steps cover
+    the pages of ``[kv_len - window, kv_len)`` only (the index maps start
+    at that range's first page), and the positions below it are masked."""
     del block_table_ref
     P = pages_per_step
     kv_refs = rest[: 2 * P]
@@ -1436,6 +1475,14 @@ def _paged_flash_decode_kernel(
     out_ref, lse_ref, m_scr, l_scr, acc_scr = rest[(4 if quant else 2) * P :]
     c = pl.program_id(chunk_dim)
     kv_len = kv_lens_ref[pl.program_id(0)]
+    kv_lo = None if window is None else jnp.maximum(kv_len - window, 0)
+
+    def chunk_start():
+        # traced where it is used: without a window the kernel's ops are,
+        # one for one, what they were before there was one
+        if window is None:
+            return c * P * page_size
+        return (jax.lax.div(kv_lo, page_size) + c * P) * page_size
 
     @pl.when(c == 0)
     def _():
@@ -1443,9 +1490,10 @@ def _paged_flash_decode_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # clamped duplicate tail slots (logical chunk >= max_pages) sit at
-    # span positions >= max_pages*page_size >= kv_len: length-masked
-    @pl.when(c * P * page_size < kv_len)
+    # clamped duplicate tail slots (logical chunk >= max_pages; with a
+    # window, past the page of position kv_len - 1) sit at span positions
+    # >= kv_len: length-masked
+    @pl.when(chunk_start() < kv_len)
     def _():
         for j in range(h_kv):  # static unroll over the slab's heads
             k_cat = jnp.concatenate(
@@ -1465,8 +1513,8 @@ def _paged_flash_decode_kernel(
                 ks_cat = vs_cat = None
             m_scr[j], l_scr[j], acc_scr[j] = _online_softmax_step(
                 q_ref[0, j], k_cat, v_cat, ks_cat, vs_cat,
-                c * P * page_size, kv_len, scale,
-                m_scr[j], l_scr[j], acc_scr[j], soft_cap,
+                chunk_start(), kv_len, scale,
+                m_scr[j], l_scr[j], acc_scr[j], soft_cap, kv_lo,
             )
 
     @pl.when(c == n_steps - 1)
@@ -1490,6 +1538,7 @@ def paged_flash_decode(
     soft_cap: float = 0.0,
     return_lse: bool = False,
     interpret: Any = None,
+    window: int | None = None,
 ):
     """Single-device GQA batch decode over a PAGED KV cache
     (≙ the reference's paged decode, flash_decode.py:130-280: the KV cache
@@ -1530,6 +1579,17 @@ def paged_flash_decode(
     completes the serving cache matrix (contiguous/paged ×
     bf16/int8), which the reference's bf16-only paged decode lacks.
 
+    ``window``: a row attends positions ``[kv_len - window, kv_len)``
+    only, and only the pages that hold them are fetched: the grid covers
+    ``ceil(window / page) + 1`` page slots a row (never more than the
+    table is wide) and the index maps start at the range's first page.
+    ``kv_lens`` counts TRUE positions; logical page ``i`` lies in table
+    column ``i % max_pages``, so a table narrower than the sequence is a
+    RING (``models/decode.py`` ``WindowPagedKVCacheSpec``) and a
+    full-width one is read where it lies. The kernels carry the window
+    in their names (``paged_flash_decode_w128_fh``). ``None``: the
+    program is, op for op, what it was before there was a window.
+
     The bf16 pool degrades to the gather-reconstructed
     :func:`_xla_paged_decode` golden when the Pallas kernel cannot run in
     this environment (resilience layer, docs/resilience.md); int8 pools
@@ -1537,12 +1597,26 @@ def paged_flash_decode(
     """
     assert q.shape[1] % k_pages.shape[1] == 0, (q.shape, k_pages.shape)
     kv_lens = kv_lens.astype(jnp.int32)
-    if k_scales is None:
-        family = "paged_flash_decode"
-    else:
+    if window is not None:
+        if k_scales is not None:
+            raise NotImplementedError(
+                "paged_flash_decode: a window over a quantized pool is not "
+                "built")
+        if window < 1:
+            raise ValueError(f"window={window} must be >= 1")
+    if k_scales is not None:
         family = (
             "paged_flash_decode_fp8" if k_pages.dtype == FP8_KV_DTYPE
             else "paged_flash_decode_q"
+        )
+        golden = None
+    else:
+        family = "paged_flash_decode"
+        twin = _xla_paged_decode if window is None else functools.partial(
+            _xla_paged_window_decode, window=window)
+        golden = lambda: twin(
+            q, k_pages, v_pages, kv_lens, block_table,
+            return_lse=return_lse, soft_cap=soft_cap,
         )
     return resilience.guarded_call(
         family,
@@ -1550,27 +1624,27 @@ def paged_flash_decode(
             q, k_pages, v_pages, kv_lens, block_table,
             k_scales=k_scales, v_scales=v_scales, fuse_heads=fuse_heads,
             pages_per_step=pages_per_step, soft_cap=soft_cap,
-            return_lse=return_lse, interpret=interpret,
+            return_lse=return_lse, interpret=interpret, window=window,
         ),
-        None if k_scales is not None else (
-            lambda: _xla_paged_decode(
-                q, k_pages, v_pages, kv_lens, block_table,
-                return_lse=return_lse, soft_cap=soft_cap,
-            )
-        ),
+        golden,
     )
 
 
 def _paged_flash_decode_fused(
     q, k_pages, v_pages, kv_lens, block_table, *,
     k_scales, v_scales, fuse_heads, pages_per_step, soft_cap, return_lse,
-    interpret,
+    interpret, window=None,
 ):
     b, hq, d = q.shape
     n_pages, h_kv, page_size, _ = k_pages.shape
     g = hq // h_kv
     max_pages = block_table.shape[1]
     quant = k_scales is not None
+    # page slots a row's steps cover: the whole table row, or the pages
+    # that can hold a window's positions
+    n_slots = max_pages if window is None else _window_pages(
+        window, page_size, max_pages)
+    tag = "" if window is None else f"_w{window}"
     d_out = d
     scale = 1.0 / math.sqrt(d)  # the TRUE head dim, before any padding
     d = _kernel_head_dim(d)
@@ -1587,8 +1661,8 @@ def _paged_flash_decode_fused(
         d * k_pages.dtype.itemsize + (4 if quant else 0)
     )
     slab_f = h_kv * slab_h
-    p_f = _auto_pages_per_step(slab_f, page_size, max_pages)
-    p_h = _auto_pages_per_step(slab_h, page_size, max_pages)
+    p_f = _auto_pages_per_step(slab_f, page_size, n_slots)
+    p_h = _auto_pages_per_step(slab_h, page_size, n_slots)
     if fuse_heads is None:
         # span-driven choice (r5 chip finding: the per-step softmax span,
         # not the page indirection or DMA size, decides throughput): each
@@ -1632,22 +1706,33 @@ def _paged_flash_decode_fused(
         jnp.bfloat16 if quant else k_pages.dtype
     )
     cost = pl.CostEstimate(
-        flops=4 * b * hq * max_pages * page_size * d,
-        bytes_accessed=(2 * b * h_kv * max_pages * page_size)
+        flops=4 * b * hq * n_slots * page_size * d,
+        bytes_accessed=(2 * b * h_kv * n_slots * page_size)
         * (d * k_pages.dtype.itemsize + (4 if quant else 0)),
-        transcendentals=b * hq * max_pages * page_size,
+        transcendentals=b * hq * n_slots * page_size,
     )
+
+    def column(i, slot, kv_lens_ref):
+        """Table column of row ``i``'s page slot ``slot``."""
+        if window is None:
+            return jnp.minimum(slot, max_pages - 1)
+        # the window's first page + slot, held at the page of the last
+        # position (a duplicate fetch the pipeline skips, length-masked)
+        # (all three are >= 0: lax's truncating div / rem are the floor's)
+        kv_len = kv_lens_ref[i]
+        lo = jax.lax.div(jnp.maximum(kv_len - window, 0), page_size)
+        last = jax.lax.div(jnp.maximum(kv_len - 1, 0), page_size)
+        return jax.lax.rem(jnp.minimum(lo + slot, last), max_pages)
+
     if fuse_heads:
         if pages_per_step is None:
             pages_per_step = max(1, p_f)
         P = pages_per_step
-        n_steps = cdiv(max_pages, P)
+        n_steps = cdiv(n_slots, P)
 
         def kv_index_map_p(p):
             def index_map(i, c, kv_lens_ref, bt_ref):
-                return (
-                    bt_ref[i, jnp.minimum(c * P + p, max_pages - 1)], 0, 0, 0,
-                )
+                return (bt_ref[i, column(i, c * P + p, kv_lens_ref)], 0, 0, 0)
             return index_map
 
         page_spec = lambda p: pl.BlockSpec(
@@ -1679,9 +1764,10 @@ def _paged_flash_decode_fused(
                 _paged_flash_decode_kernel,
                 n_steps=n_steps, pages_per_step=P,
                 page_size=page_size, scale=scale, h_kv=h_kv, chunk_dim=1,
-                quant=quant, soft_cap=soft_cap,
+                quant=quant, soft_cap=soft_cap, window=window,
             ),
-            name="paged_flash_decode_q_fh" if quant else "paged_flash_decode_fh",
+            name=("paged_flash_decode_q_fh" if quant
+                  else f"paged_flash_decode{tag}_fh"),
             grid_spec=grid_spec,
             out_shape=(
                 jax.ShapeDtypeStruct((b, h_kv, g, d), jnp.float32),
@@ -1703,11 +1789,11 @@ def _paged_flash_decode_fused(
     if pages_per_step is None:
         pages_per_step = max(1, p_h)
     P = pages_per_step
-    n_steps = cdiv(max_pages, P)
+    n_steps = cdiv(n_slots, P)
 
     def kv_index_map_p(p):
         def index_map(i, j, c, kv_lens_ref, bt_ref):
-            return (bt_ref[i, jnp.minimum(c * P + p, max_pages - 1)], j, 0, 0)
+            return (bt_ref[i, column(i, c * P + p, kv_lens_ref)], j, 0, 0)
         return index_map
 
     page_spec = lambda p: pl.BlockSpec(
@@ -1741,9 +1827,9 @@ def _paged_flash_decode_fused(
             _paged_flash_decode_kernel,
             n_steps=n_steps, pages_per_step=P,
             page_size=page_size, scale=scale, h_kv=1, chunk_dim=2,
-            quant=quant, soft_cap=soft_cap,
+            quant=quant, soft_cap=soft_cap, window=window,
         ),
-        name="paged_flash_decode_q" if quant else "paged_flash_decode",
+        name="paged_flash_decode_q" if quant else f"paged_flash_decode{tag}",
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((b, h_kv, g, d), jnp.float32),

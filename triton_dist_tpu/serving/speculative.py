@@ -88,6 +88,7 @@ from triton_dist_tpu.models.decode import (
     _mesh_outer,
     decode_step,
     prefill_cache_ranged,
+    refuse_ring,
     specs_for,
 )
 from triton_dist_tpu.models.speculative import accept_lengths
@@ -179,6 +180,8 @@ class SpeculativeBatcher(ContinuousBatcher):
             raise NotImplementedError(
                 "speculative decoding is not built for the latent cache "
                 "kind (LatentPagedCacheSpec): its verify step reads k/v pools")
+        if cfg.cache_kind == "kv_window":
+            refuse_ring("speculative decoding (its verify step)")
         if kw.get("lookahead"):
             raise NotImplementedError(
                 "lookahead sends the plain step ahead of its round; a "
