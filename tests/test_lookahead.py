@@ -1,9 +1,12 @@
-"""``ContinuousBatcher(lookahead=True)``: a round that cannot change the
-schedule sends the next step, fed on the device, before it pulls its own
-tokens (ROADMAP A6). What must hold: the same tokens in the same rounds as
-the plain batcher, a step sent in vain thrown away, and no step sent where
-the round's tokens decide what runs next."""
+"""Lookahead, what ``ContinuousBatcher`` does unless told not to: a round
+that cannot change the schedule sends the next step, fed on the device,
+before it pulls its own tokens. What must hold: the same tokens in the same
+rounds as the plain batcher (``lookahead=False``, the reference here), a
+step sent in vain thrown away, and no step sent where the round's tokens
+decide what runs next."""
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 import pytest
@@ -16,6 +19,12 @@ from triton_dist_tpu.models.decode import ContinuousBatcher, Request
 from triton_dist_tpu.models.tp_transformer import TransformerConfig
 from triton_dist_tpu.ops.allgather_gemm import AGGemmConfig
 from triton_dist_tpu.ops.gemm_reduce_scatter import GemmRSConfig
+from triton_dist_tpu.resilience import retry
+from triton_dist_tpu.serving import ServingConfig, ServingEngine
+from triton_dist_tpu.serving.speculative import (
+    SpecDecodeConfig, SpeculativeBatcher,
+)
+from triton_dist_tpu.serving.traffic import Arrival
 
 
 @pytest.fixture(scope="module")
@@ -24,12 +33,21 @@ def mesh1() -> Mesh:
 
 
 @pytest.fixture(scope="module")
-def tiny():
+def mesh4() -> Mesh:
+    return Mesh(np.array(jax.devices()[:4]), ("tp",))
+
+
+def _tiny(n_kv_heads):
     cfg = TransformerConfig(
-        vocab=32, hidden=32, ffn=64, n_layers=1, n_q_heads=4, n_kv_heads=2,
-        head_dim=8, batch=2, seq=8,
+        vocab=32, hidden=32, ffn=64, n_layers=1, n_q_heads=4,
+        n_kv_heads=n_kv_heads, head_dim=8, batch=2, seq=8,
         ag_config=AGGemmConfig(8, 16, 16), rs_config=GemmRSConfig(8, 16, 16))
     return cfg, init_params(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    return _tiny(2)
 
 
 def _reqs(cfg, shapes, prompts=5, **kw):
@@ -51,6 +69,18 @@ def _run(cfg, params, mesh, reqs, late=(), **kw):
     return dict(b.run(max_steps=400)), b.rounds, b
 
 
+def _same_tokens_in_the_same_rounds(cfg, params, mesh, shapes, **kw):
+    want, rounds, plain = _run(cfg, params, mesh, _reqs(cfg, shapes),
+                               lookahead=False, **kw)
+    got, rounds2, b = _run(cfg, params, mesh, _reqs(cfg, shapes), **kw)
+    assert got == want and rounds2 == rounds
+    assert plain.rounds_ahead == 0 and plain._ahead is None
+    # most rounds had their step sent by the round before; none in vain (a
+    # step goes ahead only where no slot can free), none left in flight
+    assert b.rounds_ahead > rounds // 3
+    assert b.ahead_discarded == 0 and b._ahead is None
+
+
 @pytest.mark.parametrize("kw", [
     dict(),                                     # token-fed, contiguous cache
     dict(prefill=True),
@@ -58,16 +88,49 @@ def _run(cfg, params, mesh, reqs, late=(), **kw):
 ], ids=["token_fed", "prefill", "prefill_paged"])
 def test_lookahead_serves_the_same_tokens_in_the_same_rounds(tiny, mesh1, kw):
     cfg, params = tiny
-    shapes = [(3, 9), (5, 4), (2, 7), (6, 1), (4, 2), (3, 12)]
-    want, rounds, plain = _run(cfg, params, mesh1, _reqs(cfg, shapes), **kw)
-    got, rounds2, b = _run(cfg, params, mesh1, _reqs(cfg, shapes),
-                           lookahead=True, **kw)
-    assert got == want and rounds2 == rounds
-    assert plain.rounds_ahead == 0 and plain._ahead is None
-    # most rounds had their step sent by the round before; none in vain (a
-    # step goes ahead only where no slot can free), none left in flight
-    assert b.rounds_ahead > rounds // 3
-    assert b.ahead_discarded == 0 and b._ahead is None
+    _same_tokens_in_the_same_rounds(
+        cfg, params, mesh1,
+        [(3, 9), (5, 4), (2, 7), (6, 1), (4, 2), (3, 12)], **kw)
+
+
+def test_lookahead_holds_on_a_mesh_of_four(mesh4):
+    """The step sent ahead under ``jit_shard_map`` over four devices: its
+    inputs advanced and placed replicated, the cache donated to a step
+    sent while the argmax before it is still queued. A page a device, so
+    the answers cross onto the second device's rows; one request (an
+    interpreted admission on four devices is ~14 s), the other slot idle."""
+    cfg, params = _tiny(4)
+    _same_tokens_in_the_same_rounds(
+        cfg, params, mesh4, [(6, 5)],
+        prefill=True, page_size=8)
+
+
+def _on_a_64_byte_boundary(like):
+    raw = np.zeros(like.nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    out = raw[start:start + like.nbytes].view(like.dtype)
+    out[:] = like
+    return out
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_a_rounds_inputs_are_the_devices_own(n_dev):
+    """The advance program may still be queued when a round's pull returns
+    and the host writes ``tok`` / ``pos`` in place, and the CPU backend
+    aliases a host array that lies on a 64-byte boundary (where a small
+    numpy array lands depends on what the process allocated before): on a
+    mesh of two, one run in eight served a position twice. What a round
+    hands the device is a copy."""
+    cfg, params = _tiny(4)
+    mesh = Mesh(np.array(jax.devices()[:n_dev]), ("tp",))
+    b = ContinuousBatcher(cfg, params, mesh, s_max=32)
+    b.tok, b.pos = _on_a_64_byte_boundary(b.tok), _on_a_64_byte_boundary(b.pos)
+    b.tok[:], b.pos[:] = 7, 3
+    tok_d, pos_d, logits = b._round_inputs()
+    b.tok[:], b.pos[:] = 9, 4
+    assert logits is None
+    assert np.asarray(tok_d).tolist() == [7, 7]
+    assert np.asarray(pos_d).tolist() == [3, 3]
 
 
 def test_nothing_compiles_after_the_first_step_sent_ahead(tiny, mesh1):
@@ -77,7 +140,7 @@ def test_nothing_compiles_after_the_first_step_sent_ahead(tiny, mesh1):
 
     cfg, params = tiny
     b = ContinuousBatcher(cfg, params, mesh1, s_max=32, prefill=True,
-                          page_size=8, lookahead=True)
+                          page_size=8)
     for r in _reqs(cfg, [(3, 3), (5, 3)]):
         b.submit(r)
     b.run(max_steps=50)
@@ -92,7 +155,10 @@ def test_nothing_compiles_after_the_first_step_sent_ahead(tiny, mesh1):
     assert b.rounds_ahead > 6 and not compiled
 
 
-def test_a_step_sent_in_vain_is_thrown_away(tiny, mesh1):
+@pytest.mark.parametrize("kw", [
+    dict(), dict(prefill=True, page_size=8),
+], ids=["token_fed", "prefill_paged"])
+def test_a_step_sent_in_vain_is_thrown_away(tiny, mesh1, kw):
     """One request on two slots, a second submitted while a step is out
     ahead: its admission moves the cache (and, token-fed, the slot), so the
     step already sent is not the next round's; the tokens stay the plain
@@ -100,12 +166,49 @@ def test_a_step_sent_in_vain_is_thrown_away(tiny, mesh1):
     cfg, params = tiny
     first, late = _reqs(cfg, [(3, 12)]), _reqs(cfg, [(4, 6)], prompts=6)
     late[0].uid = "late"
-    for kw in (dict(), dict(prefill=True, page_size=8)):
-        want, rounds, _ = _run(cfg, params, mesh1, first, late, **kw)
-        got, rounds2, b = _run(cfg, params, mesh1, first, late,
-                               lookahead=True, **kw)
-        assert got == want and rounds2 == rounds, kw
-        assert b.ahead_discarded == 1 and b.rounds_ahead > 0, kw
+    want, rounds, _ = _run(cfg, params, mesh1, first, late, lookahead=False,
+                           **kw)
+    got, rounds2, b = _run(cfg, params, mesh1, first, late, **kw)
+    assert got == want and rounds2 == rounds
+    assert b.ahead_discarded == 1 and b.rounds_ahead > 0
+
+
+def _serve(cfg, params, mesh, trace, **kw):
+    eng = ServingEngine(cfg, params, mesh, s_max=32, prefill=True,
+                        page_size=8, clock=retry.FakeClock(),
+                        serving=ServingConfig(virtual_step_s=0.05), **kw)
+    done = eng.serve(trace)
+    return {u: r.tokens for u, r in done.items()}, eng
+
+
+def test_an_arrival_while_a_slot_decodes_pays_one_step(tiny, mesh1):
+    """Through ``ServingEngine`` built with no keyword: the arrival finds
+    one step already queued, which is thrown away and run again after its
+    admission; no token changes, and the engine's snapshot says so."""
+    cfg, params = tiny
+    a, b = _reqs(cfg, [(3, 12), (4, 6)])
+    trace = [Arrival(0.0, a), Arrival(0.22, b)]
+    want, plain = _serve(cfg, params, mesh1, trace, lookahead=False)
+    got, eng = _serve(cfg, params, mesh1, trace)
+    assert got == want and len(got[1]) == 6
+    rounds = eng.snapshot()["batcher"]
+    assert rounds["ahead_discarded"] == 1
+    assert rounds["rounds"] == plain.snapshot()["batcher"]["rounds"]
+    assert plain.snapshot()["batcher"]["rounds_ahead"] == 0
+
+
+def test_the_engine_snapshot_counts_rounds_ahead_across_a_rebuild(
+        tiny, mesh1):
+    cfg, params = tiny
+    reqs = _reqs(cfg, [(3, 9), (5, 7)])
+    got, eng = _serve(cfg, params, mesh1, [Arrival(0.0, r) for r in reqs])
+    before = eng.snapshot()["batcher"]
+    assert set(before) == {"rounds", "rounds_ahead", "ahead_discarded"}
+    assert before["rounds"] > before["rounds_ahead"] > 0
+    assert before["ahead_discarded"] == 0
+    # a rebuild's batcher counts from 0: the retired one's rounds are kept
+    eng._rebuild("test")
+    assert eng._batcher.rounds == 0 and eng.snapshot()["batcher"] == before
 
 
 @pytest.mark.parametrize("kw", [
@@ -114,9 +217,9 @@ def test_a_step_sent_in_vain_is_thrown_away(tiny, mesh1):
 def test_no_step_goes_ahead_where_the_tokens_decide(tiny, mesh1, kw):
     cfg, params = tiny
     shapes = [(3, 6), (5, 4)]
-    want, _, _ = _run(cfg, params, mesh1, _reqs(cfg, shapes, **kw))
-    got, _, b = _run(cfg, params, mesh1, _reqs(cfg, shapes, **kw),
-                     lookahead=True)
+    want, _, _ = _run(cfg, params, mesh1, _reqs(cfg, shapes, **kw),
+                      lookahead=False)
+    got, _, b = _run(cfg, params, mesh1, _reqs(cfg, shapes, **kw))
     assert got == want and b.rounds_ahead == 0 and b.ahead_discarded == 0
 
 
@@ -135,10 +238,22 @@ def test_weights_swapped_under_a_step_sent_ahead(tiny, mesh1):
     assert outs[0] == outs[1]
 
 
-def test_speculative_batcher_refuses_lookahead(tiny, mesh1):
-    from triton_dist_tpu.serving.speculative import SpeculativeBatcher
-
+@pytest.mark.parametrize("kw", [dict(), dict(lookahead=True)],
+                         ids=["no_keyword", "explicit"])
+def test_speculative_batcher_runs_plain_rounds_and_refuses_lookahead(
+        tiny, mesh1, kw):
+    """Its rounds decide their inputs from pulled tokens: built with no
+    keyword it hands ``lookahead=False`` on by itself; asked for it, it
+    refuses."""
     cfg, params = tiny
-    with pytest.raises(NotImplementedError, match="lookahead"):
-        SpeculativeBatcher(cfg, params, mesh1, s_max=32, spec_decode=None,
-                           lookahead=True)
+    build = functools.partial(
+        SpeculativeBatcher, cfg, params, mesh1, s_max=32,
+        spec_decode=SpecDecodeConfig(k=0), **kw)
+    if kw:
+        with pytest.raises(NotImplementedError, match="lookahead"):
+            build()
+        return
+    b = build()
+    assert b.lookahead is False
+    b.submit(_reqs(cfg, [(3, 5)])[0])
+    assert len(dict(b.run(max_steps=50))[0]) == 5 and b.rounds_ahead == 0

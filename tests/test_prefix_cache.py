@@ -381,6 +381,12 @@ def test_armed_but_unshared_equals_disarmed(tiny1, mesh1):
     }
     assert snap_w["prefix_cache"]["hits"] == 0
     snap_w.pop("prefix_cache")
+    # the same rounds, counted apart: with a trie every round is the plain
+    # one (its rows decide what is published), without one a round sends
+    # the next step ahead (docs/serving.md "Lookahead")
+    rounds_c, rounds_w = snap_c.pop("batcher"), snap_w.pop("batcher")
+    assert rounds_c["rounds_ahead"] > 0
+    assert rounds_w == dict(rounds_c, rounds_ahead=0, ahead_discarded=0)
     assert snap_c == snap_w, "armed-but-unshared snapshot == disarmed"
 
 

@@ -56,6 +56,9 @@ TABLE = {
     "tdt.batcher.decode_round.pull": ("tdt.batcher.decode_round", set()),
     "tdt.batcher.decode_round.sample": ("tdt.batcher.decode_round", set()),
 }
+# attributes a span carries on some of its occurrences only: ``ahead`` = 1
+# on a round that sent the next round's step before its own pull
+SOMETIMES = {"tdt.batcher.decode_round": {"ahead"}}
 
 
 @pytest.fixture(autouse=True)
@@ -160,7 +163,8 @@ def test_xplane_holds_every_span_of_the_table_with_its_attributes(traced):
     seen = {s["name"] for s in prof.spans}
     assert seen == set(TABLE)
     for s in prof.spans:
-        assert set(s["stats"]) == TABLE[s["name"]][1], s
+        assert set(s["stats"]) - SOMETIMES.get(s["name"], set()) == (
+            TABLE[s["name"]][1]), s
     (serve,) = prof.named("tdt.engine.serve")
     assert serve["stats"] == {"offered": 4}
     assert sorted(s["stats"]["uid"] for s in
@@ -168,6 +172,11 @@ def test_xplane_holds_every_span_of_the_table_with_its_attributes(traced):
     rounds = [s["stats"]["round"] for s in
               prof.named("tdt.batcher.decode_round")]
     assert rounds == list(range(rounds[0], rounds[0] + len(rounds)))
+    # "a" and "b" decode together, greedy and two tokens from their end:
+    # that round looks ahead; no round beside a sampling slot does
+    ahead = [s["stats"].get("ahead", 0) for s in
+             prof.named("tdt.batcher.decode_round")]
+    assert set(ahead) == {0, 1}
     (rebuild,) = prof.named("tdt.engine.rebuild")
     assert rebuild["stats"] == {"reason": "test", "replayed": 0}
 

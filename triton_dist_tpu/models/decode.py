@@ -1064,14 +1064,16 @@ class ContinuousBatcher:
         prefill_chunk_tokens: int | None = None,
         interpret: Any = None,
         prefix_cache: Any = None,
-        lookahead: bool = False,
+        lookahead: bool = True,
     ):
         self.cfg, self.mesh, self.s_max = cfg, mesh, s_max
-        # lookahead (ROADMAP A6, "read back one step late"): a round whose
-        # tokens cannot change the schedule dispatches the NEXT step, fed on
-        # the device, before it pulls its own tokens, so the host's half of
-        # a round runs under the device's next step. Off: the round as it
-        # was, op for op.
+        # lookahead ("read back one step late"): a round whose tokens
+        # cannot change the schedule dispatches the NEXT step, fed on the
+        # device, before it pulls its own tokens, so the host's half of a
+        # round runs under the device's next step. _ahead_mask decides it
+        # round by round from what the batcher observes, and the plain
+        # round is what it falls back to. False: the plain round always,
+        # op for op (the tests' reference).
         self.lookahead = bool(lookahead)
         self._ahead: _Ahead | None = None
         self._pass_stats = None  # the last program's counters (device)
@@ -1320,8 +1322,12 @@ class ContinuousBatcher:
             # placed as the advance program places its outputs, so that the
             # step and the advance each see ONE type of input
             rep = NamedSharding(self.mesh, P(None))
-            return (jax.device_put(self.tok, rep),
-                    jax.device_put(self.pos, rep), None)
+            # copies: a backend may alias a host array it is handed (the
+            # CPU's does, where the array lies on a 64-byte boundary), the
+            # rounds write tok / pos in place, and the advance program may
+            # still be queued when this round's pull returns
+            return (jax.device_put(self.tok.copy(), rep),
+                    jax.device_put(self.pos.copy(), rep), None)
         return jnp.asarray(self.tok), jnp.asarray(self.pos), None
 
     def _pull_last(self, sp, last, i: int) -> np.ndarray:

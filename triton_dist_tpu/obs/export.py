@@ -74,6 +74,8 @@ ENGINE_SECTIONS = {
     "by_class": "armed (overload): per-priority-class counters + TTFT",
     "engine": "always: world/queue/clock facts (disagg: topology facts)",
     "overload": "armed (overload): ladder state, pressure, sheds",
+    "batcher": "always: decode rounds, rounds whose step was sent ahead, "
+               "steps sent in vain (summed across rebuilds)",
     "prefix_cache": "armed (prefix_cache): PX counters + gauges",
     "speculative": "armed (speculative): accept rate, live k, rollback "
                    "and accepted-token totals",

@@ -68,6 +68,8 @@ from triton_dist_tpu.serving.traffic import Arrival
 
 BACKPRESSURE = ("reject", "block")
 ADMISSION = ("fcfs", "spf")
+# the batcher's decode-round counters in the snapshot's "batcher" section
+_ROUND_COUNTERS = ("rounds", "rounds_ahead", "ahead_discarded")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -383,6 +385,9 @@ class ServingEngine:
         # prefix-cache counters accumulated across batcher rebuilds (each
         # rebuild starts a FRESH trie — the pool is the batcher's)
         self._px_totals: dict[str, int] = {}
+        # the retired batchers' decode-round counters (a rebuild's batcher
+        # counts from 0)
+        self._round_totals = dict.fromkeys(_ROUND_COUNTERS, 0)
         # per-step deltas feeding the controller's pressure window
         self._step_arrived = 0
         self._step_finished = 0
@@ -1221,6 +1226,8 @@ class ServingEngine:
             struck = old.drain_struck()
             self._fold_px(old.prefix_cache_stats())
             self._fold_spec(old)
+            for k in _ROUND_COUNTERS:
+                self._round_totals[k] += getattr(old, k)
             active, queued = old.export_in_flight()
             target = self._target_mesh()
             self.rebuilds += 1
@@ -1504,6 +1511,12 @@ class ServingEngine:
             # only when the alert tier is armed, so disarmed snapshots
             # stay byte-identical to pre-flight-recorder ones (pinned)
             snap["alerts"] = self._alerts.snapshot()
+        # decode rounds, and how many of them the lookahead carried: a round
+        # whose step the round before had sent, and steps sent in vain
+        snap["batcher"] = {
+            k: self._round_totals[k] + getattr(self._batcher, k)
+            for k in _ROUND_COUNTERS
+        }
         px = self._px_snapshot()
         if px is not None:
             # the ISSUE 12 surface: hit-rate, pages-shared gauge, and

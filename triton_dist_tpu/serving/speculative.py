@@ -182,12 +182,13 @@ class SpeculativeBatcher(ContinuousBatcher):
                 "kind (LatentPagedCacheSpec): its verify step reads k/v pools")
         if cfg.cache_kind == "kv_window":
             refuse_ring("speculative decoding (its verify step)")
-        if kw.get("lookahead"):
+        if kw.pop("lookahead", False):
             raise NotImplementedError(
                 "lookahead sends the plain step ahead of its round; a "
                 "draft+verify round decides its inputs from the tokens it "
                 "pulls: pass one or the other")
-        super().__init__(cfg, params, mesh, s_max=s_max, **kw)
+        super().__init__(cfg, params, mesh, s_max=s_max, lookahead=False,
+                         **kw)
         sd = spec_decode.validate()
         self.spec_decode = sd
         self.k_live = sd.k
