@@ -103,7 +103,8 @@ _LONG_POLES = (
     "test_prefix_cache_chaos.py", "test_spec_serving.py",
     "test_ragged_pipeline.py", "test_ranged_contiguous.py",
     "test_flight_recorder.py", "test_recovery.py", "test_fp8.py",
-    "test_lookahead.py", "test_gemm_rs.py", "test_chip_smoke.py",
+    "test_ssm_hybrid.py", "test_lookahead.py", "test_gemm_rs.py",
+    "test_chip_smoke.py",
     "test_ranged_paged.py", "test_ring_attention.py",
     "test_gate_up_layout.py", "test_moe.py", "test_ag_gemm.py",
     "test_fleet.py", "test_ragged.py",

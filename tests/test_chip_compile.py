@@ -197,6 +197,45 @@ def test_window_paged_flash_decode_compiles(one_chip):
     assert "tpu_custom_call" in text and "paged_flash_decode_w128" in text
 
 
+def test_state_space_kernels_compile(one_chip):
+    """The state-space / attention configuration's kernels at its
+    published widths (5120 channels, 16 states, 64 slots, 26 layers'
+    state in one pool): the scan over a bucket of 512, the one-token
+    update with the pool aliased in and out (no copy of 1.09 GB), and the
+    paged decode at a group of 20 query heads on ONE kv head."""
+    from triton_dist_tpu.ops.flash_decode import paged_flash_decode
+    from triton_dist_tpu.ops.selective_scan import (
+        selective_scan, selective_state_update,
+    )
+
+    d, n, slots, layers, bucket = 5120, 16, 64, 26, 512
+    f32 = lambda *shape: _struct(shape, jnp.float32, one_chip)
+    i32 = lambda *shape: _struct(shape, jnp.int32, one_chip)
+    text = _compiled_text(
+        functools.partial(selective_scan, interpret=False),
+        f32(bucket, d), f32(bucket, d), f32(bucket, n), f32(bucket, n),
+        f32(n, d), f32(d), f32(n, d))
+    assert "tpu_custom_call" in text and "selective_scan" in text
+    update = jax.jit(
+        lambda pool, *a: selective_state_update(pool, 3, *a, interpret=False),
+        donate_argnums=(0,))
+    compiled = update.lower(
+        f32(layers, 2, slots, n, d), i32(slots), i32(slots), f32(slots, d),
+        f32(slots, d), f32(slots, n), f32(slots, n), f32(n, d), f32(d)
+    ).compile()
+    assert "selective_state_update" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = layers * 2 * slots * n * d * 4
+    assert mem.alias_size_in_bytes >= pool_bytes > mem.temp_size_in_bytes
+    pages = S_MAX // PAGE
+    pool = _struct((2 * slots * pages, 1, PAGE, HEAD), jnp.bfloat16, one_chip)
+    text = _compiled_text(
+        functools.partial(paged_flash_decode, interpret=False),
+        _struct((slots, 20, HEAD), jnp.bfloat16, one_chip), pool, pool,
+        i32(slots), i32(slots, pages))
+    assert "tpu_custom_call" in text and "paged_flash_decode" in text
+
+
 @pytest.mark.parametrize("rows,block_m", [(32, 16), (8192, 128)])
 def test_held_expert_group_gemms_compile(one_chip, rows, block_m):
     """The routed experts' two grouped GEMMs where a chip holds 16 of 128

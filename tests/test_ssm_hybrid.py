@@ -1,0 +1,595 @@
+"""The state-space / attention family (models/ssm_hybrid.py) at toy widths
+on the CPU (4 layers: Mamba at 0 and 2, attention at 1 and 3; page 4),
+each piece against the plain reference's equations
+(perfbench/references/jamba_ssm_hybrid.py, imported as it stands: it
+shares no code with the program). Weights are float32 here, so the
+tolerances below are those of float32 arithmetic reordered (a state held
+``[N, d]`` for ``[d, N]``, online for whole softmax, a packed gate/up),
+not of bf16."""
+
+import dataclasses
+import importlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+
+from triton_dist_tpu.models import ContinuousBatcher, Request
+from triton_dist_tpu.models import ssm_hybrid
+from triton_dist_tpu.models.decode import (
+    PAGED_CACHE_KINDS, StatePagedKVCacheSpec,
+)
+
+PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
+if PERFBENCH not in sys.path:
+    sys.path.insert(0, PERFBENCH)
+from harness import cells  # noqa: E402
+
+# (the package exports a function under the module's name)
+fd = importlib.import_module("triton_dist_tpu.ops.flash_decode")
+ss = importlib.import_module("triton_dist_tpu.ops.selective_scan")
+
+# float32 everywhere: what is left is the order of the sums
+TOL = dict(rtol=2e-4, atol=2e-4)
+PAGE, S_MAX = 4, 32
+TOY = dict(
+    hidden=32, ffn=64, n_layers=4, n_q_heads=4, n_kv_heads=1, head_dim=8,
+    vocab=64, rope_theta=None, norm_eps=1e-6, dtype="float32",
+    attn_layer_period=2, attn_layer_offset=1, mamba_expand=2,
+    mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=4, mamba_conv_bias=True,
+    mamba_proj_bias=False, num_experts=1, tie_word_embeddings=True,
+    engine=dict(slots=3, s_max=S_MAX, page=PAGE, max_queue=64),
+)
+TOY["sizes"] = {k: TOY[k] for k in cells.SIZE_KEYS}
+SIZES = TOY["sizes"]
+PUBLISHED = os.path.join(PERFBENCH, "configs", "ai21-jamba2-3b.json")
+
+
+@pytest.fixture(scope="module")
+def ref():
+    mod = cells.load_module("references", "jamba_ssm_hybrid")
+    mod.configure(TOY)
+    yield mod
+    mod.configure(TOY)
+
+
+@pytest.fixture(scope="module")
+def adapter():
+    return cells.load_module("programs", "tdt_ssm_hybrid")
+
+
+@pytest.fixture(scope="module")
+def toy(ref, adapter):
+    """``(cfg, program params, plain layers, outer)`` from one seed."""
+    cfg = adapter.model_config(TOY)
+    key = ref.seed_key(7)
+    plain = [ref.layer_weights(key, li, SIZES) for li in range(TOY["n_layers"])]
+    outer = ref.outer_weights(key, SIZES)
+    params = dict(outer, layers=[adapter.pack_layer(w, cfg) for w in plain])
+    return cfg, params, plain, outer
+
+
+@pytest.fixture(scope="module")
+def published(adapter):
+    config = cells.load_json(PUBLISHED)
+    config["sizes"] = {k: config[k] for k in cells.SIZE_KEYS}
+    return config, adapter.model_config(config)
+
+
+def _ref_logits(ref, plain, outer, tokens):
+    """The reference's logits at every position of ``tokens [n, T]``."""
+    x = outer["embed"][tokens].astype(jnp.float32)
+    for w in plain:
+        x = ref.layer(x, w, SIZES)
+    n, t = tokens.shape
+    return np.asarray(ref.head(x, outer, jnp.zeros(n, jnp.int32), t, SIZES,
+                               False))
+
+
+def _mesh(cfg):
+    return Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
+
+
+def _batcher(cfg, params, **kw):
+    return ContinuousBatcher(cfg, params, _mesh(cfg), s_max=S_MAX,
+                             page_size=PAGE, prefill=True, **kw)
+
+
+def _prompt(rng, cfg, n):
+    return [int(t) for t in rng.integers(0, cfg.vocab, n)]
+
+
+# -- (a) the two kernels ---------------------------------------------------------
+
+def _scan_args(rng, L, d=64, n=16):
+    f = lambda *shape: rng.standard_normal(shape).astype(np.float32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (L, d))).astype(np.float32)
+    return [jnp.asarray(x) for x in (
+        f(L, d), dt, f(L, n), f(L, n), -np.exp(f(n, d)), f(d), f(n, d))]
+
+
+@pytest.mark.parametrize("length", [5, 64, 70, 133])
+def test_selective_scan_against_its_twin_and_the_token_by_token_recurrence(
+        ref, length):
+    """Lengths below a chunk (64), one chunk, not a multiple of the chunk,
+    and over two chunks; the state comes in non-zero."""
+    c, dt, b, cm, a, d_skip, h0 = _scan_args(np.random.default_rng(length),
+                                             length)
+    y, h = ss.selective_scan(c, dt, b, cm, a, d_skip, h0, interpret=True)
+    y_x, h_x = ss._xla_selective_scan(c, dt, b, cm, a, d_skip, h0)
+    y_r, h_r = ref.recurrence(c, dt, b, cm, a.T, d_skip, h0.T)
+    for got, want in ((y, y_x), (h, h_x), (y, y_r), (h, h_r.T)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+    # dt = 0 from position 3 on: the state stays where position 2 left it
+    stop = dt.at[3:].set(0.0)
+    _, h_stop = ss.selective_scan(c, stop, b, cm, a, d_skip, h0, interpret=True)
+    _, h_3 = ref.recurrence(c[:3], dt[:3], b[:3], cm[:3], a.T, d_skip, h0.T)
+    np.testing.assert_allclose(np.asarray(h_stop), np.asarray(h_3.T),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_selective_state_update_against_its_twin_and_the_recurrence(ref):
+    """Slots that read either row of the pool's axis of 2, a fresh slot,
+    and a stale state that is not finite under a fresh slot."""
+    rng = np.random.default_rng(1)
+    slots, d, n = 6, 64, 16
+    c, dt, b, cm, a, d_skip, _ = _scan_args(rng, slots)
+    pool = rng.standard_normal((3, 2, slots, n, d)).astype(np.float32)
+    read = jnp.asarray([0, 1, 0, 1, 1, 0])
+    fresh = jnp.asarray([0, 0, 1, 0, 1, 0])
+    pool[1, 0, 2] = np.nan
+    pool = jnp.asarray(pool)
+    y, got = ss.selective_state_update(
+        pool, 1, read, fresh, c, dt, b, cm, a, d_skip, interpret=True)
+    y_x, want = ss._xla_state_update(
+        pool, 1, read, fresh, c, dt, b, cm, a, d_skip)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_x), rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    for i in range(slots):
+        h0 = jnp.zeros((d, n)) if fresh[i] else pool[1, read[i], i].T
+        y_r, h_r = ref.recurrence(c[i:i + 1], dt[i:i + 1], b[i:i + 1],
+                                  cm[i:i + 1], a.T, d_skip, h0)
+        np.testing.assert_allclose(np.asarray(y[i]), np.asarray(y_r[0]),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got[1, 1 - read[i], i]),
+                                   np.asarray(h_r.T), rtol=1e-5, atol=1e-5)
+    # the other layers, and the rows read, are as they were
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(pool[0]))
+    np.testing.assert_array_equal(np.asarray(got[1, 1, 1]),
+                                  np.asarray(pool[1, 1, 1]))
+
+
+# -- (b) prefill, then decode, through the cache -------------------------------
+
+class _Recording(Request):
+    """A request that keeps every logit row it was sampled from and then
+    takes the best token: logits are compared, not tokens."""
+
+    def sample(self, logits, rng):
+        self.__dict__.setdefault("rows", []).append(np.array(logits))
+        return int(np.argmax(logits))
+
+
+# (prompt, new): a prompt below its bucket's edge (3 of 4), at it (4 of 4),
+# across it (5 -> 8) and over pages and buckets (13 -> 16, four pages);
+# with 3 slots the last two are admitted into slots that served before
+CASES = {"below": (3, 3), "at": (4, 3), "across": (5, 3), "long": (13, 3),
+         "readmitted": (6, 3)}
+
+
+@pytest.fixture(scope="module")
+def served(toy):
+    """Every case through ONE batcher (3 slots, so slots are re-used,
+    lookahead off: ``temperature`` > 0 keeps every round plain)."""
+    cfg, params, _, _ = toy
+    batcher = _batcher(cfg, params)
+    assert isinstance(batcher.spec, StatePagedKVCacheSpec)
+    rng = np.random.default_rng(0)
+    reqs = {name: _Recording(_prompt(rng, cfg, n_prompt), n_new,
+                             temperature=1.0, uid=name)
+            for name, (n_prompt, n_new) in CASES.items()}
+    for r in reqs.values():
+        batcher.submit(r)
+    return reqs, dict(batcher.run())
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_batcher_prefill_then_decode_matches_reference(toy, ref, served, case):
+    """Every logit row the batcher sampled from against the reference's
+    full forward over the same sequence."""
+    _, _, plain, outer = toy
+    reqs, done = served
+    r, out = reqs[case], done[case]
+    assert len(out) == r.max_new_tokens == len(r.rows)
+    seq = np.array([list(r.prompt) + out])
+    want = _ref_logits(ref, plain, outer, seq)[0]
+    first = len(r.prompt) - 1
+    np.testing.assert_allclose(
+        np.stack(r.rows), want[first:first + len(out)], **TOL)
+
+
+def test_token_fed_admission_matches_reference(toy, ref):
+    """``prefill=False``: the prompt goes in a token a step from position
+    0, where the step reads zeros for the state whatever the slot holds."""
+    cfg, params, plain, outer = toy
+    batcher = ContinuousBatcher(cfg, params, _mesh(cfg), s_max=S_MAX,
+                                page_size=PAGE)
+    rng = np.random.default_rng(5)
+    reqs = [_Recording(_prompt(rng, cfg, n), 2, temperature=1.0, uid=i)
+            for i, n in enumerate((3, 2, 4, 2))]      # the 4th re-uses a slot
+    for r in reqs:
+        batcher.submit(r)
+    done = dict(batcher.run())
+    for r in reqs:
+        seq = np.array([list(r.prompt) + done[r.uid]])
+        want = _ref_logits(ref, plain, outer, seq)[0]
+        first = len(r.prompt) - 1
+        np.testing.assert_allclose(np.stack(r.rows), want[first:first + 2],
+                                   **TOL)
+
+
+# -- (c), (f) what an admission writes -------------------------------------------
+
+def _prefill(cfg, params, spec, cache, slot, prompt, bucket):
+    """The family's prefill of ``prompt`` into ``slot`` as an admission
+    does it; ``slot=None``: every slot gets the prompt, no mask
+    (``generate``'s form)."""
+    pcfg = dataclasses.replace(cfg, seq=bucket)
+    tokens = np.zeros((cfg.batch, bucket), np.int32)
+    pick = np.zeros(cfg.batch, np.int32)
+    tokens[slot, :len(prompt)] = prompt      # (None indexes every row)
+    pick[slot] = len(prompt) - 1
+    mask = None if slot is None else jnp.arange(cfg.batch) == slot
+    fn = jax.shard_map(
+        lambda p, c, t, m, k: ssm_hybrid.prefill_cache(
+            pcfg, p, c, t.reshape(-1), spec, S_MAX, slot_mask=m, pick=k,
+            interpret=True),
+        mesh=_mesh(cfg), in_specs=(cfg.param_specs(), spec.specs(cfg), P(),
+                                   None if slot is None else P(), P()),
+        out_specs=(spec.specs(cfg), P(), P()), check_vma=False)
+    return jax.jit(fn)(params, cache, jnp.asarray(tokens), mask,
+                       jnp.asarray(pick))
+
+
+def test_a_prompt_shorter_than_its_bucket_leaves_the_state_of_its_length(
+        toy, ref):
+    """5 tokens in a bucket of 8: layer 0's state is the reference's after
+    token 5 (not after 8 rows), at the parity of position 4; its
+    convolution ring holds inputs 1..4 at rows 1, 2, 3, 0; the logits are
+    row 4's; and the counters say one slot's state was written."""
+    cfg, params, plain, outer = toy
+    spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    rng = np.random.default_rng(2)
+    prompt = _prompt(rng, cfg, 5)
+    cache, last, counters = _prefill(cfg, params, spec, spec.init(cfg, 1), 1,
+                                     prompt, 8)
+    x = outer["embed"][jnp.asarray(prompt)].astype(jnp.float32)
+    w = plain[0]
+    _, h, u = ref.mamba_parts(ref._norm(x, w["norm_in"], 1e-6), w, SIZES, False)
+    np.testing.assert_allclose(np.asarray(cache["ssm"][0, 4 % 2, 1]),
+                               np.asarray(h.T), **TOL)
+    for p in (1, 2, 3, 4):
+        np.testing.assert_allclose(np.asarray(cache["conv"][0, p % 4, 1]),
+                                   np.asarray(u[p]), **TOL)
+    want = _ref_logits(ref, plain, outer, np.array([prompt]))[0, -1]
+    np.testing.assert_allclose(np.asarray(last[1]), want, **TOL)
+    assert [int(v) for v in counters] == [1, 0]
+    # the same prompt in a bucket of its own length, in EVERY slot and with
+    # no mask (``generate``'s form): the same state in each
+    exact, last, counters = _prefill(cfg, params, spec, spec.init(cfg, 1),
+                                     None, prompt, 5)
+    assert [int(v) for v in counters] == [cfg.batch, 0]
+    for slot in range(cfg.batch):
+        np.testing.assert_allclose(np.asarray(last[slot]), want, **TOL)
+        for name in ("ssm", "conv"):
+            np.testing.assert_allclose(
+                np.asarray(cache[name][:, :, 1]),
+                np.asarray(exact[name][:, :, slot]), rtol=1e-5, atol=1e-6)
+
+
+def test_an_admission_changes_no_other_slots_state_or_pages(toy):
+    """Bitwise: slots 0 and 2 hold what they held, in every pool."""
+    cfg, params, _, _ = toy
+    spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    rng = np.random.default_rng(3)
+    before = jax.tree.map(
+        lambda x: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+        if x.dtype != jnp.int32 else x, spec.init(cfg, 1))
+    after, _, _ = _prefill(cfg, params, spec, before, 1,
+                           _prompt(rng, cfg, 7), 8)
+    others = np.array([0, 2])
+    for name in ("ssm", "conv"):
+        np.testing.assert_array_equal(
+            np.asarray(after[name][:, :, others]),
+            np.asarray(before[name][:, :, others]))
+        assert not np.array_equal(np.asarray(after[name][:, :, 1]),
+                                  np.asarray(before[name][:, :, 1]))
+    pages = np.asarray(before["block_table"][0][others]).reshape(-1)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(after[name][:, pages]),
+                                      np.asarray(before[name][:, pages]))
+
+
+# -- (d) a step sent in vain ------------------------------------------------------
+
+def _run_with_a_late_arrival(cfg, params, **kw):
+    """Two requests decode on three slots; a third arrives after the third
+    step, while a step may be out ahead."""
+    rng = np.random.default_rng(4)
+    b = _batcher(cfg, params, **kw)
+    for i, (n, new) in enumerate([(5, 7), (3, 6)]):
+        b.submit(Request(_prompt(rng, cfg, n), new, uid=i))
+    for _ in range(3):
+        b.step()
+    b.submit(Request(_prompt(rng, cfg, 6), 3, uid="late"))
+    return dict(b.run(max_steps=200)), b
+
+
+def test_a_step_sent_in_vain_serves_the_plain_rounds_tokens(toy):
+    """Lookahead on: the late admission moves the cache under a step that
+    has ALREADY advanced every live slot's state in the donated cache; the
+    step runs again and every slot's tokens are the plain batcher's."""
+    cfg, params, _, _ = toy
+    want, plain = _run_with_a_late_arrival(cfg, params, lookahead=False)
+    got, b = _run_with_a_late_arrival(cfg, params)
+    assert b.ahead_discarded >= 1 and b.rounds_ahead > 0
+    assert plain.rounds_ahead == 0 and b.rounds == plain.rounds
+    assert got == want
+
+
+def test_decode_step_twice_on_the_same_inputs_is_decode_step_once(toy):
+    """The planted form: the same ``(tok, pos)`` through the batcher's own
+    step program twice gives the same logits and, bit for bit, the same
+    cache: the second run read the state the first read, not the state it
+    wrote."""
+    cfg, params, _, _ = toy
+    b = _batcher(cfg, params, lookahead=False)
+    rng = np.random.default_rng(6)
+    for i, n in enumerate((5, 9, 2)):
+        b.submit(Request(_prompt(rng, cfg, n), 12, uid=i))
+    for _ in range(3):
+        b.step()
+    tok, pos = jnp.asarray(b.tok), jnp.asarray(b.pos)
+    copy = lambda tree: jax.tree.map(jnp.copy, tree)
+    before = copy(b.cache)
+    logits1, once = b._step(b.params, copy(before), tok, pos)
+    kept = copy(once)                       # the step donates its cache
+    logits2, twice = b._step(b.params, once, tok, pos)
+    np.testing.assert_array_equal(np.asarray(logits1), np.asarray(logits2))
+    for name, leaf in twice.items():
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(kept[name]),
+                                      err_msg=name)
+    # and the step did move the state: the test would see a double advance
+    assert not np.array_equal(np.asarray(before["ssm"]),
+                              np.asarray(twice["ssm"]))
+
+
+# -- (e) a slot that served before ----------------------------------------------
+
+def test_a_readmitted_slot_serves_what_a_fresh_batcher_serves(toy):
+    """The second request lands in slot 0, on the state the first left
+    behind there, and serves the tokens it serves in a fresh batcher."""
+    cfg, params, _, _ = toy
+    rng = np.random.default_rng(8)
+    first = Request(_prompt(rng, cfg, 9), 3, uid="first")
+    second = _prompt(rng, cfg, 6)
+    busy = _batcher(cfg, params)
+    busy.submit(first)
+    busy.run()
+    stale = np.asarray(busy.cache["ssm"][:, :, 0])
+    assert stale.any()
+    busy.submit(Request(second, 5, uid="second"))
+    got = dict(busy.run())["second"]
+    fresh = _batcher(cfg, params)
+    fresh.submit(Request(second, 5, uid="second"))
+    assert got == dict(fresh.run())["second"]
+    assert busy.spec.kind == "kv_state"
+
+
+# -- (g), (h), (j) the published configuration --------------------------------------
+
+def test_the_published_plan_has_attention_at_7_and_21_of_28(published):
+    config, cfg = published
+    plan = ssm_hybrid.layer_plan(cfg)
+    assert len(plan) == 28 == config["num_hidden_layers"]
+    assert [i for i, k in enumerate(plan) if k == "attention"] == [7, 21]
+    assert plan.count("mamba") == 26
+    assert (cfg.own_passes, cfg.cache_kind) == (True, "kv_state")
+    assert cfg.pass_counters == ("state_slots", "kv_rows")
+    assert ssm_hybrid._numbered(cfg)[7:9] == [("attention", 0), ("mamba", 7)]
+    assert ssm_hybrid._numbered(cfg)[21] == ("attention", 1)
+    assert (cfg.d_inner, cfg.d_state, cfg.d_conv, cfg.dt_rank) == (
+        5120, 16, 4, 160)
+    assert (cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim) == (20, 1, 128)
+
+
+def test_the_published_sizes_count_3_029_337_472_parameters(published):
+    """From the program's own shapes (nothing is allocated): the tied head
+    is one leaf, counted once."""
+    _, cfg = published
+    shapes = jax.eval_shape(
+        lambda k: ssm_hybrid.init_ssm_hybrid_params(k, cfg),
+        jax.random.PRNGKey(0))
+    assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes)) \
+        == 3_029_337_472
+    assert "lm_head" not in shapes
+    mamba, attn = shapes["layers"][0], shapes["layers"][7]
+    count = lambda t: sum(int(np.prod(x.shape)) for x in jax.tree.leaves(t))
+    assert (count(mamba), count(attn)) == (104_161_472, 76_682_240)
+    specs = cfg.param_specs()
+    assert jax.tree.structure(jax.tree.map(lambda x: 0, shapes)) == \
+        jax.tree.structure(jax.tree.map(
+            lambda s: 0, specs, is_leaf=lambda s: not isinstance(s, (dict, list))))
+
+
+def test_the_kv_pools_hold_the_two_attention_layers_and_the_state_its_slots(
+        published):
+    config, cfg = published
+    eng = config["engine"]
+    spec = PAGED_CACHE_KINDS["kv_state"](eng["s_max"], eng["page"],
+                                         static_table=True)
+    assert PAGED_CACHE_KINDS["kv_state"] is StatePagedKVCacheSpec
+    cache = jax.eval_shape(lambda: spec.init(cfg, 1))
+    pages = 64 * (2048 // 128)
+    assert cache["k"].shape == cache["v"].shape == (2, pages, 1, 128, 128)
+    assert cache["ssm"].shape == (26, 2, 64, 16, 5120)
+    assert cache["conv"].shape == (26, 4, 64, 5120)
+    assert cache["ssm"].dtype == cache["conv"].dtype == jnp.float32
+    assert set(spec.specs(cfg)) == set(cache)
+    state = sum(int(np.prod(cache[k].shape)) * 4 for k in ("ssm", "conv"))
+    assert state == cfg.state_bytes() == 64 * 26 * (2 * 16 + 4) * 5120 * 4
+
+
+# -- (i) what the kind refuses ----------------------------------------------------
+
+def _refusals(cfg, params):
+    from triton_dist_tpu.models.prefix_cache import PrefixCacheConfig
+    from triton_dist_tpu.serving.disagg import DisaggServingEngine
+    from triton_dist_tpu.serving.speculative import (
+        SpecDecodeConfig, SpeculativeBatcher,
+    )
+
+    one = _mesh(cfg)
+    two = Mesh(np.array(jax.devices()[:2]), (cfg.axis,))
+    spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    kw = dict(s_max=S_MAX, page_size=PAGE)
+    return {
+        "prefix cache": ("prefix_cache", lambda: ContinuousBatcher(
+            cfg, params, one, prefill=True,
+            prefix_cache=PrefixCacheConfig(), **kw)),
+        "ranged prefill": ("ranged prefill", lambda: ContinuousBatcher(
+            cfg, params, one, prefill=True, prefill_chunk_tokens=8, **kw)),
+        "contiguous cache": ("contiguous cache", lambda: ContinuousBatcher(
+            cfg, params, one, s_max=S_MAX)),
+        "wider mesh": ("wider than one device", lambda: ContinuousBatcher(
+            cfg, params, two, **kw)),
+        "wider mesh, the spec": ("one-device shard", lambda: spec.init(cfg, 2)),
+        "verify": ("speculative verify", lambda: spec.update_multi_and_attend()),
+        "the dense step": ("walks its own plan",
+                           lambda: spec.update_and_attend()),
+        "speculative decoding": (
+            "speculative decoding", lambda: SpeculativeBatcher(
+                cfg, params, one, spec_decode=SpecDecodeConfig(), **kw)),
+        "handoff": ("disaggregated handoff", lambda: DisaggServingEngine(
+            cfg, params, two, **kw)),
+        "scratch page": ("prefix cache", lambda: StatePagedKVCacheSpec(
+            S_MAX, PAGE, static_table=True, extra_pages=1).init(cfg, 1)),
+    }
+
+
+@pytest.mark.parametrize("what", [
+    "prefix cache", "ranged prefill", "contiguous cache", "wider mesh",
+    "wider mesh, the spec", "verify", "the dense step", "speculative decoding",
+    "handoff", "scratch page"])
+def test_what_a_slots_state_cannot_serve_is_refused_by_name(toy, what):
+    cfg, params, _, _ = toy
+    match, build = _refusals(cfg, params)[what]
+    with pytest.raises(NotImplementedError, match=match) as err:
+        build()
+    assert "kv_state" in str(err.value) or "one-device" in str(err.value)
+
+
+# -- (k) a group of 20 on one kv head ----------------------------------------------
+
+def test_a_group_of_20_on_one_kv_head_through_the_paged_kernel(ref):
+    """The published attention shape (20 query heads, 1 kv head, width
+    128) through ``paged_flash_decode`` interpreted, against the
+    reference's attention at each slot's last position."""
+    rng = np.random.default_rng(9)
+    b, hq, d, page, pages = 3, 20, 128, 8, 4
+    sizes = dict(head_dim=d, n_q_heads=hq, n_kv_heads=1)
+    lens = np.array([1, 13, 32], np.int32)
+    h = 64
+    w = {k: jnp.asarray(rng.standard_normal(s).astype(np.float32) * h ** -0.5)
+         for k, s in (("wq", (h, hq * d)), ("wk", (h, d)), ("wv", (h, d)))}
+    w["wo"] = jnp.eye(hq * d, dtype=jnp.float32)
+    x = rng.standard_normal((b, page * pages, h)).astype(np.float32)
+    table = rng.permutation(b * pages).reshape(b, pages).astype(np.int32)
+    kp = np.zeros((b * pages, 1, page, d), np.float32)
+    vp = np.zeros_like(kp)
+    q = np.zeros((b, hq, d), np.float32)
+    want = np.zeros((b, hq * d), np.float32)
+    for i in range(b):
+        xi = jnp.asarray(x[i, :lens[i]])
+        k, v = np.asarray(xi @ w["wk"]), np.asarray(xi @ w["wv"])
+        for p in range(lens[i]):
+            kp[table[i, p // page], 0, p % page] = k[p]
+            vp[table[i, p // page], 0, p % page] = v[p]
+        q[i] = np.asarray(xi[-1] @ w["wq"]).reshape(hq, d)
+        want[i] = np.asarray(ref.attention(xi, w, sizes, False))[-1]
+    got = fd.paged_flash_decode(
+        *(jnp.asarray(a) for a in (q, kp, vp, lens, table)), interpret=True)
+    np.testing.assert_allclose(np.asarray(got).reshape(b, -1), want,
+                               rtol=1e-4, atol=1e-4)
+
+
+# -- (l) the engine, its spans and its rebuild path --------------------------------
+
+def test_engine_serves_it_rebuilds_and_the_spans_carry_the_counters(toy):
+    """Through ``ServingEngine`` with the batcher's default of lookahead:
+    a rebuild mid-flight re-admits the in-flight requests by prefill
+    (prompt + tokens so far), and every request's tokens are the plain
+    batcher's; the round's span carries ``state_slots`` and ``kv_rows``,
+    the intake's ``state_bytes``."""
+    from triton_dist_tpu import config as tdt_config, obs
+    from triton_dist_tpu.obs import ObsConfig
+    from triton_dist_tpu.resilience import retry
+    from triton_dist_tpu.serving import ServingConfig, ServingEngine
+    from triton_dist_tpu.serving.engine import Finished
+
+    cfg, params, _, _ = toy
+    rng = np.random.default_rng(10)
+    shapes = [(6, 5), (9, 4), (3, 5), (5, 3)]
+    prompts = [_prompt(rng, cfg, n) for n, _ in shapes]
+    reqs = lambda: [Request(list(p), new, uid=f"u{i}")
+                    for i, (p, (_, new)) in enumerate(zip(prompts, shapes))]
+    plain = _batcher(cfg, params, lookahead=False)
+    for r in reqs():
+        plain.submit(r)
+    want = dict(plain.run())
+
+    before = tdt_config.get_config().obs
+    tdt_config.update(obs=ObsConfig(spans=True))
+    obs.reset()
+    try:
+        clock = retry.FakeClock()
+        with retry.clock_scope(clock):
+            eng = ServingEngine(
+                cfg, params, _mesh(cfg), s_max=S_MAX, page_size=PAGE,
+                prefill=True, clock=clock,
+                serving=ServingConfig(virtual_step_s=0.01))
+            assert eng._batcher.lookahead
+            for r in reqs():
+                eng.submit(r)
+            for _ in range(3):
+                eng._step_once()
+            assert eng._batcher.rounds_ahead > 0
+            eng._rebuild("test")
+            done = eng.run_until_idle()
+        assert eng.rebuilds == 1
+        spans = obs.spans()
+    finally:
+        tdt_config.update(obs=before)
+        obs.reset()
+    assert all(isinstance(done[u], Finished) for u in want)
+    assert {u: list(done[u].tokens) for u in want} == want
+    by_name = {}
+    for sp in spans:
+        by_name.setdefault(sp.name, []).append(sp.attrs)
+    assert [a["state_bytes"] for a in by_name["tdt.batcher.take_params"]] \
+        == [cfg.state_bytes()] * 2                  # built, and rebuilt
+    rounds = by_name["tdt.batcher.decode_round"]
+    assert rounds and all(a["state_slots"] == cfg.batch for a in rounds)
+    # 2 attention layers x the lengths the step was given, growing
+    assert all(a["kv_rows"] % 2 == 0 and a["kv_rows"] > 0 for a in rounds)
+    assert max(a["kv_rows"] for a in rounds) > 2 * 3 * 6
+    admits = by_name["tdt.batcher.admit_prefill"]
+    assert len(admits) >= 4 + 1                     # and the replayed ones
+    assert all((a["state_slots"], a["kv_rows"]) == (1, 0) for a in admits)

@@ -101,7 +101,7 @@ def test_the_plan_names_attention_and_mlp_and_the_cache_has_two_lifetimes(toy):
         ("window", "moe"))
     assert (cfg.own_passes, cfg.cache_kind) == (True, "kv_window")
     assert PAGED_CACHE_KINDS["kv_window"] is WindowPagedKVCacheSpec
-    assert sorted(PAGED_CACHE_KINDS) == ["kv", "kv_window", "latent"]
+    assert {"kv", "kv_window", "latent"} <= set(PAGED_CACHE_KINDS)
     from triton_dist_tpu.models.tp_transformer import specs_for
 
     specs = specs_for(cfg)

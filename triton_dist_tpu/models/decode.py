@@ -626,10 +626,207 @@ def refuse_ring(what: str):
         f"that stay")
 
 
-# a config's ``cache_kind`` -> the paged cache its family's passes use
+@dataclasses.dataclass(frozen=True)
+class StatePagedKVCacheSpec(PagedKVCacheSpec):
+    """The paged cache's fourth KIND: pages and per-slot RECURRENT STATE
+    side by side, for a model whose plan names state-space and attention
+    layers (``models/ssm_hybrid.py``; ``cfg.layer_kinds``).
+
+    - attention layers: ``k, v [n_attention_layers, n_pool, h_kv, page,
+      d]`` and ``block_table``, as the k/v kind's, over those layers only.
+    - state-space layers, float32, a fixed size a slot whatever the
+      context: ``ssm [n_mamba_layers, 2, slots, d_state, d_inner]`` (the
+      recurrence's state, channels on the lanes) and ``conv
+      [n_mamba_layers, d_conv, slots, d_inner]`` (the convolution's last
+      inputs).
+
+    State is not paged, has no table and is not masked by a length: a
+    stale row is not hidden, it is wrong. So both pools are RINGS KEYED BY
+    POSITION, as a page row is: the state after position ``p`` lives in
+    ``ssm[:, p % 2]``, the convolution's input at ``p`` in ``conv[:, p %
+    d_conv]``. A step at ``pos`` reads ``ssm[(pos - 1) % 2]`` and the
+    ``d_conv - 1`` rows before ``pos``, and writes ``ssm[pos % 2]`` and
+    ``conv[pos % d_conv]``: never a row it reads. That is what makes the
+    step REPEATABLE (``PAGED_CACHE_KINDS``). A slot's state is RESET by
+    position too: a step at position 0 reads zeros, a convolution row of a
+    position before 0 reads as zero, an admission by prefill writes the
+    state after its last true token (``write_state``); nothing is cleared
+    when a request leaves. An idle or finished slot's dummy step stays at
+    its position and rewrites rows the next admission overwrites or never
+    reads.
+
+    What needs a sequence's state at MORE than its last position refuses
+    this kind by name (:func:`refuse_state`): the prefix cache and a trie
+    hit, ranged or chunked prefill, speculative verify, the disaggregated
+    handoff; and any mesh wider than one device."""
+
+    kind: ClassVar[str] = "kv_state"
+
+    def init(self, cfg, n: int, n_o: int = 1) -> dict:
+        if not self.static_table:
+            raise NotImplementedError(
+                "the kv_state cache kind needs static_table=True")
+        if n != 1 or n_o != 1:
+            raise NotImplementedError(
+                f"the kv_state cache kind lives on a one-device shard "
+                f"(got {n_o} x {n} devices): a slot's state is not sharded "
+                f"over channels yet")
+        if self.extra_pages:
+            refuse_state("the prefix cache (extra_pages: its scratch page)")
+        n_pool, bt = self._table(cfg, n, n_o)
+        kinds, b = cfg.layer_kinds, cfg.batch
+        n_attn, n_mamba = kinds.count("attention"), kinds.count("mamba")
+        pool = lambda: jnp.zeros(
+            (n_attn, n_pool, cfg.n_kv_heads, self.page_size, cfg.head_dim),
+            cfg.dtype)
+        return dict(
+            k=pool(), v=pool(),
+            ssm=jnp.zeros((n_mamba, 2, b, cfg.d_state, cfg.d_inner),
+                          jnp.float32),
+            conv=jnp.zeros((n_mamba, cfg.d_conv, b, cfg.d_inner),
+                           jnp.float32),
+            block_table=bt,
+            n_alloc=jnp.zeros((bt.shape[0],), jnp.int32),
+        )
+
+    def specs(self, cfg) -> dict:
+        t = cfg.axis
+        return dict(PagedKVCacheSpec.specs(self, cfg),
+                    ssm=P(None, None, t, None, None),
+                    conv=P(None, None, t, None))
+
+    # -- the attention layers' pages ----------------------------------------
+
+    def write_and_attend(self, cfg, cache, ki: int, k_new, v_new, q, pos_b,
+                         interpret):
+        """One step of the ``ki``-th attention layer: each slot's new row
+        lands in the page of its position, then the kernel reads ``[0,
+        pos]`` out of the whole pool. ``(attn [b, hq, d] f32, cache)``."""
+        bt = cache["block_table"][0]                       # [b, pages a slot]
+        n_pool = cache["k"].shape[1]
+        col = jnp.minimum(pos_b // self.page_size, bt.shape[1] - 1)
+        # a slot at s_max owns no page: its write drops
+        ids = jnp.where(pos_b < self.s_max,
+                        bt[jnp.arange(bt.shape[0]), col], n_pool)
+        slot = pos_b % self.page_size
+        cache = dict(cache,
+                     k=_write_rows(cache["k"], ki, ids, slot, k_new),
+                     v=_write_rows(cache["v"], ki, ids, slot, v_new))
+        attn = paged_flash_decode(
+            q.astype(cache["k"].dtype),
+            _pool_pages(cache["k"]), _pool_pages(cache["v"]),
+            jnp.clip(pos_b + 1, 0, self.s_max), bt + ki * n_pool,
+            interpret=interpret,
+        )
+        return attn, cache
+
+    def write_prompt(self, cache, ki: int, k, v, slots):
+        """Prefill's rows ``k, v [n, L, h_kv, d]`` of the ``ki``-th
+        attention layer into the page ranges of ``slots [n]``, as whole
+        pages (rows past a prompt's end are junk no length exposes)."""
+        n, L = k.shape[:2]
+        ps = self.page_size
+        n_pages = -(-L // ps)
+        pad = ((0, 0), (0, n_pages * ps - L), (0, 0), (0, 0))
+        ids = cache["block_table"][0][slots, :n_pages].reshape(-1)
+        as_pages = lambda x: jnp.swapaxes(
+            jnp.pad(x, pad).reshape(n, n_pages, ps, *x.shape[2:]), 2, 3
+        ).reshape(-1, x.shape[2], ps, x.shape[3])
+        put = lambda pool, x: pool.at[ki, ids].set(
+            as_pages(x).astype(pool.dtype), mode="drop")
+        return dict(cache, k=put(cache["k"], k), v=put(cache["v"], v))
+
+    # -- the state-space layers' state ----------------------------------------
+
+    def conv_step(self, cache, ki: int, u, pos_b, w, bias):
+        """One step of the ``ki``-th state-space layer's causal depthwise
+        convolution: ``u [b, d]`` is the input at ``pos_b``, ``w [d_conv,
+        d]`` tap-major (the last tap is the newest input). ``(bias + sum_j
+        w[j] * u_{pos - d_conv + 1 + j}  [b, d] f32, cache)``; an input
+        before position 0 is zero, whatever its ring row holds."""
+        ring = cache["conv"][ki]                           # [K, b, d]
+        K = ring.shape[0]
+        out = bias + w[K - 1] * u
+        for r in range(K):
+            # ring row r holds the input of the last position == r (mod K):
+            # tap j of this step, unless it is the row this step writes
+            j = (r - pos_b + K - 1) % K
+            live = (j < K - 1) & (pos_b - (K - 1) + j >= 0)
+            tap = jnp.take(w, jnp.minimum(j, K - 2), axis=0)   # [b, d]
+            # a select, not a product: a stale row may hold anything
+            out = out + jnp.where(live[:, None], tap * ring[r], 0.0)
+        slots = jnp.arange(u.shape[0])
+        conv = cache["conv"].at[ki, pos_b % K, slots].set(u, mode="drop")
+        return out, dict(cache, conv=conv)
+
+    def state_step(self, cache, ki: int, c, dt, b_in, c_out, a, d_skip, pos_b,
+                   interpret):
+        """One step of the ``ki``-th state-space layer's recurrence for
+        every slot (``ops/selective_scan.selective_state_update``): reads
+        the state after ``pos - 1`` (zeros at position 0), writes the
+        state after ``pos``. ``(y [b, d] f32, cache)``."""
+        from triton_dist_tpu.ops.selective_scan import selective_state_update
+
+        y, ssm = selective_state_update(
+            cache["ssm"], ki, (pos_b - 1) % 2, pos_b == 0, c, dt, b_in,
+            c_out, a, d_skip, interpret=interpret)
+        return y, dict(cache, ssm=ssm)
+
+    def write_state(self, cache, ki: int, slots, lens, u, h):
+        """An admission's state of the ``ki``-th state-space layer for
+        ``slots [n]`` whose prompts hold ``lens [n]`` true tokens: ``h [n,
+        d_state, d]`` the recurrence's state after the LAST TRUE token,
+        ``u [n, L, d]`` the convolution's inputs, whose last ``d_conv``
+        true rows go to their ring rows. No other slot's rows are
+        touched."""
+        K = cache["conv"].shape[1]
+        last = lens[:, None] - 1
+        # ring row r <- the last position p < len with p % K == r (none yet:
+        # any row; the step masks it by position)
+        src = last - (last - jnp.arange(K, dtype=jnp.int32)) % K   # [n, K]
+        rows = jnp.take_along_axis(
+            u, jnp.clip(src, 0, u.shape[1] - 1)[:, :, None], axis=1)
+        conv = cache["conv"].at[
+            ki, jnp.arange(K)[None, :], slots[:, None]].set(rows)
+        ssm = cache["ssm"].at[ki, (lens - 1) % 2, slots].set(h)
+        return dict(cache, conv=conv, ssm=ssm)
+
+    def update_and_attend(self, *a, **kw):
+        raise NotImplementedError(
+            "the dense family's decode step reads k/v pools of every layer: "
+            "a kv_state model walks its own plan (cfg.decode_step)")
+
+    def update_multi_and_attend(self, *a, **kw):
+        refuse_state("speculative verify / ranged prefill")
+
+
+def refuse_state(what: str):
+    raise NotImplementedError(
+        f"{what} is not built for the kv_state cache kind "
+        f"(StatePagedKVCacheSpec): a slot's recurrent state is the state "
+        f"after its LAST position only, and it needs the state at a "
+        f"position inside the sequence (to share, to resume from, to roll "
+        f"back to or to hand over)")
+
+
+# a config's ``cache_kind`` -> the paged cache its family's passes use.
+#
+# THE CONTRACT every kind signs: A STEP IS REPEATABLE. ``decode_step`` run
+# twice on the same ``(tok, pos)`` leaves the cache as running it once does,
+# and a slot's step reads nothing that a step of the same slot at the same
+# or a later position has written. The batcher's lookahead leans on it: a
+# step sent ahead of a round that then admits a request is thrown away and
+# run again (``ContinuousBatcher._round_inputs``) on the cache that the
+# vain step already wrote to (it is donated). The page kinds keep the
+# contract because a row is keyed by its position (the second run writes
+# the same k/v rows again); ``kv_state`` keeps it by keying its state the
+# same way: a parity axis of 2 on the recurrence's state and a ring of
+# ``d_conv`` rows on the convolution's inputs, read at ``pos - 1`` and
+# before, written at ``pos`` (``StatePagedKVCacheSpec``).
 PAGED_CACHE_KINDS = {
     spec.kind: spec for spec in (
-        PagedKVCacheSpec, LatentPagedCacheSpec, WindowPagedKVCacheSpec)}
+        PagedKVCacheSpec, LatentPagedCacheSpec, WindowPagedKVCacheSpec,
+        StatePagedKVCacheSpec)}
 
 
 def _decode_mlp(c, x, p, me, n, n_o, interpret):
@@ -1308,8 +1505,9 @@ class ContinuousBatcher:
         """``(tok, pos, logits)`` of the round about to run: its inputs on
         the device and, where the round before sent this step ahead and
         neither a slot nor the cache nor the weights moved since, the
-        logits it already has (else ``None``: the step is still to run; a
-        step sent in vain wrote only rows that this one writes again)."""
+        logits it already has (else ``None``: the step is still to run, on
+        the cache the step sent in vain wrote to; every cache kind's step
+        is repeatable, ``PAGED_CACHE_KINDS``)."""
         a, self._ahead = self._ahead, None
         if a is not None:
             if (a.epoch == self._epoch and np.array_equal(a.tok, self.tok)

@@ -56,13 +56,13 @@ def expert_bytes(params: dict) -> int:
                for k in ("we_gate_up", "we_down") if k in p)
 
 
-def require_one_shard(cfg, family: str) -> None:
+def require_one_shard(cfg, family: str,
+                      missing: str = "the expert exchange across chips") -> None:
     n = _axis_size(cfg.axis)
     if n != 1:
         raise NotImplementedError(
             f"the {family} model serves on a one-device shard: axis "
-            f"{cfg.axis!r} has {n} devices and the expert exchange across "
-            f"chips is not built")
+            f"{cfg.axis!r} has {n} devices and {missing} is not built")
 
 
 def swiglu(x, w_gate_up, w_down):

@@ -61,7 +61,9 @@ from jax.sharding import Mesh
 
 from triton_dist_tpu import obs as _obs
 from triton_dist_tpu.obs import metrics as _mx
-from triton_dist_tpu.models.decode import Request, refuse_ring
+from triton_dist_tpu.models.decode import (
+    Request, refuse_ring, refuse_state,
+)
 from triton_dist_tpu.resilience import elastic, faults, health
 from triton_dist_tpu.resilience import retry as _retry
 from triton_dist_tpu.serving.engine import (
@@ -358,6 +360,9 @@ class DisaggServingEngine:
         if cfg.cache_kind == "kv_window":
             refuse_ring("the disaggregated handoff (whole page chains "
                         "streamed between pools)")
+        if cfg.cache_kind == "kv_state":
+            refuse_state("the disaggregated handoff (page chains streamed "
+                         "between pools; a slot's state rides no page)")
         self.serving = (serving or DisaggServingConfig()).validate()
         # the elastic namespace BOTH pools share (pool-offset PE
         # attribution keys it by topology-global index); None = the

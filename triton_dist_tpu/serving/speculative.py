@@ -89,6 +89,7 @@ from triton_dist_tpu.models.decode import (
     decode_step,
     prefill_cache_ranged,
     refuse_ring,
+    refuse_state,
     specs_for,
 )
 from triton_dist_tpu.models.speculative import accept_lengths
@@ -182,6 +183,9 @@ class SpeculativeBatcher(ContinuousBatcher):
                 "kind (LatentPagedCacheSpec): its verify step reads k/v pools")
         if cfg.cache_kind == "kv_window":
             refuse_ring("speculative decoding (its verify step)")
+        if cfg.cache_kind == "kv_state":
+            refuse_state("speculative decoding (its verify step and the "
+                         "roll-back of rejected drafts)")
         if kw.pop("lookahead", False):
             raise NotImplementedError(
                 "lookahead sends the plain step ahead of its round; a "
