@@ -24,6 +24,10 @@ if PERFBENCH not in sys.path:
     sys.path.insert(0, PERFBENCH)
 from harness import cells  # noqa: E402
 
+from admission_helpers import (  # noqa: E402
+    admission_kernel_operands, check_admission,
+)
+
 # float32 everywhere: what is left is the order of the sums
 TOL = dict(rtol=2e-4, atol=2e-4)
 
@@ -138,6 +142,36 @@ def test_batcher_prefill_then_decode_matches_reference(toy, ref):
         first = len(r.prompt) - 1
         np.testing.assert_allclose(
             np.stack(r.rows), want[first:first + len(out)], **TOL)
+
+
+@pytest.mark.parametrize("length,bucket", [(5, 8), (16, 16)])
+@pytest.mark.parametrize("slot", [0, -1])
+def test_an_admission_runs_and_writes_the_admitted_slot_only(
+        toy, slot, length, bucket):
+    """A one-hot mask on the first or the last slot, a prompt shorter than
+    its bucket or filling it: the other slot's pages bit-identical, the
+    admitted slot's latent rows and logit row the unmasked whole-batch
+    pass's, and one slot's rows counted."""
+    cfg, params, _, _ = toy
+    spec = LatentPagedCacheSpec(64, 8, static_table=True)
+    stats = check_admission(
+        cfg, params, spec, 64, {"lat": "block_table"}, slot % cfg.batch,
+        length, bucket, n_moe=2, tol=TOL, seed=bucket + slot)
+    assert 1 <= int(stats[2]) <= bucket and int(stats[0]) <= 2 * 8
+
+
+def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
+    """The grouped GEMMs of an admission read the same operands at 2 slots
+    and at 4: one slot's ``bucket x topk`` assignments, aligned."""
+    cfg, params, _, _ = toy
+    spec = LatentPagedCacheSpec(64, 8, static_table=True)
+    two, four = (admission_kernel_operands(cfg, params, spec, 64, 64, b)
+                 for b in (2, 4))
+    assert len(two) == 2 * 2 and two == four        # 2 GEMMs x 2 expert layers
+    # the sorted rows: 64 x top-2 assignments, each of the 8 experts padded
+    # to a 128-row block (128 + 8 x 127, rounded up), whatever the batch
+    # (2 slots' rows would be 1280, 4 slots' 1536)
+    assert {s[0] for call in two for s in call if len(s) == 2} == {1152}
 
 
 def test_absorbed_equals_expanded_on_the_same_cache(toy):
@@ -343,6 +377,6 @@ def test_engine_serves_it_and_the_spans_carry_the_routing_counters(toy):
         assert 2 <= attrs["experts_hit"] <= attrs["assignments"]
         assert 1 <= attrs["expert_load_max"] <= 2
     for attrs in admits:
-        # a whole-batch pass: 2 slots x bucket rows x top-2 x 2 layers
-        assert attrs["assignments"] == 2 * attrs["bucket"] * 2 * 2
+        # the admitted slot's rows: bucket x top-2 x 2 expert layers
+        assert attrs["assignments"] == attrs["bucket"] * 2 * 2
         assert attrs["experts_hit"] <= 2 * 8

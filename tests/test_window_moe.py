@@ -31,6 +31,10 @@ if PERFBENCH not in sys.path:
     sys.path.insert(0, PERFBENCH)
 from harness import cells, correct  # noqa: E402
 
+from admission_helpers import (  # noqa: E402
+    admission_kernel_operands, check_admission,
+)
+
 # (the package exports a function under the module's name)
 fd = importlib.import_module("triton_dist_tpu.ops.flash_decode")
 
@@ -187,6 +191,43 @@ def test_batcher_prefill_then_decode_matches_reference(toy, ref, served, case):
     first = len(r.prompt) - 1
     np.testing.assert_allclose(
         np.stack(r.rows), want[first:first + len(out)], **TOL)
+
+
+POOLS = {"k_full": "block_table", "v_full": "block_table",
+         "k_win": "block_table_win", "v_win": "block_table_win"}
+
+
+@pytest.mark.parametrize("length,bucket", [(5, 8), (14, 16)])
+@pytest.mark.parametrize("slot", [0, -1])
+def test_an_admission_runs_and_writes_the_admitted_slot_only(
+        toy, slot, length, bucket):
+    """A one-hot mask on the first or the last slot, a prompt shorter than
+    its bucket and one longer than the ring (12 positions, so its window
+    layers' rows wrap): the other slot's pages and rings bit-identical,
+    the admitted slot's rows and logit row the unmasked whole-batch
+    pass's, and one slot's rows counted."""
+    cfg, params, _, _ = toy
+    spec = WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    assert 5 < spec.ring(cfg) * PAGE < 14
+    counters = check_admission(
+        cfg, params, spec, S_MAX, POOLS, slot % cfg.batch, length, bucket,
+        n_moe=3, tol=TOL, seed=bucket + slot)
+    # every expert is held here; an admission reads no key rows
+    assert [int(v) for v in counters[3:]] == [0, 0, 0]
+
+
+def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
+    """The grouped GEMMs of an admission read the same operands at 2 slots
+    and at 4: one slot's ``bucket x topk`` assignments, aligned."""
+    cfg, params, _, _ = toy
+    spec = WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    two, four = (admission_kernel_operands(cfg, params, spec, S_MAX, 32, b)
+                 for b in (2, 4))
+    assert len(two) == 2 * 3 and two == four        # 2 GEMMs x 3 expert layers
+    # the sorted rows: 32 x top-2 assignments, each of the 8 experts padded
+    # to a 128-row block (64 + 8 x 127, rounded up), whatever the batch
+    # (4 slots' rows would be 1280)
+    assert {s[0] for call in two for s in call if len(s) == 2} == {1152}
 
 
 def _ring_pools(rng, b, h_kv, d, lens, ring):
@@ -541,6 +582,7 @@ def test_engine_serves_it_with_lookahead_and_the_spans_carry_the_counters(
     late = [a for a in rounds if a["window_rows"] == 3 * 2 * WINDOW]
     assert late and max(a["full_rows"] for a in late) > 2 * WINDOW
     for attrs in admits:
+        # the admitted slot's rows: bucket x top-2 x 3 expert layers
         assert (attrs["assignments"] + attrs["assignments_elsewhere"]
-                == 2 * attrs["bucket"] * 2 * 3)
+                == attrs["bucket"] * 2 * 3)
         assert attrs["window_rows"] == attrs["full_rows"] == 0

@@ -572,18 +572,17 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
         )
         return attn, cache
 
-    def write_prompt(self, cfg, cache, kind: str, ki: int, k, v, lens,
-                     slot_mask=None):
-        """Prefill's rows ``k, v [b, L, h_kv, d]`` of the ``ki``-th layer
-        of its kind, as whole pages: a full layer's positions ``[0, L)``
-        into the slot's page range; a window layer's LAST ``ring * page``
-        true positions (``lens [b]``: padding past a prompt's end would
-        overwrite what its window still sees) at their ring addresses.
-        ``slot_mask`` gates the scatter INDICES."""
+    def write_prompt(self, cfg, cache, kind: str, ki: int, k, v, lens, slots):
+        """Prefill's rows ``k, v [n, L, h_kv, d]`` of the ``ki``-th layer
+        of its kind for ``slots [n]``, as whole pages: a full layer's
+        positions ``[0, L)`` into each slot's page range; a window layer's
+        LAST ``ring * page`` true positions (``lens [n]``: padding past a
+        prompt's end would overwrite what its window still sees) at their
+        ring addresses. No other slot's pages are touched."""
         kn, vn, tn = self._pool_of(kind)
-        b, L = k.shape[:2]
+        n, L = k.shape[:2]
         ps = self.page_size
-        bt = cache[tn][0]
+        bt = cache[tn][0][slots]                           # [n, pages a slot]
         if kind == "window":
             span = bt.shape[1] * ps
             # ring address j holds the last position p < len with
@@ -599,14 +598,11 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
                 pad = ((0, 0), (0, n_pages * ps - L), (0, 0), (0, 0))
                 k, v = jnp.pad(k, pad), jnp.pad(v, pad)
             ids = bt[:, :n_pages]
-        n_pool = cache[kn].shape[1]
-        if slot_mask is not None:
-            ids = jnp.where(slot_mask[:, None], ids, n_pool)   # OOB -> dropped
         as_pages = lambda x: jnp.swapaxes(
-            x.reshape(b, -1, ps, *x.shape[2:]), 2, 3
+            x.reshape(n, -1, ps, *x.shape[2:]), 2, 3
         ).reshape(-1, x.shape[2], ps, x.shape[3])
         put = lambda pool, x: pool.at[ki, ids.reshape(-1)].set(
-            as_pages(x).astype(pool.dtype), mode="drop")
+            as_pages(x).astype(pool.dtype))
         return dict(cache, **{kn: put(cache[kn], k), vn: put(cache[vn], v)})
 
     def update_and_attend(self, *a, **kw):

@@ -19,6 +19,10 @@ the family's own.
   own experts give, and nothing stands in for the others. The share that
   holds the bank's first expert adds the shared expert (one share of a
   layer does).
+- :func:`admitted_rows` / :func:`last_rows`: the slots whose rows a
+  prefill pass runs and where their logit rows go, for every layer-plan
+  family (``models/ssm_hybrid.py`` asks them too): an admission runs the
+  rows it admits.
 """
 
 from __future__ import annotations
@@ -63,6 +67,29 @@ def require_one_shard(cfg, family: str,
         raise NotImplementedError(
             f"the {family} model serves on a one-device shard: axis "
             f"{cfg.axis!r} has {n} devices and {missing} is not built")
+
+
+def admitted_rows(prompt, slot_mask, pick, b: int, L: int):
+    """What a prefill pass of ``prompt [b*L]`` runs: ``(slots [n], tokens
+    [n, L], pick [n])``. With a one-hot ``slot_mask [b]`` (an admission)
+    the ONE slot it names: the other slots are mid-sequence, and a whole
+    batch of buckets is ``b`` times the work; the slot is found here,
+    inside the program, so one program a bucket serves every slot. Without
+    a mask (``generate``) every slot. ``pick [b]`` is each prompt's last
+    true position (default ``L - 1``). The mask must be one-hot:
+    ``argmax`` of an empty one is slot 0."""
+    if pick is None:
+        pick = jnp.full((b,), L - 1, jnp.int32)
+    slots = (jnp.arange(b, dtype=jnp.int32) if slot_mask is None
+             else jnp.argmax(slot_mask)[None].astype(jnp.int32))
+    pick = jnp.clip(pick, 0, L - 1)[slots]
+    return slots, prompt.reshape(b, L)[slots], pick
+
+
+def last_rows(rows, slots, b: int):
+    """A pass's ``last [b, V]``: the logit ``rows [n, V]`` of the slots
+    that ran, zeros elsewhere (the batcher reads the admitted slot's)."""
+    return jnp.zeros((b, rows.shape[-1]), rows.dtype).at[slots].set(rows)
 
 
 def swiglu(x, w_gate_up, w_down):

@@ -40,6 +40,13 @@ Equations (``x [T, H]``; RMSNorm everywhere; softmax and router in f32):
   router still scores every expert, the layer computes the part of the
   result its own experts give, and nothing stands in for the others.
 
+PREFILL (an admission) computes THE ADMITTED SLOT'S ROWS ONLY, ``[1,
+bucket]``, the slot found from ``slot_mask`` inside the pass
+(``gated_experts.admitted_rows``), and writes that slot's pages and no
+other's: the other slots are mid-sequence, and a whole batch of buckets is
+``slots`` times the work. Without a mask (``generate``) every slot's rows
+run.
+
 Serving runs this family on a ONE-device shard: the expert exchange across
 chips is not built, and the entry points refuse a wider axis by name.
 """
@@ -56,8 +63,9 @@ from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.models.gated_experts import (  # noqa: F401  (the
     # family's names for what both plan families share)
-    DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, dense_mlp,
-    expert_bytes, moe_mlp, require_one_shard, route, routing_stats,
+    DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, admitted_rows,
+    dense_mlp, expert_bytes, last_rows, moe_mlp, require_one_shard, route,
+    routing_stats,
 )
 from triton_dist_tpu.models.tp_transformer import TransformerConfig, rmsnorm
 from triton_dist_tpu.ops.mla_decode import latent_row, mla_paged_decode
@@ -328,36 +336,36 @@ def forward_logits(cfg: MLAMoEConfig, params, tokens, interpret=None):
 
 def prefill_cache(cfg: MLAMoEConfig, params, cache, prompt, spec, s_max,
                   slot_mask=None, pick=None, interpret=None):
-    """Bulk prefill (inside shard_map, one-device shard): the expanded
-    forward over ``prompt [b*L]``, every position's latent row written to
-    the pool through the slots' static page ranges (``slot_mask`` gates
-    the scatter INDICES, the paged discipline), and the head applied to
-    the picked row of each slot only. Returns ``(cache, last [b, V],
-    stats int32[3])``."""
+    """Bulk prefill (inside shard_map, one-device shard) of ``prompt
+    [b*L]``: the expanded forward, every position's latent row written to
+    the pool through its slot's static page range, and the head applied to
+    the picked row of each slot only. With ``slot_mask`` (an admission)
+    ONLY THE MASKED SLOT'S ROWS run, and only its pages are written;
+    without, every slot's. Returns ``(cache, last [b, V], stats
+    int32[3])``; ``last`` holds the rows of the slots that ran, zeros
+    elsewhere."""
     require_one_shard(cfg, "latent-attention / gated-expert")
     c = cfg
     b, L = c.batch, c.seq
     ps = spec.page_size
+    slots, tokens, pick = admitted_rows(prompt, slot_mask, pick, b, L)
+    n = len(slots)
     sink: list = []
-    x, stats = forward_hidden(c, params, prompt, b, L, interpret, sink)
+    x, stats = forward_hidden(
+        c, params, tokens.reshape(-1), n, L, interpret, sink)
     n_pages = -(-L // ps)
-    ids = cache["block_table"][0][:, :n_pages]              # [b, n_pages]
-    n_pool = cache["lat"].shape[1]
-    if slot_mask is not None:
-        ids = jnp.where(slot_mask[:, None], ids, n_pool)    # OOB -> dropped
+    ids = cache["block_table"][0][slots, :n_pages].reshape(-1)
     lat = cache["lat"]
     for li, rows in enumerate(sink):
-        rows = rows.reshape(b, L, -1)
+        rows = rows.reshape(n, L, -1)
         if n_pages * ps != L:
             rows = jnp.pad(rows, ((0, 0), (0, n_pages * ps - L), (0, 0)))
-        lat = lat.at[li, ids.reshape(-1)].set(
-            rows.reshape(b * n_pages, ps, -1).astype(lat.dtype), mode="drop")
+        lat = lat.at[li, ids].set(
+            rows.reshape(n * n_pages, ps, -1).astype(lat.dtype))
     cache = dict(cache, lat=lat)
-    if pick is None:
-        pick = jnp.full((b,), L - 1, jnp.int32)
-    rows = jnp.arange(b, dtype=jnp.int32) * L + jnp.clip(pick, 0, L - 1)
+    rows = jnp.arange(n, dtype=jnp.int32) * L + pick
     xs = rmsnorm(x[rows], params["final_norm"], c.norm_eps)
-    return cache, xs @ params["lm_head"], stats
+    return cache, last_rows(xs @ params["lm_head"], slots, b), stats
 
 
 def decode_step(cfg: MLAMoEConfig, params, cache, tokens, pos, *, spec,

@@ -59,7 +59,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from triton_dist_tpu.models.gated_experts import dense_mlp, require_one_shard
+from triton_dist_tpu.models.gated_experts import (
+    admitted_rows, dense_mlp, last_rows, require_one_shard,
+)
 from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig, _causal_gqa_attention, rmsnorm,
 )
@@ -333,12 +335,7 @@ def prefill_cache(cfg: SSMHybridConfig, params, cache, prompt, spec, s_max,
     require_one_shard(cfg, FAMILY, NOT_BUILT)
     c = cfg
     b, L = c.batch, c.seq
-    if pick is None:
-        pick = jnp.full((b,), L - 1, jnp.int32)
-    slots = (jnp.arange(b, dtype=jnp.int32) if slot_mask is None
-             else jnp.argmax(slot_mask)[None].astype(jnp.int32))
-    pick = jnp.clip(pick, 0, L - 1)[slots]
-    tokens = prompt.reshape(b, L)[slots]
+    slots, tokens, pick = admitted_rows(prompt, slot_mask, pick, b, L)
     sink: list = []
     x = forward_hidden(c, params, tokens, pick + 1, interpret, sink)
     for (kind, ki), kept in zip(_numbered(c), sink):
@@ -347,8 +344,7 @@ def prefill_cache(cfg: SSMHybridConfig, params, cache, prompt, spec, s_max,
         else:
             cache = spec.write_prompt(cache, ki, *kept, slots)
     rows = _head(c, params, x[jnp.arange(len(slots)), pick])
-    last = jnp.zeros((b, rows.shape[-1]), rows.dtype).at[slots].set(rows)
-    return cache, last, _counters(len(slots), 0)
+    return cache, last_rows(rows, slots, b), _counters(len(slots), 0)
 
 
 def decode_step(cfg: SSMHybridConfig, params, cache, tokens, pos, *, spec,

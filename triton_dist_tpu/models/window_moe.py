@@ -35,7 +35,12 @@ PREFILL attends full layers as the dense family does and window layers
 over a BAND (blocks of ``window`` queries against their own and the
 previous block of keys: no ``[L, L]`` scores for them); DECODE reads the
 pools through ``ops/flash_decode.paged_flash_decode`` (``window=`` on a
-window layer: two pages a row at page 128, whatever the context).
+window layer: two pages a row at page 128, whatever the context). An
+admission computes THE ADMITTED SLOT'S ROWS ONLY, ``[1, bucket]``, the slot
+found from ``slot_mask`` inside the pass (``gated_experts.admitted_rows``),
+and writes that slot's pages and rings and no other's: the other slots are
+mid-sequence, and a whole batch of buckets is ``slots`` times the work.
+Without a mask (``generate``) every slot's rows run.
 
 Serving runs this family on a ONE-device shard: the expert exchange across
 chips is not built, and the entry points refuse a wider axis by name.
@@ -52,8 +57,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.models.gated_experts import (
-    DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, dense_mlp,
-    expert_bytes, moe_mlp, require_one_shard,
+    DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, admitted_rows,
+    dense_mlp, expert_bytes, last_rows, moe_mlp, require_one_shard,
 )
 from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig, _causal_gqa_attention, rmsnorm, rope,
@@ -339,25 +344,28 @@ def forward_logits(cfg: WindowMoEConfig, params, tokens, interpret=None):
 
 def prefill_cache(cfg: WindowMoEConfig, params, cache, prompt, spec, s_max,
                   slot_mask=None, pick=None, interpret=None):
-    """Bulk prefill (inside shard_map, one-device shard): the forward over
-    ``prompt [b*L]``; a full layer's rows go to its slot's page range, a
-    window layer's last ring of rows (counted from each slot's true
-    length, ``pick + 1``) to their ring addresses; the head on the picked
-    row of each slot only. Returns ``(cache, last [b, V], counters)``."""
+    """Bulk prefill (inside shard_map, one-device shard) of ``prompt
+    [b*L]``. With ``slot_mask`` (an admission) ONLY THE MASKED SLOT'S ROWS
+    run, and only its pages and rings are written; without, every slot's.
+    A full layer's rows go to the slot's page range, a window layer's last
+    ring of rows (counted from the slot's true length, ``pick + 1``) to
+    their ring addresses; the head on the picked row of each slot only.
+    Returns ``(cache, last [b, V], counters)``; ``last`` holds the rows of
+    the slots that ran, zeros elsewhere."""
     require_one_shard(cfg, FAMILY)
     c = cfg
     b, L = c.batch, c.seq
+    slots, tokens, pick = admitted_rows(prompt, slot_mask, pick, b, L)
+    n = len(slots)
     sink: list = []
-    x, stats = forward_hidden(c, params, prompt, b, L, interpret, sink)
-    if pick is None:
-        pick = jnp.full((b,), L - 1, jnp.int32)
-    pick = jnp.clip(pick, 0, L - 1)
+    x, stats = forward_hidden(
+        c, params, tokens.reshape(-1), n, L, interpret, sink)
     for (kind, ki, _), (k, v) in zip(_numbered(c), sink):
-        cache = spec.write_prompt(c, cache, kind, ki, k, v, pick + 1,
-                                  slot_mask)
-    rows = jnp.arange(b, dtype=jnp.int32) * L + pick
+        cache = spec.write_prompt(c, cache, kind, ki, k, v, pick + 1, slots)
+    rows = jnp.arange(n, dtype=jnp.int32) * L + pick
     xs = rmsnorm(x[rows], params["final_norm"], c.norm_eps)
-    return cache, xs @ params["lm_head"], _counters(c, stats, b * L, 0, 0)
+    last = last_rows(xs @ params["lm_head"], slots, b)
+    return cache, last, _counters(c, stats, n * L, 0, 0)
 
 
 def decode_step(cfg: WindowMoEConfig, params, cache, tokens, pos, *, spec,
