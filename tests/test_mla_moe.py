@@ -27,6 +27,7 @@ from harness import cells  # noqa: E402
 from admission_helpers import (  # noqa: E402
     admission_kernel_operands, check_admission,
 )
+from scope_helpers import check_pass  # noqa: E402
 
 # float32 everywhere: what is left is the order of the sums
 TOL = dict(rtol=2e-4, atol=2e-4)
@@ -172,6 +173,24 @@ def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
     # to a 128-row block (128 + 8 x 127, rounded up), whatever the batch
     # (2 slots' rows would be 1280, 4 slots' 1536)
     assert {s[0] for call in two for s in call if len(s) == 2} == {1152}
+
+
+# the family's row of the table of scopes (docs/observability.md): the toy
+# plan has a dense layer and two expert layers, each with a shared expert
+SCOPES = {"attn", "attn/qkv", "attn/kv_write", "attn/out",
+          "ffn", "ffn/gate_up", "ffn/act", "ffn/down", "ffn/route",
+          "ffn/experts", "ffn/shared", "head"}
+
+
+@pytest.mark.parametrize("which", ["step", "admission"])
+def test_every_part_of_a_pass_says_which_part_it_is(toy, which):
+    """The lowered step and admission carry every scope of the family's
+    row and no other ``tdt.`` name, and every matrix product and kernel
+    call lies under a part; only the step calls the decode kernel."""
+    cfg, params, _, _ = toy
+    spec = LatentPagedCacheSpec(64, 8, static_table=True)
+    mesh = Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
+    check_pass(which, cfg, params, spec, mesh, 64, SCOPES)
 
 
 def test_absorbed_equals_expanded_on_the_same_cache(toy):

@@ -29,6 +29,8 @@ if PERFBENCH not in sys.path:
     sys.path.insert(0, PERFBENCH)
 from harness import cells  # noqa: E402
 
+from scope_helpers import check_pass  # noqa: E402
+
 # (the package exports a function under the module's name)
 fd = importlib.import_module("triton_dist_tpu.ops.flash_decode")
 ss = importlib.import_module("triton_dist_tpu.ops.selective_scan")
@@ -318,6 +320,23 @@ def test_an_admission_changes_no_other_slots_state_or_pages(toy):
 
 
 # -- (d) a step sent in vain ------------------------------------------------------
+
+# the family's row of the table of scopes (docs/observability.md): a
+# mixer is ``ssm`` or ``attn`` by the plan, every MLP the dense ``ffn``
+SCOPES = {"attn", "attn/qkv", "attn/kv_write", "attn/out",
+          "ssm", "ssm/proj", "ssm/conv", "ssm/scan",
+          "ffn", "ffn/gate_up", "ffn/act", "ffn/down", "head"}
+
+
+@pytest.mark.parametrize("which", ["step", "admission"])
+def test_every_part_of_a_pass_says_which_part_it_is(toy, which):
+    """The lowered step and admission carry every scope of the family's
+    row and no other ``tdt.`` name, and every matrix product and kernel
+    call lies under a part; only the step calls the decode kernel."""
+    cfg, params, _, _ = toy
+    spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    check_pass(which, cfg, params, spec, _mesh(cfg), S_MAX, SCOPES)
+
 
 def _run_with_a_late_arrival(cfg, params, **kw):
     """Two requests decode on three slots; a third arrives after the third

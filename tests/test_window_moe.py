@@ -34,6 +34,7 @@ from harness import cells, correct  # noqa: E402
 from admission_helpers import (  # noqa: E402
     admission_kernel_operands, check_admission,
 )
+from scope_helpers import check_pass  # noqa: E402
 
 # (the package exports a function under the module's name)
 fd = importlib.import_module("triton_dist_tpu.ops.flash_decode")
@@ -228,6 +229,24 @@ def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
     # to a 128-row block (64 + 8 x 127, rounded up), whatever the batch
     # (4 slots' rows would be 1280)
     assert {s[0] for call in two for s in call if len(s) == 2} == {1152}
+
+
+# the family's row of the table of scopes (docs/observability.md): window
+# and full layers are both ``attn``; the toy plan has a dense layer and
+# three expert layers, each with a shared expert
+SCOPES = {"attn", "attn/qkv", "attn/kv_write", "attn/out",
+          "ffn", "ffn/gate_up", "ffn/act", "ffn/down", "ffn/route",
+          "ffn/experts", "ffn/shared", "head"}
+
+
+@pytest.mark.parametrize("which", ["step", "admission"])
+def test_every_part_of_a_pass_says_which_part_it_is(toy, which):
+    """The lowered step and admission carry every scope of the family's
+    row and no other ``tdt.`` name, and every matrix product and kernel
+    call lies under a part; only the step calls the decode kernel."""
+    cfg, params, _, _ = toy
+    spec = WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    check_pass(which, cfg, params, spec, _mesh(cfg), S_MAX, SCOPES)
 
 
 def _ring_pools(rng, b, h_kv, d, lens, ring):

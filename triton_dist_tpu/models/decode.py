@@ -44,6 +44,7 @@ from triton_dist_tpu.ops.flash_decode import (
     paged_flash_decode,
     paged_flash_decode_distributed,
 )
+from triton_dist_tpu.obs.scopes import scope as _scope
 from triton_dist_tpu.obs.tracer import span as _span
 from triton_dist_tpu.utils import axis_size as _axis_size
 
@@ -169,16 +170,18 @@ class KVCacheSpec:
         own_b = me == pos_b // s_shard                   # [b]
         safe_off = jnp.where(own_b, pos_b % s_shard, s_shard)
         bidx = jnp.arange(cfg.batch)
-        cache = dict(
-            cache,
-            k=_write_rows(cache["k"], li, bidx, safe_off, k_new),
-            v=_write_rows(cache["v"], li, bidx, safe_off, v_new),
-        )
-        attn = flash_decode_distributed(
-            q.astype(cache["k"].dtype), cache["k"][li], cache["v"][li],
-            _local_lens(pos_b, me, s_shard),
-            axis=cfg.axis, config=fd_config, interpret=interpret,
-        )
+        with _scope("attn/kv_write"):
+            cache = dict(
+                cache,
+                k=_write_rows(cache["k"], li, bidx, safe_off, k_new),
+                v=_write_rows(cache["v"], li, bidx, safe_off, v_new),
+            )
+        with _scope("attn/decode"):
+            attn = flash_decode_distributed(
+                q.astype(cache["k"].dtype), cache["k"][li], cache["v"][li],
+                _local_lens(pos_b, me, s_shard),
+                axis=cfg.axis, config=fd_config, interpret=interpret,
+            )
         return attn, cache
 
     def update_multi_and_attend(
@@ -206,17 +209,19 @@ class KVCacheSpec:
         bmat = jnp.broadcast_to(
             jnp.arange(cfg.batch)[:, None], safe_off.shape
         )
-        cache = dict(
-            cache,
-            k=_write_rows(cache["k"], li, bmat, safe_off, k_new),
-            v=_write_rows(cache["v"], li, bmat, safe_off, v_new),
-        )
+        with _scope("attn/kv_write"):
+            cache = dict(
+                cache,
+                k=_write_rows(cache["k"], li, bmat, safe_off, k_new),
+                v=_write_rows(cache["v"], li, bmat, safe_off, v_new),
+            )
         # row i attends global positions < pos0 + i + 1: the ranged entry
         # derives the per-(sequence, chunk-row) local prefix from pos0
-        attn = flash_ranged_prefill_distributed(
-            q.astype(cache["k"].dtype), cache["k"][li], cache["v"][li], pos0,
-            axis=cfg.axis, config=fd_config, interpret=interpret,
-        )
+        with _scope("attn/decode"):
+            attn = flash_ranged_prefill_distributed(
+                q.astype(cache["k"].dtype), cache["k"][li], cache["v"][li],
+                pos0, axis=cfg.axis, config=fd_config, interpret=interpret,
+            )
         return attn, cache
 
 
@@ -360,20 +365,22 @@ class PagedKVCacheSpec:
         own_b = me == pos_b // s_shard                   # [b]
         n_pool = cache["k"].shape[1]
         safe_ids = jnp.where(own_b, page_ids, n_pool)    # OOB → dropped
-        cache = dict(
-            cache,
-            k=_write_rows(cache["k"], li, safe_ids, slot_b, k_new),
-            v=_write_rows(cache["v"], li, safe_ids, slot_b, v_new),
-        )
+        with _scope("attn/kv_write"):
+            cache = dict(
+                cache,
+                k=_write_rows(cache["k"], li, safe_ids, slot_b, k_new),
+                v=_write_rows(cache["v"], li, safe_ids, slot_b, v_new),
+            )
         # the kernel reads its pages out of the WHOLE pool: the table is
         # shifted to this layer's run of pages
-        attn = paged_flash_decode_distributed(
-            q.astype(cache["k"].dtype),
-            _pool_pages(cache["k"]), _pool_pages(cache["v"]),
-            _local_lens(pos_b, me, s_shard),
-            cache["block_table"][0] + li * n_pool,
-            axis=cfg.axis, interpret=interpret,
-        )
+        with _scope("attn/decode"):
+            attn = paged_flash_decode_distributed(
+                q.astype(cache["k"].dtype),
+                _pool_pages(cache["k"]), _pool_pages(cache["v"]),
+                _local_lens(pos_b, me, s_shard),
+                cache["block_table"][0] + li * n_pool,
+                axis=cfg.axis, interpret=interpret,
+            )
         return attn, cache
 
     def update_multi_and_attend(
@@ -408,16 +415,18 @@ class PagedKVCacheSpec:
         n_pool = cache["k"].shape[1]
         safe_ids = jnp.where(own, page_ids, n_pool)        # OOB → dropped
         slot = off_mat % self.page_size
-        cache = dict(
-            cache,
-            k=_write_rows(cache["k"], li, safe_ids, slot, k_new),
-            v=_write_rows(cache["v"], li, safe_ids, slot, v_new),
-        )
-        attn = paged_flash_ranged_prefill_distributed(
-            q.astype(cache["k"].dtype),
-            _pool_pages(cache["k"]), _pool_pages(cache["v"]),
-            pos0, bt + li * n_pool, axis=cfg.axis, interpret=interpret,
-        )
+        with _scope("attn/kv_write"):
+            cache = dict(
+                cache,
+                k=_write_rows(cache["k"], li, safe_ids, slot, k_new),
+                v=_write_rows(cache["v"], li, safe_ids, slot, v_new),
+            )
+        with _scope("attn/decode"):
+            attn = paged_flash_ranged_prefill_distributed(
+                q.astype(cache["k"].dtype),
+                _pool_pages(cache["k"]), _pool_pages(cache["v"]),
+                pos0, bt + li * n_pool, axis=cfg.axis, interpret=interpret,
+            )
         return attn, cache
 
 
@@ -559,17 +568,19 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
         # a slot at s_max owns no page: its write drops
         safe_ids = jnp.where(pos_b < self.s_max, page_ids, n_pool)
         slot = pos_b % self.page_size
-        cache = dict(
-            cache,
-            **{kn: _write_rows(cache[kn], ki, safe_ids, slot, k_new),
-               vn: _write_rows(cache[vn], ki, safe_ids, slot, v_new)})
-        attn = paged_flash_decode(
-            q.astype(cache[kn].dtype),
-            _pool_pages(cache[kn]), _pool_pages(cache[vn]),
-            jnp.clip(pos_b + 1, 0, self.s_max), bt + ki * n_pool,
-            window=cfg.window if kind == "window" else None,
-            interpret=interpret,
-        )
+        with _scope("attn/kv_write"):
+            cache = dict(
+                cache,
+                **{kn: _write_rows(cache[kn], ki, safe_ids, slot, k_new),
+                   vn: _write_rows(cache[vn], ki, safe_ids, slot, v_new)})
+        with _scope("attn/decode"):
+            attn = paged_flash_decode(
+                q.astype(cache[kn].dtype),
+                _pool_pages(cache[kn]), _pool_pages(cache[vn]),
+                jnp.clip(pos_b + 1, 0, self.s_max), bt + ki * n_pool,
+                window=cfg.window if kind == "window" else None,
+                interpret=interpret,
+            )
         return attn, cache
 
     def write_prompt(self, cfg, cache, kind: str, ki: int, k, v, lens, slots):
@@ -705,15 +716,17 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
         ids = jnp.where(pos_b < self.s_max,
                         bt[jnp.arange(bt.shape[0]), col], n_pool)
         slot = pos_b % self.page_size
-        cache = dict(cache,
-                     k=_write_rows(cache["k"], ki, ids, slot, k_new),
-                     v=_write_rows(cache["v"], ki, ids, slot, v_new))
-        attn = paged_flash_decode(
-            q.astype(cache["k"].dtype),
-            _pool_pages(cache["k"]), _pool_pages(cache["v"]),
-            jnp.clip(pos_b + 1, 0, self.s_max), bt + ki * n_pool,
-            interpret=interpret,
-        )
+        with _scope("attn/kv_write"):
+            cache = dict(cache,
+                         k=_write_rows(cache["k"], ki, ids, slot, k_new),
+                         v=_write_rows(cache["v"], ki, ids, slot, v_new))
+        with _scope("attn/decode"):
+            attn = paged_flash_decode(
+                q.astype(cache["k"].dtype),
+                _pool_pages(cache["k"]), _pool_pages(cache["v"]),
+                jnp.clip(pos_b + 1, 0, self.s_max), bt + ki * n_pool,
+                interpret=interpret,
+            )
         return attn, cache
 
     def write_prompt(self, cache, ki: int, k, v, slots):
@@ -740,19 +753,21 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
         d]`` tap-major (the last tap is the newest input). ``(bias + sum_j
         w[j] * u_{pos - d_conv + 1 + j}  [b, d] f32, cache)``; an input
         before position 0 is zero, whatever its ring row holds."""
-        ring = cache["conv"][ki]                           # [K, b, d]
-        K = ring.shape[0]
-        out = bias + w[K - 1] * u
-        for r in range(K):
-            # ring row r holds the input of the last position == r (mod K):
-            # tap j of this step, unless it is the row this step writes
-            j = (r - pos_b + K - 1) % K
-            live = (j < K - 1) & (pos_b - (K - 1) + j >= 0)
-            tap = jnp.take(w, jnp.minimum(j, K - 2), axis=0)   # [b, d]
-            # a select, not a product: a stale row may hold anything
-            out = out + jnp.where(live[:, None], tap * ring[r], 0.0)
-        slots = jnp.arange(u.shape[0])
-        conv = cache["conv"].at[ki, pos_b % K, slots].set(u, mode="drop")
+        with _scope("ssm/conv"):
+            ring = cache["conv"][ki]                       # [K, b, d]
+            K = ring.shape[0]
+            out = bias + w[K - 1] * u
+            for r in range(K):
+                # ring row r holds the input of the last position == r (mod
+                # K): tap j of this step, unless it is the row this step
+                # writes
+                j = (r - pos_b + K - 1) % K
+                live = (j < K - 1) & (pos_b - (K - 1) + j >= 0)
+                tap = jnp.take(w, jnp.minimum(j, K - 2), axis=0)   # [b, d]
+                # a select, not a product: a stale row may hold anything
+                out = out + jnp.where(live[:, None], tap * ring[r], 0.0)
+            slots = jnp.arange(u.shape[0])
+            conv = cache["conv"].at[ki, pos_b % K, slots].set(u, mode="drop")
         return out, dict(cache, conv=conv)
 
     def state_step(self, cache, ki: int, c, dt, b_in, c_out, a, d_skip, pos_b,
@@ -763,9 +778,10 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
         state after ``pos``. ``(y [b, d] f32, cache)``."""
         from triton_dist_tpu.ops.selective_scan import selective_state_update
 
-        y, ssm = selective_state_update(
-            cache["ssm"], ki, (pos_b - 1) % 2, pos_b == 0, c, dt, b_in,
-            c_out, a, d_skip, interpret=interpret)
+        with _scope("ssm/scan"):
+            y, ssm = selective_state_update(
+                cache["ssm"], ki, (pos_b - 1) % 2, pos_b == 0, c, dt, b_in,
+                c_out, a, d_skip, interpret=interpret)
         return y, dict(cache, ssm=ssm)
 
     def write_state(self, cache, ki: int, slots, lens, u, h):
@@ -830,79 +846,84 @@ def _decode_mlp(c, x, p, me, n, n_o, interpret):
     per-group batch for decode, batch × chunk for the speculative verify
     step): dense SwiGLU, all-experts-einsum TP-MoE, or EP dispatch over
     the a2a (flat and hierarchical). Returns the updated residual."""
-    m = x.shape[0]
-    h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
-    if isinstance(c, EPMoETransformerConfig):
-        # EP serving decode (the reference's headline inference
-        # configuration — its LL a2a IS decode-shaped EP dispatch,
-        # README.md:87): each PE takes its row slice of the group's
-        # replicated activations, dispatches over the EP transport to
-        # the expert owners, and the combined shard all-gathers back.
-        # HIERARCHICAL (ep_outer set): sources are every (outer, inner)
-        # PE — the group's slice divides again over the inner axis — and
-        # the two-phase dispatch (node-dedup over the slow axis, expert
-        # scatter on the fast one) spans the whole mesh: the reference's
-        # 4-node × 8-GPU serving shape (test_ep_moe_inference.py) with
-        # DCN as the outer axis.
-        from triton_dist_tpu.models.tp_transformer import ep_moe_apply
+    with _scope("ffn"):
+        m = x.shape[0]
+        h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
+        if isinstance(c, EPMoETransformerConfig):
+            # EP serving decode (the reference's headline inference
+            # configuration — its LL a2a IS decode-shaped EP dispatch,
+            # README.md:87): each PE takes its row slice of the group's
+            # replicated activations, dispatches over the EP transport to
+            # the expert owners, and the combined shard all-gathers back.
+            # HIERARCHICAL (ep_outer set): sources are every (outer, inner)
+            # PE — the group's slice divides again over the inner axis —
+            # and the two-phase dispatch (node-dedup over the slow axis,
+            # expert scatter on the fast one) spans the whole mesh: the
+            # reference's 4-node × 8-GPU serving shape
+            # (test_ep_moe_inference.py) with DCN as the outer axis.
+            from triton_dist_tpu.models.tp_transformer import ep_moe_apply
 
-        if m % n:
-            raise ValueError(
-                f"EP serving decode shards its rows over the "
-                f"{c.axis!r} axis: per-group rows={m} must divide "
-                f"evenly over {n} PEs"
+            if m % n:
+                raise ValueError(
+                    f"EP serving decode shards its rows over the "
+                    f"{c.axis!r} axis: per-group rows={m} must divide "
+                    f"evenly over {n} PEs"
+                )
+            m_loc = m // n
+            h_loc = jax.lax.dynamic_slice_in_dim(h, me * m_loc, m_loc, 0)
+            # per-(src, dest) slab worst case: a src PE holds m_loc rows,
+            # each with topk assignments (flat) / at most one deduplicated
+            # copy per destination node (hierarchical)
+            y_loc = ep_moe_apply(
+                c, h_loc, p,
+                c.ep_max_m or (m_loc if n_o > 1 else m_loc * c.topk),
+                interpret=interpret,
             )
-        m_loc = m // n
-        h_loc = jax.lax.dynamic_slice_in_dim(h, me * m_loc, m_loc, 0)
-        # per-(src, dest) slab worst case: a src PE holds m_loc rows,
-        # each with topk assignments (flat) / at most one deduplicated
-        # copy per destination node (hierarchical)
-        y_loc = ep_moe_apply(
-            c, h_loc, p,
-            c.ep_max_m or (m_loc if n_o > 1 else m_loc * c.topk),
-            interpret=interpret,
-        )
-        y = jax.lax.all_gather(y_loc, c.axis, axis=0, tiled=True)
-        return x + y.astype(x.dtype)
-    if isinstance(c, MoETransformerConfig):
-        # decode-shaped MoE: at serving row counts every expert's F-shard
-        # weights stream from HBM regardless (weight-bound), so computing
-        # ALL experts with dense einsums + a one-hot topk combine is the
-        # TPU-shaped move — no gather/sort on a [m, H] activation.
-        # (Prefill-sized token counts go through the fused AG-GroupGEMM
-        # pipeline instead.)
-        from triton_dist_tpu.ops.moe_utils import select_experts
+            y = jax.lax.all_gather(y_loc, c.axis, axis=0, tiled=True)
+            return x + y.astype(x.dtype)
+        if isinstance(c, MoETransformerConfig):
+            # decode-shaped MoE: at serving row counts every expert's F-shard
+            # weights stream from HBM regardless (weight-bound), so computing
+            # ALL experts with dense einsums + a one-hot topk combine is the
+            # TPU-shaped move — no gather/sort on a [m, H] activation.
+            # (Prefill-sized token counts go through the fused AG-GroupGEMM
+            # pipeline instead.)
+            from triton_dist_tpu.ops.moe_utils import select_experts
 
-        logits = h.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-        tw, ids = select_experts(logits, c.topk)           # [m, topk]
-        # int8 expert banks (quantize_moe_serving_params) read the int8
-        # stream in the einsums — HALF the HBM bytes this weight-bound
-        # step is made of — and the per-(e, col) scales apply AFTER the
-        # contraction (exact: the scale is constant over the contracted
-        # dim) in the f32 stages that already exist (gelu input /
-        # combine), costing zero precision.
-        quant = "w_up_scale" in p
-        w_up = p["w_up"].astype(h.dtype) if quant else p["w_up"]
-        w_down = p["w_down"].astype(x.dtype) if quant else p["w_down"]
-        hE = jnp.einsum("bh,ehf->ebf", h, w_up)            # [E, m, F/n]
-        hE = hE.astype(jnp.float32)
-        if quant:
-            hE = hE * p["w_up_scale"]                      # [E,1,F] bcasts
-        act = jax.nn.gelu(hE).astype(x.dtype)
-        yE = jnp.einsum("ebf,efh->ebh", act, w_down)
-        yE = yE.astype(jnp.float32)
-        if quant:
-            yE = yE * p["w_down_scale"]
-        wE = (
-            jnp.zeros((m, c.n_experts), jnp.float32)
-            .at[jnp.arange(m)[:, None], ids]
-            .add(tw)
-        )
-        y = jnp.einsum("be,ebh->bh", wE, yE)  # yE already f32
-        return x + jax.lax.psum(y.astype(x.dtype), c.axis)
-    gate, up = unpack_gate_up(h @ p["w_gate_up"], c)
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    return x + jax.lax.psum(act @ p["w_down"], c.axis)
+            logits = h.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+            tw, ids = select_experts(logits, c.topk)           # [m, topk]
+            # int8 expert banks (quantize_moe_serving_params) read the int8
+            # stream in the einsums — HALF the HBM bytes this weight-bound
+            # step is made of — and the per-(e, col) scales apply AFTER the
+            # contraction (exact: the scale is constant over the contracted
+            # dim) in the f32 stages that already exist (gelu input /
+            # combine), costing zero precision.
+            quant = "w_up_scale" in p
+            w_up = p["w_up"].astype(h.dtype) if quant else p["w_up"]
+            w_down = p["w_down"].astype(x.dtype) if quant else p["w_down"]
+            hE = jnp.einsum("bh,ehf->ebf", h, w_up)            # [E, m, F/n]
+            hE = hE.astype(jnp.float32)
+            if quant:
+                hE = hE * p["w_up_scale"]                      # [E,1,F] bcasts
+            act = jax.nn.gelu(hE).astype(x.dtype)
+            yE = jnp.einsum("ebf,efh->ebh", act, w_down)
+            yE = yE.astype(jnp.float32)
+            if quant:
+                yE = yE * p["w_down_scale"]
+            wE = (
+                jnp.zeros((m, c.n_experts), jnp.float32)
+                .at[jnp.arange(m)[:, None], ids]
+                .add(tw)
+            )
+            y = jnp.einsum("be,ebh->bh", wE, yE)  # yE already f32
+            return x + jax.lax.psum(y.astype(x.dtype), c.axis)
+        with _scope("ffn/gate_up"):
+            gu = h @ p["w_gate_up"]
+        with _scope("ffn/act"):
+            gate, up = unpack_gate_up(gu, c)
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+        with _scope("ffn/down"):
+            return x + jax.lax.psum(act @ p["w_down"], c.axis)
 
 
 def decode_step(
@@ -952,49 +973,56 @@ def decode_step(
         pos_b = jax.lax.dynamic_slice_in_dim(pos_g, my_o * b_att, b_att, 0)
     else:
         pos_b = pos_g
-    x = params["embed"][tokens]  # [b_att, H] replicated per group
-    cache = spec.pre_step(c, cache, pos_b, me, n)
+    with _scope("head"):
+        x = params["embed"][tokens]  # [b_att, H] replicated per group
+    with _scope("attn"):
+        cache = spec.pre_step(c, cache, pos_b, me, n)
 
     for li, p in enumerate(params["layers"]):
         # --- attention (SP flash decode over the sharded cache) ---
-        h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv_loc = h @ p["wqkv"]                            # [b, qkv/n] local
-        # head-complete qkv: PE-major concat == kv-group-major (the groups
-        # are sharded contiguously), so a tiled all_gather restores the
-        # global group order
-        qkv = jax.lax.all_gather(qkv_loc, c.axis, axis=1, tiled=True)
-        qkv = qkv.reshape(c.batch, c.n_kv_heads, g + 2, d)
-        q = qkv[:, :, :g, :].reshape(c.batch, 1, c.n_q_heads, d)
-        k_new = qkv[:, :, g, :].reshape(c.batch, 1, c.n_kv_heads, d)
-        v_new = qkv[:, :, g + 1, :]                         # [b, h_kv, d]
-        # per-sequence rotary position (ragged decode): vmap over batch
-        rope_b = jax.vmap(lambda xi, pi: rope(xi, pi, c.rope_theta))
-        q = rope_b(q, pos_b[:, None])[:, 0]                 # [b, hq, d]
-        k_new = rope_b(k_new, pos_b[:, None])[:, 0]         # [b, h_kv, d]
+        with _scope("attn"):
+            h = rmsnorm(x, p["attn_norm"], c.norm_eps)
+            with _scope("attn/qkv"):
+                qkv_loc = h @ p["wqkv"]                # [b, qkv/n] local
+                # head-complete qkv: PE-major concat == kv-group-major (the
+                # groups are sharded contiguously), so a tiled all_gather
+                # restores the global group order
+                qkv = jax.lax.all_gather(qkv_loc, c.axis, axis=1, tiled=True)
+            qkv = qkv.reshape(c.batch, c.n_kv_heads, g + 2, d)
+            q = qkv[:, :, :g, :].reshape(c.batch, 1, c.n_q_heads, d)
+            k_new = qkv[:, :, g, :].reshape(c.batch, 1, c.n_kv_heads, d)
+            v_new = qkv[:, :, g + 1, :]                     # [b, h_kv, d]
+            # per-sequence rotary position (ragged decode): vmap over batch
+            rope_b = jax.vmap(lambda xi, pi: rope(xi, pi, c.rope_theta))
+            q = rope_b(q, pos_b[:, None])[:, 0]             # [b, hq, d]
+            k_new = rope_b(k_new, pos_b[:, None])[:, 0]     # [b, h_kv, d]
 
-        attn, cache = spec.update_and_attend(
-            c, cache, li, k_new, v_new, q, pos_b, me, n, fd_config, interpret
-        )                                                    # [b, hq, d] f32
-        # row-parallel out-proj on the LOCAL head slice + psum
-        attn_loc = jax.lax.dynamic_slice_in_dim(
-            attn, me * (c.n_q_heads // n), c.n_q_heads // n, axis=1
-        ).reshape(c.batch, -1).astype(x.dtype)
-        x = x + jax.lax.psum(attn_loc @ p["wo"], c.axis)
+            attn, cache = spec.update_and_attend(
+                c, cache, li, k_new, v_new, q, pos_b, me, n, fd_config,
+                interpret,
+            )                                                # [b, hq, d] f32
+            # row-parallel out-proj on the LOCAL head slice + psum
+            with _scope("attn/out"):
+                attn_loc = jax.lax.dynamic_slice_in_dim(
+                    attn, me * (c.n_q_heads // n), c.n_q_heads // n, axis=1
+                ).reshape(c.batch, -1).astype(x.dtype)
+                x = x + jax.lax.psum(attn_loc @ p["wo"], c.axis)
 
         # --- MLP (shared row-wise helper: decode feeds [b, H] rows, the
         # speculative verify step feeds [b*S, H]) ---
         x = _decode_mlp(c, x, p, me, n, n_o, interpret)
 
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    logits_loc = x @ params["lm_head"]                       # [b_att, V/n]
-    logits = jax.lax.all_gather(logits_loc, c.axis, axis=1, tiled=True)
-    if n_o > 1:
-        # back to the replicated [b, V] layout the host loop expects:
-        # outer groups are batch-major, so a leading-dim gather restores
-        # global slot order
-        logits = jax.lax.all_gather(
-            logits, _outer_of(cfg), axis=0, tiled=True
-        )
+    with _scope("head"):
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        logits_loc = x @ params["lm_head"]                   # [b_att, V/n]
+        logits = jax.lax.all_gather(logits_loc, c.axis, axis=1, tiled=True)
+        if n_o > 1:
+            # back to the replicated [b, V] layout the host loop expects:
+            # outer groups are batch-major, so a leading-dim gather restores
+            # global slot order
+            logits = jax.lax.all_gather(
+                logits, _outer_of(cfg), axis=0, tiled=True
+            )
     return logits, cache
 
 
@@ -2287,66 +2315,73 @@ def prefill_cache(
     model = model_cls(c)
     model.kv_sink = []
     logits_loc = model(prompt_loc, params)            # [b*L, V/n]
-    for li, (k_loc, v_loc) in enumerate(model.kv_sink):
-        # heads are sharded contiguously, so a tiled gather on the head
-        # dim restores global head order: [b, L, h_kv, d]
-        k_full = jax.lax.all_gather(k_loc, c.axis, axis=2, tiled=True)
-        v_full = jax.lax.all_gather(v_loc, c.axis, axis=2, tiled=True)
-        k_full = jnp.swapaxes(k_full, 1, 2)           # [b, h_kv, L, d]
-        v_full = jnp.swapaxes(v_full, 1, 2)
-        kd = cache["k"].dtype
-        # this PE's window [me*s_shard, me*s_shard + s_shard) of the
-        # prompt: pad by ONE shard (not to s_max — a long-context cache
-        # would otherwise allocate n x the PE's shard per layer as a
-        # temp) and slice; a window past L is all-zero either way, so
-        # clamping the start into the padded region stays correct
-        zpad = jnp.zeros((b, c.n_kv_heads, s_shard, c.head_dim), kd)
-        k_buf = jnp.concatenate([k_full.astype(kd), zpad], axis=2)
-        v_buf = jnp.concatenate([v_full.astype(kd), zpad], axis=2)
-        start = jnp.minimum(me * s_shard, L)
-        k_new = jax.lax.dynamic_slice_in_dim(k_buf, start, s_shard, 2)
-        v_new = jax.lax.dynamic_slice_in_dim(v_buf, start, s_shard, 2)
-        if paged:
-            # page pool write: this PE's window splits into its slot's
-            # STATIC page range; slot_mask gates the scatter INDICES (the
-            # paged discipline — out-of-range ids drop), not the values
-            ps = spec.page_size
-            pps = s_shard // ps
-            kp = k_new.reshape(b, c.n_kv_heads, pps, ps, c.head_dim)
-            vp = v_new.reshape(b, c.n_kv_heads, pps, ps, c.head_dim)
-            kp = jnp.swapaxes(kp, 1, 2).reshape(b * pps, c.n_kv_heads, ps, c.head_dim)
-            vp = jnp.swapaxes(vp, 1, 2).reshape(b * pps, c.n_kv_heads, ps, c.head_dim)
-            ids = cache["block_table"][0]                # [b, pps] static
-            n_pool = cache["k"].shape[1]
+    # every layer's post-RoPE k/v into the decode cache
+    with _scope("attn"), _scope("attn/kv_write"):
+        for li, (k_loc, v_loc) in enumerate(model.kv_sink):
+            # heads are sharded contiguously, so a tiled gather on the head
+            # dim restores global head order: [b, L, h_kv, d]
+            k_full = jax.lax.all_gather(k_loc, c.axis, axis=2, tiled=True)
+            v_full = jax.lax.all_gather(v_loc, c.axis, axis=2, tiled=True)
+            k_full = jnp.swapaxes(k_full, 1, 2)           # [b, h_kv, L, d]
+            v_full = jnp.swapaxes(v_full, 1, 2)
+            kd = cache["k"].dtype
+            # this PE's window [me*s_shard, me*s_shard + s_shard) of the
+            # prompt: pad by ONE shard (not to s_max — a long-context cache
+            # would otherwise allocate n x the PE's shard per layer as a
+            # temp) and slice; a window past L is all-zero either way, so
+            # clamping the start into the padded region stays correct
+            zpad = jnp.zeros((b, c.n_kv_heads, s_shard, c.head_dim), kd)
+            k_buf = jnp.concatenate([k_full.astype(kd), zpad], axis=2)
+            v_buf = jnp.concatenate([v_full.astype(kd), zpad], axis=2)
+            start = jnp.minimum(me * s_shard, L)
+            k_new = jax.lax.dynamic_slice_in_dim(k_buf, start, s_shard, 2)
+            v_new = jax.lax.dynamic_slice_in_dim(v_buf, start, s_shard, 2)
+            if paged:
+                # page pool write: this PE's window splits into its slot's
+                # STATIC page range; slot_mask gates the scatter INDICES (the
+                # paged discipline — out-of-range ids drop), not the values
+                ps = spec.page_size
+                pps = s_shard // ps
+                kp = k_new.reshape(b, c.n_kv_heads, pps, ps, c.head_dim)
+                vp = v_new.reshape(b, c.n_kv_heads, pps, ps, c.head_dim)
+                kp = jnp.swapaxes(kp, 1, 2).reshape(
+                    b * pps, c.n_kv_heads, ps, c.head_dim)
+                vp = jnp.swapaxes(vp, 1, 2).reshape(
+                    b * pps, c.n_kv_heads, ps, c.head_dim)
+                ids = cache["block_table"][0]            # [b, pps] static
+                n_pool = cache["k"].shape[1]
+                if slot_mask is not None:
+                    ids = jnp.where(slot_mask[:, None], ids, n_pool)  # drop
+                cache = dict(
+                    cache,
+                    k=cache["k"].at[li, ids.reshape(-1)].set(
+                        kp.astype(kd), mode="drop"
+                    ),
+                    v=cache["v"].at[li, ids.reshape(-1)].set(
+                        vp.astype(kd), mode="drop"
+                    ),
+                )
+                continue
             if slot_mask is not None:
-                ids = jnp.where(slot_mask[:, None], ids, n_pool)  # drop
+                sel = slot_mask.reshape(b, 1, 1, 1)
+                k_new = jnp.where(sel, k_new, cache["k"][li])
+                v_new = jnp.where(sel, v_new, cache["v"][li])
             cache = dict(
                 cache,
-                k=cache["k"].at[li, ids.reshape(-1)].set(
-                    kp.astype(kd), mode="drop"
-                ),
-                v=cache["v"].at[li, ids.reshape(-1)].set(
-                    vp.astype(kd), mode="drop"
-                ),
+                k=cache["k"].at[li].set(k_new),
+                v=cache["v"].at[li].set(v_new),
             )
-            continue
-        if slot_mask is not None:
-            sel = slot_mask.reshape(b, 1, 1, 1)
-            k_new = jnp.where(sel, k_new, cache["k"][li])
-            v_new = jnp.where(sel, v_new, cache["v"][li])
-        cache = dict(
-            cache,
-            k=cache["k"].at[li].set(k_new),
-            v=cache["v"].at[li].set(v_new),
-        )
     if pick is None:
         pick = jnp.full((b,), L - 1, jnp.int32)
     rows = jnp.arange(b, dtype=jnp.int32) * L + jnp.clip(pick, 0, L - 1)
-    sel = logits_loc[rows]                            # [b, V/n]
-    last = jax.lax.all_gather(sel, c.axis, axis=1, tiled=True)  # [b, V]
-    if n_o > 1:
-        # restore the global batch layout the host loop schedules against
-        last = jax.lax.all_gather(last, _outer_of(c), axis=0, tiled=True)
+    with _scope("head"):
+        sel = logits_loc[rows]                        # [b, V/n]
+        last = jax.lax.all_gather(sel, c.axis, axis=1, tiled=True)  # [b, V]
+        if n_o > 1:
+            # restore the global batch layout the host loop schedules
+            # against
+            last = jax.lax.all_gather(
+                last, _outer_of(c), axis=0, tiled=True)
     return cache, last
 
 
@@ -2411,39 +2446,44 @@ def prefill_cache_ranged(
     m = b * S
     pos_flat = (pos0_b[:, None] + jnp.arange(S, dtype=jnp.int32)).reshape(-1)
 
-    x = params["embed"][tokens.reshape(-1)]                # [m, H] b-major
+    with _scope("head"):
+        x = params["embed"][tokens.reshape(-1)]            # [m, H] b-major
     for li, p in enumerate(params["layers"]):
-        h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv_loc = h @ p["wqkv"]                            # [m, qkv/n]
-        qkv = jax.lax.all_gather(qkv_loc, c.axis, axis=1, tiled=True)
-        qkv = qkv.reshape(m, c.n_kv_heads, g + 2, d)
-        q = qkv[:, :, :g, :].reshape(m, 1, c.n_q_heads, d)
-        k_new = qkv[:, :, g, :].reshape(m, 1, c.n_kv_heads, d)
-        v_new = qkv[:, :, g + 1, :]                        # [m, h_kv, d]
-        rope_b = jax.vmap(lambda xi, pi: rope(xi, pi, c.rope_theta))
-        q = rope_b(q, pos_flat[:, None])[:, 0]             # [m, hq, d]
-        k_new = rope_b(k_new, pos_flat[:, None])[:, 0]     # [m, h_kv, d]
+        with _scope("attn"):
+            h = rmsnorm(x, p["attn_norm"], c.norm_eps)
+            with _scope("attn/qkv"):
+                qkv_loc = h @ p["wqkv"]                    # [m, qkv/n]
+                qkv = jax.lax.all_gather(qkv_loc, c.axis, axis=1, tiled=True)
+            qkv = qkv.reshape(m, c.n_kv_heads, g + 2, d)
+            q = qkv[:, :, :g, :].reshape(m, 1, c.n_q_heads, d)
+            k_new = qkv[:, :, g, :].reshape(m, 1, c.n_kv_heads, d)
+            v_new = qkv[:, :, g + 1, :]                    # [m, h_kv, d]
+            rope_b = jax.vmap(lambda xi, pi: rope(xi, pi, c.rope_theta))
+            q = rope_b(q, pos_flat[:, None])[:, 0]         # [m, hq, d]
+            k_new = rope_b(k_new, pos_flat[:, None])[:, 0]  # [m, h_kv, d]
 
-        attn, cache = spec.update_multi_and_attend(
-            c, cache, li,
-            k_new.reshape(b, S, c.n_kv_heads, d),
-            v_new.reshape(b, S, c.n_kv_heads, d),
-            q.reshape(b, S, c.n_q_heads, d),
-            pos0_b, me, n, fd_config, interpret,
-        )                                                  # [b, S, hq, d]
-        attn_loc = jax.lax.dynamic_slice_in_dim(
-            attn.reshape(m, c.n_q_heads, d),
-            me * (c.n_q_heads // n), c.n_q_heads // n, axis=1,
-        ).reshape(m, -1).astype(x.dtype)
-        x = x + jax.lax.psum(attn_loc @ p["wo"], c.axis)
+            attn, cache = spec.update_multi_and_attend(
+                c, cache, li,
+                k_new.reshape(b, S, c.n_kv_heads, d),
+                v_new.reshape(b, S, c.n_kv_heads, d),
+                q.reshape(b, S, c.n_q_heads, d),
+                pos0_b, me, n, fd_config, interpret,
+            )                                              # [b, S, hq, d]
+            with _scope("attn/out"):
+                attn_loc = jax.lax.dynamic_slice_in_dim(
+                    attn.reshape(m, c.n_q_heads, d),
+                    me * (c.n_q_heads // n), c.n_q_heads // n, axis=1,
+                ).reshape(m, -1).astype(x.dtype)
+                x = x + jax.lax.psum(attn_loc @ p["wo"], c.axis)
         x = _decode_mlp(c, x, p, me, n, n_o, interpret)
 
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    logits_loc = x @ params["lm_head"]                     # [m, V/n]
-    logits = jax.lax.all_gather(logits_loc, c.axis, axis=1, tiled=True)
-    logits = logits.reshape(b, S, c.vocab)
-    if n_o > 1:
-        logits = jax.lax.all_gather(
-            logits, _outer_of(cfg), axis=0, tiled=True
-        )
+    with _scope("head"):
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        logits_loc = x @ params["lm_head"]                 # [m, V/n]
+        logits = jax.lax.all_gather(logits_loc, c.axis, axis=1, tiled=True)
+        logits = logits.reshape(b, S, c.vocab)
+        if n_o > 1:
+            logits = jax.lax.all_gather(
+                logits, _outer_of(cfg), axis=0, tiled=True
+            )
     return logits, cache

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from triton_dist_tpu.models.tp_transformer import unpack_gate_up
+from triton_dist_tpu.obs.scopes import scope
 from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
 from triton_dist_tpu.ops.moe_utils import (
     gather_sorted_rows, moe_align_block_size, scatter_add_unsorted,
@@ -42,8 +43,6 @@ from triton_dist_tpu.utils import axis_size as _axis_size
 
 # counters a pass returns, summed over its expert layers (docs/observability.md)
 MOE_STATS = ("experts_hit", "assignments", "expert_load_max")
-# scope names that survive into the device trace's op names
-EXPERT_SCOPE = "moe_experts"
 # rows per grouped-GEMM block: small at decode, where a step's assignments
 # spread over more experts than there are rows (chip, PR 28: 16-row blocks
 # over the min(E, T) alignment 1.376 ms a layer, 32-row 1.410, 8-row 1.363)
@@ -101,9 +100,13 @@ def swiglu(x, w_gate_up, w_down):
 
 
 def dense_mlp(c, h, p):
-    gate, up = unpack_gate_up(h @ p["w_gate_up"], c)
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
-    return act @ p["w_down"]
+    with scope("ffn/gate_up"):
+        gu = h @ p["w_gate_up"]
+    with scope("ffn/act"):
+        gate, up = unpack_gate_up(gu, c)
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+    with scope("ffn/down"):
+        return act @ p["w_down"]
 
 
 def route(c, h, p):
@@ -164,22 +167,28 @@ def add_stats(stats, st):
 
 def moe_mlp(c, h, p, block_m: int, interpret=None):
     """Routed experts (the share held here) + the shared expert on rows
-    ``h [m, H]``: ``(y [m, H], stats int32[3])``."""
+    ``h [m, H]``: ``(y [m, H], stats int32[3])``. Inside ``scope("ffn")``:
+    ``ffn/route`` is what a routed layer runs around its GEMMs (scores and
+    top-k, the alignment, the gather of sorted rows, the weighted combine),
+    ``ffn/experts`` the two grouped GEMMs and the activation between
+    them, ``ffn/shared`` the shared expert."""
     m = h.shape[0]
     first, n_held = c.held
-    w, ids = route(c, h, p)
-    local = ids - first
-    here = (local >= 0) & (local < n_held)
-    # an assignment to an expert held elsewhere keeps its row (shapes are
-    # static) with weight 0: its part of the result is that other chip's
-    # to add
-    local = jnp.where(here, local, 0)
-    w = jnp.where(here, w, 0.0)
-    if n_held == c.n_experts:
-        al = moe_align_block_size(
-            local.reshape(-1), n_held, block_m, ragged=True)
-    else:
-        al = _align_share(local, here, n_held, block_m)
+    with scope("ffn/route"):
+        w, ids = route(c, h, p)
+        local = ids - first
+        here = (local >= 0) & (local < n_held)
+        # an assignment to an expert held elsewhere keeps its row (shapes
+        # are static) with weight 0: its part of the result is that other
+        # chip's to add
+        local = jnp.where(here, local, 0)
+        w = jnp.where(here, w, 0.0)
+        if n_held == c.n_experts:
+            al = moe_align_block_size(
+                local.reshape(-1), n_held, block_m, ragged=True)
+        else:
+            al = _align_share(local, here, n_held, block_m)
+        a = gather_sorted_rows(h, al, c.topk)
     # one B tile = one expert's whole gate (or up, or down) matrix where
     # VMEM has the room (_tile_n): an expert's weights stream once per
     # GEMM however many blocks it fills
@@ -191,8 +200,7 @@ def moe_mlp(c, h, p, block_m: int, interpret=None):
     gg_down = GroupGemmConfig(
         block_m=block_m, block_n=_tile_n(fe, c.hidden, size), block_k=fe,
         ragged=True)
-    with jax.named_scope(EXPERT_SCOPE):
-        a = gather_sorted_rows(h, al, c.topk)
+    with scope("ffn/experts"):
         gu = group_gemm(a, p["we_gate_up"], al.expert_ids,
                         valid_rows=al.valid_rows, config=gg_up,
                         interpret=interpret)
@@ -201,8 +209,12 @@ def moe_mlp(c, h, p, block_m: int, interpret=None):
         y = group_gemm(act, p["we_down"], al.expert_ids,
                        valid_rows=al.valid_rows, config=gg_down,
                        interpret=interpret)
+    with scope("ffn/route"):
         out = scatter_add_unsorted(y, al, w, m)             # f32
     if first == 0 and c.n_shared_experts:
-        out = out + swiglu(h, p["ws_gate_up"], p["ws_down"]).astype(
-            jnp.float32)
-    return out.astype(h.dtype), routing_stats(local, here, n_held)
+        with scope("ffn/shared"):
+            out = out + swiglu(h, p["ws_gate_up"], p["ws_down"]).astype(
+                jnp.float32)
+    with scope("ffn/route"):
+        stats = routing_stats(local, here, n_held)
+    return out.astype(h.dtype), stats

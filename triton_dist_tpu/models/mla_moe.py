@@ -68,6 +68,7 @@ from triton_dist_tpu.models.gated_experts import (  # noqa: F401  (the
     routing_stats,
 )
 from triton_dist_tpu.models.tp_transformer import TransformerConfig, rmsnorm
+from triton_dist_tpu.obs.scopes import scope
 from triton_dist_tpu.ops.mla_decode import latent_row, mla_paged_decode
 
 @dataclasses.dataclass(frozen=True)
@@ -296,11 +297,12 @@ def mla_attend_absorbed(
 
 
 def _mlp(c, kind: str, x, p, block_m, interpret, stats):
-    h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
-    if kind == "dense":
-        return x + dense_mlp(c, h, p), stats
-    y, st = moe_mlp(c, h, p, block_m, interpret)
-    return x + y, add_stats(stats, st)
+    with scope("ffn"):
+        h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
+        if kind == "dense":
+            return x + dense_mlp(c, h, p), stats
+        y, st = moe_mlp(c, h, p, block_m, interpret)
+        return x + y, add_stats(stats, st)
 
 
 # -- the passes ------------------------------------------------------------------
@@ -313,15 +315,19 @@ def forward_hidden(cfg: MLAMoEConfig, params, tokens, b: int, s: int,
     ``[b*s, row]``."""
     c = cfg
     positions = jnp.tile(jnp.arange(s, dtype=jnp.int32), b)
-    x = params["embed"][tokens]
+    with scope("head"):
+        x = params["embed"][tokens]
     stats = jnp.zeros((3,), jnp.int32)
     for kind, p in zip(layer_plan(c), params["layers"]):
-        h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        q_n, q_r, c_kv, k_r = _mla_project(c, h, p, positions)
-        if sink is not None:
-            sink.append(_latent_rows(c, c_kv, k_r))
-        attn = mla_attend_expanded(c, q_n, q_r, c_kv, k_r, p, b, s)
-        x = x + attn @ p["wo"]
+        with scope("attn"):
+            h = rmsnorm(x, p["attn_norm"], c.norm_eps)
+            with scope("attn/qkv"):
+                q_n, q_r, c_kv, k_r = _mla_project(c, h, p, positions)
+            if sink is not None:
+                sink.append(_latent_rows(c, c_kv, k_r))
+            attn = mla_attend_expanded(c, q_n, q_r, c_kv, k_r, p, b, s)
+            with scope("attn/out"):
+                x = x + attn @ p["wo"]
         x, stats = _mlp(c, kind, x, p, PREFILL_BLOCK_M, interpret, stats)
     return x, stats
 
@@ -330,8 +336,9 @@ def forward_logits(cfg: MLAMoEConfig, params, tokens, interpret=None):
     """Whole-sequence logits ``[b, s, V]`` of ``tokens [b, s]`` (tests)."""
     b, s = tokens.shape
     x, _ = forward_hidden(cfg, params, tokens.reshape(-1), b, s, interpret)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return (x @ params["lm_head"]).reshape(b, s, -1)
+    with scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        return (x @ params["lm_head"]).reshape(b, s, -1)
 
 
 def prefill_cache(cfg: MLAMoEConfig, params, cache, prompt, spec, s_max,
@@ -354,18 +361,21 @@ def prefill_cache(cfg: MLAMoEConfig, params, cache, prompt, spec, s_max,
     x, stats = forward_hidden(
         c, params, tokens.reshape(-1), n, L, interpret, sink)
     n_pages = -(-L // ps)
-    ids = cache["block_table"][0][slots, :n_pages].reshape(-1)
-    lat = cache["lat"]
-    for li, rows in enumerate(sink):
-        rows = rows.reshape(n, L, -1)
-        if n_pages * ps != L:
-            rows = jnp.pad(rows, ((0, 0), (0, n_pages * ps - L), (0, 0)))
-        lat = lat.at[li, ids].set(
-            rows.reshape(n * n_pages, ps, -1).astype(lat.dtype))
+    with scope("attn"), scope("attn/kv_write"):
+        ids = cache["block_table"][0][slots, :n_pages].reshape(-1)
+        lat = cache["lat"]
+        for li, rows in enumerate(sink):
+            rows = rows.reshape(n, L, -1)
+            if n_pages * ps != L:
+                rows = jnp.pad(rows, ((0, 0), (0, n_pages * ps - L), (0, 0)))
+            lat = lat.at[li, ids].set(
+                rows.reshape(n * n_pages, ps, -1).astype(lat.dtype))
     cache = dict(cache, lat=lat)
-    rows = jnp.arange(n, dtype=jnp.int32) * L + pick
-    xs = rmsnorm(x[rows], params["final_norm"], c.norm_eps)
-    return cache, last_rows(xs @ params["lm_head"], slots, b), stats
+    with scope("head"):
+        rows = jnp.arange(n, dtype=jnp.int32) * L + pick
+        xs = rmsnorm(x[rows], params["final_norm"], c.norm_eps)
+        last = last_rows(xs @ params["lm_head"], slots, b)
+    return cache, last, stats
 
 
 def decode_step(cfg: MLAMoEConfig, params, cache, tokens, pos, *, spec,
@@ -378,24 +388,35 @@ def decode_step(cfg: MLAMoEConfig, params, cache, tokens, pos, *, spec,
     b = c.batch
     ps, s_max = spec.page_size, spec.s_max
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    x = params["embed"][tokens]
-    bt = cache["block_table"][0]
-    n_pool = cache["lat"].shape[1]
-    # a parked slot (pos = s_max) is owned by no page: its write drops
-    own = pos_b < s_max
-    page_ids = bt[jnp.arange(b), jnp.minimum(pos_b // ps, bt.shape[1] - 1)]
-    safe_ids = jnp.where(own, page_ids, n_pool)
-    kv_lens = jnp.clip(pos_b + 1, 0, s_max)
+    with scope("head"):
+        x = params["embed"][tokens]
+    with scope("attn"):
+        bt = cache["block_table"][0]
+        n_pool = cache["lat"].shape[1]
+        # a parked slot (pos = s_max) is owned by no page: its write drops
+        own = pos_b < s_max
+        page_ids = bt[
+            jnp.arange(b), jnp.minimum(pos_b // ps, bt.shape[1] - 1)]
+        safe_ids = jnp.where(own, page_ids, n_pool)
+        kv_lens = jnp.clip(pos_b + 1, 0, s_max)
     lat = cache["lat"]
     stats = jnp.zeros((3,), jnp.int32)
     for li, (kind, p) in enumerate(zip(layer_plan(c), params["layers"])):
-        h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        q_n, q_r, c_kv, k_r = _mla_project(c, h, p, pos_b)
-        lat = lat.at[li, safe_ids, pos_b % ps].set(
-            _latent_rows(c, c_kv, k_r).astype(lat.dtype), mode="drop")
-        attn = mla_attend_absorbed(
-            c, q_n, q_r, p, lat, li, kv_lens, bt, interpret)
-        x = x + attn.astype(x.dtype) @ p["wo"]
+        with scope("attn"):
+            h = rmsnorm(x, p["attn_norm"], c.norm_eps)
+            with scope("attn/qkv"):
+                q_n, q_r, c_kv, k_r = _mla_project(c, h, p, pos_b)
+            with scope("attn/kv_write"):
+                lat = lat.at[li, safe_ids, pos_b % ps].set(
+                    _latent_rows(c, c_kv, k_r).astype(lat.dtype),
+                    mode="drop")
+            with scope("attn/decode"):
+                attn = mla_attend_absorbed(
+                    c, q_n, q_r, p, lat, li, kv_lens, bt, interpret)
+            with scope("attn/out"):
+                x = x + attn.astype(x.dtype) @ p["wo"]
         x, stats = _mlp(c, kind, x, p, DECODE_BLOCK_M, interpret, stats)
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
-    return x @ params["lm_head"], dict(cache, lat=lat), stats
+    with scope("head"):
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
+        logits = x @ params["lm_head"]
+    return logits, dict(cache, lat=lat), stats

@@ -65,9 +65,12 @@ from triton_dist_tpu.models.gated_experts import (
 from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig, _causal_gqa_attention, rmsnorm,
 )
+from triton_dist_tpu.obs.scopes import scope
 from triton_dist_tpu.ops.selective_scan import selective_scan
 
 MIXER_KINDS = ("mamba", "attention")
+# the part of the layer a mixer of each kind is (obs/scopes.py)
+MIXER_SCOPES = {"mamba": "ssm", "attention": "attn"}
 FAMILY = "state-space / attention"
 NOT_BUILT = "a slot's state sharded over channels"
 
@@ -232,7 +235,8 @@ def _project(c: SSMHybridConfig, x, p, lead: tuple):
 def _split_in(c, x, p):
     """``x [m, H]`` -> the convolution's input ``u`` (f32) and the gate
     ``z``, ``[m, d]`` each."""
-    uz = x @ p["w_in"]
+    with scope("ssm/proj"):
+        uz = x @ p["w_in"]
     return _f32(uz[..., :c.d_inner]), uz[..., c.d_inner:]
 
 
@@ -241,23 +245,25 @@ def _scan_inputs(c, conv, p):
     activated) -> what the recurrence reads, all f32: ``(c, dt [m, d], B,
     C [m, N], A [N, d], D [d])``."""
     n, r, eps = c.d_state, c.dt_rank, c.norm_eps
-    act = jax.nn.silu(conv)
-    rbc = jnp.dot(act.astype(p["w_x"].dtype), p["w_x"],
-                  preferred_element_type=jnp.float32)
-    dt_in = rmsnorm(rbc[..., :r], _f32(p["dt_norm"]), eps)
-    b_in = rmsnorm(rbc[..., r:r + n], _f32(p["b_norm"]), eps)
-    c_out = rmsnorm(rbc[..., r + n:], _f32(p["c_norm"]), eps)
-    dt = jax.nn.softplus(
-        jnp.dot(dt_in.astype(p["w_dt"].dtype), p["w_dt"],
-                preferred_element_type=jnp.float32) + _f32(p["b_dt"]))
-    return (act, dt, b_in, c_out, -jnp.exp(_f32(p["a_log"])),
-            _f32(p["d_skip"]))
+    with scope("ssm/proj"):
+        act = jax.nn.silu(conv)
+        rbc = jnp.dot(act.astype(p["w_x"].dtype), p["w_x"],
+                      preferred_element_type=jnp.float32)
+        dt_in = rmsnorm(rbc[..., :r], _f32(p["dt_norm"]), eps)
+        b_in = rmsnorm(rbc[..., r:r + n], _f32(p["b_norm"]), eps)
+        c_out = rmsnorm(rbc[..., r + n:], _f32(p["c_norm"]), eps)
+        dt = jax.nn.softplus(
+            jnp.dot(dt_in.astype(p["w_dt"].dtype), p["w_dt"],
+                    preferred_element_type=jnp.float32) + _f32(p["b_dt"]))
+        return (act, dt, b_in, c_out, -jnp.exp(_f32(p["a_log"])),
+                _f32(p["d_skip"]))
 
 
 def _gate_out(y, z, p):
     """``(y * silu(z)) W_out``."""
-    gated = y * jax.nn.silu(_f32(z))
-    return gated.astype(p["w_out"].dtype) @ p["w_out"]
+    with scope("ssm/proj"):
+        gated = y * jax.nn.silu(_f32(z))
+        return gated.astype(p["w_out"].dtype) @ p["w_out"]
 
 
 def _mamba_prompt(c: SSMHybridConfig, x, p, lens, interpret):
@@ -267,17 +273,19 @@ def _mamba_prompt(c: SSMHybridConfig, x, p, lens, interpret):
     n, L, _ = x.shape
     K = c.d_conv
     u, z = _split_in(c, x, p)
-    w = _f32(p["conv_w"])
-    padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
-    conv = _f32(p["conv_b"]) + sum(
-        w[j] * padded[:, j:j + L] for j in range(K))
+    with scope("ssm/conv"):
+        w = _f32(p["conv_w"])
+        padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
+        conv = _f32(p["conv_b"]) + sum(
+            w[j] * padded[:, j:j + L] for j in range(K))
     act, dt, b_in, c_out, a, d_skip = _scan_inputs(c, conv, p)
     # the scan stops at the prompt's end: dt = 0 leaves the state as it was
     dt = jnp.where((jnp.arange(L) < lens[:, None])[..., None], dt, 0.0)
     h0 = jnp.zeros((c.d_state, c.d_inner), jnp.float32)
-    y, h = zip(*(
-        selective_scan(act[i], dt[i], b_in[i], c_out[i], a, d_skip, h0,
-                       interpret=interpret) for i in range(n)))
+    with scope("ssm/scan"):
+        y, h = zip(*(
+            selective_scan(act[i], dt[i], b_in[i], c_out[i], a, d_skip, h0,
+                           interpret=interpret) for i in range(n)))
     return _gate_out(jnp.stack(y), z, p), u, jnp.stack(h)
 
 
@@ -299,28 +307,34 @@ def forward_hidden(cfg: SSMHybridConfig, params, tokens, lens=None,
     n, L = tokens.shape
     if lens is None:
         lens = jnp.full((n,), L, jnp.int32)
-    x = params["embed"][tokens]
+    with scope("head"):
+        x = params["embed"][tokens]
     for kind, p in zip(layer_plan(c), params["layers"]):
-        h = rmsnorm(x, p["norm_in"], c.norm_eps)
-        if kind == "mamba":
-            y, *kept = _mamba_prompt(c, h, p, lens, interpret)
-        else:
-            q, k, v = _project(c, h.reshape(n * L, -1), p, (n, L))
-            kept = (k, v)
-            y = (_causal_gqa_attention(q, k, v, c).reshape(n * L, -1)
-                 @ p["wo"]).reshape(n, L, -1)
-        if sink is not None:
-            sink.append(tuple(kept))
-        x = x + y
-        h = rmsnorm(x, p["norm_ff"], c.norm_eps)
-        x = x + dense_mlp(c, h.reshape(n * L, -1), p).reshape(n, L, -1)
+        with scope(MIXER_SCOPES[kind]):
+            h = rmsnorm(x, p["norm_in"], c.norm_eps)
+            if kind == "mamba":
+                y, *kept = _mamba_prompt(c, h, p, lens, interpret)
+            else:
+                with scope("attn/qkv"):
+                    q, k, v = _project(c, h.reshape(n * L, -1), p, (n, L))
+                kept = (k, v)
+                attn = _causal_gqa_attention(q, k, v, c).reshape(n * L, -1)
+                with scope("attn/out"):
+                    y = (attn @ p["wo"]).reshape(n, L, -1)
+            if sink is not None:
+                sink.append(tuple(kept))
+            x = x + y
+        with scope("ffn"):
+            h = rmsnorm(x, p["norm_ff"], c.norm_eps)
+            x = x + dense_mlp(c, h.reshape(n * L, -1), p).reshape(n, L, -1)
     return x
 
 
 def _head(cfg, params, x):
     """Logits of rows ``x [m, H]`` through the tied head."""
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return jnp.einsum("mh,vh->mv", x, params["embed"])
+    with scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        return jnp.einsum("mh,vh->mv", x, params["embed"])
 
 
 def prefill_cache(cfg: SSMHybridConfig, params, cache, prompt, spec, s_max,
@@ -340,9 +354,11 @@ def prefill_cache(cfg: SSMHybridConfig, params, cache, prompt, spec, s_max,
     x = forward_hidden(c, params, tokens, pick + 1, interpret, sink)
     for (kind, ki), kept in zip(_numbered(c), sink):
         if kind == "mamba":
-            cache = spec.write_state(cache, ki, slots, pick + 1, *kept)
+            with scope("ssm"):
+                cache = spec.write_state(cache, ki, slots, pick + 1, *kept)
         else:
-            cache = spec.write_prompt(cache, ki, *kept, slots)
+            with scope("attn"), scope("attn/kv_write"):
+                cache = spec.write_prompt(cache, ki, *kept, slots)
     rows = _head(c, params, x[jnp.arange(len(slots)), pick])
     return cache, last_rows(rows, slots, b), _counters(len(slots), 0)
 
@@ -357,23 +373,29 @@ def decode_step(cfg: SSMHybridConfig, params, cache, tokens, pos, *, spec,
     c = cfg
     b = c.batch
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    x = params["embed"][tokens]
+    with scope("head"):
+        x = params["embed"][tokens]
     for (kind, ki), p in zip(_numbered(c), params["layers"]):
-        h = rmsnorm(x, p["norm_in"], c.norm_eps)
-        if kind == "mamba":
-            u, z = _split_in(c, h, p)
-            conv, cache = spec.conv_step(
-                cache, ki, u, pos_b, _f32(p["conv_w"]), _f32(p["conv_b"]))
-            y, cache = spec.state_step(
-                cache, ki, *_scan_inputs(c, conv, p), pos_b, interpret)
-            y = _gate_out(y, z, p)
-        else:
-            q, k_new, v_new = _project(c, h, p, (b,))
-            attn, cache = spec.write_and_attend(
-                c, cache, ki, k_new, v_new, q, pos_b, interpret)
-            y = attn.reshape(b, -1).astype(x.dtype) @ p["wo"]
-        x = x + y
-        x = x + dense_mlp(c, rmsnorm(x, p["norm_ff"], c.norm_eps), p)
+        with scope(MIXER_SCOPES[kind]):
+            h = rmsnorm(x, p["norm_in"], c.norm_eps)
+            if kind == "mamba":
+                u, z = _split_in(c, h, p)
+                conv, cache = spec.conv_step(
+                    cache, ki, u, pos_b, _f32(p["conv_w"]),
+                    _f32(p["conv_b"]))
+                y, cache = spec.state_step(
+                    cache, ki, *_scan_inputs(c, conv, p), pos_b, interpret)
+                y = _gate_out(y, z, p)
+            else:
+                with scope("attn/qkv"):
+                    q, k_new, v_new = _project(c, h, p, (b,))
+                attn, cache = spec.write_and_attend(
+                    c, cache, ki, k_new, v_new, q, pos_b, interpret)
+                with scope("attn/out"):
+                    y = attn.reshape(b, -1).astype(x.dtype) @ p["wo"]
+            x = x + y
+        with scope("ffn"):
+            x = x + dense_mlp(c, rmsnorm(x, p["norm_ff"], c.norm_eps), p)
     lens = jnp.clip(pos_b + 1, 0, spec.s_max)
     return _head(c, params, x), cache, _counters(
         b, c.layer_kinds.count("attention") * jnp.sum(lens))

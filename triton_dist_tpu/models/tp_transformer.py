@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
+from triton_dist_tpu.obs.scopes import scope as _scope
 from triton_dist_tpu.ops.allgather_gemm import AGGemmConfig
 from triton_dist_tpu.ops.gemm_reduce_scatter import GemmRSConfig
 from triton_dist_tpu.ops.grads import ag_gemm_grad, gemm_rs_grad
@@ -300,41 +301,52 @@ class TPTransformer:
         d = c.head_dim
 
         # --- attention ---
-        h = rmsnorm(x, p["attn_norm"], c.norm_eps)
-        qkv = self._col(h, p["wqkv"])
-        qkv = qkv.reshape(b, s, hkv_loc, g + 2, d)  # local kv groups
-        q = qkv[..., :g, :].reshape(b, s, hq_loc, d)
-        k = qkv[..., g, :]
-        v = qkv[..., g + 1, :]
-        pos = jnp.arange(s, dtype=jnp.int32)
-        q = rope(q, pos, c.rope_theta)
-        k = rope(k, pos, c.rope_theta)
-        if getattr(self, "kv_sink", None) is not None:
-            # prefill capture (models/decode.prefill_cache): the post-RoPE
-            # per-layer k/v in this PE's head shard, [b, s, hkv_loc, d]
-            self.kv_sink.append((k, v))
-        attn = _causal_gqa_attention(q, k, v, c)   # [b, s, q_dim/n]
-        x = x + self._row(attn.reshape(b * s, hq_loc * d), p["wo"])
+        with _scope("attn"):
+            h = rmsnorm(x, p["attn_norm"], c.norm_eps)
+            with _scope("attn/qkv"):
+                qkv = self._col(h, p["wqkv"])
+            qkv = qkv.reshape(b, s, hkv_loc, g + 2, d)  # local kv groups
+            q = qkv[..., :g, :].reshape(b, s, hq_loc, d)
+            k = qkv[..., g, :]
+            v = qkv[..., g + 1, :]
+            pos = jnp.arange(s, dtype=jnp.int32)
+            q = rope(q, pos, c.rope_theta)
+            k = rope(k, pos, c.rope_theta)
+            if getattr(self, "kv_sink", None) is not None:
+                # prefill capture (models/decode.prefill_cache): the
+                # post-RoPE per-layer k/v in this PE's head shard,
+                # [b, s, hkv_loc, d]
+                self.kv_sink.append((k, v))
+            attn = _causal_gqa_attention(q, k, v, c)   # [b, s, q_dim/n]
+            with _scope("attn/out"):
+                x = x + self._row(attn.reshape(b * s, hq_loc * d), p["wo"])
 
-        return x + self._mlp(x, p)
+        with _scope("ffn"):
+            return x + self._mlp(x, p)
 
     def _mlp(self, x: jax.Array, p: dict) -> jax.Array:
         """Dense SwiGLU MLP half of the block (overridden by the MoE model)."""
         c = self.cfg
         h = rmsnorm(x, p["mlp_norm"], c.norm_eps)
-        gate, up = unpack_gate_up(self._col(h, p["w_gate_up"]), c)  # [m, F/n]
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-        return self._row(act, p["w_down"])
+        with _scope("ffn/gate_up"):
+            gu = self._col(h, p["w_gate_up"])              # [m, 2F/n]
+        with _scope("ffn/act"):
+            gate, up = unpack_gate_up(gu, c)               # [m, F/n]
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+        with _scope("ffn/down"):
+            return self._row(act, p["w_down"])
 
     def __call__(self, tokens_loc: jax.Array, params: dict) -> jax.Array:
         """tokens_loc ``[m_loc]`` int32 → vocab-sharded logits
         ``[m_tot, V/n]``."""
         c = self.cfg
-        x = params["embed"][tokens_loc]            # [m_loc, H]
+        with _scope("head"):
+            x = params["embed"][tokens_loc]        # [m_loc, H]
         for p in params["layers"]:
             x = self.block(x, p)
-        x = rmsnorm(x, params["final_norm"], c.norm_eps)
-        return self._col(x, params["lm_head"])     # [m_tot, V/n]
+        with _scope("head"):
+            x = rmsnorm(x, params["final_norm"], c.norm_eps)
+            return self._col(x, params["lm_head"])  # [m_tot, V/n]
 
     def loss(self, tokens_loc, targets, params) -> jax.Array:
         """Vocab-parallel cross-entropy (no PE sees the full logits):

@@ -63,6 +63,7 @@ from triton_dist_tpu.models.gated_experts import (
 from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig, _causal_gqa_attention, rmsnorm, rope,
 )
+from triton_dist_tpu.obs.scopes import scope
 
 ATTENTION_KINDS = ("window", "full")
 FAMILY = "window-attention / gated-expert"
@@ -286,12 +287,13 @@ def banded_attention(q, k, v, window: int) -> jax.Array:
 
 def _mlp(c, mlp: str, x, p, block_m, interpret, stats):
     """``x + norm(mlp(x))`` and the pass's routing counters."""
-    if mlp == "dense":
-        y = dense_mlp(c, x, p)
-    else:
-        y, st = moe_mlp(c, x, p, block_m, interpret)
-        stats = add_stats(stats, st)
-    return x + rmsnorm(y, p["mlp_norm"], c.norm_eps), stats
+    with scope("ffn"):
+        if mlp == "dense":
+            y = dense_mlp(c, x, p)
+        else:
+            y, st = moe_mlp(c, x, p, block_m, interpret)
+            stats = add_stats(stats, st)
+        return x + rmsnorm(y, p["mlp_norm"], c.norm_eps), stats
 
 
 def _counters(c, stats, rows: int, window_rows, full_rows):
@@ -316,20 +318,24 @@ def forward_hidden(cfg: WindowMoEConfig, params, tokens, b: int, s: int,
     layer, rotated)."""
     c = cfg
     positions = jnp.arange(s, dtype=jnp.int32)
-    x = params["embed"][tokens]
+    with scope("head"):
+        x = params["embed"][tokens]
     stats = jnp.zeros((3,), jnp.int32)
     for (kind, mlp), p in zip(layer_plan(c), params["layers"]):
-        q, k, v = _project(c, x, p, (b, s))
-        if kind == "window":
-            q = rope(q, positions, c.rope_theta)
-            k = rope(k, positions, c.rope_theta)
-            attn = banded_attention(q, k, v, c.window)
-        else:
-            attn = _causal_gqa_attention(q, k, v, c)
-        if sink is not None:
-            sink.append((k, v))
-        y = attn.reshape(b * s, -1) @ p["wo"]
-        x = x + rmsnorm(y, p["attn_norm"], c.norm_eps)
+        with scope("attn"):
+            with scope("attn/qkv"):
+                q, k, v = _project(c, x, p, (b, s))
+            if kind == "window":
+                q = rope(q, positions, c.rope_theta)
+                k = rope(k, positions, c.rope_theta)
+                attn = banded_attention(q, k, v, c.window)
+            else:
+                attn = _causal_gqa_attention(q, k, v, c)
+            if sink is not None:
+                sink.append((k, v))
+            with scope("attn/out"):
+                y = attn.reshape(b * s, -1) @ p["wo"]
+                x = x + rmsnorm(y, p["attn_norm"], c.norm_eps)
         x, stats = _mlp(c, mlp, x, p, PREFILL_BLOCK_M, interpret, stats)
     return x, stats
 
@@ -338,8 +344,9 @@ def forward_logits(cfg: WindowMoEConfig, params, tokens, interpret=None):
     """Whole-sequence logits ``[b, s, V]`` of ``tokens [b, s]`` (tests)."""
     b, s = tokens.shape
     x, _ = forward_hidden(cfg, params, tokens.reshape(-1), b, s, interpret)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return (x @ params["lm_head"]).reshape(b, s, -1)
+    with scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        return (x @ params["lm_head"]).reshape(b, s, -1)
 
 
 def prefill_cache(cfg: WindowMoEConfig, params, cache, prompt, spec, s_max,
@@ -360,11 +367,14 @@ def prefill_cache(cfg: WindowMoEConfig, params, cache, prompt, spec, s_max,
     sink: list = []
     x, stats = forward_hidden(
         c, params, tokens.reshape(-1), n, L, interpret, sink)
-    for (kind, ki, _), (k, v) in zip(_numbered(c), sink):
-        cache = spec.write_prompt(c, cache, kind, ki, k, v, pick + 1, slots)
-    rows = jnp.arange(n, dtype=jnp.int32) * L + pick
-    xs = rmsnorm(x[rows], params["final_norm"], c.norm_eps)
-    last = last_rows(xs @ params["lm_head"], slots, b)
+    with scope("attn"), scope("attn/kv_write"):
+        for (kind, ki, _), (k, v) in zip(_numbered(c), sink):
+            cache = spec.write_prompt(
+                c, cache, kind, ki, k, v, pick + 1, slots)
+    with scope("head"):
+        rows = jnp.arange(n, dtype=jnp.int32) * L + pick
+        xs = rmsnorm(x[rows], params["final_norm"], c.norm_eps)
+        last = last_rows(xs @ params["lm_head"], slots, b)
     return cache, last, _counters(c, stats, n * L, 0, 0)
 
 
@@ -379,22 +389,29 @@ def decode_step(cfg: WindowMoEConfig, params, cache, tokens, pos, *, spec,
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     # per-sequence rotary position (ragged decode): vmap over the batch
     rope_b = jax.vmap(lambda xi, pi: rope(xi, pi, c.rope_theta))
-    x = params["embed"][tokens]
+    with scope("head"):
+        x = params["embed"][tokens]
     stats = jnp.zeros((3,), jnp.int32)
     for (kind, ki, mlp), p in zip(_numbered(c), params["layers"]):
-        q, k_new, v_new = _project(c, x, p, (b,))
-        if kind == "window":
-            q = rope_b(q[:, None], pos_b[:, None])[:, 0]
-            k_new = rope_b(k_new[:, None], pos_b[:, None])[:, 0]
-        attn, cache = spec.write_and_attend(
-            c, cache, kind, ki, k_new, v_new, q, pos_b, interpret)
-        y = attn.reshape(b, -1).astype(x.dtype) @ p["wo"]
-        x = x + rmsnorm(y, p["attn_norm"], c.norm_eps)
+        with scope("attn"):
+            with scope("attn/qkv"):
+                q, k_new, v_new = _project(c, x, p, (b,))
+            if kind == "window":
+                q = rope_b(q[:, None], pos_b[:, None])[:, 0]
+                k_new = rope_b(k_new[:, None], pos_b[:, None])[:, 0]
+            attn, cache = spec.write_and_attend(
+                c, cache, kind, ki, k_new, v_new, q, pos_b, interpret)
+            with scope("attn/out"):
+                y = attn.reshape(b, -1).astype(x.dtype) @ p["wo"]
+                x = x + rmsnorm(y, p["attn_norm"], c.norm_eps)
         x, stats = _mlp(c, mlp, x, p, DECODE_BLOCK_M, interpret, stats)
-    x = rmsnorm(x, params["final_norm"], c.norm_eps)
+    with scope("head"):
+        x = rmsnorm(x, params["final_norm"], c.norm_eps)
     lens = jnp.clip(pos_b + 1, 0, spec.s_max)
     kinds = c.layer_types
-    return x @ params["lm_head"], cache, _counters(
+    with scope("head"):
+        logits = x @ params["lm_head"]
+    return logits, cache, _counters(
         c, stats, b,
         kinds.count("window") * jnp.sum(jnp.minimum(lens, c.window)),
         kinds.count("full") * jnp.sum(lens))
