@@ -106,6 +106,47 @@ def test_paged_flash_decode_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.float8_e4m3fn])
+def test_quantized_paged_flash_decode_compiles(one_chip, dtype):
+    """The int8 / fp8 pools' form (no cell runs it): the fused grid
+    multiplies every kv head of a buffer at once, so the bf16 copy it
+    makes of a buffer counts against the scoped VMEM the page slots are
+    sized by (PR 38: 25 MB asked of 16 before it did)."""
+    from triton_dist_tpu.ops.flash_decode import paged_flash_decode
+
+    pool, table = _paged_args(one_chip)
+    pool = _struct(pool.shape, dtype, one_chip)
+    scales = _struct((pool.shape[0], N_KV, 1, PAGE), jnp.float32, one_chip)
+    q = _struct((SLOTS, N_Q, HEAD), jnp.bfloat16, one_chip)
+    lens = _struct((SLOTS,), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda q, k, v, n, t, ks, vs: paged_flash_decode(
+            q, k, v, n, t, k_scales=ks, v_scales=vs, interpret=False),
+        q, pool, pool, lens, table, scales, scales,
+    )
+    assert "tpu_custom_call" in text and "paged_flash_decode_q_fh" in text
+
+
+def test_many_kv_head_paged_flash_decode_compiles(one_chip):
+    """64 kv heads: one fused page slot is a 128-position sliver of the
+    scoped VMEM, so the auto choice is the PER-HEAD grid, which no cell's
+    shape takes any more; with a window, a soft cap and the lse, the
+    options no other compile here sets."""
+    from triton_dist_tpu.ops.flash_decode import paged_flash_decode
+
+    heads, pages = 64, S_MAX // PAGE
+    pool = _struct((4 * pages, heads, PAGE, HEAD), jnp.bfloat16, one_chip)
+    text = _compiled_text(
+        functools.partial(paged_flash_decode, window=300, soft_cap=30.0,
+                          return_lse=True, interpret=False),
+        _struct((4, heads, HEAD), jnp.bfloat16, one_chip), pool, pool,
+        _struct((4,), jnp.int32, one_chip),
+        _struct((4, pages), jnp.int32, one_chip),
+    )
+    assert "paged_flash_decode_w300" in text
+    assert "paged_flash_decode_w300_fh" not in text
+
+
 @pytest.mark.parametrize("rows", [16, 128])
 def test_paged_flash_verify_compiles(one_chip, rows):
     """The ranged-prefill kernel: ``rows`` query positions per slot (a

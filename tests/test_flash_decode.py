@@ -618,3 +618,139 @@ def test_paged_decode_soft_cap_nonpow2():
     got = paged_flash_decode(q, kp, vp, kv_lens, bt, soft_cap=25.0)
     want = _ref_decode_capped(q, k, v, kv_lens, soft_cap=25.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
+
+
+# -- the walk's discipline: a row's live pages, never its table row ----------
+
+_WALK_PAGE, _WALK_PAGES = 16, 8          # a row holds 128 positions
+_WALK_POISON = 1e30                      # finite: 0 x it is 0, any p x it is not
+_WALK_LENS = {
+    "zero": [0, 0, 0],
+    "one": [1, 1, 1],
+    "page-1": [_WALK_PAGE - 1] * 3,
+    "page": [_WALK_PAGE] * 3,
+    "page+1": [_WALK_PAGE + 1] * 3,
+    "three_pages": [3 * _WALK_PAGE] * 3,
+    "whole_row": [_WALK_PAGE * _WALK_PAGES] * 3,
+    "mixed": [0, _WALK_PAGE * _WALK_PAGES, 17, 0, 47, 1, 100],
+}
+
+
+def _poisoned_pools(rng, lens, h_kv, d, window, page=_WALK_PAGE,
+                    pages=_WALK_PAGES):
+    """Contiguous k, v and their pools in which ONLY the positions a row
+    attends hold them: every other position of a live page, every dead
+    page and the page every dead table column names hold the poison. With
+    a window the table is a ring (column = logical page % its width)."""
+    width = pages if window is None else min(-(-window // page) + 1, pages)
+    b = len(lens)
+    k = rng.standard_normal((b, h_kv, page * pages, d)).astype(np.float32)
+    v = rng.standard_normal(k.shape).astype(np.float32)
+    dead = b * width                                  # the poisoned page
+    kp = np.full((dead + 1, h_kv, page, d), _WALK_POISON, np.float32)
+    vp = kp.copy()
+    table = np.full((b, width), dead, np.int32)
+    ids = rng.permutation(dead).reshape(b, width)
+    for i, n in enumerate(lens):
+        lo = 0 if window is None else max(n - window, 0)
+        for pos in range(lo, n):
+            col = (pos // page) % width
+            table[i, col] = ids[i, col]
+            kp[ids[i, col], :, pos % page] = k[i, :, pos]
+            vp[ids[i, col], :, pos % page] = v[i, :, pos]
+    return k, v, kp, vp, table
+
+
+def _windowed_golden(q, k, v, lens, window):
+    b, hq, d = q.shape
+    h_kv = k.shape[1]
+    s = np.einsum("bhgd,bhsd->bhgs", q.reshape(b, h_kv, hq // h_kv, d), k)
+    s /= np.sqrt(d)
+    pos = np.arange(k.shape[2])[None, :]
+    lo = 0 if window is None else np.maximum(lens - window, 0)
+    seen = (pos < lens[:, None]) & (pos >= np.reshape(lo, (-1, 1)))
+    s = np.where(seen[:, None, None, :], s, -np.inf)
+    m = np.max(s, axis=-1, keepdims=True, initial=-1e30)
+    p = np.exp(s - m)
+    l = p.sum(-1, keepdims=True)
+    out = np.einsum("bhgs,bhsd->bhgd", p / np.maximum(l, 1e-30), v)
+    return out.reshape(b, hq, d), (m + np.log(np.maximum(l, 1e-30))).reshape(b, hq)
+
+
+@pytest.mark.parametrize("window", [None, _WALK_PAGE * 3 // 2])
+@pytest.mark.parametrize("fuse_heads", [True, False])
+@pytest.mark.parametrize("lens", list(_WALK_LENS))
+def test_paged_decode_walks_live_pages_only(lens, fuse_heads, window):
+    """Every position no length exposes is poisoned (a large finite value:
+    the pool's dead positions, whole dead pages, and the page that every
+    table column past a row's last live page names), and the stale VMEM
+    of the interpreter is NaN: the result is the contiguous golden's, so
+    no dead page slot reached ``p x v`` and no live one was skipped. Three
+    pages a chunk: rows of several chunks, a short last one, empty rows
+    between live ones."""
+    rng = np.random.default_rng(len(lens) + 7 * fuse_heads)
+    lens = np.asarray(_WALK_LENS[lens], np.int32)
+    h_kv, g, d = 2, 2, 128
+    k, v, kp, vp, table = _poisoned_pools(rng, lens, h_kv, d, window)
+    q = rng.standard_normal((len(lens), h_kv * g, d)).astype(np.float32)
+    want, want_lse = _windowed_golden(q, k, v, lens, window)
+    for pages_per_step in (None, 3):
+        got, lse = paged_flash_decode(
+            *(jnp.asarray(x) for x in (q, kp, vp, lens, table)),
+            fuse_heads=fuse_heads, window=window,
+            pages_per_step=pages_per_step, return_lse=True, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+        live = lens > 0
+        np.testing.assert_allclose(
+            np.asarray(lse)[live], want_lse[live], rtol=2e-5, atol=2e-5)
+        assert np.isneginf(np.asarray(lse)[~live]).all()
+
+
+@pytest.mark.parametrize("fuse_heads", [True, False])
+@pytest.mark.parametrize("form", ["plain", "window", "int8"])
+def test_paged_decode_keeps_another_rows_nan_out(form, fuse_heads):
+    """A request whose KV went non-finite stays that request's alone
+    (``ContinuousBatcher._poison_slot``'s containment): rows 0 and 1 walk
+    whole rows of NaN pages (V, and for an int8 pool its V scales), one
+    into each of the two buffers, and the rows after them are short, so
+    the span their last chunk is multiplied over is wider than their live
+    pages (3 live of 4, 5 of 8 or of 6): the dead page slots still hold
+    the NaN rows' pages. The later rows read bit for bit what they read
+    beside finite neighbours. It fails on any form that lets a page slot
+    it did not fetch into ``p x v``: ``0 x NaN`` is NaN."""
+    from triton_dist_tpu.ops.flash_decode import quantize_kv_pages
+
+    rng = np.random.default_rng(3 + fuse_heads)
+    whole = _WALK_PAGE * _WALK_PAGES
+    # (two short of the row: a window of 5 pages then lies over 6)
+    lens = np.asarray([whole - 2, whole - 2, 3 * _WALK_PAGE - 5, 5 * _WALK_PAGE,
+                       _WALK_PAGE + 1, whole - 2 * _WALK_PAGE, 1], np.int32)
+    window = 5 * _WALK_PAGE if form == "window" else None
+    h_kv, g, d = 2, 2, 128
+    k, v, kp, vp, table = _poisoned_pools(rng, lens, h_kv, d, window)
+    q = jnp.asarray(
+        rng.standard_normal((len(lens), h_kv * g, d)).astype(np.float32))
+    bad = table[:2].ravel()                 # every page of rows 0 and 1
+
+    def decode(v_pool, **scales):
+        return np.asarray(paged_flash_decode(
+            q, pools[0], v_pool, jnp.asarray(lens), jnp.asarray(table),
+            fuse_heads=fuse_heads, window=window, interpret=True, **scales))
+
+    if form == "int8":
+        # (a finite poison: an int8 payload holds no NaN, its scales do)
+        kp, vp = np.clip(kp, -4, 4), np.clip(vp, -4, 4)
+        *pools, ks, vs = quantize_kv_pages(jnp.asarray(kp), jnp.asarray(vp))
+        clean = decode(pools[1], k_scales=ks, v_scales=vs)
+        got = decode(pools[1], k_scales=ks.at[bad].set(jnp.nan),
+                     v_scales=vs.at[bad].set(jnp.nan))
+    else:
+        pools = [jnp.asarray(kp), jnp.asarray(vp)]
+        clean = decode(pools[1])
+        pools[0] = pools[0].at[bad].set(jnp.nan)
+        got = decode(pools[1].at[bad].set(jnp.nan))
+        want, _ = _windowed_golden(np.asarray(q), k, v, lens, window)
+        np.testing.assert_allclose(got[2:], want[2:], rtol=2e-5, atol=2e-5)
+    assert not np.array_equal(got[:2], clean[:2])   # the poison is live
+    assert np.isfinite(got[2:]).all()
+    np.testing.assert_array_equal(got[2:], clean[2:])

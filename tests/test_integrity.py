@@ -618,6 +618,69 @@ def test_serving_poison_quarantine_survivors_byte_identical(tiny1, _mesh1):
 
 
 @pytest.mark.chaos
+@pytest.mark.parametrize("pools", ["v", "kv"])
+def test_serving_nan_kv_stays_in_its_slot(_mesh1, pools):
+    """The containment ``_poison_slot`` argues from, held at the KERNEL
+    (ISSUE 38): slot 0's pages turn NaN in the pool (not its logits:
+    those follow on their own). The others stream byte-identically,
+    although slot 0 keeps its length, evicted or not, so the paged decode
+    fetches its NaN pages every step, into a buffer whose page slots the
+    shorter row after it leaves unfetched (3 live pages under a 4-page
+    span, 5 under 8). A NaN v reaches slot 0's logits and exactly that
+    request is lost; under a NaN k as well the softmax's sum is NaN and
+    the finalize emits zeros for it, so the request finishes, on
+    garbage."""
+    from triton_dist_tpu.models import init_params
+    from triton_dist_tpu.serving import (
+        Finished, Poisoned, ServingConfig, ServingEngine,
+    )
+
+    cfg = _tiny_cfg(batch=4)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    shapes = [(20, 6), (5, 12), (9, 10), (10, 8)]
+
+    def engine():
+        clock = retry.FakeClock()
+        retry.set_clock(clock)
+        return ServingEngine(
+            cfg, params, _mesh1, s_max=32, page_size=4, prefill=True,
+            clock=clock, serving=ServingConfig(virtual_step_s=0.01))
+
+    eng = engine()
+    for r in _requests(cfg, shapes):
+        eng.submit(r)
+    golden = eng.run_until_idle()
+    assert all(isinstance(r, Finished) for r in golden.values())
+
+    resilience.reset()
+    tdt_config.update(integrity=IntegrityConfig())
+    eng2 = engine()
+    orig = eng2._batcher._step
+    calls = {"n": 0}
+
+    def step_on_nan_pages(params_, cache, tok, pos):
+        calls["n"] += 1
+        if calls["n"] == 2:
+            pages = np.asarray(cache["block_table"])[0, 0]    # slot 0's
+            cache = dict(cache, **{
+                x: cache[x].at[:, pages].set(jnp.nan) for x in pools})
+        return orig(params_, cache, tok, pos)
+
+    eng2._batcher._step = step_on_nan_pages
+    for r in _requests(cfg, shapes):
+        eng2.submit(r)
+    done = eng2.run_until_idle()
+    lost = {u for u, r in done.items() if isinstance(r, Poisoned)}
+    assert lost == ({0} if pools == "v" else set())
+    assert done[0].tokens != golden[0].tokens
+    for uid in (1, 2, 3):
+        assert isinstance(done[uid], Finished)
+        assert done[uid].tokens == golden[uid].tokens, (
+            f"survivor {uid} must stream byte-identically")
+    assert eng2.snapshot()["requests"].get("poisoned", 0) == len(lost)
+
+
+@pytest.mark.chaos
 def test_serving_step_integrity_error_rebuilds_and_replays(tiny1, _mesh1,
                                                            monkeypatch):
     """A whole-step IntegrityError (canary/guard tripping INSIDE the

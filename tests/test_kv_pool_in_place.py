@@ -289,3 +289,39 @@ def test_one_pass_equals_the_slicing_reference_bit_for_bit(multi, attend):
     scratch = [pe * (cache["k"].shape[1] // n) + B * (S_MAX // n // PAGE)
                for pe in range(n)]
     assert changed[:, scratch].sum() == cfg.n_layers * (CHUNK if multi else 1)
+
+
+# -- what a step walks against what its tables hold ---------------------------
+
+@pytest.mark.parametrize("kind", ["kv", "kv_window", "kv_state", "latent"])
+def test_pages_walked_follow_the_lengths(kind):
+    """``pages_walked`` (the round span's ``kv_pages_live`` /
+    ``kv_pages_table``): the pages the lengths expose, a window layer's
+    the pages of ``[len - window, len)``, times the attention layers of
+    each kind; the table's side is capacity and does not move. The latent
+    kind's kernel still walks the table row, and says so."""
+    import types
+
+    from triton_dist_tpu.models.decode import PAGED_CACHE_KINDS
+
+    spec = PAGED_CACHE_KINDS[kind](S_MAX, PAGE, static_table=True)
+    cfg = types.SimpleNamespace(
+        n_layers=5, window=6, layer_types=("window",) * 3 + ("full",) * 2,
+        layer_kinds=("mamba",) * 4 + ("attention",))
+    lens = np.array([0, 1, PAGE, PAGE + 1, S_MAX], np.int32)
+    full = 0 + 1 + 1 + 2 + S_MAX // PAGE
+    # [len - 6, len) over pages of 4: none, 1, 1, 2 and, ending on a page's
+    # last position, 2; a ring of ceil(6 / 4) + 1 = 3 pages a slot
+    win, ring = 0 + 1 + 1 + 2 + 2, 3
+    want = {
+        "kv": (full * 5, len(lens) * (S_MAX // PAGE) * 5),
+        "kv_window": (full * 2 + win * 3,
+                      len(lens) * (S_MAX // PAGE * 2 + ring * 3)),
+        "kv_state": (full * 1, len(lens) * (S_MAX // PAGE) * 1),
+        "latent": (len(lens) * (S_MAX // PAGE) * 5,) * 2,
+    }[kind]
+    assert spec.pages_walked(cfg, lens) == want
+    # a window that ends one position into a page sees three pages of it
+    if kind == "kv_window":
+        live, _ = spec.pages_walked(cfg, np.array([2 * PAGE + 1], np.int32))
+        assert live == 3 * 2 + 3 * 3

@@ -342,10 +342,32 @@ def test_a_window_of_s_max_is_the_dense_familys_attention(toy):
         np.asarray(_causal_gqa_attention(q4, k4, v4, cfg)), **TOL)
 
 
+def test_the_dense_walk_and_a_window_of_s_max_agree_on_poisoned_tables():
+    """Both forms walk a row's live pages and no others: over a pool whose
+    dead pages hold a large finite value, with every table column past a
+    row's last live page naming such a page, ``window=None`` and ``window
+    = s_max`` give the masked decode over the contiguous cache."""
+    from tests.test_flash_decode import _poisoned_pools
+
+    rng = np.random.default_rng(6)
+    lens = np.array([1, PAGE, 3 * PAGE + 1, S_MAX], np.int32)
+    k, v, kp, vp, table = _poisoned_pools(
+        rng, lens, 2, 128, None, page=PAGE, pages=S_MAX // PAGE)
+    q = rng.standard_normal((len(lens), 4, 128)).astype(np.float32)
+    want = _masked_decode(q, k, v, lens, S_MAX)
+    args = [jnp.asarray(x) for x in (q, kp, vp, lens, table)]
+    for window in (None, S_MAX, 2 * S_MAX):
+        got = fd.paged_flash_decode(*args, window=window, interpret=True)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
 def test_the_dense_call_lowers_to_the_parents_program():
     """``window=None``: the jaxpr of ``paged_flash_decode`` at one shape is,
-    letter for letter, the one taken on the commit before there was a
-    window (tests/golden/): no dense cell's kernel moved."""
+    letter for letter, the one kept under tests/golden/ (regenerated by
+    PR 38, which moved the page loop into the kernel on purpose: the file
+    is the dense cells' kernel as that PR left it), so a later edit that
+    means the window form alone cannot move the dense cells' kernel
+    unseen; and the kernels keep the names the benchmark's readers find."""
     b, hq, h_kv, d, page, pages = 4, 8, 2, 128, 16, 8
     q = jax.ShapeDtypeStruct((b, hq, d), jnp.bfloat16)
     pool = jax.ShapeDtypeStruct((b * pages, h_kv, page, d), jnp.bfloat16)
@@ -595,6 +617,10 @@ def test_engine_serves_it_with_lookahead_and_the_spans_carry_the_counters(
         # 3 window layers read at most the window, the full layer all
         assert 0 < attrs["window_rows"] <= 3 * 2 * WINDOW
         assert attrs["full_rows"] >= attrs["window_rows"] // 3
+        # 2 slots x (the full layer's 8 pages + 3 window layers' rings of
+        # 3): the tables' side is capacity; the walk's follows the lengths
+        assert attrs["kv_pages_table"] == 2 * (S_MAX // PAGE + 3 * 3)
+        assert 0 < attrs["kv_pages_live"] < attrs["kv_pages_table"]
     assert any(a["assignments_elsewhere"] > 0 for a in rounds)
     # both slots live and past the window: 3 layers x 2 slots x 8 rows,
     # while the full layer's rows go on growing

@@ -45,7 +45,7 @@ from triton_dist_tpu.ops.flash_decode import (
     paged_flash_decode_distributed,
 )
 from triton_dist_tpu.obs.scopes import scope as _scope
-from triton_dist_tpu.obs.tracer import span as _span
+from triton_dist_tpu.obs.tracer import NULL_SPAN, span as _span
 from triton_dist_tpu.utils import axis_size as _axis_size
 
 
@@ -262,6 +262,20 @@ class PagedKVCacheSpec:
     # (WindowPagedKVCacheSpec); a config names its family's kind
     kind: ClassVar[str] = "kv"
 
+    def attention_layers(self, cfg) -> int:
+        """Layers whose k/v rows live in pages."""
+        return cfg.n_layers
+
+    def pages_walked(self, cfg, lens: np.ndarray) -> tuple[int, int]:
+        """``(live, table)`` of one step over slots of lengths ``lens``
+        (host arithmetic): the pages those lengths expose, which is what
+        ``paged_flash_decode`` walks (docs/serving.md "The walk's
+        discipline"), and the pages the table rows could name; over every
+        PE's shard of a sequence."""
+        layers = self.attention_layers(cfg)
+        return (int((-(-lens // self.page_size)).sum()) * layers,
+                lens.size * (self.s_max // self.page_size) * layers)
+
     def _geometry(self, cfg, n: int, n_o: int = 1) -> tuple[int, int]:
         s_shard = _shard_of(self.s_max, n)
         if s_shard % self.page_size != 0:
@@ -469,6 +483,12 @@ class LatentPagedCacheSpec(PagedKVCacheSpec):
             f"{what} is not built for the latent cache kind "
             f"(LatentPagedCacheSpec): it reads k/v pools")
 
+    def pages_walked(self, cfg, lens: np.ndarray) -> tuple[int, int]:
+        """``mla_paged_decode`` still walks the whole table row (PERF.md
+        §7): live is what it walks, the table."""
+        table = PagedKVCacheSpec.pages_walked(self, cfg, lens)[1]
+        return table, table
+
     def update_and_attend(self, *a, **kw):
         self._refuse("the k/v decode step")
 
@@ -544,6 +564,18 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
             k_full=kv["k"], v_full=kv["v"], k_win=kv["k"], v_win=kv["v"],
             block_table=kv["block_table"],
             block_table_win=kv["block_table"], n_alloc=kv["n_alloc"])
+
+    def pages_walked(self, cfg, lens: np.ndarray) -> tuple[int, int]:
+        page, ring = self.page_size, self.ring(cfg)
+        n_full = cfg.layer_types.count("full")
+        n_win = cfg.layer_types.count("window")
+        full = -(-lens // page)
+        # the pages of [len - window, len), as the kernel walks them
+        win = np.where(lens > 0, np.minimum(
+            (lens - 1) // page - np.maximum(lens - cfg.window, 0) // page + 1,
+            ring), 0)
+        return (int(full.sum()) * n_full + int(win.sum()) * n_win,
+                lens.size * (self.s_max // page * n_full + ring * n_win))
 
     def _pool_of(self, kind: str) -> tuple[str, str, str]:
         """``(k pool, v pool, table)`` names of an attention kind."""
@@ -703,6 +735,9 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
                     conv=P(None, None, t, None))
 
     # -- the attention layers' pages ----------------------------------------
+
+    def attention_layers(self, cfg) -> int:
+        return cfg.layer_kinds.count("attention")
 
     def write_and_attend(self, cfg, cache, ki: int, k_new, v_new, q, pos_b,
                          interpret):
@@ -2130,10 +2165,24 @@ class ContinuousBatcher:
         for name, value in zip(self._counters, values):
             sp.set(name, int(value))
 
+    def _set_page_counts(self, sp) -> None:
+        """``kv_pages_live`` / ``kv_pages_table`` of the step this round
+        took: the pages its lengths expose against what its tables hold,
+        from the positions the step was given (an idle slot keeps its
+        last one; a parked slot sits at ``s_max``). Counted only while
+        something records the span."""
+        if sp is not NULL_SPAN and isinstance(
+                self.spec, PagedKVCacheSpec):
+            live, table = self.spec.pages_walked(
+                self.cfg, np.clip(self.pos + 1, 0, self.spec.s_max))
+            sp.set("kv_pages_live", live)
+            sp.set("kv_pages_table", table)
+
     def _take_round(self, sp, nxt, logits_h, row_ok) -> None:
         """The host half of a decode round: every live slot takes its
         token (or feeds its next prompt token), finished requests leave
         their slots, and the round's span gets its counts."""
+        self._set_page_counts(sp)
         live = feeding = tokens = finished = 0
         for i, req in enumerate(self.slot_req):
             if req is None:

@@ -88,6 +88,10 @@ def _scoped_vmem_limit_bytes() -> int:
 # pages_per_step: the contiguous sweep's winning block_s on chip (r5) —
 # smaller spans pay the per-tile mask/max/exp/sum fixed costs too often.
 _TARGET_SPAN = 4096
+# Narrowest span at which the paged decode's fused-heads grid is preferred
+# over a per-head grid that affords a wider one (chip, PR 38: see
+# _paged_flash_decode_fused).
+_FUSED_MIN_SPAN = 512
 
 
 def _auto_pages_per_step(
@@ -211,21 +215,26 @@ def _online_softmax_step(
     scores through ``soft_cap * tanh(s / soft_cap)`` BEFORE the length
     mask, after any int8 dequant scale — the reference's logit soft-cap,
     in the one place all five kernel paths share. ``kv_lo`` (window
-    layers; None adds no op) also masks the positions below it."""
+    layers; None adds no op) also masks the positions below it. Leading
+    dims of ``q``, the tiles and the carry (the paged decode's kv heads)
+    are batch dims of both matmuls: one body for every head of a step."""
     if ks_row is not None:
         k_b = k_b.astype(jnp.bfloat16)
         v_b = v_b.astype(jnp.bfloat16)
-    s = jax.lax.dot_general(                            # [g, sc]
-        q, k_b, (((1,), (1,)), ((), ())),
+    lead = q.ndim - 2                 # leading (head) dims ride as batch
+    batch = (tuple(range(lead)),) * 2
+    s = jax.lax.dot_general(                            # [.., g, sc]
+        q, k_b, (((lead + 1,), (lead + 1,)), batch),
         preferred_element_type=jnp.float32,
     ) * (scale if ks_row is None else ks_row * scale)
     if soft_cap:
         s = soft_cap * jnp.tanh(s / soft_cap)
-    span = chunk_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    span = chunk_start + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, lead + 1)
     s = jnp.where(span < kv_len, s, NEG_INF)
     if kv_lo is not None:
         s = jnp.where(span >= kv_lo, s, NEG_INF)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     # all-masked rows keep m_new == -inf: subtract a clamped copy so the
     # update is exp(-inf) = 0, not exp(-inf - -inf) = NaN. The verify
     # kernel hits this (per-ROW lengths — a zero-length row shares its
@@ -233,11 +242,12 @@ def _online_softmax_step(
     # merely made it unreachable.
     m_safe = jnp.maximum(m_new, -1e30)
     alpha = jnp.exp(m_prev - m_safe)
-    p = jnp.exp(s - m_safe)                             # [g, sc]
-    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    p = jnp.exp(s - m_safe)                             # [.., g, sc]
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
     pv = p if vs_row is None else p * vs_row
-    acc_new = acc_prev * alpha + jax.lax.dot(
-        pv.astype(v_b.dtype), v_b, preferred_element_type=jnp.float32
+    acc_new = acc_prev * alpha + jax.lax.dot_general(
+        pv.astype(v_b.dtype), v_b, (((lead + 1,), (lead,)), batch),
+        preferred_element_type=jnp.float32,
     )
     return m_new, l_new, acc_new
 
@@ -1448,80 +1458,186 @@ def flash_ranged_prefill_fp8_distributed(
     return merged.reshape(b, S, hq, d)
 
 
+def _live_pages(kv_len, page_size: int, n_slots: int, window: int | None):
+    """``(first, n)``: the logical pages that hold the positions a row of
+    length ``kv_len`` attends — ``[0, kv_len)``, or a window's ``[kv_len -
+    window, kv_len)`` — run from page ``first`` for ``n`` pages: through the
+    page of position ``kv_len - 1``, none for an empty row, never more than
+    the ``n_slots`` the table (or the window) can hold."""
+    # (the operands are >= 0: lax's truncating div is the floor's)
+    last = jax.lax.div(jnp.maximum(kv_len - 1, 0), page_size)
+    first = 0 if window is None else jax.lax.div(
+        jnp.maximum(kv_len - window, 0), page_size)
+    n = jnp.where(kv_len > 0, jnp.minimum(last - first + 1, n_slots), 0)
+    return first, n
+
+
 def _paged_flash_decode_kernel(
     kv_lens_ref, block_table_ref, q_ref, *rest,
-    n_steps: int, pages_per_step: int, page_size: int,
-    scale: float, h_kv: int, chunk_dim: int, quant: bool = False,
-    soft_cap: float = 0.0, window: int | None = None,
+    pages_per_step: int, page_size: int, scale: float, per_head: bool,
+    n_slots: int, quant: bool = False, soft_cap: float = 0.0,
+    window: int | None = None,
 ):
-    """Paged decode over ``pages_per_step`` pages concatenated into one
-    [g, P·page] span per step (r5 chip finding: the span, not the page
-    indirection, is the cost — the contiguous winner's shape is
-    block_s=4096 = 16 pages). ONE body for BOTH grids: the fused-heads
-    grid passes the pool's ``h_kv`` and ``chunk_dim=1``; the per-head
-    grid is the ``h_kv=1, chunk_dim=2`` instance (its blocks/scratches
-    carry a leading head dim of 1). Physical pages arrive via the
-    prefetched block table (≙ the reference's block_table indirection,
-    flash_decode.py:136,203). ``quant``: int8 page pools — 2P extra
-    scale-page slots follow the data slots, concatenated into per-
-    position scale rows exactly as :func:`flash_decode_quant` folds
-    them (payload DMAs at half the bytes). ``window``: the steps cover
-    the pages of ``[kv_len - window, kv_len)`` only (the index maps start
-    at that range's first page), and the positions below it are masked."""
-    del block_table_ref
+    """Paged decode of one (row, kv head) — per-head grid ``(b, h_kv)`` —
+    or of one row's every kv head — fused grid ``(b,)`` — a grid step. ONE
+    body for both: the buffers carry a head dim of 1 or of the pool's
+    ``h_kv``.
+
+    The page loop is HERE, and its trip count is data: the pools stay in
+    HBM, and the step walks the row's LIVE pages (:func:`_live_pages`) in
+    chunks of ``pages_per_step``, each page its own DMA into one of two
+    VMEM buffers, the chunk one [g, P·page] online-softmax span (r5 chip
+    finding: the span, not the page indirection, is the cost). While a
+    chunk is multiplied the next one is in flight — the same row's, or,
+    at a row's last chunk, the first chunk of the NEXT grid step, whose
+    table row and length are in SMEM too — so only the call's first DMA
+    is exposed. The grid therefore runs in order on one core (every
+    dimension ``arbitrary``), and ``slot_ref`` carries the buffer the
+    step's first chunk lands in from step to step.
+
+    The last chunk of a row is multiplied over the narrowest of the spans
+    P, P/2, P/4, ... that covers its live pages. That span's dead page
+    slots (past the row's last live page) are not fetched: they hold
+    whatever an earlier chunk left there — uninitialised VMEM, or ANOTHER
+    row's page. The length mask discards their scores, but ``p·v``
+    multiplies them by 0, and ``0 x NaN`` is NaN — so the V (and V-scale)
+    slots ``[live, width)`` are zeroed before the multiply: nothing but
+    the row's own pages ever reaches its product, and a request whose KV
+    went non-finite stays that request's alone (``_poison_slot``'s
+    containment, models/decode.py).
+
+    ``quant``: int8 / fp8 pools — the per-position scale rows ride their
+    own page DMAs and are concatenated exactly as
+    :func:`flash_decode_quant` folds them. ``window``: the walk starts at
+    the page of position ``kv_len - window`` (table column = logical page
+    modulo the table's width: a ring), and the positions below the window
+    are masked."""
     P = pages_per_step
-    kv_refs = rest[: 2 * P]
-    s_refs = rest[2 * P : 4 * P] if quant else ()
-    out_ref, lse_ref, m_scr, l_scr, acc_scr = rest[(4 if quant else 2) * P :]
-    c = pl.program_id(chunk_dim)
-    kv_len = kv_lens_ref[pl.program_id(0)]
+    if quant:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, out_ref, lse_ref, k_buf, v_buf,
+         ks_buf, vs_buf, sems, slot_ref, m_scr, l_scr, acc_scr) = rest
+    else:
+        (k_hbm, v_hbm, out_ref, lse_ref, k_buf, v_buf, sems, slot_ref,
+         m_scr, l_scr, acc_scr) = rest
+        ks_hbm = vs_hbm = ks_buf = vs_buf = None
+    b, max_pages = block_table_ref.shape
+    i = pl.program_id(0)
+    if per_head:
+        j = pl.program_id(1)
+        wrap = j == pl.num_programs(1) - 1
+        nxt_i, nxt_j = jnp.where(wrap, i + 1, i), jnp.where(wrap, 0, j + 1)
+        first_step = jnp.logical_and(i == 0, j == 0)
+    else:
+        j = nxt_j = None
+        nxt_i, first_step = i + 1, i == 0
+    kv_len = kv_lens_ref[i]
     kv_lo = None if window is None else jnp.maximum(kv_len - window, 0)
+    first, n_live = _live_pages(kv_len, page_size, n_slots, window)
+    n_chunks = jax.lax.div(n_live + P - 1, P)
 
-    def chunk_start():
-        # traced where it is used: without a window the kernel's ops are,
-        # one for one, what they were before there was one
-        if window is None:
-            return c * P * page_size
-        return (jax.lax.div(kv_lo, page_size) + c * P) * page_size
+    def chunk_dmas(then, slot, row, head, chunk, valid=True):
+        """``then`` (start / wait) on each page DMA of ``row``'s ``chunk``
+        into buffer ``slot``: its live pages, none where not ``valid``."""
+        row_first, row_live = _live_pages(
+            kv_lens_ref[row], page_size, n_slots, window)
 
-    @pl.when(c == 0)
+        def one_page(p, carry):
+            logical = row_first + chunk * P + p
+            page = block_table_ref[row, logical if window is None
+                                   else jax.lax.rem(logical, max_pages)]
+            src = (page, pl.ds(head, 1)) if per_head else (page,)
+            at = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                then(pltpu.make_async_copy(
+                    hbm.at[src], buf.at[slot, :, at], sems.at[slot]))
+            if quant:  # the scale rows of the page, whole tiles
+                for hbm, buf in ((ks_hbm, ks_buf), (vs_hbm, vs_buf)):
+                    then(pltpu.make_async_copy(
+                        hbm.at[src], buf.at[slot, p], sems.at[slot]))
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.where(valid, jnp.clip(row_live - chunk * P, 0, P), 0),
+            one_page, 0)
+
+    start = lambda dma: dma.start()
+    wait = lambda dma: dma.wait()
+    # the next grid step's first chunk, where there is a next step
+    nxt = (jnp.minimum(nxt_i, b - 1), nxt_j, 0)
+    has_nxt = nxt_i < b
+
+    @pl.when(first_step)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        slot_ref[0] = 0
+        chunk_dmas(start, 0, i, j, 0)
 
-    # clamped duplicate tail slots (logical chunk >= max_pages; with a
-    # window, past the page of position kv_len - 1) sit at span positions
-    # >= kv_len: length-masked
-    @pl.when(chunk_start() < kv_len)
-    def _():
-        for j in range(h_kv):  # static unroll over the slab's heads
-            k_cat = jnp.concatenate(
-                [kv_refs[2 * p][0, j] for p in range(P)], axis=0
-            ) if P > 1 else kv_refs[0][0, j]
-            v_cat = jnp.concatenate(
-                [kv_refs[2 * p + 1][0, j] for p in range(P)], axis=0
-            ) if P > 1 else kv_refs[1][0, j]
-            if quant:  # int8 page pools: per-position scale rows ride
-                ks_cat = jnp.concatenate(
-                    [s_refs[2 * p][0, j] for p in range(P)], axis=1
-                ) if P > 1 else s_refs[0][0, j]
-                vs_cat = jnp.concatenate(
-                    [s_refs[2 * p + 1][0, j] for p in range(P)], axis=1
-                ) if P > 1 else s_refs[1][0, j]
-            else:
-                ks_cat = vs_cat = None
-            m_scr[j], l_scr[j], acc_scr[j] = _online_softmax_step(
-                q_ref[0, j], k_cat, v_cat, ks_cat, vs_cat,
-                chunk_start(), kv_len, scale,
-                m_scr[j], l_scr[j], acc_scr[j], soft_cap, kv_lo,
-            )
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    slot0 = slot_ref[0]
 
-    @pl.when(c == n_steps - 1)
-    def _():
-        out_ref[0], lse_ref[0] = _finalize_softmax(
-            m_scr[:], l_scr[:], acc_scr[:]
+    def multiply(slot, chunk, width: int):
+        """The online-softmax step of the step's heads (a batch dim of its
+        matmuls) over the first ``width`` page slots of buffer ``slot``."""
+        span = pl.ds(0, width * page_size)
+        if quant:  # per-position scale rows ride
+            ks, vs = (
+                jnp.concatenate(
+                    [buf[slot, p] for p in range(width)], axis=-1
+                ) if width > 1 else buf[slot, 0]
+                for buf in (ks_buf, vs_buf))
+        else:
+            ks = vs = None
+        m_scr[...], l_scr[...], acc_scr[...] = _online_softmax_step(
+            q_ref[0], k_buf[slot, :, span], v_buf[slot, :, span], ks, vs,
+            (first + chunk * P) * page_size, kv_len, scale,
+            m_scr[...], l_scr[...], acc_scr[...], soft_cap, kv_lo,
         )
+
+    def zero_v_page(slot, p, carry):
+        """Page slot ``p`` of buffer ``slot`` leaves the product: stale
+        VMEM never enters p·v (see the docstring)."""
+        at = pl.ds(pl.multiple_of(p * page_size, page_size), page_size)
+        v_buf[slot, :, at] = jnp.zeros(
+            (v_buf.shape[1], page_size, v_buf.shape[3]), v_buf.dtype)
+        if quant:
+            vs_buf[slot, p] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
+        return carry
+
+    # the spans a chunk may be multiplied over: P, and its halves
+    widths = sorted({cdiv(P, 1 << s) for s in range(P.bit_length())})
+
+    def one_chunk(c, carry):
+        slot = jax.lax.rem(slot0 + c, 2)
+        more = c + 1 < n_chunks
+        # what is multiplied next goes into the other buffer first: this
+        # row's next chunk, or the next grid step's first
+        after = [x if y is None else jnp.where(more, x, y)
+                 for x, y in zip((i, j, c + 1), nxt)]
+        chunk_dmas(start, 1 - slot, *after, jnp.logical_or(more, has_nxt))
+        chunk_dmas(wait, slot, i, j, c)
+        live = jnp.clip(n_live - c * P, 0, P)
+        # the narrowest span that covers the live pages; the page slots it
+        # holds past them were not fetched
+        cover = jnp.int32(widths[0])
+        for below, width in zip(widths, widths[1:]):
+            cover = jnp.where(live > below, width, cover)
+        jax.lax.fori_loop(
+            live, cover, functools.partial(zero_v_page, slot), 0)
+        for width in widths:
+            pl.when(cover == width)(
+                functools.partial(multiply, slot, c, width))
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, one_chunk, 0)
+
+    @pl.when(n_chunks == 0)
+    def _():  # an empty row hands the next step its first chunk itself
+        chunk_dmas(start, slot0, *nxt, has_nxt)
+
+    slot_ref[0] = jax.lax.rem(slot0 + n_chunks, 2)
+    out_ref[0], lse_ref[0] = _finalize_softmax(
+        m_scr[...], l_scr[...], acc_scr[...])
 
 
 def paged_flash_decode(
@@ -1547,29 +1663,51 @@ def paged_flash_decode(
 
     q: ``[b, q_heads, d]``; k_pages, v_pages: ``[n_pages, kv_heads,
     page_size, d]``; kv_lens: ``[b]`` int32; block_table: ``[b, max_pages]``
-    int32 physical page ids (entries beyond the valid length may be
-    arbitrary in-range values). Returns like :func:`flash_decode`.
+    int32 physical page ids (entries past a row's last live page are
+    never read). Returns like :func:`flash_decode`.
 
-    TPU-native form of the indirection: the block table rides scalar
-    prefetch (SMEM), and the K/V BlockSpec index_map reads it to steer each
-    grid step's page fetch — the double-buffered pipeline then streams
-    pages exactly as the contiguous kernel streams chunks.
+    TPU-native form of the indirection: ``kv_lens`` and the block table
+    ride scalar prefetch (SMEM), the pools stay in HBM, and the kernel
+    itself copies pages into VMEM: the page loop runs INSIDE a grid step
+    and ends at the page of position ``kv_len - 1`` (docs/serving.md "The
+    walk's discipline"). **What is fetched:** the pages that hold a row's
+    attended positions, each whole, each once a kv head (per-head grid)
+    or once (fused grid): a table's width is capacity, never work; an
+    empty row fetches nothing. Two buffers of ``pages_per_step`` page
+    slots alternate, one multiplied while the other fills, and a step's
+    last chunk overlaps the NEXT grid step's first, so the grid runs in
+    order (every dimension ``arbitrary``). **The stale-buffer rule:** a
+    page slot that was not fetched holds whatever was there; the length
+    mask discards its scores, but ``p x v`` would multiply it by 0 and
+    ``0 x NaN`` is NaN, and what was there may be ANOTHER row's page: so
+    the dead V slots of the span a row's last chunk is multiplied over
+    are zeroed before the multiply. Only a row's own pages reach its
+    product (the positions of its own last page past ``kv_len`` included,
+    as they always did).
 
     ``fuse_heads``: a page holds every kv head's slab, so the fused-heads
-    grid (b, step) fetches each physical page in ONE DMA; the per-head
-    grid (b, h_kv, step) fetches page_size·d slices. Default (None) =
-    auto, decided by the per-step softmax SPAN each grid can afford
-    under the scoped-VMEM budget (r5 chip finding: span, not DMA size,
-    decides throughput — per-head at span 4096 measured 347 µs where
-    fused capped at 1792 gave 392, and the span-256 grids 577). Pass
-    True/False to pin.
+    grid (b,) fetches each physical page in ONE DMA and multiplies the
+    heads as a batch dim of its matmuls; the per-head grid (b, h_kv)
+    fetches page_size·d slices. Default (None) = auto: fused wherever one
+    of its page slots fits the scoped-VMEM budget and its span reaches
+    512, or all the per-head grid could span (the per-head grid's slabs
+    are h_kv times smaller, so many-kv-head pools always compile). A grid step costs ~0.8 µs however little it
+    walks (chip, PR 38: 64 steps of one page 50 µs, 8 fused steps 20), so
+    fewer, larger steps win at every length measured. Pass True/False to
+    pin.
 
-    ``pages_per_step``: physical pages CONCATENATED into one online-
-    softmax span per grid step (each page still its own DMA, P in
-    flight). None = auto: reach a 4096 span, bounded by the VMEM
-    budget and the table width. The one-page grids measured 571 µs vs
-    the contiguous kernel's 359 for identical bytes (r5); the span fix
-    recovers all of it and the indirection costs nothing.
+    ``pages_per_step``: page slots of a buffer = physical pages
+    CONCATENATED into one online-softmax span (each page still its own
+    DMA, P in flight). None = auto: reach a 4096 span, bounded by the
+    VMEM budget and the table width. The r5 span finding (on the old
+    grids: per-head at span 4096 measured 347 µs where fused capped at
+    1792 gave 392, and the span-256 grids 577) holds in this form at the
+    long end — per-head over 14 live pages of 16: P = 16 104 µs, P = 4
+    174, P = 2 246 (chip, PR 38: 8 rows, 8 kv heads, page 128) — and is
+    met from the short end by the span ladder: a row's last chunk is
+    multiplied over the narrowest of P, P/2, P/4, ... page slots that
+    covers its live pages, so three live pages cost a 4-page span, not
+    the buffer's.
 
     ``k_scales``/``v_scales`` (``[n_pages, kv_heads, 1, page_size]``
     f32, from :func:`quantize_kv_pages`): int8 page pools — the paged
@@ -1580,15 +1718,15 @@ def paged_flash_decode(
     bf16/int8), which the reference's bf16-only paged decode lacks.
 
     ``window``: a row attends positions ``[kv_len - window, kv_len)``
-    only, and only the pages that hold them are fetched: the grid covers
-    ``ceil(window / page) + 1`` page slots a row (never more than the
-    table is wide) and the index maps start at the range's first page.
+    only, and only the pages that hold them are fetched: the walk starts
+    at the range's first page and covers at most ``ceil(window / page) +
+    1`` pages a row (never more than the table is wide).
     ``kv_lens`` counts TRUE positions; logical page ``i`` lies in table
     column ``i % max_pages``, so a table narrower than the sequence is a
     RING (``models/decode.py`` ``WindowPagedKVCacheSpec``) and a
     full-width one is read where it lies. The kernels carry the window
-    in their names (``paged_flash_decode_w128_fh``). ``None``: the
-    program is, op for op, what it was before there was a window.
+    in their names (``paged_flash_decode_w128_fh``). ``None``: the same
+    walk from page 0.
 
     The bf16 pool degrades to the gather-reconstructed
     :func:`_xla_paged_decode` golden when the Pallas kernel cannot run in
@@ -1640,7 +1778,7 @@ def _paged_flash_decode_fused(
     g = hq // h_kv
     max_pages = block_table.shape[1]
     quant = k_scales is not None
-    # page slots a row's steps cover: the whole table row, or the pages
+    # page slots a row's walk can cover: the whole table row, or the pages
     # that can hold a window's positions
     n_slots = max_pages if window is None else _window_pages(
         window, page_size, max_pages)
@@ -1656,32 +1794,37 @@ def _paged_flash_decode_fused(
         assert v_scales is not None
         assert k_scales.shape == (n_pages, h_kv, 1, page_size), k_scales.shape
         assert v_scales.shape == k_scales.shape, (v_scales.shape, k_scales.shape)
-    # int8 pools stream half the payload bytes plus the f32 scale rows
+    # what a page slot of one kv head takes of VMEM, as a K or V slab of
+    # the two double buffers (4 of them a slot): int8 / fp8 pools hold half
+    # the payload bytes, plus an f32 scale row (a [1, page] row fills 8
+    # sublanes) and the bf16 copy the multiply makes of one buffer
     slab_h = page_size * (
-        d * k_pages.dtype.itemsize + (4 if quant else 0)
+        d * k_pages.dtype.itemsize + ((32 + d) if quant else 0)
     )
     slab_f = h_kv * slab_h
     p_f = _auto_pages_per_step(slab_f, page_size, n_slots)
     p_h = _auto_pages_per_step(slab_h, page_size, n_slots)
     if fuse_heads is None:
-        # span-driven choice (r5 chip finding: the per-step softmax span,
-        # not the page indirection or DMA size, decides throughput): each
-        # grid shape concatenates as many page slots as its double-
-        # buffered slabs afford — pick the grid that reaches the wider
-        # span; ties go to fused (one DMA per page covers all heads), but
-        # only when at least one fused slot actually fits the budget.
-        # This preserves the old guarantee that many-kv-head pools never
-        # fail to compile: per-head slabs are h_kv× smaller.
-        if quant:
-            # int8 pools halve payload bytes and add per-page scale
-            # fetches: the per-head grid's [page, d] slices drop to tens
-            # of KB and the pipeline goes DMA-ISSUE-bound (chip r5:
-            # per-head 478 µs vs fused 218 at the serving shape, even
-            # though per-head affords the wider span) — prefer the fused
-            # grid whenever one of its slots fits.
-            fuse_heads = p_f >= 1
-        else:
-            fuse_heads = p_f >= 1 and p_f >= p_h
+        # the fused grid wherever one of its page slots fits the budget
+        # and its span is not a sliver: a grid step costs ~0.8 us however
+        # little it walks (the DMA latency one step of lookahead cannot
+        # hide), and the fused grid has h_kv times fewer of them, each
+        # page ONE DMA for all heads. v5e, 8 rows x 8 kv heads, 3 / 8 / 14
+        # live pages of 16 (PR 38): fused at P = 4 or 8 30 / 58 / 93 us,
+        # per-head at P = 16 57 / 76 / 104; fused at P = 2 (a 256 span,
+        # heads unrolled) 45 / 88 / 142: below _FUSED_MIN_SPAN the
+        # per-head grid, whose slabs afford the wider span, wins the long
+        # end. int8 / fp8 pools (half the payload bytes, per-page scale
+        # fetches) go DMA-issue-bound per head (chip r5: 478 us against
+        # fused 218, on the old grids; not measured again on this form: no
+        # cell runs a quantized pool): fused whenever a slot fits. A walk
+        # narrower than _FUSED_MIN_SPAN altogether (a window layer's 2
+        # page slots, a 4-page table of small pages) is fused when it is
+        # all the per-head grid could span too. Many-kv-head pools still
+        # never fail to compile: per-head slabs are h_kv times smaller.
+        fuse_heads = p_f >= 1 and (
+            quant
+            or p_f * page_size >= min(_FUSED_MIN_SPAN, p_h * page_size))
     if pages_per_step is None and (p_f if fuse_heads else p_h) == 0:
         # the SELECTED grid (auto never picks a dead grid while the other
         # lives, but an explicit fuse_heads can force one) affords not even
@@ -1705,144 +1848,71 @@ def _paged_flash_decode_fused(
     q4 = q.reshape(b, h_kv, g, d).astype(
         jnp.bfloat16 if quant else k_pages.dtype
     )
+    # an upper bound: what a call reads and multiplies follows kv_lens
     cost = pl.CostEstimate(
         flops=4 * b * hq * n_slots * page_size * d,
         bytes_accessed=(2 * b * h_kv * n_slots * page_size)
         * (d * k_pages.dtype.itemsize + (4 if quant else 0)),
         transcendentals=b * hq * n_slots * page_size,
     )
-
-    def column(i, slot, kv_lens_ref):
-        """Table column of row ``i``'s page slot ``slot``."""
-        if window is None:
-            return jnp.minimum(slot, max_pages - 1)
-        # the window's first page + slot, held at the page of the last
-        # position (a duplicate fetch the pipeline skips, length-masked)
-        # (all three are >= 0: lax's truncating div / rem are the floor's)
-        kv_len = kv_lens_ref[i]
-        lo = jax.lax.div(jnp.maximum(kv_len - window, 0), page_size)
-        last = jax.lax.div(jnp.maximum(kv_len - 1, 0), page_size)
-        return jax.lax.rem(jnp.minimum(lo + slot, last), max_pages)
-
-    if fuse_heads:
-        if pages_per_step is None:
-            pages_per_step = max(1, p_f)
-        P = pages_per_step
-        n_steps = cdiv(n_slots, P)
-
-        def kv_index_map_p(p):
-            def index_map(i, c, kv_lens_ref, bt_ref):
-                return (bt_ref[i, column(i, c * P + p, kv_lens_ref)], 0, 0, 0)
-            return index_map
-
-        page_spec = lambda p: pl.BlockSpec(
-            (1, h_kv, page_size, d), kv_index_map_p(p)
-        )
-        scale_spec = lambda p: pl.BlockSpec(
-            (1, h_kv, 1, page_size), kv_index_map_p(p)
-        )
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, n_steps),
-            in_specs=[
-                pl.BlockSpec((1, h_kv, g, d), lambda i, c, *_: (i, 0, 0, 0)),
-                *(page_spec(p) for p in range(P) for _ in (0, 1)),
-                *(scale_spec(p) for p in range(P) for _ in (0, 1) if quant),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, h_kv, g, d), lambda i, c, *_: (i, 0, 0, 0)),
-                pl.BlockSpec((1, h_kv, g, 1), lambda i, c, *_: (i, 0, 0, 0)),
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((h_kv, g, 1), jnp.float32),
-                pltpu.VMEM((h_kv, g, 1), jnp.float32),
-                pltpu.VMEM((h_kv, g, d), jnp.float32),
-            ],
-        )
-        out, lse = dist_pallas_call(
-            functools.partial(
-                _paged_flash_decode_kernel,
-                n_steps=n_steps, pages_per_step=P,
-                page_size=page_size, scale=scale, h_kv=h_kv, chunk_dim=1,
-                quant=quant, soft_cap=soft_cap, window=window,
-            ),
-            name=("paged_flash_decode_q_fh" if quant
-                  else f"paged_flash_decode{tag}_fh"),
-            grid_spec=grid_spec,
-            out_shape=(
-                jax.ShapeDtypeStruct((b, h_kv, g, d), jnp.float32),
-                jax.ShapeDtypeStruct((b, h_kv, g, 1), jnp.float32),
-            ),
-            cost_estimate=cost,
-            dimension_semantics=("parallel", "arbitrary"),
-            uses_barrier=False,
-            interpret=interpret,
-        )(
-            kv_lens.astype(jnp.int32), block_table.astype(jnp.int32),
-            q4, *(kv for _ in range(P) for kv in (k_pages, v_pages)),
-            *(sc for _ in range(P) for sc in (k_scales, v_scales) if quant),
-        )
-        out = out.reshape(b, hq, d)[..., :d_out]
-        lse = lse.reshape(b, hq)
-        return (out, lse) if return_lse else out
-
     if pages_per_step is None:
-        pages_per_step = max(1, p_h)
-    P = pages_per_step
-    n_steps = cdiv(n_slots, P)
-
-    def kv_index_map_p(p):
-        def index_map(i, j, c, kv_lens_ref, bt_ref):
-            return (bt_ref[i, column(i, c * P + p, kv_lens_ref)], j, 0, 0)
-        return index_map
-
-    page_spec = lambda p: pl.BlockSpec(
-        (1, 1, page_size, d), kv_index_map_p(p)
-    )
-    scale_spec = lambda p: pl.BlockSpec(
-        (1, 1, 1, page_size), kv_index_map_p(p)
-    )
+        pages_per_step = max(1, p_f if fuse_heads else p_h)
+    P = min(pages_per_step, n_slots)
+    # kv heads a grid step holds; the per-head grid is the 1-head instance
+    # of the same body (a leading head dim of 1 on blocks and buffers)
+    heads = h_kv if fuse_heads else 1
+    if fuse_heads:
+        grid = (b,)
+        block = lambda i, *_: (i, 0, 0, 0)
+    else:
+        grid = (b, h_kv)
+        block = lambda i, j, *_: (i, j, 0, 0)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h_kv, n_steps),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda i, j, c, *_: (i, j, 0, 0)),
-            *(page_spec(p) for p in range(P) for _ in (0, 1)),
-            *(scale_spec(p) for p in range(P) for _ in (0, 1) if quant),
+            pl.BlockSpec((1, heads, g, d), block),
+            *([in_hbm] * (4 if quant else 2)),
         ],
         out_specs=(
-            pl.BlockSpec((1, 1, g, d), lambda i, j, c, *_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda i, j, c, *_: (i, j, 0, 0)),
+            pl.BlockSpec((1, heads, g, d), block),
+            pl.BlockSpec((1, heads, g, 1), block),
         ),
         scratch_shapes=[
-            pltpu.VMEM((1, g, 1), jnp.float32),
-            pltpu.VMEM((1, g, 1), jnp.float32),
-            pltpu.VMEM((1, g, d), jnp.float32),
+            # two buffers of P page slots: one multiplied, one in flight
+            *[pltpu.VMEM((2, heads, P * page_size, d), k_pages.dtype)] * 2,
+            *[pltpu.VMEM((2, P, heads, 1, page_size), jnp.float32)]
+            * (2 if quant else 0),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((heads, g, 1), jnp.float32),
+            pltpu.VMEM((heads, g, 1), jnp.float32),
+            pltpu.VMEM((heads, g, d), jnp.float32),
         ],
     )
-    # pages are viewed [n_pages, h_kv, page_size, d] → block (1,1,ps,d);
-    # the shared body's h_kv=1 instance (leading head dim on scratches)
+    name = "paged_flash_decode_q" if quant else f"paged_flash_decode{tag}"
     out, lse = dist_pallas_call(
         functools.partial(
             _paged_flash_decode_kernel,
-            n_steps=n_steps, pages_per_step=P,
-            page_size=page_size, scale=scale, h_kv=1, chunk_dim=2,
-            quant=quant, soft_cap=soft_cap, window=window,
+            pages_per_step=P, page_size=page_size, scale=scale,
+            per_head=not fuse_heads, n_slots=n_slots, quant=quant,
+            soft_cap=soft_cap, window=window,
         ),
-        name="paged_flash_decode_q" if quant else f"paged_flash_decode{tag}",
+        name=name + ("_fh" if fuse_heads else ""),
         grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((b, h_kv, g, d), jnp.float32),
             jax.ShapeDtypeStruct((b, h_kv, g, 1), jnp.float32),
         ),
         cost_estimate=cost,
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        # in order, on one core: a step starts the next step's first DMAs
+        dimension_semantics=("arbitrary",) * len(grid),
         uses_barrier=False,
         interpret=interpret,
     )(
-        kv_lens.astype(jnp.int32), block_table.astype(jnp.int32),
-        q4, *(kv for _ in range(P) for kv in (k_pages, v_pages)),
-        *(sc for _ in range(P) for sc in (k_scales, v_scales) if quant),
+        kv_lens, block_table.astype(jnp.int32), q4, k_pages, v_pages,
+        *((k_scales, v_scales) if quant else ()),
     )
     out = out.reshape(b, hq, d)[..., :d_out]
     lse = lse.reshape(b, hq)
