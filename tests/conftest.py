@@ -94,6 +94,7 @@ def pytest_configure(config):
 # that exist).
 _LONG_POLES = (
     "test_spec_soak.py", "test_ranged_engine.py", "test_window_moe.py",
+    "test_prerouted_moe.py",    # PR 39: ~as heavy as test_window_moe.py
     "test_emitter.py",
     "test_mla_moe.py", "test_disagg.py", "test_ranged_batcher.py",
     "test_serving.py", "test_prefill_work.py", "test_prefix_cache_soak.py",
@@ -104,6 +105,7 @@ _LONG_POLES = (
     "test_ragged_pipeline.py", "test_ranged_contiguous.py",
     "test_flight_recorder.py", "test_recovery.py", "test_fp8.py",
     "test_ssm_hybrid.py", "test_lookahead.py", "test_gemm_rs.py",
+    "test_chip_compile.py",     # PR 39: +4 compiles at published widths
     "test_chip_smoke.py",
     "test_ranged_paged.py", "test_ring_attention.py",
     "test_gate_up_layout.py", "test_moe.py", "test_ag_gemm.py",

@@ -456,3 +456,122 @@ def test_kv_pool_is_written_and_read_in_place(topo, rows):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // n_layers
+
+
+# -- SmallThinker-21BA3B at its published widths (perfbench's configuration) ------
+
+@pytest.mark.parametrize("window", [4096, None], ids=["w4096", "full"])
+def test_flash_prefill_compiles(one_chip, window):
+    """The tiled prefill attention at the bucket the cell admits: one
+    slot's 8192 rows, 28 q heads on 4 kv heads of 128 (a group of 7
+    stacked into one 896-row operand), no ``[L, L]`` temporary."""
+    from triton_dist_tpu.ops.flash_prefill import flash_prefill
+
+    L, hq, h_kv = 8192, 28, 4
+    compiled = jax.jit(functools.partial(
+        flash_prefill, window=window, interpret=False)).lower(
+        _struct((1, L, hq, HEAD), jnp.bfloat16, one_chip),
+        _struct((1, L, h_kv, HEAD), jnp.bfloat16, one_chip),
+        _struct((1, L, h_kv, HEAD), jnp.bfloat16, one_chip),
+        _struct((1,), jnp.int32, one_chip)).compile()
+    name = "flash_prefill" + (f"_w{window}" if window else "")
+    assert "tpu_custom_call" in compiled.as_text() and name in compiled.as_text()
+    # the output and nothing that grows with L squared
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * L * hq * HEAD * 2
+
+
+@pytest.fixture(scope="module")
+def smallthinker(topo):
+    """``(cfg, spec, mesh, params' and cache's shapes)`` of the
+    benchmark's SmallThinker configuration on one described chip."""
+    import sys
+
+    perfbench = os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "perfbench")
+    if perfbench not in sys.path:
+        sys.path.insert(0, perfbench)
+    from harness import cells
+
+    from triton_dist_tpu.models.decode import WindowPagedKVCacheSpec
+    from triton_dist_tpu.models.window_moe import init_window_moe_params
+
+    cell = cells.Cell(cells.benchmark(), "smallthinker-21b-a3b.doc-reason")
+    adapter = cells.load_module("programs", cell.config["program"])
+    cfg = adapter.model_config(cell.config, interpret=False)
+    eng = cell.config["engine"]
+    spec = WindowPagedKVCacheSpec(eng["s_max"], eng["page"], static_table=True)
+    mesh = Mesh(np.array(topo.devices[:1]), (cfg.axis,))
+    place = lambda shapes, specs: jax.tree.map(
+        lambda x, s: _struct(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, specs)
+    params = place(
+        jax.eval_shape(functools.partial(init_window_moe_params, cfg=cfg),
+                       jax.random.PRNGKey(0)), cfg.param_specs())
+    cache = place(jax.eval_shape(lambda: spec.init(cfg, 1)), spec.specs(cfg))
+    return cfg, spec, mesh, params, cache
+
+
+def test_smallthinker_step_compiles(smallthinker):
+    """The decode step at the published widths: 32 slots, rings of 33
+    pages, a full layer's table row of 128 pages, a group of 7; the
+    cache aliased in and out, the window walk named for its window."""
+    from triton_dist_tpu.models import decode
+
+    cfg, spec, mesh, params, cache = smallthinker
+    assert (spec.ring(cfg), cfg.n_q_heads // cfg.n_kv_heads) == (33, 7)
+    assert cache["block_table"].shape == (1, 32, 128)
+    assert cache["block_table_win"].shape == (1, 32, 33)
+    rep = NamedSharding(mesh, P())
+    cs = spec.specs(cfg)
+    fn = jax.jit(jax.shard_map(
+        lambda p, c, t, pos: decode.decode_step(
+            cfg, p, c, t, pos, spec=spec, interpret=False),
+        mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P()),
+        out_specs=(P(), cs, P()), check_vma=False), donate_argnums=(1,))
+    compiled = fn.lower(params, cache, _struct((32,), jnp.int32, rep),
+                        _struct((32,), jnp.int32, rep)).compile()
+    text = compiled.as_text()
+    assert "paged_flash_decode_w4096" in text and "group_gemm" in text
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(cache[k].shape) * 2 for k in (
+        "k_full", "v_full", "k_win", "v_win"))
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_smallthinker_admission_compiles_with_no_square_of_scores(smallthinker):
+    """One slot's admission at bucket 8192 through the tiled kernel: both
+    attention kinds, the last 4224 true rows landed in a ring of 33 pages;
+    by the compiler's count the temporaries are under 2 GB (one layer's
+    materialized scores would be 7.5 GB) and no array holds ``L x L`` or
+    ``L x 2 window`` elements."""
+    import dataclasses
+
+    from triton_dist_tpu.models import decode
+
+    cfg, spec, mesh, params, cache = smallthinker
+    L = 8192
+    pcfg = dataclasses.replace(cfg, seq=L)
+    rep = NamedSharding(mesh, P())
+    cs = spec.specs(cfg)
+
+    def fn(p, c, prompt, mask, pick):
+        return decode.prefill_cache(
+            pcfg, p, c, prompt.reshape(-1), spec, spec.s_max, slot_mask=mask,
+            pick=pick)
+
+    compiled = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
+        out_specs=(cs, P(), P()), check_vma=False),
+        donate_argnums=(1,)).lower(
+        params, cache, _struct((32, L), jnp.int32, rep),
+        _struct((32,), jnp.bool_, rep), _struct((32,), jnp.int32, rep)
+    ).compile()
+    text = compiled.as_text()
+    assert "flash_prefill_w4096" in text and "flash_prefill" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    # no array of the program has two dimensions of L or more: neither
+    # ``[.., L, L]`` scores nor ``[.., L, 2 x window]`` bands
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:bf16|f32|s32)\[([\d,]+)\]", text)}
+    assert not [s for s in shapes if sum(d >= L for d in s) >= 2]

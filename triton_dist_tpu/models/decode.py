@@ -1867,6 +1867,7 @@ class ContinuousBatcher:
         bucket = self._bucket(L)
         with _span("tdt.batcher.admit_prefill", uid=str(req.uid), slot=i,
                    prompt_len=L, bucket=bucket) as sp:
+            self._set_prefill_blocks(sp, L, bucket)
             args = self._prefill_inputs(i, req, bucket)
             with _span("tdt.batcher.admit_prefill.dispatch"):
                 self.cache, last = self._prefill_prog(bucket)(
@@ -2164,6 +2165,21 @@ class ContinuousBatcher:
     def _set_counters(self, sp, values) -> None:
         for name, value in zip(self._counters, values):
             sp.set(name, int(value))
+
+    def _set_prefill_blocks(self, sp, length: int, bucket: int) -> None:
+        """``prefill_blocks_live`` / ``prefill_blocks_square`` of an
+        admission whose family attends through the tiled prefill kernel
+        (``cfg.prefill_blocks``: the key blocks the prompt's band holds
+        against the causal square of its bucket; nothing for a bucket
+        whose scores are materialized). Counted only while something
+        records the span."""
+        count = getattr(self.cfg, "prefill_blocks", None)
+        if sp is NULL_SPAN or count is None:
+            return
+        blocks = count(length, bucket)
+        if blocks is not None:
+            sp.set("prefill_blocks_live", blocks[0])
+            sp.set("prefill_blocks_square", blocks[1])
 
     def _set_page_counts(self, sp) -> None:
         """``kv_pages_live`` / ``kv_pages_table`` of the step this round

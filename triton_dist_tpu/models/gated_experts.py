@@ -1,19 +1,28 @@
 """The gated-expert MLP that the layer-plan families share
 (``models/mla_moe.py``: latent attention; ``models/window_moe.py``: window
 and full GQA): ONE place for the router, the routed experts through the
-grouped GEMM, the shared expert, the leading dense SwiGLU and the routing
+grouped GEMM, the shared expert, the leading dense gated MLP and the routing
 counters. A family's config ``c`` brings ``hidden``, ``topk``,
-``routed_scaling``, ``expert_ffn``, ``n_shared_experts`` and ``held``
-(first expert, count held here); where the norm and the residual go is
-the family's own.
+``routed_scaling``, ``expert_ffn``, ``n_shared_experts``, ``held`` (first
+expert, count held here), ``scoring`` and ``gate_act`` (both read from the
+model's published config); where the norm and the residual go, and WHICH
+ROWS the router reads, is the family's own.
 
-- ``s = sigmoid(x W_r)``; chosen = top-k of ``s + b``;
-  ``w = s[chosen] / sum(s[chosen]) * routed_scaling``;
-  ``y = sum_k w_k E_k(x) + E_shared(x)``, each ``E`` a SwiGLU. No token is
-  dropped, there is no capacity. The routed part runs as two grouped GEMMs
-  (``ops/group_gemm.py``) over the assignments sorted by expert
-  (``ops/moe_utils.moe_align_block_size``), at decode and at prefill
-  alike: only experts that were hit are read.
+- ``scoring == "sigmoid"``: ``s = sigmoid(r W_r)``; chosen = top-k of
+  ``s + b``; ``w = s[chosen] / sum(s[chosen]) * routed_scaling``.
+  ``scoring == "softmax"``: chosen = top-k of ``r W_r``, ``w`` = softmax
+  over the chosen (no bias leaf). ``r`` are the rows the family routes on:
+  the MLP's own input ``x`` (:func:`moe_mlp` alone), or rows of its
+  choosing routed EARLIER (:func:`route_rows`, handed to :func:`moe_mlp` as
+  ``routing``: a model whose router reads the layer's input before
+  attention).
+- ``y = sum_k w_k E_k(x) + E_shared(x)``, each ``E`` a gated MLP
+  ``down(act(gate(x)) * up(x))``, ``act`` = ``gate_act`` (``silu`` |
+  ``relu``). No token is dropped, there is no capacity. The routed part
+  runs as two grouped GEMMs (``ops/group_gemm.py``) over the assignments
+  sorted by expert (``ops/moe_utils.moe_align_block_size``), at decode and
+  at prefill alike: only experts that were hit are read. Without shared
+  experts (``n_shared_experts`` 0) there is no shared part and no leaf.
 - ``held = (first, count)`` is the chip's share of the bank: the router
   still scores every expert, the layer computes the part of the result its
   own experts give, and nothing stands in for the others. The share that
@@ -91,11 +100,20 @@ def last_rows(rows, slots, b: int):
     return jnp.zeros((b, rows.shape[-1]), rows.dtype).at[slots].set(rows)
 
 
-def swiglu(x, w_gate_up, w_down):
-    """SwiGLU with gate | up stored as contiguous halves."""
+GATE_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+SCORINGS = ("sigmoid", "softmax")
+
+
+def _act(c, gate):
+    """The gate's activation (``c.gate_act``), in float32."""
+    return GATE_ACTS[c.gate_act](gate.astype(jnp.float32))
+
+
+def gated_mlp(c, x, w_gate_up, w_down):
+    """A gated MLP with gate | up stored as contiguous halves."""
     gu = x @ w_gate_up
     f = gu.shape[-1] // 2
-    act = jax.nn.silu(gu[:, :f].astype(jnp.float32)).astype(x.dtype) * gu[:, f:]
+    act = _act(c, gu[:, :f]).astype(x.dtype) * gu[:, f:]
     return act @ w_down
 
 
@@ -104,17 +122,18 @@ def dense_mlp(c, h, p):
         gu = h @ p["w_gate_up"]
     with scope("ffn/act"):
         gate, up = unpack_gate_up(gu, c)
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h.dtype) * up
+        act = _act(c, gate).astype(h.dtype) * up
     with scope("ffn/down"):
         return act @ p["w_down"]
 
 
 def route(c, h, p):
     """``(weights [m, topk] f32, ids [m, topk] int32)`` over the WHOLE
-    bank, whatever share of it is held here."""
+    bank, whatever share of it is held here. The choice bias is a leaf of
+    the sigmoid-scored models only."""
     logits = h.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     return select_experts(
-        logits, c.topk, scoring="sigmoid", bias=p["router_bias"],
+        logits, c.topk, scoring=c.scoring, bias=p.get("router_bias"),
         scale=c.routed_scaling,
     )
 
@@ -165,17 +184,14 @@ def add_stats(stats, st):
                       jnp.maximum(stats[2], st[2])])
 
 
-def moe_mlp(c, h, p, block_m: int, interpret=None):
-    """Routed experts (the share held here) + the shared expert on rows
-    ``h [m, H]``: ``(y [m, H], stats int32[3])``. Inside ``scope("ffn")``:
-    ``ffn/route`` is what a routed layer runs around its GEMMs (scores and
-    top-k, the alignment, the gather of sorted rows, the weighted combine),
-    ``ffn/experts`` the two grouped GEMMs and the activation between
-    them, ``ffn/shared`` the shared expert."""
-    m = h.shape[0]
+def route_rows(c, rows, p, block_m: int):
+    """The routing of ``rows [m, H]`` through layer ``p``'s router:
+    ``(weights, local expert ids, held here?, alignment)``, what
+    :func:`moe_mlp` takes as ``routing``. Inside ``scope("ffn")``; its ops
+    are ``ffn/route``'s (scores and top-k, the alignment)."""
     first, n_held = c.held
     with scope("ffn/route"):
-        w, ids = route(c, h, p)
+        w, ids = route(c, rows, p)
         local = ids - first
         here = (local >= 0) & (local < n_held)
         # an assignment to an expert held elsewhere keeps its row (shapes
@@ -188,6 +204,23 @@ def moe_mlp(c, h, p, block_m: int, interpret=None):
                 local.reshape(-1), n_held, block_m, ragged=True)
         else:
             al = _align_share(local, here, n_held, block_m)
+    return w, local, here, al
+
+
+def moe_mlp(c, h, p, block_m: int, interpret=None, routing=None):
+    """Routed experts (the share held here) + the shared expert on rows
+    ``h [m, H]``: ``(y [m, H], stats int32[3])``. ``routing`` is
+    :func:`route_rows` of the rows the model's router reads, where those
+    are not ``h`` (issued earlier in the pass); None routes on ``h``.
+    Inside ``scope("ffn")``: ``ffn/route`` is what a routed layer runs
+    around its GEMMs (scores and top-k, the alignment, the gather of
+    sorted rows, the weighted combine), ``ffn/experts`` the two grouped
+    GEMMs and the activation between them, ``ffn/shared`` the shared
+    expert."""
+    m = h.shape[0]
+    first, n_held = c.held
+    w, local, here, al = routing or route_rows(c, h, p, block_m)
+    with scope("ffn/route"):
         a = gather_sorted_rows(h, al, c.topk)
     # one B tile = one expert's whole gate (or up, or down) matrix where
     # VMEM has the room (_tile_n): an expert's weights stream once per
@@ -204,8 +237,7 @@ def moe_mlp(c, h, p, block_m: int, interpret=None):
         gu = group_gemm(a, p["we_gate_up"], al.expert_ids,
                         valid_rows=al.valid_rows, config=gg_up,
                         interpret=interpret)
-        act = (jax.nn.silu(gu[:, :fe].astype(jnp.float32)).astype(h.dtype)
-               * gu[:, fe:])
+        act = _act(c, gu[:, :fe]).astype(h.dtype) * gu[:, fe:]
         y = group_gemm(act, p["we_down"], al.expert_ids,
                        valid_rows=al.valid_rows, config=gg_down,
                        interpret=interpret)
@@ -213,8 +245,8 @@ def moe_mlp(c, h, p, block_m: int, interpret=None):
         out = scatter_add_unsorted(y, al, w, m)             # f32
     if first == 0 and c.n_shared_experts:
         with scope("ffn/shared"):
-            out = out + swiglu(h, p["ws_gate_up"], p["ws_down"]).astype(
-                jnp.float32)
+            out = out + gated_mlp(
+                c, h, p["ws_gate_up"], p["ws_down"]).astype(jnp.float32)
     with scope("ffn/route"):
         stats = routing_stats(local, here, n_held)
     return out.astype(h.dtype), stats

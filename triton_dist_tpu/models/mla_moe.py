@@ -96,6 +96,9 @@ class MLAMoEConfig(TransformerConfig):
     own_passes: ClassVar[bool] = True
     cache_kind: ClassVar[str] = "latent"
     pass_counters: ClassVar[tuple[str, ...]] = MOE_STATS
+    # the family's published router and gate (gated_experts reads them)
+    scoring: ClassVar[str] = "sigmoid"
+    gate_act: ClassVar[str] = "silu"
 
     def __post_init__(self):
         if self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim:

@@ -93,6 +93,8 @@ class SSMHybridConfig(TransformerConfig):
     # rows its attention layers read (from the slots' lengths; 0 on an
     # admission's pass)
     pass_counters: ClassVar[tuple[str, ...]] = ("state_slots", "kv_rows")
+    # the MLPs' gate, as gated_experts.dense_mlp reads it
+    gate_act: ClassVar[str] = "silu"
 
     def __post_init__(self):
         if not 0 <= self.attn_layer_offset < self.attn_layer_period:

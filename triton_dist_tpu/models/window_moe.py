@@ -1,6 +1,7 @@
 """GQA decoder whose layers attend through a WINDOW or in FULL, with gated
-sparse experts: the EXAONE-MoE family's block (K-EXAONE-236B-A23B), served
-through the same batcher, block tables and spans as the other families.
+sparse experts: the EXAONE-MoE block (K-EXAONE-236B-A23B) and the
+SmallThinker block (SmallThinker-21BA3B-Instruct), served through the same
+batcher, block tables and spans as the other families.
 
 The LAYER PLAN (:func:`layer_plan`) names two things a layer: the
 attention kind (``window`` | ``full``, ``cfg.layer_types``) and the MLP
@@ -16,26 +17,60 @@ The gated-expert MLP is ``models/gated_experts.py``, the one
 Equations (``x [T, H]``; RMSNorm everywhere; softmax, norms and router in
 f32):
 
-- attention: ``[q | k | v] = x W_qkv`` (no bias; stored kv-group-major as
-  the dense family's ``wqkv``); ``q`` and ``k`` normed over the head
-  width; on a WINDOW layer both are then rotated (the dense family's
-  half-split convention), a FULL layer is not rotated. Scores ``q.k /
-  sqrt(d)``; a window layer lets position ``p`` see ``max(0, p - window +
-  1) .. p``, a full layer ``0 .. p``. ``y = softmax(s) v W_o``.
-- each sub-layer's norm is applied to its OUTPUT before the residual add:
-  ``x = x + norm(attn(x))``, ``x = x + norm(mlp(x))``; no input norm.
-- MLP: a dense SwiGLU, or router + routed experts + shared expert
+- attention: ``[q | k | v] = h W_qkv`` (no bias; stored kv-group-major as
+  the dense family's ``wqkv``); with ``qk_norm``, ``q`` and ``k`` normed
+  over the head width; on a WINDOW layer both are then rotated (the dense
+  family's half-split convention), a FULL layer is not rotated. Scores
+  ``q.k / sqrt(d)``; a window layer lets position ``p`` see ``max(0, p -
+  window + 1) .. p``, a full layer ``0 .. p``. ``y = softmax(s) v W_o``.
+- MLP: a dense gated MLP, or router + routed experts + shared experts
   (``gated_experts.moe_mlp``), ``experts_held`` the chip's share.
+
+What differs between the family's two published blocks is read from the
+model's config, each a field named for what it is (K-EXAONE's value is the
+default; nobody tunes these):
+
+- ``norm_placement``: ``"output"`` (K-EXAONE): each sub-layer's norm on
+  its OUTPUT, ``x = x + norm(attn(x))``, ``x = x + norm(mlp(x))``, no
+  input norm. ``"input"`` (SmallThinker): pre-norm, ``u = x + attn(norm_in
+  (x))``, ``x' = u + mlp(norm_post(u))``. The leaves are ``attn_norm`` and
+  ``mlp_norm`` in both.
+- ``qk_norm``: the q/k norms, present (K-EXAONE) or not (no leaf).
+- ``router_rows``: ``"mlp_input"`` (K-EXAONE: the router reads the rows
+  the experts multiply) or ``"layer_input"`` (SmallThinker: the router
+  reads the layer's UN-NORMED input ``x_l``, and the routing of layer
+  ``l`` is issued BEFORE its attention, as the model is written: what an
+  expert exchange would hide under attention, ROADMAP B1).
+- ``scoring``: ``"sigmoid"`` with a choice bias and ``routed_scaling``
+  (K-EXAONE), or ``"softmax"`` over the chosen logits, no bias leaf
+  (SmallThinker: ``moe_primary_router_apply_softmax`` with
+  ``norm_topk_prob``).
+- ``gate_act``: ``"silu"`` (K-EXAONE) or ``"relu"`` (SmallThinker).
+- ``first_k_dense`` 0 and ``n_shared_experts`` 0 allocate no such leaf.
+
+SmallThinker's layer, whole (``x_l [T, H]``, eps 1e-6): ``r = x_l W_r``,
+the ``topk`` largest logits chosen, weights softmax over them; ``h =
+norm_in(x_l)``, ``q, k, v = h W_q, h W_k, h W_v`` (no bias, no q/k norm),
+rotated where ``rope_layout[l] == 1`` (= ``sliding_window_layout[l]``: the
+window layers), ``u = x_l + softmax(q.k / sqrt(d)) v W_o``; ``m =
+norm_post(u)``, ``x_{l+1} = u + sum_e w_e (relu(m W_gate,e) * m W_up,e)
+W_down,e``.
 - ``vocab`` rows of embedding and head: where ``vocab_held = (first,
   count)`` is set, ``count == vocab`` rows of a larger vocabulary live
   here and token ids count from ``first`` (:func:`slice_vocab`): logits,
   argmax and the traffic are over the slice.
 
-PREFILL attends full layers as the dense family does and window layers
-over a BAND (blocks of ``window`` queries against their own and the
-previous block of keys: no ``[L, L]`` scores for them); DECODE reads the
-pools through ``ops/flash_decode.paged_flash_decode`` (``window=`` on a
-window layer: two pages a row at page 128, whatever the context). An
+PREFILL attends a bucket past ``MATERIALIZED_UP_TO`` rows through
+``ops/flash_prefill.flash_prefill`` (``window=`` on a window layer):
+tiled, online softmax, the key blocks outside the band or past the
+prompt's true length neither fetched nor multiplied, no ``[L, L]`` and no
+``[L, 2 x window]`` array. A smaller bucket keeps the materialized forms
+(:func:`prefill_attention` is the one place that chooses, from the bucket
+and the window): a window layer whose window can clip over a BAND (blocks
+of ``window`` queries against their own and the previous block of keys),
+any other layer as the dense family does. DECODE reads the pools through
+``ops/flash_decode.paged_flash_decode`` (``window=`` on a window layer:
+``ceil(window / page) + 1`` pages a row at most, whatever the context). An
 admission computes THE ADMITTED SLOT'S ROWS ONLY, ``[1, bucket]``, the slot
 found from ``slot_mask`` inside the pass (``gated_experts.admitted_rows``),
 and writes that slot's pages and rings and no other's: the other slots are
@@ -57,15 +92,29 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from triton_dist_tpu.models.gated_experts import (
-    DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, admitted_rows,
-    dense_mlp, expert_bytes, last_rows, moe_mlp, require_one_shard,
+    DECODE_BLOCK_M, GATE_ACTS, MOE_STATS, PREFILL_BLOCK_M, SCORINGS,
+    add_stats, admitted_rows, dense_mlp, expert_bytes, last_rows, moe_mlp,
+    require_one_shard, route_rows,
 )
 from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig, _causal_gqa_attention, rmsnorm, rope,
 )
 from triton_dist_tpu.obs.scopes import scope
+from triton_dist_tpu.ops.flash_prefill import blocks_walked, flash_prefill
 
 ATTENTION_KINDS = ("window", "full")
+NORM_PLACEMENTS = ("output", "input")
+ROUTER_ROWS = ("mlp_input", "layer_input")
+# The largest bucket whose scores prefill still MATERIALIZES (the band for
+# a window that clips, the causal square otherwise); above it the tiled
+# kernel. Measured on the chip at both ends (PERF.md section 6, PR 39): at
+# bucket 256 / window 128 an admission through the kernel takes 12.76 ms
+# against 12.10 (five more kernel launches for ~0.1 ms of work each) and a
+# run's set-up 2-3.6 s more (five more kernels traced and lowered: past
+# the 10% bound on `setup_s`); at bucket 8192 only the kernel can run (one
+# layer's materialized scores are 7.5 GB). Every bucket measured through
+# the materialized path so far was 2048 rows or fewer.
+MATERIALIZED_UP_TO = 2048
 FAMILY = "window-attention / gated-expert"
 
 
@@ -86,6 +135,12 @@ class WindowMoEConfig(TransformerConfig):
     experts_held: tuple[int, int] | None = None
     # (first row, count) of a larger vocabulary held here; count == vocab
     vocab_held: tuple[int, int] | None = None
+    # the published block (module docstring); K-EXAONE's are the defaults
+    norm_placement: str = "output"
+    qk_norm: bool = True
+    router_rows: str = "mlp_input"
+    scoring: str = "sigmoid"
+    gate_act: str = "silu"
 
     own_passes: ClassVar[bool] = True
     cache_kind: ClassVar[str] = "kv_window"
@@ -103,6 +158,12 @@ class WindowMoEConfig(TransformerConfig):
                 f"{ATTENTION_KINDS} for each of the {self.n_layers} layers")
         if self.window < 1:
             raise ValueError(f"window={self.window} must be >= 1")
+        for name, known in (("norm_placement", NORM_PLACEMENTS),
+                            ("router_rows", ROUTER_ROWS),
+                            ("scoring", SCORINGS), ("gate_act", GATE_ACTS)):
+            if getattr(self, name) not in known:
+                raise ValueError(
+                    f"{name}={getattr(self, name)!r} is none of {tuple(known)}")
         first, count = self.held
         if not (0 <= first and first + count <= self.n_experts and count > 0):
             raise ValueError(f"experts_held={self.experts_held} outside the "
@@ -129,6 +190,23 @@ class WindowMoEConfig(TransformerConfig):
 
     def prefill_cache(self, params, cache, prompt, spec, s_max, **kw):
         return prefill_cache(self, params, cache, prompt, spec, s_max, **kw)
+
+    def prefill_blocks(self, length: int, bucket: int):
+        """``(live, square)`` key blocks of one admission through the
+        tiled kernel (host arithmetic, ``ops/flash_prefill.blocks_walked``
+        over the plan): what it walks for a prompt of ``length`` in
+        ``bucket``, every layer and kv head, and what the causal square of
+        the bucket holds at the same block sizes. None where the bucket's
+        scores are materialized (:func:`prefill_attention`)."""
+        if bucket <= MATERIALIZED_UP_TO:
+            return None
+        g = self.n_q_heads // self.n_kv_heads
+        live = square = 0
+        for kind in self.layer_types:
+            a, b = blocks_walked(
+                [length], bucket, g, _clipping_window(self, kind, bucket))
+            live, square = live + a, square + b
+        return live * self.n_kv_heads, square * self.n_kv_heads
 
 
 def layer_plan(cfg: WindowMoEConfig) -> tuple[tuple[str, str], ...]:
@@ -161,25 +239,27 @@ def _layer_shapes(c: WindowMoEConfig, mlp: str) -> dict:
     fe, (_, held) = c.expert_ffn, c.held
     out = dict(
         wqkv=((h, c.qkv_dim), h),       # kv-group-major: g q heads | k | v
-        q_norm=((d,), None),
-        k_norm=((d,), None),
         wo=((c.q_dim, h), c.q_dim),
-        attn_norm=((h,), None),         # on the attention's OUTPUT
-        mlp_norm=((h,), None),          # on the MLP's OUTPUT
+        # on the sub-layer's OUTPUT or INPUT (cfg.norm_placement)
+        attn_norm=((h,), None),
+        mlp_norm=((h,), None),
     )
+    if c.qk_norm:
+        out.update(q_norm=((d,), None), k_norm=((d,), None))
     if mlp == "dense":
         out.update(w_gate_up=((h, 2 * c.ffn), h), w_down=((c.ffn, h), c.ffn))
-    else:
+        return out
+    out.update(
+        router=((h, c.n_experts), h),
+        # gate | up as contiguous halves: banks are never column-sharded
+        we_gate_up=((held, h, 2 * fe), h),
+        we_down=((held, fe, h), fe),
+    )
+    if c.scoring == "sigmoid":
+        out.update(router_bias=((c.n_experts,), "bias"))
+    if c.n_shared_experts:
         fs = fe * c.n_shared_experts
-        out.update(
-            router=((h, c.n_experts), h),
-            router_bias=((c.n_experts,), "bias"),
-            # gate | up as contiguous halves: banks are never column-sharded
-            we_gate_up=((held, h, 2 * fe), h),
-            we_down=((held, fe, h), fe),
-            ws_gate_up=((h, 2 * fs), h),
-            ws_down=((fs, h), fs),
-        )
+        out.update(ws_gate_up=((h, 2 * fs), h), ws_down=((fs, h), fs))
     return out
 
 
@@ -240,14 +320,17 @@ def pack_qkv(wq, wk, wv, cfg) -> jax.Array:
 # -- the block's pieces --------------------------------------------------------
 
 def _project(c: WindowMoEConfig, x, p, lead: tuple):
-    """``x [m, H]`` -> normed ``q [*lead, hq, d]``, normed ``k`` and ``v
-    [*lead, h_kv, d]`` (``lead`` multiplies to ``m``); not yet rotated."""
+    """``x [m, H]`` -> ``q [*lead, hq, d]``, ``k`` and ``v [*lead, h_kv,
+    d]`` (``lead`` multiplies to ``m``), q and k normed where the model
+    has the norms; not yet rotated."""
     g, d = c.n_q_heads // c.n_kv_heads, c.head_dim
     qkv = (x @ p["wqkv"]).reshape(*lead, c.n_kv_heads, g + 2, d)
     q = qkv[..., :g, :].reshape(*lead, c.n_q_heads, d)
     k, v = qkv[..., g, :], qkv[..., g + 1, :]
-    return (rmsnorm(q, p["q_norm"], c.norm_eps),
-            rmsnorm(k, p["k_norm"], c.norm_eps), v)
+    if c.qk_norm:
+        q = rmsnorm(q, p["q_norm"], c.norm_eps)
+        k = rmsnorm(k, p["k_norm"], c.norm_eps)
+    return q, k, v
 
 
 def banded_attention(q, k, v, window: int) -> jax.Array:
@@ -285,15 +368,65 @@ def banded_attention(q, k, v, window: int) -> jax.Array:
     return out.reshape(b, nb * w, hq * d)[:, :s].astype(q.dtype)
 
 
-def _mlp(c, mlp: str, x, p, block_m, interpret, stats):
-    """``x + norm(mlp(x))`` and the pass's routing counters."""
+def _clipping_window(c, kind: str, L: int) -> int | None:
+    """The window of a layer of ``kind`` over ``L`` positions; None where
+    it attends in full or its window is too wide to clip a row."""
+    return c.window if kind == "window" and c.window < L else None
+
+
+def prefill_attention(c: WindowMoEConfig, kind: str, q, k, v, lens,
+                      interpret=None) -> jax.Array:
+    """An admission's attention of one layer: ``q [n, L, hq, d]``, ``k, v
+    [n, L, h_kv, d]`` -> ``[n, L, hq*d]``. The ONE place that chooses the
+    form, from the bucket ``L`` and the window alone (``MATERIALIZED_UP_TO``
+    says why): the tiled kernel past it; under it the band where a window
+    can clip, the causal square where none can. ``lens [n]`` (true
+    lengths) bounds the kernel's walk; the materialized forms compute
+    every row of the bucket."""
+    L = q.shape[1]
+    window = _clipping_window(c, kind, L)
+    if L > MATERIALIZED_UP_TO:
+        with scope("attn/prefill"):
+            return flash_prefill(q, k, v, lens, window=window,
+                                 interpret=interpret)
+    if window is None:
+        return _causal_gqa_attention(q, k, v, c)
+    return banded_attention(q, k, v, window)
+
+
+def _sub_in(c, x, p, norm: str):
+    """A sub-layer's input: normed where the block norms its inputs."""
+    return (rmsnorm(x, p[norm], c.norm_eps)
+            if c.norm_placement == "input" else x)
+
+
+def _sub_out(c, x, y, p, norm: str):
+    """The residual add: ``y`` normed where the block norms its outputs."""
+    return x + (rmsnorm(y, p[norm], c.norm_eps)
+                if c.norm_placement == "output" else y)
+
+
+def _route_ahead(c, mlp: str, x, p, block_m: int):
+    """The routing of an expert layer whose router reads the LAYER'S
+    INPUT ``x`` (un-normed), issued before the layer's attention; None
+    where the router reads the MLP's own input."""
+    if mlp != "moe" or c.router_rows != "layer_input":
+        return None
     with scope("ffn"):
+        return route_rows(c, x, p, block_m)
+
+
+def _mlp(c, mlp: str, x, p, block_m, interpret, stats, routing=None):
+    """The MLP sub-layer with its residual, and the pass's routing
+    counters."""
+    with scope("ffn"):
+        h = _sub_in(c, x, p, "mlp_norm")
         if mlp == "dense":
-            y = dense_mlp(c, x, p)
+            y = dense_mlp(c, h, p)
         else:
-            y, st = moe_mlp(c, x, p, block_m, interpret)
+            y, st = moe_mlp(c, h, p, block_m, interpret, routing)
             stats = add_stats(stats, st)
-        return x + rmsnorm(y, p["mlp_norm"], c.norm_eps), stats
+        return _sub_out(c, x, y, p, "mlp_norm"), stats
 
 
 def _counters(c, stats, rows: int, window_rows, full_rows):
@@ -310,33 +443,38 @@ def _counters(c, stats, rows: int, window_rows, full_rows):
 # -- the passes ------------------------------------------------------------------
 
 def forward_hidden(cfg: WindowMoEConfig, params, tokens, b: int, s: int,
-                   interpret=None, sink=None):
+                   interpret=None, sink=None, lens=None):
     """Forward over ``tokens [b*s]`` (b-major): the final residual
     ``[b*s, H]`` (before the last norm) and the pass's routing counters
     ``int32[3]``. ``sink`` (a list) collects each layer's ``(k, v)``
-    ``[b, s, h_kv, d]`` as the pools store them (k normed and, on a window
-    layer, rotated)."""
+    ``[b, s, h_kv, d]`` as the pools store them (k normed where the model
+    norms it and, on a window layer, rotated). ``lens [b]`` are the
+    sequences' true lengths (default ``s``): attention walks no key block
+    past them."""
     c = cfg
     positions = jnp.arange(s, dtype=jnp.int32)
+    if lens is None:
+        lens = jnp.full((b,), s, jnp.int32)
     with scope("head"):
         x = params["embed"][tokens]
     stats = jnp.zeros((3,), jnp.int32)
     for (kind, mlp), p in zip(layer_plan(c), params["layers"]):
+        routing = _route_ahead(c, mlp, x, p, PREFILL_BLOCK_M)
         with scope("attn"):
             with scope("attn/qkv"):
-                q, k, v = _project(c, x, p, (b, s))
+                q, k, v = _project(
+                    c, _sub_in(c, x, p, "attn_norm"), p, (b, s))
             if kind == "window":
                 q = rope(q, positions, c.rope_theta)
                 k = rope(k, positions, c.rope_theta)
-                attn = banded_attention(q, k, v, c.window)
-            else:
-                attn = _causal_gqa_attention(q, k, v, c)
+            attn = prefill_attention(c, kind, q, k, v, lens, interpret)
             if sink is not None:
                 sink.append((k, v))
             with scope("attn/out"):
                 y = attn.reshape(b * s, -1) @ p["wo"]
-                x = x + rmsnorm(y, p["attn_norm"], c.norm_eps)
-        x, stats = _mlp(c, mlp, x, p, PREFILL_BLOCK_M, interpret, stats)
+                x = _sub_out(c, x, y, p, "attn_norm")
+        x, stats = _mlp(c, mlp, x, p, PREFILL_BLOCK_M, interpret, stats,
+                        routing)
     return x, stats
 
 
@@ -366,7 +504,7 @@ def prefill_cache(cfg: WindowMoEConfig, params, cache, prompt, spec, s_max,
     n = len(slots)
     sink: list = []
     x, stats = forward_hidden(
-        c, params, tokens.reshape(-1), n, L, interpret, sink)
+        c, params, tokens.reshape(-1), n, L, interpret, sink, pick + 1)
     with scope("attn"), scope("attn/kv_write"):
         for (kind, ki, _), (k, v) in zip(_numbered(c), sink):
             cache = spec.write_prompt(
@@ -393,9 +531,11 @@ def decode_step(cfg: WindowMoEConfig, params, cache, tokens, pos, *, spec,
         x = params["embed"][tokens]
     stats = jnp.zeros((3,), jnp.int32)
     for (kind, ki, mlp), p in zip(_numbered(c), params["layers"]):
+        routing = _route_ahead(c, mlp, x, p, DECODE_BLOCK_M)
         with scope("attn"):
             with scope("attn/qkv"):
-                q, k_new, v_new = _project(c, x, p, (b,))
+                q, k_new, v_new = _project(
+                    c, _sub_in(c, x, p, "attn_norm"), p, (b,))
             if kind == "window":
                 q = rope_b(q[:, None], pos_b[:, None])[:, 0]
                 k_new = rope_b(k_new[:, None], pos_b[:, None])[:, 0]
@@ -403,8 +543,9 @@ def decode_step(cfg: WindowMoEConfig, params, cache, tokens, pos, *, spec,
                 c, cache, kind, ki, k_new, v_new, q, pos_b, interpret)
             with scope("attn/out"):
                 y = attn.reshape(b, -1).astype(x.dtype) @ p["wo"]
-                x = x + rmsnorm(y, p["attn_norm"], c.norm_eps)
-        x, stats = _mlp(c, mlp, x, p, DECODE_BLOCK_M, interpret, stats)
+                x = _sub_out(c, x, y, p, "attn_norm")
+        x, stats = _mlp(c, mlp, x, p, DECODE_BLOCK_M, interpret, stats,
+                        routing)
     with scope("head"):
         x = rmsnorm(x, params["final_norm"], c.norm_eps)
     lens = jnp.clip(pos_b + 1, 0, spec.s_max)

@@ -23,8 +23,10 @@ PREFIX = "tdt."
 PARTS: dict[str, tuple[str, ...]] = {
     # an attention layer whole: norm, projections and their gathers, rope,
     # the cache's page-table work, the k/v (latent, ring) write, the decode
-    # kernel or an admission's scores-softmax-PV, the out-projection
-    "attn": ("qkv", "kv_write", "decode", "out"),
+    # kernel or an admission's scores-softmax-PV (under ``prefill`` where
+    # the tiled kernel computes it: ops/flash_prefill.py), the
+    # out-projection
+    "attn": ("qkv", "kv_write", "decode", "prefill", "out"),
     # a feed-forward whole, dense or routed: norm, gate/up, activation,
     # down; the router with alignment, gather and combine, the two grouped
     # GEMMs with the activation between them, the shared expert
