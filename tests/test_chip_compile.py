@@ -242,32 +242,49 @@ def test_state_space_kernels_compile(one_chip):
     """The state-space / attention configuration's kernels at its
     published widths (5120 channels, 16 states, 64 slots, 26 layers'
     state in one pool): the scan over a bucket of 512, the one-token
-    update with the pool aliased in and out (no copy of 1.09 GB), and the
-    paged decode at a group of 20 query heads on ONE kv head."""
+    update with the pool aliased in and out (no copy of 1.09 GB), the
+    one-token convolution with its ring aliased in and out (no copy of 136
+    MB; taps and bias as stored, bf16), and the paged decode at a group of
+    20 query heads on ONE kv head."""
+    from triton_dist_tpu.ops.conv_ring import conv_ring_step
     from triton_dist_tpu.ops.flash_decode import paged_flash_decode
     from triton_dist_tpu.ops.selective_scan import (
         selective_scan, selective_state_update,
     )
 
-    d, n, slots, layers, bucket = 5120, 16, 64, 26, 512
+    d, n, slots, layers, bucket, taps = 5120, 16, 64, 26, 512, 4
     f32 = lambda *shape: _struct(shape, jnp.float32, one_chip)
     i32 = lambda *shape: _struct(shape, jnp.int32, one_chip)
+    bf16 = lambda *shape: _struct(shape, jnp.bfloat16, one_chip)
     text = _compiled_text(
         functools.partial(selective_scan, interpret=False),
         f32(bucket, d), f32(bucket, d), f32(bucket, n), f32(bucket, n),
         f32(n, d), f32(d), f32(n, d))
     assert "tpu_custom_call" in text and "selective_scan" in text
-    update = jax.jit(
-        lambda pool, *a: selective_state_update(pool, 3, *a, interpret=False),
-        donate_argnums=(0,))
-    compiled = update.lower(
-        f32(layers, 2, slots, n, d), i32(slots), i32(slots), f32(slots, d),
-        f32(slots, d), f32(slots, n), f32(slots, n), f32(n, d), f32(d)
-    ).compile()
-    assert "selective_state_update" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    pool_bytes = layers * 2 * slots * n * d * 4
-    assert mem.alias_size_in_bytes >= pool_bytes > mem.temp_size_in_bytes
+
+    def in_place(step, pool, *args):
+        """``step(pool, layer 3, *args)`` compiled with the pool donated:
+        its text, having checked that the pool is aliased, not copied."""
+        compiled = jax.jit(
+            lambda pool, *a: step(pool, 3, *a, interpret=False),
+            donate_argnums=(0,)).lower(pool, *args).compile()
+        mem = compiled.memory_analysis()
+        pool_bytes = int(np.prod(pool.shape)) * 4
+        assert mem.alias_size_in_bytes >= pool_bytes > mem.temp_size_in_bytes
+        return compiled.as_text()
+
+    text = in_place(
+        selective_state_update, f32(layers, 2, slots, n, d), i32(slots),
+        f32(slots, d), f32(slots, d), bf16(d), f32(slots, n), f32(slots, n),
+        f32(n, d), bf16(d))
+    assert "selective_state_update" in text
+    text = in_place(
+        conv_ring_step, f32(layers, taps, slots, d), f32(slots, d),
+        i32(slots), bf16(taps, d), bf16(d))
+    assert "conv_ring_step" in text
+    # the stored leaves reach the kernel as they are: no convert, no copy
+    assert not re.search(r"= (bf16|f32)\[(4,)?5120\]\S* (convert|copy|fusion)\(",
+                         text)
     pages = S_MAX // PAGE
     pool = _struct((2 * slots * pages, 1, PAGE, HEAD), jnp.bfloat16, one_chip)
     text = _compiled_text(

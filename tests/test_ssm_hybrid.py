@@ -135,37 +135,45 @@ def test_selective_scan_against_its_twin_and_the_token_by_token_recurrence(
                                rtol=1e-5, atol=1e-5)
 
 
-def test_selective_state_update_against_its_twin_and_the_recurrence(ref):
-    """Slots that read either row of the pool's axis of 2, a fresh slot,
-    and a stale state that is not finite under a fresh slot."""
+@pytest.mark.parametrize("stored", ["float32", "bfloat16"])
+def test_selective_state_update_against_its_twin_and_the_recurrence(ref, stored):
+    """Slots at even and odd positions (either row of the pool's axis of
+    2 is read), two at position 0, and a stale state that is not finite
+    under one of them; the step's bias and ``D`` come in the dtype they
+    are stored in and ``dt = softplus(dt_in + b_dt)`` is the kernel's."""
     rng = np.random.default_rng(1)
     slots, d, n = 6, 64, 16
     c, dt, b, cm, a, d_skip, _ = _scan_args(rng, slots)
+    b_dt, d_skip = (jnp.asarray(x, stored) for x in (
+        rng.standard_normal(d).astype(np.float32), d_skip))
+    wide = lambda x: x.astype(jnp.float32)
+    dt_in = jnp.log(jnp.expm1(dt)) - wide(b_dt)
+    dt = jax.nn.softplus(dt_in + wide(b_dt))
     pool = rng.standard_normal((3, 2, slots, n, d)).astype(np.float32)
-    read = jnp.asarray([0, 1, 0, 1, 1, 0])
-    fresh = jnp.asarray([0, 0, 1, 0, 1, 0])
-    pool[1, 0, 2] = np.nan
+    pos = jnp.asarray([1, 2, 0, 4, 0, 3])
+    read = (np.asarray(pos) - 1) % 2
+    pool[1, 1, 2] = np.nan
     pool = jnp.asarray(pool)
     y, got = ss.selective_state_update(
-        pool, 1, read, fresh, c, dt, b, cm, a, d_skip, interpret=True)
+        pool, 1, pos, c, dt_in, b_dt, b, cm, a, d_skip, interpret=True)
     y_x, want = ss._xla_state_update(
-        pool, 1, read, fresh, c, dt, b, cm, a, d_skip)
+        pool, 1, pos, c, dt_in, b_dt, b, cm, a, d_skip)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_x), rtol=1e-5,
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
     for i in range(slots):
-        h0 = jnp.zeros((d, n)) if fresh[i] else pool[1, read[i], i].T
+        h0 = jnp.zeros((d, n)) if pos[i] == 0 else pool[1, read[i], i].T
         y_r, h_r = ref.recurrence(c[i:i + 1], dt[i:i + 1], b[i:i + 1],
-                                  cm[i:i + 1], a.T, d_skip, h0)
+                                  cm[i:i + 1], a.T, wide(d_skip), h0)
         np.testing.assert_allclose(np.asarray(y[i]), np.asarray(y_r[0]),
                                    rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(np.asarray(got[1, 1 - read[i], i]),
                                    np.asarray(h_r.T), rtol=1e-5, atol=1e-5)
     # the other layers, and the rows read, are as they were
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(pool[0]))
-    np.testing.assert_array_equal(np.asarray(got[1, 1, 1]),
-                                  np.asarray(pool[1, 1, 1]))
+    np.testing.assert_array_equal(np.asarray(got[1, 0, 0]),
+                                  np.asarray(pool[1, 0, 0]))
 
 
 # -- (b) prefill, then decode, through the cache -------------------------------

@@ -782,41 +782,41 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
 
     # -- the state-space layers' state ----------------------------------------
 
-    def conv_step(self, cache, ki: int, u, pos_b, w, bias):
+    def conv_step(self, cache, ki: int, u, pos_b, w, bias, interpret):
         """One step of the ``ki``-th state-space layer's causal depthwise
         convolution: ``u [b, d]`` is the input at ``pos_b``, ``w [d_conv,
-        d]`` tap-major (the last tap is the newest input). ``(bias + sum_j
-        w[j] * u_{pos - d_conv + 1 + j}  [b, d] f32, cache)``; an input
-        before position 0 is zero, whatever its ring row holds."""
+        d]`` tap-major (the last tap is the newest input) and ``bias [d]``
+        AS STORED (the kernel widens them). ``(bias + sum_j w[j] * u_{pos
+        - d_conv + 1 + j}  [b, d] f32, cache)``; an input before position 0
+        is zero, whatever its ring row holds.
+
+        One kernel, in place in the ring (``ops/conv_ring.conv_ring_step``,
+        whose XLA twin is the plain form of this step): ring row ``r``
+        holds the input of the last position ``== r (mod d_conv)``, which
+        is tap ``(r - pos + d_conv - 1) % d_conv`` of this step unless it
+        is the row this step writes, ``pos % d_conv``; a row is masked by
+        a select, not a product (a stale row may hold anything), and the
+        step never reads the row it writes: run twice it leaves the same
+        ring."""
+        from triton_dist_tpu.ops.conv_ring import conv_ring_step
+
         with _scope("ssm/conv"):
-            ring = cache["conv"][ki]                       # [K, b, d]
-            K = ring.shape[0]
-            out = bias + w[K - 1] * u
-            for r in range(K):
-                # ring row r holds the input of the last position == r (mod
-                # K): tap j of this step, unless it is the row this step
-                # writes
-                j = (r - pos_b + K - 1) % K
-                live = (j < K - 1) & (pos_b - (K - 1) + j >= 0)
-                tap = jnp.take(w, jnp.minimum(j, K - 2), axis=0)   # [b, d]
-                # a select, not a product: a stale row may hold anything
-                out = out + jnp.where(live[:, None], tap * ring[r], 0.0)
-            slots = jnp.arange(u.shape[0])
-            conv = cache["conv"].at[ki, pos_b % K, slots].set(u, mode="drop")
+            out, conv = conv_ring_step(cache["conv"], ki, u, pos_b, w, bias,
+                                       interpret=interpret)
         return out, dict(cache, conv=conv)
 
-    def state_step(self, cache, ki: int, c, dt, b_in, c_out, a, d_skip, pos_b,
-                   interpret):
+    def state_step(self, cache, ki: int, c, dt_in, b_dt, b_in, c_out, a,
+                   d_skip, pos_b, interpret):
         """One step of the ``ki``-th state-space layer's recurrence for
-        every slot (``ops/selective_scan.selective_state_update``): reads
-        the state after ``pos - 1`` (zeros at position 0), writes the
-        state after ``pos``. ``(y [b, d] f32, cache)``."""
+        every slot (``ops/selective_scan.selective_state_update``, handed
+        ``b_dt`` and ``d_skip`` as stored): reads the state after ``pos -
+        1`` (zeros at position 0), writes the state after ``pos``."""
         from triton_dist_tpu.ops.selective_scan import selective_state_update
 
         with _scope("ssm/scan"):
             y, ssm = selective_state_update(
-                cache["ssm"], ki, (pos_b - 1) % 2, pos_b == 0, c, dt, b_in,
-                c_out, a, d_skip, interpret=interpret)
+                cache["ssm"], ki, pos_b, c, dt_in, b_dt, b_in, c_out, a,
+                d_skip, interpret=interpret)
         return y, dict(cache, ssm=ssm)
 
     def write_state(self, cache, ki: int, slots, lens, u, h):

@@ -36,9 +36,10 @@ recurrence and its state in f32):
   causal; ``softmax(s) v W_o``.
 
 DECODE walks every slot one token on: a state-space layer through
-``StatePagedKVCacheSpec.conv_step`` / ``state_step`` (the kernel
-``selective_state_update``), an attention layer through
-``paged_flash_decode``. PREFILL (an admission) computes THE ADMITTED
+``StatePagedKVCacheSpec.conv_step`` / ``state_step`` (the kernels
+``conv_ring_step`` and ``selective_state_update``, each handed the
+layer's per-channel vectors as they are stored), an attention layer
+through ``paged_flash_decode``. PREFILL (an admission) computes THE ADMITTED
 SLOT'S ROWS ONLY, ``[1, bucket]``, the slot found from ``slot_mask``
 inside the pass, and writes that slot's state and pages and no other's:
 the other slots are mid-sequence, and a whole batch of buckets is ``slots``
@@ -244,21 +245,22 @@ def _split_in(c, x, p):
 
 def _scan_inputs(c, conv, p):
     """The convolution's output ``conv [m, d]`` (f32, bias added, not yet
-    activated) -> what the recurrence reads, all f32: ``(c, dt [m, d], B,
-    C [m, N], A [N, d], D [d])``."""
+    activated) -> what the recurrence reads, all f32: ``(c, dt_in [m, d],
+    B, C [m, N], A [N, d])``; ``dt_in`` is the step BEFORE its bias and
+    softplus (``dt = softplus(dt_in + b_dt)``: in a decode step the
+    recurrence kernel's prologue, which takes ``b_dt`` and ``d_skip`` as
+    stored)."""
     n, r, eps = c.d_state, c.dt_rank, c.norm_eps
     with scope("ssm/proj"):
         act = jax.nn.silu(conv)
         rbc = jnp.dot(act.astype(p["w_x"].dtype), p["w_x"],
                       preferred_element_type=jnp.float32)
-        dt_in = rmsnorm(rbc[..., :r], _f32(p["dt_norm"]), eps)
+        dt_n = rmsnorm(rbc[..., :r], _f32(p["dt_norm"]), eps)
         b_in = rmsnorm(rbc[..., r:r + n], _f32(p["b_norm"]), eps)
         c_out = rmsnorm(rbc[..., r + n:], _f32(p["c_norm"]), eps)
-        dt = jax.nn.softplus(
-            jnp.dot(dt_in.astype(p["w_dt"].dtype), p["w_dt"],
-                    preferred_element_type=jnp.float32) + _f32(p["b_dt"]))
-        return (act, dt, b_in, c_out, -jnp.exp(_f32(p["a_log"])),
-                _f32(p["d_skip"]))
+        dt_in = jnp.dot(dt_n.astype(p["w_dt"].dtype), p["w_dt"],
+                        preferred_element_type=jnp.float32)
+        return act, dt_in, b_in, c_out, -jnp.exp(_f32(p["a_log"]))
 
 
 def _gate_out(y, z, p):
@@ -280,9 +282,12 @@ def _mamba_prompt(c: SSMHybridConfig, x, p, lens, interpret):
         padded = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
         conv = _f32(p["conv_b"]) + sum(
             w[j] * padded[:, j:j + L] for j in range(K))
-    act, dt, b_in, c_out, a, d_skip = _scan_inputs(c, conv, p)
+    act, dt_in, b_in, c_out, a = _scan_inputs(c, conv, p)
+    with scope("ssm/proj"):
+        dt = jax.nn.softplus(dt_in + _f32(p["b_dt"]))
     # the scan stops at the prompt's end: dt = 0 leaves the state as it was
     dt = jnp.where((jnp.arange(L) < lens[:, None])[..., None], dt, 0.0)
+    d_skip = _f32(p["d_skip"])
     h0 = jnp.zeros((c.d_state, c.d_inner), jnp.float32)
     with scope("ssm/scan"):
         y, h = zip(*(
@@ -382,11 +387,13 @@ def decode_step(cfg: SSMHybridConfig, params, cache, tokens, pos, *, spec,
             h = rmsnorm(x, p["norm_in"], c.norm_eps)
             if kind == "mamba":
                 u, z = _split_in(c, h, p)
+                # the per-channel leaves go to their kernels as stored
                 conv, cache = spec.conv_step(
-                    cache, ki, u, pos_b, _f32(p["conv_w"]),
-                    _f32(p["conv_b"]))
+                    cache, ki, u, pos_b, p["conv_w"], p["conv_b"], interpret)
+                act, dt_in, b_in, c_out, a = _scan_inputs(c, conv, p)
                 y, cache = spec.state_step(
-                    cache, ki, *_scan_inputs(c, conv, p), pos_b, interpret)
+                    cache, ki, act, dt_in, p["b_dt"], b_in, c_out, a,
+                    p["d_skip"], pos_b, interpret)
                 y = _gate_out(y, z, p)
             else:
                 with scope("attn/qkv"):

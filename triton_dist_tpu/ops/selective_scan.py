@@ -22,11 +22,20 @@ as a column ``[N, 1]`` that broadcasts along the lanes.
   prompt's true length inside a padded bucket.
 - :func:`selective_state_update` (``selective_state_update``): every slot
   of a batch one token on, in place in the pool ``[layers, 2, slots, N,
-  d]``: slot ``i`` READS row ``read[i]`` of the pool's axis of 2 and WRITES
-  the other, so running the update again from the same inputs reads the
-  same state and writes the same result (``models/decode.py``
-  ``StatePagedKVCacheSpec`` keys the axis by position). ``fresh[i]`` reads
-  zeros instead: a sequence's first token.
+  d]``, whose axis of 2 is keyed by position (``models/decode.py``
+  ``StatePagedKVCacheSpec``): slot ``i`` at ``pos[i]`` READS the row of
+  ``pos[i] - 1`` and WRITES the row of ``pos[i]``, the other, so running
+  the update again from the same inputs reads the same state and writes
+  the same result. A slot at position 0 reads zeros instead: a sequence's
+  first token. ``pos`` is the ONE vector of the slots the kernel is
+  handed in scalar memory (the layer, a scalar, is the other prefetched
+  operand: a program's 26 calls share one trace, ``ops/per_layer.py``);
+  the step's bias and ``D`` come as they are stored and are widened
+  inside (``dt = softplus(dt_in + b_dt)`` is the kernel's prologue): no
+  XLA fusion reads a ``[d]`` leaf on the way. The token's ``c``, ``dt_in``
+  and ``y`` are whole ``[slots, d]`` blocks a slot's row is read from and
+  written to: a block of one row would need them re-laid ``[slots, 1,
+  d]``, three copies a layer.
 
 Each has an XLA twin (the resilience layer's golden; the unit tests'
 second opinion).
@@ -44,6 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from triton_dist_tpu import resilience
 from triton_dist_tpu.ops.common import dist_pallas_call
+from triton_dist_tpu.ops.per_layer import in_hbm, layer_index, traced_once
 from triton_dist_tpu.utils import round_up
 
 # the names the kernels carry in a device trace (perfbench reads them)
@@ -146,35 +156,41 @@ def selective_scan(c, dt, b, cm, a, d_skip, h0, *, interpret: Any = None):
 
 # -- one token of every slot, in the pool ---------------------------------------
 
-def _update_kernel(read_ref, fresh_ref, c_ref, dt_ref, b_ref, cm_ref, a_ref,
-                   d_ref, h_in_ref, y_ref, h_out_ref):
-    """Grid ``(slot,)``: the slot's state comes from the pool's row
-    ``read[i]`` and goes to the other (the block index maps say which)."""
-    del read_ref
+def _update_kernel(li_ref, pos_ref, c_ref, dt_ref, bdt_ref, b_ref, cm_ref,
+                   a_ref, d_ref, h_in_ref, y_ref, h_out_ref):
+    """Grid ``(slot,)``: the slot's state comes from the pool's row of
+    ``pos[i] - 1`` and goes to the row of ``pos[i]`` (the block index maps
+    say which)."""
+    del li_ref                          # the index maps' (the pool's layer)
     i = pl.program_id(0)
     h = h_in_ref[0, 0, 0]
     # a select, not a product: what a finished request left may not be finite
-    h = jnp.where(jnp.broadcast_to(fresh_ref[i], h.shape) != 0, 0.0, h)
-    h, y = _advance(h, a_ref[:], dt_ref[0], c_ref[0], b_ref[i], cm_ref[i],
-                    d_ref[:])
+    h = jnp.where(jnp.broadcast_to(pos_ref[i], h.shape) == 0, 0.0, h)
+    row = pl.ds(i, 1)
+    dt = jax.nn.softplus(
+        dt_ref[row, :] + bdt_ref[:].astype(jnp.float32)[None])
+    h, y = _advance(h, a_ref[:], dt, c_ref[row, :], b_ref[i], cm_ref[i],
+                    d_ref[:].astype(jnp.float32)[None])
     h_out_ref[0, 0, 0] = h
-    y_ref[0] = y
+    y_ref[row, :] = y
 
 
-def _xla_state_update(pool, li, read, fresh, c, dt, b, cm, a, d_skip):
+def _xla_state_update(pool, li, pos, c, dt_in, b_dt, b, cm, a, d_skip):
     slots = jnp.arange(c.shape[0])
-    h = jnp.where(fresh[:, None, None] != 0, 0.0, pool[li, read, slots])
+    dt = jax.nn.softplus(dt_in + b_dt.astype(jnp.float32))
+    h = jnp.where((pos == 0)[:, None, None], 0.0,
+                  pool[li, (pos + 1) % 2, slots])
     h = (jnp.exp(dt[:, None, :] * a[None]) * h
          + (dt * c)[:, None, :] * b[:, :, None])
-    y = jnp.einsum("bnd,bn->bd", h, cm) + d_skip * c
-    return y, pool.at[li, 1 - read, slots].set(h)
+    y = jnp.einsum("bnd,bn->bd", h, cm) + d_skip.astype(jnp.float32) * c
+    return y, pool.at[li, pos % 2, slots].set(h)
 
 
-def _state_update_fused(pool, li, read, fresh, c, dt, b, cm, a, d_skip, *,
+@traced_once
+def _state_update_fused(li, pool, pos, c, dt_in, b_dt, b, cm, a, d_skip, *,
                         interpret):
     slots, d = c.shape
     n = a.shape[0]
-    row = pl.BlockSpec((1, 1, d), lambda i, *_: (i, 0, 0))
     whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
     block = (1, 1, 1, n, d)
     y, pool = dist_pallas_call(
@@ -184,44 +200,49 @@ def _state_update_fused(pool, li, read, fresh, c, dt, b, cm, a, d_skip, *,
             num_scalar_prefetch=2,
             grid=(slots,),
             in_specs=[
-                row, row, whole((slots, n, 1)), whole((slots, n, 1)),
-                whole((n, d)), whole((1, d)),
-                pl.BlockSpec(block, lambda i, rd, fr: (li, rd[i], i, 0, 0)),
+                whole((slots, d)), whole((slots, d)), whole((d,)),
+                whole((slots, n, 1)), whole((slots, n, 1)), whole((n, d)),
+                whole((d,)),
+                pl.BlockSpec(
+                    block, lambda i, li, p: (li[0], (p[i] + 1) % 2, i, 0, 0)),
             ],
             out_specs=(
-                row,
-                pl.BlockSpec(block, lambda i, rd, fr: (li, 1 - rd[i], i, 0, 0)),
+                whole((slots, d)),
+                pl.BlockSpec(
+                    block, lambda i, li, p: (li[0], p[i] % 2, i, 0, 0)),
             ),
         ),
-        out_shape=(jax.ShapeDtypeStruct((slots, 1, d), jnp.float32),
+        out_shape=(jax.ShapeDtypeStruct((slots, d), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)),
-        # the pool is updated where it lies: operand 8 (the two prefetched
+        # the pool is updated where it lies: operand 9 (the two prefetched
         # vectors count) is output 1
-        input_output_aliases={8: 1},
+        input_output_aliases={9: 1},
         cost_estimate=pl.CostEstimate(
-            flops=7 * slots * d * n, transcendentals=slots * d * n,
+            flops=7 * slots * d * n, transcendentals=slots * d * (n + 2),
             bytes_accessed=4 * slots * (2 * n * d + 3 * d + 2 * n)),
         dimension_semantics=("arbitrary",),
         uses_barrier=False,
         interpret=interpret,
-    )(read, fresh, c[:, None], dt[:, None], b[..., None], cm[..., None], a,
-      d_skip[None], pool)
-    return y[:, 0], pool
+    )(li, pos, c, dt_in, in_hbm(b_dt, interpret),
+      b[..., None], cm[..., None], a, in_hbm(d_skip, interpret), pool)
+    return y, pool
 
 
-def selective_state_update(pool, li: int, read, fresh, c, dt, b, cm, a,
+def selective_state_update(pool, li: int, pos, c, dt_in, b_dt, b, cm, a,
                            d_skip, *, interpret: Any = None):
     """Every slot one token on, in the pool. ``pool [layers, 2, slots, N,
-    d]`` float32, ``li`` the (static) layer, ``read [slots]`` the row of
-    the axis of 2 each slot's state is READ from (it is written to the
-    other), ``fresh [slots]`` non-zero where the state read is zeros;
-    ``c, dt [slots, d]``, ``b, cm [slots, N]``, ``a [N, d]``, ``d_skip
-    [d]`` -> ``(y [slots, d], pool)``."""
-    read, fresh = (x.astype(jnp.int32) for x in (read, fresh))
-    args = tuple(x.astype(jnp.float32) for x in (c, dt, b, cm, a, d_skip))
+    d]`` float32, ``li`` the layer, ``pos [slots]`` each slot's
+    position: its state is READ from row ``(pos - 1) % 2`` of the axis of 2
+    (zeros at position 0) and written to row ``pos % 2``; ``c, dt_in
+    [slots, d]``, the step before its bias and softplus, ``b, cm [slots,
+    N]``, ``a [N, d]`` float32; ``b_dt, d_skip [d]`` in the dtype they are
+    stored in -> ``(y [slots, d], pool)``."""
+    pos = pos.astype(jnp.int32)
+    c, dt_in, b, cm, a = (x.astype(jnp.float32) for x in (c, dt_in, b, cm, a))
+    args = (pos, c, dt_in, b_dt, b, cm, a, d_skip)
     return resilience.guarded_call(
         UPDATE_KERNEL,
-        lambda: _state_update_fused(pool, li, read, fresh, *args,
+        lambda: _state_update_fused(layer_index(li), pool, *args,
                                     interpret=interpret),
-        lambda: _xla_state_update(pool, li, read, fresh, *args),
+        lambda: _xla_state_update(pool, li, *args),
     )
