@@ -95,6 +95,7 @@ def pytest_configure(config):
 _LONG_POLES = (
     "test_spec_soak.py", "test_ranged_engine.py", "test_window_moe.py",
     "test_prerouted_moe.py",    # PR 39: ~as heavy as test_window_moe.py
+    "test_sparse_mla_moe.py",   # PR 41: ~350 s alone (a toy serve of 52 rounds)
     "test_emitter.py",
     "test_mla_moe.py", "test_disagg.py", "test_ranged_batcher.py",
     "test_serving.py", "test_prefill_work.py", "test_prefix_cache_soak.py",

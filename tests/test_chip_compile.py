@@ -592,3 +592,144 @@ def test_smallthinker_admission_compiles_with_no_square_of_scores(smallthinker):
     shapes = {tuple(int(d) for d in dims.split(","))
               for dims in re.findall(r"\b(?:bf16|f32|s32)\[([\d,]+)\]", text)}
     assert not [s for s in shapes if sum(d >= L for d in s) >= 2]
+
+
+def _latent_cell(topo, name: str):
+    """``(cfg, spec, mesh, params' and cache's shapes)`` of one of the
+    benchmark's latent-attention configurations on one described chip."""
+    import sys
+
+    perfbench = os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "perfbench")
+    if perfbench not in sys.path:
+        sys.path.insert(0, perfbench)
+    from harness import cells
+
+    from triton_dist_tpu.models.decode import LatentPagedCacheSpec
+    from triton_dist_tpu.models.mla_moe import init_mla_moe_params
+
+    cell = cells.Cell(cells.benchmark(), name)
+    adapter = cells.load_module("programs", cell.config["program"])
+    cfg = adapter.model_config(cell.config, interpret=False)
+    eng = cell.config["engine"]
+    spec = LatentPagedCacheSpec(eng["s_max"], eng["page"], static_table=True)
+    mesh = Mesh(np.array(topo.devices[:1]), (cfg.axis,))
+    place = lambda shapes, specs: jax.tree.map(
+        lambda x, s: _struct(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, specs)
+    params = place(
+        jax.eval_shape(functools.partial(init_mla_moe_params, cfg=cfg),
+                       jax.random.PRNGKey(0)), cfg.param_specs())
+    cache = place(jax.eval_shape(lambda: spec.init(cfg, 1)), spec.specs(cfg))
+    return cfg, spec, mesh, params, cache
+
+
+def _compiled_step(cfg, spec, mesh, params, cache):
+    from triton_dist_tpu.models import decode
+
+    rep = NamedSharding(mesh, P())
+    cs = spec.specs(cfg)
+    fn = jax.jit(jax.shard_map(
+        lambda p, c, t, pos: decode.decode_step(
+            cfg, p, c, t, pos, spec=spec, interpret=False),
+        mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P()),
+        out_specs=(P(), cs, P()), check_vma=False), donate_argnums=(1,))
+    return fn.lower(params, cache, _struct((cfg.batch,), jnp.int32, rep),
+                    _struct((cfg.batch,), jnp.int32, rep)).compile()
+
+
+def test_joyai_step_compiles_to_the_parents_program(topo):
+    """The plain latent plan (JoyAI-LLM-Flash, the benchmark's cell) after
+    the family learned a second attention kind: its decode step at the
+    published widths, compiled for the described chip, holds the SAME
+    INSTRUCTIONS as before, counted by opcode and result shape (names,
+    metadata and the kernels' serialized bodies left out: those move with
+    a line number). tests/golden/joyai_step.v5e_ops.json was written from
+    the commit before PR 41 by this very reduction; regenerate it only
+    with a PR that means to change JoyAI's step."""
+    import collections
+    import json
+
+    compiled = _compiled_step(*_latent_cell(topo, "joyai-llm-flash.reason"))
+    ops = collections.Counter(
+        m.group(2) + " " + m.group(1) for m in re.finditer(
+            r"^\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(",
+            compiled.as_text(), re.M))
+    golden = os.path.join(os.path.dirname(__file__), "golden",
+                          "joyai_step.v5e_ops.json")
+    with open(golden) as f:
+        want = json.load(f)
+    diff = {k: (want.get(k, 0), ops.get(k, 0))
+            for k in set(want) | set(ops) if want.get(k, 0) != ops.get(k, 0)}
+    assert not diff, diff
+
+
+@pytest.fixture(scope="module")
+def dots(topo):
+    return _latent_cell(topo, "dots3-note-prev-ep8.doc-reason")
+
+
+def test_dots_step_compiles(dots):
+    """The sparse latent plan's step at the published widths: 32 slots,
+    full layers of 128 heads on rows of 640 behind the indexer (64 x 128,
+    top-2048 of a table row of 128 pages), window layers of 64 heads on
+    rows of 1152 in rings of 6 pages; every pool aliased in and out, each
+    kernel under its own name."""
+    cfg, spec, mesh, params, cache = dots
+    assert (spec.ring(cfg), cfg.latent_row, cfg.window_latent_row) == (
+        6, 640, 1152)
+    assert {k: v.shape for k, v in cache.items() if "lat" in k or k == "idx"
+            } == {"lat": (2, 4096, 128, 640), "idx": (2, 4096, 128, 128),
+                  "lat_win": (3, 192, 128, 1152)}
+    compiled = _compiled_step(cfg, spec, mesh, params, cache)
+    text = compiled.as_text()
+    for name in ("index_score", "sparse_mla_decode", "ring_mla_decode",
+                 "group_gemm"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    pools = sum(np.prod(cache[k].shape) * 2 for k in ("lat", "idx", "lat_win"))
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_dots_admission_compiles_with_no_square_of_float_scores(dots):
+    """One slot's admission at bucket 8192: the indexer's scores a block
+    of 1024 rows at a time, the selection as ``int8 [L, L]``, both tiled
+    attentions (q/k of 192 and 256 against values of 128). No float32
+    array holds ``L x L`` scores (one full layer's materialized scores of
+    128 heads would be 34 GB), and the temporaries fit beside the
+    weights."""
+    import dataclasses
+
+    from triton_dist_tpu.models import decode
+
+    cfg, spec, mesh, params, cache = dots
+    L = 8192
+    pcfg = dataclasses.replace(cfg, seq=L)
+    rep = NamedSharding(mesh, P())
+    cs = spec.specs(cfg)
+
+    def fn(p, c, prompt, mask, pick):
+        return decode.prefill_cache(
+            pcfg, p, c, prompt.reshape(-1), spec, spec.s_max, slot_mask=mask,
+            pick=pick)
+
+    compiled = jax.jit(jax.shard_map(
+        fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
+        out_specs=(cs, P(), P()), check_vma=False),
+        donate_argnums=(1,)).lower(
+        params, cache, _struct((32, L), jnp.int32, rep),
+        _struct((32,), jnp.bool_, rep), _struct((32,), jnp.int32, rep)
+    ).compile()
+    text = compiled.as_text()
+    for name in ("index_score_prefill", "mla_flash_prefill_w513",
+                 "mla_flash_prefill"):
+        assert name in text, name
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15e9
+    # the selection leaves as int8; in float32 the scores exist a block of
+    # rows at a time (the index queries happen to be [L, 64 x 128] bf16)
+    # (so an ``[L, L]`` shape in the text may be those, inside a fusion:
+    # what is held to is the block of scores and the temporaries' size)
+    assert "s8[1,8192,8192]" in text and "f32[1024,8192]" in text
+    assert mem.temp_size_in_bytes < 4.5e9

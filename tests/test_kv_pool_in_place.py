@@ -293,21 +293,30 @@ def test_one_pass_equals_the_slicing_reference_bit_for_bit(multi, attend):
 
 # -- what a step walks against what its tables hold ---------------------------
 
-@pytest.mark.parametrize("kind", ["kv", "kv_window", "kv_state", "latent"])
+@pytest.mark.parametrize(
+    "kind", ["kv", "kv_window", "kv_state", "latent", "latent_sparse"])
 def test_pages_walked_follow_the_lengths(kind):
     """``pages_walked`` (the round span's ``kv_pages_live`` /
     ``kv_pages_table``): the pages the lengths expose, a window layer's
     the pages of ``[len - window, len)``, times the attention layers of
     each kind; the table's side is capacity and does not move. The latent
-    kind's kernel still walks the table row, and says so."""
+    kind's kernel still walks the table row under a plain plan, and says
+    so; under a sparse plan (an indexer, window layers) its full layers
+    walk the live pages of their index keys and its window layers their
+    rings."""
     import types
 
     from triton_dist_tpu.models.decode import PAGED_CACHE_KINDS
 
-    spec = PAGED_CACHE_KINDS[kind](S_MAX, PAGE, static_table=True)
+    spec = PAGED_CACHE_KINDS[kind.split("_sparse")[0]](
+        S_MAX, PAGE, static_table=True)
+    types5 = ("window",) * 3 + ("full",) * 2
+    sparse = kind == "latent_sparse"
     cfg = types.SimpleNamespace(
-        n_layers=5, window=6, layer_types=("window",) * 3 + ("full",) * 2,
-        layer_kinds=("mamba",) * 4 + ("attention",))
+        n_layers=5, window=6, layer_types=types5,
+        layer_kinds=("mamba",) * 4 + ("attention",),
+        attention_kinds=types5 if sparse else ("full",) * 5,
+        index_topk=8 if sparse else 0)
     lens = np.array([0, 1, PAGE, PAGE + 1, S_MAX], np.int32)
     full = 0 + 1 + 1 + 2 + S_MAX // PAGE
     # [len - 6, len) over pages of 4: none, 1, 1, 2 and, ending on a page's
@@ -319,6 +328,8 @@ def test_pages_walked_follow_the_lengths(kind):
                       len(lens) * (S_MAX // PAGE * 2 + ring * 3)),
         "kv_state": (full * 1, len(lens) * (S_MAX // PAGE) * 1),
         "latent": (len(lens) * (S_MAX // PAGE) * 5,) * 2,
+        "latent_sparse": (full * 2 + win * 3,
+                          len(lens) * (S_MAX // PAGE * 2 + ring * 3)),
     }[kind]
     assert spec.pages_walked(cfg, lens) == want
     # a window that ends one position into a page sees three pages of it

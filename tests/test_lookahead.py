@@ -93,6 +93,34 @@ def test_lookahead_serves_the_same_tokens_in_the_same_rounds(tiny, mesh1, kw):
         [(3, 9), (5, 4), (2, 7), (6, 1), (4, 2), (3, 12)], **kw)
 
 
+@pytest.mark.parametrize("kw", [
+    dict(),
+    dict(prefill=True, page_size=8),
+], ids=["contiguous", "prefill_paged"])
+def test_a_slot_whose_request_left_stands_where_an_unused_one_does(
+        tiny, mesh1, kw):
+    """The step advances every slot and is not told which are live: a
+    slot that kept its request's last token and position re-read that
+    whole context every round, and an expert layer routed its row to the
+    same experts every round, which a share of a bank then fetched for
+    nothing. A request that leaves hands its slot back at token 0,
+    position 0, and the request beside it is served what it gets alone."""
+    cfg, params = tiny
+    shapes = [(5, 12), (6, 2)]
+    alone, _, _ = _run(cfg, params, mesh1, _reqs(cfg, shapes)[:1], **kw)
+    b = ContinuousBatcher(cfg, params, mesh1, s_max=32, **kw)
+    for r in _reqs(cfg, shapes):
+        b.submit(r)
+    while not any(uid == 1 for uid, _ in b.finished):
+        b.step()
+    i = b.slot_req.index(None)
+    assert b.slot_req[1 - i].uid == 0
+    assert (b.tok[i], b.pos[i]) == (0, 0) and b.pos[1 - i] > 0
+    got = dict(b.run(max_steps=100))
+    assert got[0] == alone[0] and len(got[0]) == 12
+    assert not b.pos.any() and not b.tok.any()
+
+
 def test_lookahead_holds_on_a_mesh_of_four(mesh4):
     """The step sent ahead under ``jit_shard_map`` over four devices: its
     inputs advanced and placed replicated, the cache donated to a step

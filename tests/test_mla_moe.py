@@ -200,17 +200,24 @@ def test_absorbed_equals_expanded_on_the_same_cache(toy):
     p = params["layers"][1]
     L, page = 19, 8
     h = jax.random.normal(jax.random.PRNGKey(3), (L, cfg.hidden), jnp.float32)
-    q_n, q_r, c_kv, k_r = mla_moe._mla_project(cfg, h, p, jnp.arange(L))
-    want = mla_moe.mla_attend_expanded(cfg, q_n, q_r, c_kv, k_r, p, 1, L)[-1]
-    rows = mla_moe._latent_rows(cfg, c_kv, k_r)
+    geo = cfg.geometry("full")
+    q_n, q_r, c_kv, k_r, _ = mla_moe._mla_project(
+        cfg, geo, h, p, jnp.arange(L))
+    want = mla_moe.mla_attend_expanded(geo, q_n, q_r, c_kv, k_r, p, 1, L)[-1]
+    rows = mla_moe._latent_rows(geo, c_kv, k_r)
     rows = jnp.pad(rows, ((0, 3 * page - L), (0, 0))).reshape(3, page, -1)
     # pages scattered in a pool of 5, layer 1 of 2
     table = jnp.array([[4, 0, 2]], jnp.int32)
     pool = jnp.zeros((2, 5, page, cfg.latent_row), jnp.float32)
     pool = pool.at[1, table[0]].set(rows)
-    got = mla_moe.mla_attend_absorbed(
-        cfg, q_n[-1:], q_r[-1:], p, pool, 1, jnp.array([L], jnp.int32),
-        table, None)
+    from triton_dist_tpu.ops.mla_decode import mla_paged_decode
+
+    q_lat = jnp.einsum("bhd,chd->bhc", q_n[-1:], p["wkv_b_k"])
+    o_lat = mla_paged_decode(
+        mla_moe._latent_rows(geo, q_lat, q_r[-1:]), pool, 1,
+        jnp.array([L], jnp.int32), table, d_v=geo.kv_rank,
+        scale=geo.head_dim ** -0.5)
+    got = jnp.einsum("bhc,chd->bhd", o_lat, p["wkv_b_v"]).reshape(1, -1)
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 

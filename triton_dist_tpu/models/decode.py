@@ -126,6 +126,60 @@ def _pool_pages(pool):
     return pool.reshape((-1,) + pool.shape[2:])
 
 
+# -- the arithmetic of a RING of pages, once: the k/v kind of two lifetimes
+# (WindowPagedKVCacheSpec) and the latent kind's window layers
+# (LatentPagedCacheSpec) both keep a window layer's rows this way --------------
+
+def ring_pages(window: int, page_size: int, s_max: int) -> int:
+    """Pages of a slot's ring in a window layer: what holds ``window``
+    consecutive positions and the page being written, never more than a
+    whole sequence's."""
+    return min(-(-window // page_size) + 1, s_max // page_size)
+
+
+def _ring_table(b: int, ring: int) -> jax.Array:
+    """``block_table_win [1, b, ring]``: each slot its own run of ring
+    pages."""
+    return (jnp.arange(b, dtype=jnp.int32)[:, None] * ring
+            + jnp.arange(ring, dtype=jnp.int32)[None, :])[None]
+
+
+def _step_address(bt, pos_b, page_size: int, s_max: int, n_pool: int,
+                  ring: bool):
+    """``(page ids [b], row in the page [b])`` where a step writes each
+    slot's row of position ``pos_b``: the table column of the position's
+    logical page, modulo the table's width on a ring. A slot at ``s_max``
+    owns no page: its id is ``n_pool``, out of range, and the write
+    drops."""
+    col = pos_b // page_size
+    if ring:
+        col = col % bt.shape[1]
+    page_ids = bt[jnp.arange(bt.shape[0]),
+                  jnp.minimum(col, bt.shape[1] - 1)]
+    safe_ids = jnp.where(pos_b < s_max, page_ids, n_pool)
+    return safe_ids, pos_b % page_size
+
+
+def _ring_pages_walked(lens: np.ndarray, window: int, page_size: int,
+                       ring: int) -> np.ndarray:
+    """Pages of ``[len - window, len)`` a slot, as the window kernels walk
+    them (host arithmetic)."""
+    return np.where(lens > 0, np.minimum(
+        (lens - 1) // page_size
+        - np.maximum(lens - window, 0) // page_size + 1, ring), 0)
+
+
+def _ring_sources(lens, span: int, L: int):
+    """``[n, span]``: the prompt row that ring address ``j`` holds after a
+    prefill of ``lens [n]`` true positions padded to ``L``: the last
+    position ``p < len`` with ``p % span == j`` (none yet: any row, the
+    mask never reads it). Padding past a prompt's end would overwrite what
+    its window still sees, so the count is from the TRUE length."""
+    last = lens[:, None] - 1
+    src = last - (last - jnp.arange(span, dtype=jnp.int32)) % span
+    return jnp.clip(src, 0, L - 1)
+
+
 @dataclasses.dataclass(frozen=True)
 class KVCacheSpec:
     """Contiguous cache geometry: per layer ``[b, h_kv, s_max, d]`` sharded
@@ -448,14 +502,38 @@ class PagedKVCacheSpec:
 class LatentPagedCacheSpec(PagedKVCacheSpec):
     """The paged cache's second KIND: one latent row a token and layer
     (``models/mla_moe.py``: normed kv latent | rotated shared key | pad,
-    shared by every head) in ONE pool ``lat [n_layers, n_pages, page,
-    row]`` instead of ``k`` and ``v`` pools of ``n_kv_heads x head_dim``.
-    Block table, static page ranges, ``extra_pages`` and the
-    drop-out-of-range write discipline are the k/v kind's, unchanged; the
-    model writes rows at prefill and at each step and reads them through
-    ``ops/mla_decode.py``. Static tables only (the batcher's)."""
+    shared by every head) instead of ``k`` and ``v`` pools of ``n_kv_heads
+    x head_dim``. The model's plan (``cfg.layer_types``) may name latent
+    attention of two geometries, and the kind then keeps rows of two
+    WIDTHS on two page LIFETIMES, and a third pool beside them:
+
+    - full layers: ``lat [n_full_layers, n_pool, page, cfg.latent_row]``
+      over ``s_max / page`` pages a slot, ``block_table``. A model whose
+      every layer is full (no ``layer_types``) holds this pool alone, as
+      the kind did before it learned the others.
+    - window layers: ``lat_win [n_window_layers, b * ring, page,
+      cfg.window_latent_row]`` over a RING of ``ceil(window / page) + 1``
+      pages a slot, ``block_table_win``: position ``p`` lives in ring page
+      ``(p // page) % ring`` and is overwritten ``ring * page`` positions
+      later. The ring's arithmetic is the k/v window kind's
+      (``ring_pages`` / ``_step_address`` / ``_ring_sources``).
+    - a learned indexer's keys (``cfg.index_topk``): ``idx
+      [n_full_layers, n_pool, page, cfg.index_head_dim]``, one a token a
+      full layer, on the full layers' table.
+
+    Block table, static page ranges and the drop-out-of-range write
+    discipline are the k/v kind's; the model hands rows in
+    (:meth:`write_and_attend` a step, :meth:`write_prompt` at prefill) and
+    the kernels of ``ops/mla_decode.py`` / ``ops/sparse_index.py`` read the
+    pools where they lie. Stale rows (a slot's last request, a ring's last
+    lap, index keys past the new length) are hidden by the length and the
+    window, never cleared. Static tables only (the batcher's)."""
 
     kind: ClassVar[str] = "latent"
+
+    def ring(self, cfg) -> int:
+        """Pages of a slot's ring in each window layer."""
+        return ring_pages(cfg.window, self.page_size, self.s_max)
 
     def init(self, cfg, n: int, n_o: int = 1) -> dict:
         if not self.static_table:
@@ -467,16 +545,33 @@ class LatentPagedCacheSpec(PagedKVCacheSpec):
                 f"(got {n_o} x {n} devices): a latent row is not sharded "
                 f"over sequence or heads yet")
         n_pool, bt = self._table(cfg, n, n_o)
-        lat = jnp.zeros(
-            (cfg.n_layers, n_pool, self.page_size, cfg.latent_row),
-            cfg.dtype)
-        return dict(lat=lat, block_table=bt,
-                    n_alloc=jnp.zeros((bt.shape[0],), jnp.int32))
+        kinds = cfg.attention_kinds
+        n_full, n_win = kinds.count("full"), kinds.count("window")
+        pool = lambda layers, pages, row: jnp.zeros(
+            (layers, pages, self.page_size, row), cfg.dtype)
+        out = dict(lat=pool(n_full, n_pool, cfg.latent_row), block_table=bt,
+                   n_alloc=jnp.zeros((bt.shape[0],), jnp.int32))
+        if n_win:
+            if self.extra_pages:
+                refuse_ring("the prefix cache (extra_pages: its scratch "
+                            "page)")
+            ring, b = self.ring(cfg), cfg.batch
+            out.update(lat_win=pool(n_win, b * ring, cfg.window_latent_row),
+                       block_table_win=_ring_table(b, ring))
+        if cfg.index_topk:
+            out.update(idx=pool(n_full, n_pool, cfg.index_head_dim))
+        return out
 
     def specs(self, cfg) -> dict:
         kv = PagedKVCacheSpec.specs(self, cfg)
-        return dict(lat=P(None, cfg.axis, None, None),
-                    block_table=kv["block_table"], n_alloc=kv["n_alloc"])
+        lat = P(None, cfg.axis, None, None)
+        out = dict(lat=lat, block_table=kv["block_table"],
+                   n_alloc=kv["n_alloc"])
+        if "window" in cfg.attention_kinds:
+            out.update(lat_win=lat, block_table_win=kv["block_table"])
+        if cfg.index_topk:
+            out.update(idx=lat)
+        return out
 
     def _refuse(self, what: str):
         raise NotImplementedError(
@@ -484,10 +579,113 @@ class LatentPagedCacheSpec(PagedKVCacheSpec):
             f"(LatentPagedCacheSpec): it reads k/v pools")
 
     def pages_walked(self, cfg, lens: np.ndarray) -> tuple[int, int]:
-        """``mla_paged_decode`` still walks the whole table row (PERF.md
-        §7): live is what it walks, the table."""
-        table = PagedKVCacheSpec.pages_walked(self, cfg, lens)[1]
-        return table, table
+        """A plan of full layers with no indexer: ``mla_paged_decode``
+        still walks the whole table row (PERF.md section 7), live is what
+        it walks, the table. With an indexer a full layer walks its LIVE
+        pages, in the index keys' pool and then in the latent rows' under
+        the selection's mask (the rows it attends are ``selected_rows``, a
+        counter of the pass); a window layer the pages of ``[len - window,
+        len)`` in its ring."""
+        kinds = cfg.attention_kinds
+        n_full, n_win = kinds.count("full"), kinds.count("window")
+        page = self.page_size
+        table = lens.size * (self.s_max // page) * n_full
+        full = int((-(-lens // page)).sum()) * n_full if cfg.index_topk \
+            else table
+        if not n_win:
+            return full, table
+        ring = self.ring(cfg)
+        win = _ring_pages_walked(lens, cfg.window, page, ring)
+        return (full + int(win.sum()) * n_win,
+                table + lens.size * ring * n_win)
+
+    def _pool_of(self, kind: str) -> tuple[str, str]:
+        """``(latent pool, table)`` names of an attention kind."""
+        return (("lat_win", "block_table_win") if kind == "window"
+                else ("lat", "block_table"))
+
+    def write_and_attend(self, cfg, cache, kind: str, ki: int, row, q, pos_b,
+                         *, d_v: int, scale: float, index=None,
+                         interpret=None):
+        """One step of the ``ki``-th layer of its attention ``kind``: each
+        slot's new latent ``row [b, row width]`` lands in its page (a full
+        layer's page of the position, a window layer's ring page), then
+        the absorbed queries ``q [b, heads, row width]`` read the kind's
+        pool: a window layer ``[pos - window + 1, pos]`` through its ring;
+        a full layer ``[0, pos]``, or, with ``index = (key [b, d_i],
+        queries [b, G, d_i], weights [b, G])``, the ``cfg.index_topk``
+        positions of largest index score: the slot's new index key lands
+        beside its row, the slot's live keys are scored where they lie,
+        and the latent rows' live pages are read where they lie with the
+        unselected positions masked. ``(o_lat [b, heads, d_v] f32,
+        cache)``."""
+        from triton_dist_tpu.ops.mla_decode import (
+            mla_paged_decode, sparse_mla_decode,
+        )
+
+        name, tn = self._pool_of(kind)
+        bt = cache[tn][0]                                  # [b, pages a slot]
+        n_pool = cache[name].shape[1]
+        ids, slot = _step_address(
+            bt, pos_b, self.page_size, self.s_max, n_pool, kind == "window")
+        lens = jnp.clip(pos_b + 1, 0, self.s_max)
+        with _scope("attn/kv_write"):
+            pool = cache[name].at[ki, ids, slot].set(
+                row.astype(cache[name].dtype), mode="drop")
+            cache = dict(cache, **{name: pool})
+        if index is None:
+            with _scope("attn/decode"):
+                return mla_paged_decode(
+                    q, pool, ki, lens, bt, d_v=d_v, scale=scale,
+                    window=cfg.window if kind == "window" else None,
+                    interpret=interpret), cache
+        from triton_dist_tpu.ops.sparse_index import (
+            index_scores_paged, topk_mask,
+        )
+
+        key, q_idx, w_idx = index
+        with _scope("attn/index"):
+            idx = cache["idx"].at[ki, ids, slot].set(
+                key.astype(cache["idx"].dtype), mode="drop")
+            cache = dict(cache, idx=idx)
+            scores = index_scores_paged(
+                q_idx, w_idx, idx, ki, lens, bt, scale=cfg.index_scale,
+                interpret=interpret)
+            keep = topk_mask(scores, cfg.index_topk)
+        with _scope("attn/decode"):
+            return sparse_mla_decode(
+                q, pool, ki, lens, bt, keep, d_v=d_v, scale=scale,
+                interpret=interpret), cache
+
+    def write_prompt(self, cache, kind: str, ki: int, rows, lens, slots,
+                     index_keys=None):
+        """Prefill's latent ``rows [n, L, row width]`` of the ``ki``-th
+        layer of its kind for ``slots [n]``, as whole pages: a full
+        layer's positions ``[0, L)`` into each slot's page range (with
+        its ``index_keys [n, L, d_i]`` beside them); a window layer's LAST
+        ``ring * page`` true positions (``lens [n]``) at their ring
+        addresses. No other slot's pages are touched."""
+        name, tn = self._pool_of(kind)
+        n, L = rows.shape[:2]
+        ps = self.page_size
+        if kind == "window":
+            ids = cache[tn][0][slots]                      # [n, ring]
+            src = _ring_sources(lens, ids.shape[1] * ps, L)
+            rows = jnp.take_along_axis(rows, src[:, :, None], axis=1)
+        else:
+            ids = cache[tn][0][slots, :-(-L // ps)]        # [n, pages of L]
+
+        def put(pool, x):
+            pad = ids.shape[1] * ps - x.shape[1]
+            if pad:
+                x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+            return pool.at[ki, ids.reshape(-1)].set(
+                x.reshape(-1, ps, x.shape[-1]).astype(pool.dtype))
+
+        cache = dict(cache, **{name: put(cache[name], rows)})
+        if index_keys is not None:
+            cache = dict(cache, idx=put(cache["idx"], index_keys))
+        return cache
 
     def update_and_attend(self, *a, **kw):
         self._refuse("the k/v decode step")
@@ -528,8 +726,7 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
 
     def ring(self, cfg) -> int:
         """Pages of a slot's ring in each window layer."""
-        return min(-(-cfg.window // self.page_size) + 1,
-                   self.s_max // self.page_size)
+        return ring_pages(cfg.window, self.page_size, self.s_max)
 
     def init(self, cfg, n: int, n_o: int = 1) -> dict:
         if not self.static_table:
@@ -544,8 +741,7 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
             refuse_ring("the prefix cache (extra_pages: its scratch page)")
         n_pool, bt = self._table(cfg, n, n_o)
         ring, b = self.ring(cfg), cfg.batch
-        bt_win = (jnp.arange(b, dtype=jnp.int32)[:, None] * ring
-                  + jnp.arange(ring, dtype=jnp.int32)[None, :])[None]
+        bt_win = _ring_table(b, ring)
         kinds = cfg.layer_types
         pool = lambda layers, pages: jnp.zeros(
             (layers, pages, cfg.n_kv_heads, self.page_size, cfg.head_dim),
@@ -570,10 +766,7 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
         n_full = cfg.layer_types.count("full")
         n_win = cfg.layer_types.count("window")
         full = -(-lens // page)
-        # the pages of [len - window, len), as the kernel walks them
-        win = np.where(lens > 0, np.minimum(
-            (lens - 1) // page - np.maximum(lens - cfg.window, 0) // page + 1,
-            ring), 0)
+        win = _ring_pages_walked(lens, cfg.window, page, ring)
         return (int(full.sum()) * n_full + int(win.sum()) * n_win,
                 lens.size * (self.s_max // page * n_full + ring * n_win))
 
@@ -592,14 +785,8 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
         kn, vn, tn = self._pool_of(kind)
         bt = cache[tn][0]                                  # [b, pages a slot]
         n_pool = cache[kn].shape[1]
-        col = pos_b // self.page_size
-        if kind == "window":
-            col = col % bt.shape[1]
-        page_ids = bt[jnp.arange(bt.shape[0]),
-                      jnp.minimum(col, bt.shape[1] - 1)]
-        # a slot at s_max owns no page: its write drops
-        safe_ids = jnp.where(pos_b < self.s_max, page_ids, n_pool)
-        slot = pos_b % self.page_size
+        safe_ids, slot = _step_address(
+            bt, pos_b, self.page_size, self.s_max, n_pool, kind == "window")
         with _scope("attn/kv_write"):
             cache = dict(
                 cache,
@@ -627,12 +814,7 @@ class WindowPagedKVCacheSpec(PagedKVCacheSpec):
         ps = self.page_size
         bt = cache[tn][0][slots]                           # [n, pages a slot]
         if kind == "window":
-            span = bt.shape[1] * ps
-            # ring address j holds the last position p < len with
-            # p % span == j (none yet: any row, the mask never reads it)
-            last = lens[:, None] - 1
-            src = last - (last - jnp.arange(span, dtype=jnp.int32)) % span
-            src = jnp.clip(src, 0, L - 1)[:, :, None, None]
+            src = _ring_sources(lens, bt.shape[1] * ps, L)[:, :, None, None]
             k, v = (jnp.take_along_axis(x, src, axis=1) for x in (k, v))
             ids = bt
         else:
@@ -690,9 +872,9 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
     position too: a step at position 0 reads zeros, a convolution row of a
     position before 0 reads as zero, an admission by prefill writes the
     state after its last true token (``write_state``); nothing is cleared
-    when a request leaves. An idle or finished slot's dummy step stays at
-    its position and rewrites rows the next admission overwrites or never
-    reads.
+    when a request leaves. A vacant slot's dummy step stands at position 0
+    (``ContinuousBatcher._vacate``) and rewrites rows the next admission
+    overwrites or never reads.
 
     What needs a sequence's state at MORE than its last position refuses
     this kind by name (:func:`refuse_state`): the prefix cache and a trie
@@ -1821,7 +2003,7 @@ class ContinuousBatcher:
                 req.eos_id is not None and t0 == req.eos_id
             ):
                 self.finished.append((req.uid, self.slot_out[i]))
-                self.slot_req[i] = None
+                self._vacate(i)
                 if self._px is not None:
                     self._px.release(i)
                     self._px_dirty = True
@@ -1891,7 +2073,7 @@ class ContinuousBatcher:
                 req.eos_id is not None and t0 == req.eos_id
             ):
                 self.finished.append((req.uid, self.slot_out[i]))
-                self.slot_req[i] = None
+                self._vacate(i)
 
     def _admit(self) -> None:
         if not self.queue:
@@ -2028,6 +2210,18 @@ class ContinuousBatcher:
         PagePrefixCache` (tests / fault harnesses), or None."""
         return self._px
 
+    def _vacate(self, i: int) -> None:
+        """Slot ``i``'s request has left. The step advances every slot
+        and is not told which are live, so the slot goes back to where a
+        slot never used stands, token 0 at position 0: its dummy steps
+        read one row and not the context the request held, every vacant
+        row routes to the same experts, and what they write the next
+        admission overwrites (with a prefix cache the row is on scratch
+        by then)."""
+        self.slot_req[i] = None
+        self.tok[i] = 0
+        self.pos[i] = 0
+
     def _poison_slot(self, i: int, reason: str) -> None:
         """Evict slot ``i``'s request as poisoned. Containment argument:
         decode rows never mix across the batch dim (attention is
@@ -2040,7 +2234,7 @@ class ContinuousBatcher:
 
         req = self.slot_req[i]
         self.poisoned.append((req.uid, list(self.slot_out[i]), reason))
-        self.slot_req[i] = None
+        self._vacate(i)
         self._chunk.pop(i, None)
         health.record_poisoned_request("continuous_batcher", req.uid, reason)
         if self._px is not None:
@@ -2053,7 +2247,7 @@ class ContinuousBatcher:
             for j in readers:
                 r = self.slot_req[j]
                 self._px.release(j)
-                self.slot_req[j] = None
+                self._vacate(j)
                 self._chunk.pop(j, None)
                 self.struck.append((
                     r.uid, f"shared prefix page struck: {reason}"
@@ -2184,8 +2378,8 @@ class ContinuousBatcher:
     def _set_page_counts(self, sp) -> None:
         """``kv_pages_live`` / ``kv_pages_table`` of the step this round
         took: the pages its lengths expose against what its tables hold,
-        from the positions the step was given (an idle slot keeps its
-        last one; a parked slot sits at ``s_max``). Counted only while
+        from the positions the step was given (a vacant slot stands at
+        0; a parked slot sits at ``s_max``). Counted only while
         something records the span."""
         if sp is not NULL_SPAN and isinstance(
                 self.spec, PagedKVCacheSpec):
@@ -2237,7 +2431,7 @@ class ContinuousBatcher:
                 if done:
                     finished += 1
                     self.finished.append((req.uid, self.slot_out[i]))
-                    self.slot_req[i] = None
+                    self._vacate(i)
                     if self._px is not None:
                         self._px.release(i)
                         self._px_dirty = True

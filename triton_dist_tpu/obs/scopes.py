@@ -25,8 +25,9 @@ PARTS: dict[str, tuple[str, ...]] = {
     # the cache's page-table work, the k/v (latent, ring) write, the decode
     # kernel or an admission's scores-softmax-PV (under ``prefill`` where
     # the tiled kernel computes it: ops/flash_prefill.py), the
-    # out-projection
-    "attn": ("qkv", "kv_write", "decode", "prefill", "out"),
+    # out-projection; a learned indexer's projections, scores and selection
+    # (``index``) and the headwise gate on the attention's output (``gate``)
+    "attn": ("qkv", "kv_write", "decode", "prefill", "out", "index", "gate"),
     # a feed-forward whole, dense or routed: norm, gate/up, activation,
     # down; the router with alignment, gather and combine, the two grouped
     # GEMMs with the activation between them, the shared expert

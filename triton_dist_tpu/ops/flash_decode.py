@@ -198,7 +198,7 @@ def _pad_head_dim(x, d_pad: int):
 
 def _online_softmax_step(
     q, k_b, v_b, ks_row, vs_row, chunk_start, kv_len, scale,
-    m_prev, l_prev, acc_prev, soft_cap=0.0, kv_lo=None,
+    m_prev, l_prev, acc_prev, soft_cap=0.0, kv_lo=None, bias=None,
 ):
     """One KV-chunk update of one head's online-softmax carry; the single
     source of the decode math for the per-head AND fused-heads kernels.
@@ -215,7 +215,9 @@ def _online_softmax_step(
     scores through ``soft_cap * tanh(s / soft_cap)`` BEFORE the length
     mask, after any int8 dequant scale — the reference's logit soft-cap,
     in the one place all five kernel paths share. ``kv_lo`` (window
-    layers; None adds no op) also masks the positions below it. Leading
+    layers; None adds no op) also masks the positions below it; ``bias``
+    (``[1, sc]`` float32, 0 or ``-inf`` a position; None adds no op) masks
+    the positions a sparse selection left out. Leading
     dims of ``q``, the tiles and the carry (the paged decode's kv heads)
     are batch dims of both matmuls: one body for every head of a step."""
     if ks_row is not None:
@@ -234,6 +236,8 @@ def _online_softmax_step(
     s = jnp.where(span < kv_len, s, NEG_INF)
     if kv_lo is not None:
         s = jnp.where(span >= kv_lo, s, NEG_INF)
+    if bias is not None:
+        s = s + bias
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     # all-masked rows keep m_new == -inf: subtract a clamped copy so the
     # update is exp(-inf) = 0, not exp(-inf - -inf) = NaN. The verify

@@ -1,5 +1,6 @@
 """Tiled causal prefill attention with an online softmax: no ``[L, L]``
-scores, an optional sliding window, grouped query heads.
+scores, an optional sliding window, grouped query heads, a value width of
+its own, an optional per-row SELECTION of keys.
 
 ``flash_prefill(q [n, L, hq, d], k, v [n, L, h_kv, d], lens [n], window)
 -> [n, L, hq * d]``: position ``p`` of sequence ``i`` attends ``max(0, p -
@@ -21,6 +22,15 @@ blocks (those the diagonal or the window's lower edge crosses) build a
 mask; the blocks between them take the unmasked body. bf16 operands,
 float32 scores, sums and accumulator. q is scaled by ``1 / sqrt(d)`` once,
 in the operand.
+
+``v`` may be narrower or wider than ``q`` and ``k`` (latent attention in
+its expanded form: q/k of 192 or 256, values of 128): the value buffers,
+the accumulator and the output take ``v``'s width. ``keep [n, L, L]`` int8
+(``ops/sparse_index.selection_mask``) lets row ``t`` see key ``j`` only
+where ``keep[t, j] != 0``: the block of it that meets a key block rides
+that block's DMAs, every block takes the masked body, and a key block in
+which a row keeps nothing still costs its multiply (the walk's bounds are
+the causal ones).
 
 What a call costs: :func:`blocks_walked` (host arithmetic, the kernel's own
 bounds) against the causal square's.
@@ -87,10 +97,14 @@ def blocks_walked(lens, L: int, g: int,
 
 
 def _flash_prefill_kernel(
-    lens_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, q_scr, m_scr,
-    l_scr, acc_scr, *, bq: int, bk: int, g: int, d: int, scale: float,
-    window: int | None,
+    lens_ref, q_ref, k_hbm, v_hbm, *rest, bq: int, bk: int, g: int, d: int,
+    scale: float, window: int | None, dv: int, selected: bool,
 ):
+    if selected:
+        (keep_hbm, o_ref, k_buf, v_buf, keep_buf, sems, q_scr, m_scr, l_scr,
+         acc_scr) = rest
+    else:
+        o_ref, k_buf, v_buf, sems, q_scr, m_scr, l_scr, acc_scr = rest
     i, j, qb = (pl.program_id(a) for a in range(3))
     q0 = qb * bq
     kb_lo, n_blocks = _key_blocks(q0, lens_ref[i], bq, bk, window)
@@ -98,9 +112,16 @@ def _flash_prefill_kernel(
     def copies(kb, slot):
         rows = pl.ds(pl.multiple_of(kb * bk, bk), bk)
         cols = pl.ds(pl.multiple_of(j * d, d), d)
-        return [pltpu.make_async_copy(
-            hbm.at[i, rows, cols], buf.at[slot], sems.at[t, slot])
-            for t, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))]
+        v_cols = cols if dv == d else pl.ds(pl.multiple_of(j * dv, dv), dv)
+        out = [pltpu.make_async_copy(
+            hbm.at[i, rows, at], buf.at[slot], sems.at[t, slot])
+            for t, (hbm, buf, at) in enumerate(
+                ((k_hbm, k_buf, cols), (v_hbm, v_buf, v_cols)))]
+        if selected:  # the rows' selection among this block's keys
+            out.append(pltpu.make_async_copy(
+                keep_hbm.at[i, pl.ds(pl.multiple_of(q0, bq), bq), rows],
+                keep_buf.at[slot], sems.at[2, slot]))
+        return out
 
     @pl.when(n_blocks > 0)
     def _():
@@ -126,6 +147,9 @@ def _flash_prefill_kernel(
             ok = key <= pos
             if window is not None:
                 ok = jnp.logical_and(ok, key > pos - window)
+            if selected:
+                ok = jnp.logical_and(
+                    ok, keep_buf[slot].astype(jnp.int32) != 0)
             s = jnp.where(ok, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -153,6 +177,9 @@ def _flash_prefill_kernel(
             dma.wait()
         k0 = kb * bk
         # the diagonal crosses the block, or the window's lower edge does
+        if selected:  # every block is masked by the rows' selection
+            multiply(slot, k0, True)
+            return carry
         edge = k0 + bk - 1 > q0
         if window is not None:
             edge = jnp.logical_or(edge, k0 < q0 + bq - window)
@@ -165,16 +192,16 @@ def _flash_prefill_kernel(
     l = l_scr[...]
     out = jnp.where(l > 0, acc_scr[...] / jnp.maximum(l, 1e-30), 0.0)
     for h in range(g):
-        o_ref[0, :, pl.ds(h * d, d)] = out[h * bq:(h + 1) * bq].astype(
+        o_ref[0, :, pl.ds(h * dv, dv)] = out[h * bq:(h + 1) * bq].astype(
             o_ref.dtype)
 
 
-def xla_flash_prefill(q, k, v, lens, window: int | None = None):
+def xla_flash_prefill(q, k, v, lens, window: int | None = None, keep=None):
     """The plain twin: explicit ``[L, L]`` scores in float32 (the golden
     of the resilience layer and of the tests; small sizes only)."""
     del lens  # rows past a length are padding: any finite value serves
     n, L, hq, d = q.shape
-    h_kv = k.shape[2]
+    h_kv, dv = k.shape[2], v.shape[3]
     f32 = jnp.float32
     qg = q.reshape(n, L, h_kv, hq // h_kv, d).astype(f32)
     s = jnp.einsum("nqhgd,nkhd->nhgqk", qg, k.astype(f32)) / math.sqrt(d)
@@ -182,89 +209,113 @@ def xla_flash_prefill(q, k, v, lens, window: int | None = None):
     ok = kp <= qp
     if window is not None:
         ok = ok & (kp > qp - window)
+    if keep is not None:
+        ok = ok[None, None, None] & (keep != 0)[:, None, None]
     s = jnp.where(ok, s, NEG_INF)
     o = jnp.einsum("nhgqk,nkhd->nqhgd", jax.nn.softmax(s, -1), v.astype(f32))
-    return o.reshape(n, L, hq * d).astype(q.dtype)
+    return o.reshape(n, L, hq * dv).astype(q.dtype)
 
 
 def flash_prefill(
     q: jax.Array, k: jax.Array, v: jax.Array, lens: jax.Array, *,
     window: int | None = None, block_q: int | None = None,
     block_k: int | None = None, interpret: Any = None,
+    keep: jax.Array | None = None, name: str = "flash_prefill",
 ) -> jax.Array:
     """Causal (optionally windowed) grouped-query attention of whole
     prompts, tiled: see the module's docstring. ``lens [n]`` int32 counts
     each sequence's TRUE positions (it bounds the walk; it masks nothing a
     true query can see). ``block_q`` / ``block_k`` default to
     :func:`default_blocks`; ``L`` is padded to a multiple of both. The
-    kernel carries the window in its name (``flash_prefill_w4096``).
-    Degrades to :func:`xla_flash_prefill` where the kernel cannot run and
-    the resilience layer allows it."""
-    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+    kernel carries the window in its name (``flash_prefill_w4096``) after
+    ``name`` (a family whose trace should tell its calls apart gives its
+    own). ``v [n, L, h_kv, d_v]`` may have a width of its own: ``[n, L, hq
+    * d_v]`` comes back. ``keep [n, L, L]`` int8 (one query head a kv head
+    only) masks row ``t`` to the keys with ``keep[t, j] != 0``. Degrades
+    to :func:`xla_flash_prefill` where the kernel cannot run and the
+    resilience layer allows it."""
+    if (q.shape[2] % k.shape[2] or k.shape[:3] != v.shape[:3]
+            or q.shape[3] != k.shape[3]):
         raise ValueError(
             f"q {q.shape} is no whole group of query heads a kv head of "
             f"k {k.shape}, v {v.shape}")
     if window is not None and window < 1:
         raise ValueError(f"window={window} must be >= 1")
+    if keep is not None and (q.shape[2] != k.shape[2] or window is not None):
+        raise ValueError("a selection of keys goes with one query head a "
+                         "kv head and no window")
     lens = lens.astype(jnp.int32)
     return resilience.guarded_call(
-        "flash_prefill",
+        name,
         lambda: _flash_prefill(q, k, v, lens, window, block_q, block_k,
-                               interpret),
-        lambda: xla_flash_prefill(q, k, v, lens, window),
+                               interpret, keep, name),
+        lambda: xla_flash_prefill(q, k, v, lens, window, keep),
     )
 
 
-def _flash_prefill(q, k, v, lens, window, block_q, block_k, interpret):
+def _flash_prefill(q, k, v, lens, window, block_q, block_k, interpret,
+                   keep=None, name="flash_prefill"):
     n, L, hq, d_true = q.shape
-    h_kv = k.shape[2]
+    h_kv, dv_true = k.shape[2], v.shape[3]
     g = hq // h_kv
     bq, bk = default_blocks(L, g)
     bq, bk = block_q or bq, block_k or bk
     # a kv head's columns are a lane-aligned block of the flattened row
     d = cdiv(d_true, LANES) * LANES
+    dv = cdiv(dv_true, LANES) * LANES
     step = bq * bk // math.gcd(bq, bk)
     Lp = cdiv(L, step) * step
-    pad = ((0, 0), (0, Lp - L), (0, 0), (0, d - d_true))
-    flat = lambda x: (jnp.pad(x, pad) if Lp != L or d != d_true else x
-                      ).reshape(n, Lp, -1)
-    q2, k2, v2 = flat(q), flat(k), flat(v)
+
+    def flat(x, width):
+        pad = ((0, 0), (0, Lp - L), (0, 0), (0, width - x.shape[3]))
+        return (jnp.pad(x, pad) if Lp != L or width != x.shape[3] else x
+                ).reshape(n, Lp, -1)
+
+    q2, k2, v2 = flat(q, d), flat(k, d), flat(v, dv)
     block = pl.BlockSpec((1, bq, g * d), lambda i, j, qb, *_: (i, qb, j))
+    out_block = pl.BlockSpec(
+        (1, bq, g * dv), lambda i, j, qb, *_: (i, qb, j))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     rows = g * bq
+    selected = keep is not None
+    if selected and Lp != L:
+        keep = jnp.pad(keep, ((0, 0), (0, Lp - L), (0, Lp - L)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n, h_kv, Lp // bq),
-        in_specs=[block, in_hbm, in_hbm],
-        out_specs=block,
+        in_specs=[block, in_hbm, in_hbm] + [in_hbm] * selected,
+        out_specs=out_block,
         scratch_shapes=[
             pltpu.VMEM((2, bk, d), k.dtype),
-            pltpu.VMEM((2, bk, d), v.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((2, bk, dv), v.dtype),
+            *([pltpu.VMEM((2, bq, bk), jnp.int8)] * selected),
+            pltpu.SemaphoreType.DMA((2 + selected, 2)),
             pltpu.VMEM((rows, d), k.dtype),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
         ],
     )
     # an upper bound (the causal square): what a call walks follows lens
     pairs = n * hq * Lp * Lp // 2
     cost = pl.CostEstimate(
-        flops=4 * pairs * d, transcendentals=pairs,
-        bytes_accessed=q.dtype.itemsize * (2 * q2.size + k2.size + v2.size))
+        flops=2 * pairs * (d + dv), transcendentals=pairs,
+        bytes_accessed=q.dtype.itemsize * (
+            q2.size + n * Lp * hq * dv + k2.size + v2.size))
     tag = "" if window is None else f"_w{window}"
     out = dist_pallas_call(
         functools.partial(
             _flash_prefill_kernel, bq=bq, bk=bk, g=g, d=d,
-            scale=1.0 / math.sqrt(d_true), window=window),
-        name=f"flash_prefill{tag}",
+            scale=1.0 / math.sqrt(d_true), window=window, dv=dv,
+            selected=selected),
+        name=f"{name}{tag}",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q2.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, Lp, hq * dv), q.dtype),
         cost_estimate=cost,
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         uses_barrier=False,
         interpret=interpret,
-    )(lens, q2, k2, v2)
-    if Lp != L or d != d_true:
-        out = out.reshape(n, Lp, hq, d)[:, :L, :, :d_true]
-    return out.reshape(n, L, hq * d_true)
+    )(lens, q2, k2, v2, *((keep.astype(jnp.int8),) if selected else ()))
+    if Lp != L or dv != dv_true:
+        out = out.reshape(n, Lp, hq, dv)[:, :L, :, :dv_true]
+    return out.reshape(n, L, hq * dv_true)

@@ -577,7 +577,7 @@ class SpeculativeBatcher(ContinuousBatcher):
                 ):
                     finished += 1
                     self.finished.append((req.uid, self.slot_out[i]))
-                    self.slot_req[i] = None
+                    self._vacate(i)
                     if self._px is not None:
                         self._px.release(i)
                         self._px_dirty = True
