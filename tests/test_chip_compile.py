@@ -497,6 +497,39 @@ def test_flash_prefill_compiles(one_chip, window):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * L * hq * HEAD * 2
 
 
+def _compiled_admission(cfg, spec, mesh, params, cache, L: int):
+    """One slot's admission at bucket ``L`` as the batcher's program calls
+    it, compiled in the chip's posture: where kernels are interpreted
+    (here, by default) ``ops/moe_utils`` validates the combine's contract
+    under a ``lax.cond`` whose other branch is a float32 scatter as wide as
+    the sorted rows, which no chip compiles."""
+    import dataclasses
+
+    from triton_dist_tpu.models import decode
+
+    pcfg = dataclasses.replace(cfg, seq=L)
+    rep = NamedSharding(mesh, P())
+    cs = spec.specs(cfg)
+
+    def fn(p, c, prompt, mask, pick):
+        return decode.prefill_cache(
+            pcfg, p, c, prompt.reshape(-1), spec, spec.s_max, slot_mask=mask,
+            pick=pick)
+
+    posture = tdt_config.get_config().interpret
+    tdt_config.update(interpret=False)
+    try:
+        return jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
+            out_specs=(cs, P(), P()), check_vma=False),
+            donate_argnums=(1,)).lower(
+            params, cache, _struct((32, L), jnp.int32, rep),
+            _struct((32,), jnp.bool_, rep), _struct((32,), jnp.int32, rep)
+        ).compile()
+    finally:
+        tdt_config.update(interpret=posture)
+
+
 @pytest.fixture(scope="module")
 def smallthinker(topo):
     """``(cfg, spec, mesh, params' and cache's shapes)`` of the
@@ -562,28 +595,8 @@ def test_smallthinker_admission_compiles_with_no_square_of_scores(smallthinker):
     by the compiler's count the temporaries are under 2 GB (one layer's
     materialized scores would be 7.5 GB) and no array holds ``L x L`` or
     ``L x 2 window`` elements."""
-    import dataclasses
-
-    from triton_dist_tpu.models import decode
-
-    cfg, spec, mesh, params, cache = smallthinker
     L = 8192
-    pcfg = dataclasses.replace(cfg, seq=L)
-    rep = NamedSharding(mesh, P())
-    cs = spec.specs(cfg)
-
-    def fn(p, c, prompt, mask, pick):
-        return decode.prefill_cache(
-            pcfg, p, c, prompt.reshape(-1), spec, spec.s_max, slot_mask=mask,
-            pick=pick)
-
-    compiled = jax.jit(jax.shard_map(
-        fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
-        out_specs=(cs, P(), P()), check_vma=False),
-        donate_argnums=(1,)).lower(
-        params, cache, _struct((32, L), jnp.int32, rep),
-        _struct((32,), jnp.bool_, rep), _struct((32,), jnp.int32, rep)
-    ).compile()
+    compiled = _compiled_admission(*smallthinker, L)
     text = compiled.as_text()
     assert "flash_prefill_w4096" in text and "flash_prefill" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
@@ -646,7 +659,12 @@ def test_joyai_step_compiles_to_the_parents_program(topo):
     metadata and the kernels' serialized bodies left out: those move with
     a line number). tests/golden/joyai_step.v5e_ops.json was written from
     the commit before PR 41 by this very reduction; regenerate it only
-    with a PR that means to change JoyAI's step."""
+    with a PR that means to change JoyAI's step. PR 42 did, in the
+    counters vector alone: it holds a fourth int32 (the sorted rows
+    walked, a constant of the step), so the vector's own entries read
+    ``s32[4]`` for ``s32[3]`` (its four pads, three adds and one fusion
+    of the sum over layers) beside one more ``s32[1]`` constant; every
+    other instruction is the parent's."""
     import collections
     import json
 
@@ -699,32 +717,19 @@ def test_dots_admission_compiles_with_no_square_of_float_scores(dots):
     array holds ``L x L`` scores (one full layer's materialized scores of
     128 heads would be 34 GB), and the temporaries fit beside the
     weights."""
-    import dataclasses
-
-    from triton_dist_tpu.models import decode
-
-    cfg, spec, mesh, params, cache = dots
     L = 8192
-    pcfg = dataclasses.replace(cfg, seq=L)
-    rep = NamedSharding(mesh, P())
-    cs = spec.specs(cfg)
-
-    def fn(p, c, prompt, mask, pick):
-        return decode.prefill_cache(
-            pcfg, p, c, prompt.reshape(-1), spec, spec.s_max, slot_mask=mask,
-            pick=pick)
-
-    compiled = jax.jit(jax.shard_map(
-        fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
-        out_specs=(cs, P(), P()), check_vma=False),
-        donate_argnums=(1,)).lower(
-        params, cache, _struct((32, L), jnp.int32, rep),
-        _struct((32,), jnp.bool_, rep), _struct((32,), jnp.int32, rep)
-    ).compile()
+    compiled = _compiled_admission(*dots, L)
     text = compiled.as_text()
     for name in ("index_score_prefill", "mla_flash_prefill_w513",
                  "mla_flash_prefill"):
         assert name in text, name
+    # the routed experts walk the 545 sorted blocks a chunk of 72 at a
+    # time inside one loop a layer (gated_experts.moe_mlp): gate|up exists
+    # at a chunk's rows and never at the alignment's, and each chunk's
+    # down GEMM writes its rows of the one result (8 chunks' rows) in place
+    assert "bf16[9216,3072]" in text and "bf16[69760,3072]" not in text
+    assert sum(" while(" in line and "bf16[73728,5120]" in line
+               for line in text.splitlines()) == 4
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15e9
     # the selection leaves as int8; in float32 the scores exist a block of
@@ -732,4 +737,4 @@ def test_dots_admission_compiles_with_no_square_of_float_scores(dots):
     # (so an ``[L, L]`` shape in the text may be those, inside a fusion:
     # what is held to is the block of scores and the temporaries' size)
     assert "s8[1,8192,8192]" in text and "f32[1024,8192]" in text
-    assert mem.temp_size_in_bytes < 4.5e9
+    assert mem.temp_size_in_bytes < 2.5e9

@@ -170,9 +170,11 @@ def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
                  for b in (2, 4))
     assert len(two) == 2 * 2 and two == four        # 2 GEMMs x 2 expert layers
     # the sorted rows: 64 x top-2 assignments, each of the 8 experts padded
-    # to a 128-row block (128 + 8 x 127, rounded up), whatever the batch
-    # (2 slots' rows would be 1280, 4 slots' 1536)
-    assert {s[0] for call in two for s in call if len(s) == 2} == {1152}
+    # to a 128-row block (128 + 8 x 127, rounded up = 1152), whatever the
+    # batch (2 slots' rows would be 1280, 4 slots' 1536), walked a chunk of
+    # one block at a time (gated_experts._chunk_blocks at expert_ffn 32):
+    # a chunk's rows in, and the whole result the down GEMM writes into
+    assert {s[0] for call in two for s in call if len(s) == 2} == {128, 1152}
 
 
 # the family's row of the table of scopes (docs/observability.md): the toy
@@ -283,15 +285,16 @@ def test_shares_of_the_bank_add_up_to_the_layer(toy, ref):
 
 
 def test_routing_counters_on_a_hand_made_routing():
-    """(f) experts_hit, assignments, expert_load_max."""
+    """(f) experts_hit, assignments, expert_load_max, and the sorted rows
+    the layer's pass walked as the pass hands them over."""
     ids = jnp.array([[0, 3], [3, 5], [3, 0], [7, 3]], jnp.int32)
-    st = mla_moe.routing_stats(ids, jnp.ones_like(ids, bool), 8)
-    assert [int(x) for x in st] == [4, 8, 4]          # 0,3,5,7; 8; expert 3
+    st = mla_moe.routing_stats(ids, jnp.ones_like(ids, bool), 8, 48)
+    assert [int(x) for x in st] == [4, 8, 4, 48]      # 0,3,5,7; 8; expert 3
     # a share holding experts 4..7: local ids, the rest is elsewhere
     local = ids - 4
     here = (local >= 0) & (local < 4)
     st = mla_moe.routing_stats(jnp.where(here, local, 0), here, 4)
-    assert [int(x) for x in st] == [2, 2, 1]          # experts 5 and 7
+    assert [int(x) for x in st] == [2, 2, 1, 0]       # experts 5 and 7
 
 
 def test_lookahead_same_tokens_and_counters_round_for_round(toy):
@@ -406,3 +409,59 @@ def test_engine_serves_it_and_the_spans_carry_the_routing_counters(toy):
         # the admitted slot's rows: bucket x top-2 x 2 expert layers
         assert attrs["assignments"] == attrs["bucket"] * 2 * 2
         assert attrs["experts_hit"] <= 2 * 8
+
+
+def test_an_admissions_span_says_how_far_its_sorted_row_pass_went(adapter):
+    """``sorted_rows_walked`` on ``tdt.batcher.admit_prefill``: with ONE
+    expert layer of 16 experts and a bucket of at most 64 rows no expert
+    fills a 128-row block, so the experts hit are the live blocks, and the
+    pass walked them and no more (a chunk is one block of 128 rows at
+    ``expert_ffn`` 32: ``gated_experts._chunk_blocks``), never the whole
+    alignment of 17; a decode round walks its one straight-line call's
+    every row, a constant."""
+    from triton_dist_tpu import config as tdt_config, obs
+    from triton_dist_tpu.models import gated_experts
+    from triton_dist_tpu.obs import ObsConfig
+    from triton_dist_tpu.resilience import retry
+    from triton_dist_tpu.serving import Arrival, ServingConfig, ServingEngine
+
+    one = dict(TOY, n_layers=2, n_routed_experts=16)
+    one["sizes"] = {k: one[k] for k in cells.SIZE_KEYS}
+    cfg = adapter.model_config(one)
+    params = mla_moe.init_mla_moe_params(jax.random.PRNGKey(5), cfg)
+    chunk = gated_experts._chunk_blocks(cfg, gated_experts.PREFILL_BLOCK_M)
+    assert chunk == 1 and mla_moe.layer_plan(cfg).count("moe") == 1
+    mesh = Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
+    before = tdt_config.get_config().obs
+    tdt_config.update(obs=ObsConfig(spans=True))
+    obs.reset()
+    try:
+        clock = retry.FakeClock()
+        with retry.clock_scope(clock):
+            eng = ServingEngine(
+                cfg, params, mesh, s_max=64, page_size=8, prefill=True,
+                clock=clock, serving=ServingConfig(virtual_step_s=0.01))
+            rng = np.random.default_rng(2)
+            eng.serve([
+                Arrival(0.0, Request(list(rng.integers(0, cfg.vocab, n)), 2,
+                                     uid=f"u{n}"))
+                for n in (3, 7, 12)])
+        spans = obs.spans()
+    finally:
+        tdt_config.update(obs=before)
+        obs.reset()
+    admits = [sp.attrs for sp in spans
+              if sp.name == "tdt.batcher.admit_prefill"]
+    assert len(admits) == 3
+    for attrs in admits:
+        t = attrs["bucket"] * cfg.topk
+        n_blocks = -(-(t + min(16, t) * 127) // 128)
+        assert n_blocks > chunk                 # the chunked pass
+        live = attrs["experts_hit"]
+        assert attrs["sorted_rows_walked"] == (
+            min(-(-live // chunk) * chunk, n_blocks) * 128)
+        assert attrs["sorted_rows_walked"] < n_blocks * 128
+    rounds = [sp.attrs for sp in spans
+              if sp.name == "tdt.batcher.decode_round"]
+    # 2 slots x top-2 = 4 assignments on at most 4 experts: 4 + 4 x 15 rows
+    assert rounds and {a["sorted_rows_walked"] for a in rounds} == {64}

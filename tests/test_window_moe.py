@@ -214,7 +214,7 @@ def test_an_admission_runs_and_writes_the_admitted_slot_only(
         cfg, params, spec, S_MAX, POOLS, slot % cfg.batch, length, bucket,
         n_moe=3, tol=TOL, seed=bucket + slot)
     # every expert is held here; an admission reads no key rows
-    assert [int(v) for v in counters[3:]] == [0, 0, 0]
+    assert [int(v) for v in counters[len(gated_experts.MOE_STATS):]] == [0, 0, 0]
 
 
 def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
@@ -226,9 +226,11 @@ def test_the_lowered_admission_does_not_grow_with_the_batch(toy):
                  for b in (2, 4))
     assert len(two) == 2 * 3 and two == four        # 2 GEMMs x 3 expert layers
     # the sorted rows: 32 x top-2 assignments, each of the 8 experts padded
-    # to a 128-row block (64 + 8 x 127, rounded up), whatever the batch
-    # (4 slots' rows would be 1280)
-    assert {s[0] for call in two for s in call if len(s) == 2} == {1152}
+    # to a 128-row block (64 + 8 x 127, rounded up = 1152), whatever the
+    # batch (4 slots' rows would be 1280), walked a chunk of one block at a
+    # time (gated_experts._chunk_blocks at expert_ffn 32): a chunk's rows
+    # in, and the whole result the down GEMM writes into
+    assert {s[0] for call in two for s in call if len(s) == 2} == {128, 1152}
 
 
 # the family's row of the table of scopes (docs/observability.md): window

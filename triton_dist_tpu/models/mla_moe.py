@@ -96,8 +96,8 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.models.gated_experts import (  # noqa: F401  (the
     # family's names for what both plan families share)
     DECODE_BLOCK_M, MOE_STATS, PREFILL_BLOCK_M, add_stats, admitted_rows,
-    dense_mlp, expert_bytes, last_rows, moe_mlp, require_one_shard, route,
-    routing_stats,
+    dense_mlp, expert_bytes, last_rows, moe_mlp, no_stats, require_one_shard,
+    route, routing_stats,
 )
 from triton_dist_tpu.models.tp_transformer import TransformerConfig, rmsnorm
 from triton_dist_tpu.obs.scopes import scope
@@ -636,7 +636,7 @@ def forward_hidden(cfg: MLAMoEConfig, params, tokens, b: int, s: int,
         lens = jnp.full((b,), s, jnp.int32)
     with scope("head"):
         x = params["embed"][tokens]
-    stats = jnp.zeros((3,), jnp.int32)
+    stats = no_stats()
     for (kind, mlp), p in zip(layer_kinds(c), params["layers"]):
         g = c.geometry(kind)
         with scope("attn"):
@@ -712,7 +712,7 @@ def decode_step(cfg: MLAMoEConfig, params, cache, tokens, pos, *, spec,
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     with scope("head"):
         x = params["embed"][tokens]
-    stats = jnp.zeros((3,), jnp.int32)
+    stats = no_stats()
     for (kind, ki, mlp), p in zip(_numbered(c), params["layers"]):
         g = c.geometry(kind)
         with scope("attn"):

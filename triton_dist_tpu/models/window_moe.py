@@ -94,7 +94,7 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.models.gated_experts import (
     DECODE_BLOCK_M, GATE_ACTS, MOE_STATS, PREFILL_BLOCK_M, SCORINGS,
     add_stats, admitted_rows, dense_mlp, expert_bytes, last_rows, moe_mlp,
-    require_one_shard, route_rows,
+    no_stats, require_one_shard, route_rows,
 )
 from triton_dist_tpu.models.tp_transformer import (
     TransformerConfig, _causal_gqa_attention, rmsnorm, rope,
@@ -457,7 +457,7 @@ def forward_hidden(cfg: WindowMoEConfig, params, tokens, b: int, s: int,
         lens = jnp.full((b,), s, jnp.int32)
     with scope("head"):
         x = params["embed"][tokens]
-    stats = jnp.zeros((3,), jnp.int32)
+    stats = no_stats()
     for (kind, mlp), p in zip(layer_plan(c), params["layers"]):
         routing = _route_ahead(c, mlp, x, p, PREFILL_BLOCK_M)
         with scope("attn"):
@@ -529,7 +529,7 @@ def decode_step(cfg: WindowMoEConfig, params, cache, tokens, pos, *, spec,
     rope_b = jax.vmap(lambda xi, pi: rope(xi, pi, c.rope_theta))
     with scope("head"):
         x = params["embed"][tokens]
-    stats = jnp.zeros((3,), jnp.int32)
+    stats = no_stats()
     for (kind, ki, mlp), p in zip(_numbered(c), params["layers"]):
         routing = _route_ahead(c, mlp, x, p, DECODE_BLOCK_M)
         with scope("attn"):

@@ -203,7 +203,7 @@ def _ragged_dot_group_gemm(
 
 def _group_gemm_xla(
     a_sorted, b, expert_ids, *, valid_rows, scale, ragged, bm, out_dtype,
-    act_fn, **_,
+    act_fn, into=None, **_,
 ):
     """The golden slow path (the program the kernel is tested against):
     globally expert-sort the blocks, one ``jax.lax.ragged_dot`` over the
@@ -234,12 +234,25 @@ def _group_gemm_xla(
     if ragged:
         rows = jnp.arange(bm, dtype=jnp.int32)[None, :, None]
         out = jnp.where(rows < valid_rows[:, None, None], out, 0.0)
-    return out.reshape(nb * bm, -1).astype(out_dtype)
+    out = out.reshape(nb * bm, -1).astype(out_dtype)
+    if into is not None:
+        buffer, first_block = into
+        out = jax.lax.dynamic_update_slice_in_dim(
+            buffer, out, first_block * bm, 0)
+    return out
+
+
+def dead_blocks_refetch_none(valid_rows: jax.Array) -> jax.Array:
+    """:func:`group_gemm`'s ``a_blocks`` for a ragged alignment: each block
+    its own, a block of no valid row the last live block before it (block
+    0 before the first)."""
+    blocks = jnp.arange(valid_rows.shape[0], dtype=jnp.int32)
+    return jax.lax.cummax(jnp.where(valid_rows > 0, blocks, 0))
 
 
 def _group_gemm_fused(
     a_sorted, b, expert_ids, *, valid_rows, scale, ragged, bm, out_dtype,
-    act_fn, cfg, interpret,
+    act_fn, cfg, interpret, a_blocks=None, into=None,
 ):
     t_pad, k_dim = a_sorted.shape
     n_exp, _, n_dim = b.shape
@@ -249,23 +262,37 @@ def _group_gemm_fused(
     # parallel dims must form a grid prefix: n-tiles first (megablox order)
     grid = (n_dim // bn, t_pad // bm, n_k)
     w8 = scale is not None
+    out_shape = jax.ShapeDtypeStruct((t_pad, n_dim), out_dtype)
+    aliases = None
     if ragged:
+        # scalar-prefetched, in this order: e_ref, v_ref, then the index
+        # maps' own. ``a``: the row block of A each block FETCHES (a dead
+        # block reads no row of it, so it may name the block before's: an
+        # unchanged block index costs no fetch, as ops/mla_decode.py's
+        # page_map does past a sequence's length). ``first``: where in
+        # ``into``'s buffer the result's first block goes.
+        scalars = {"e": expert_ids, "v": valid_rows.astype(jnp.int32)}
+        if a_blocks is not None:
+            scalars["a"] = a_blocks.astype(jnp.int32)
+        if into is not None:
+            buffer, first_block = into
+            scalars["first"] = jnp.asarray(first_block, jnp.int32).reshape(1)
+        at = {name: n for n, name in enumerate(scalars)}
+        a_row = (lambda i, s: s[at["a"]][i]) if "a" in at else (lambda i, s: i)
+        o_row = ((lambda i, s: s[at["first"]][0] + i) if "first" in at
+                 else (lambda i, s: i))
         in_specs = [
-            pl.BlockSpec((bm, bk), lambda j, i, kk, e_ref, v_ref: (i, kk)),
+            pl.BlockSpec((bm, bk), lambda j, i, kk, *s: (a_row(i, s), kk)),
             pl.BlockSpec(
-                (1, bk, bn),
-                lambda j, i, kk, e_ref, v_ref: (e_ref[i], kk, j),
+                (1, bk, bn), lambda j, i, kk, *s: (s[at["e"]][i], kk, j),
             ),
         ]
-        args = [expert_ids, valid_rows.astype(jnp.int32), a_sorted, b]
-        out_spec = pl.BlockSpec(
-            (bm, bn), lambda j, i, kk, e_ref, v_ref: (i, j)
-        )
+        args = [*scalars.values(), a_sorted, b]
+        out_spec = pl.BlockSpec((bm, bn), lambda j, i, kk, *s: (o_row(i, s), j))
         if w8:
             in_specs.append(
                 pl.BlockSpec(
-                    (1, 1, bn),
-                    lambda j, i, kk, e_ref, v_ref: (e_ref[i], 0, j),
+                    (1, 1, bn), lambda j, i, kk, *s: (s[at["e"]][i], 0, j),
                 )
             )
     else:
@@ -296,12 +323,31 @@ def _group_gemm_fused(
         fmt=OperandFormat(w8 and not fp8, fp8), ragged=ragged,
         panel=_panel_for(bm) if ragged else 0,
     )
+    if into is not None:
+        # the buffer is the output, aliased: blocks the grid does not
+        # write keep what they held. It stays in HBM, and no ref of it
+        # reaches the body
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        aliases = {len(args): 0}
+        args.append(buffer)
+        out_shape = jax.ShapeDtypeStruct(buffer.shape, buffer.dtype)
+    if ragged and len(scalars) > 2:
+        # the body knows e_ref, v_ref, a_ref, b_ref, [s_ref], o_ref, acc_ref
+        body, n_own, at_buffer = kernel, len(scalars) - 2, 4 + w8
+
+        def kernel(*refs):
+            refs = list(refs)
+            del refs[2:2 + n_own]
+            if into is not None:
+                del refs[at_buffer]
+            body(*refs)
+
     return dist_pallas_call(
         kernel,
         name=name,
-        out_shape=jax.ShapeDtypeStruct((t_pad, n_dim), out_dtype),
+        out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2 if ragged else 1,
+            num_scalar_prefetch=len(scalars) if ragged else 1,
             grid=grid,
             in_specs=in_specs,
             out_specs=out_spec,
@@ -317,6 +363,7 @@ def _group_gemm_fused(
             ),
         ),
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        input_output_aliases=aliases,
         uses_barrier=False,
         interpret=interpret,
     )(*args)
@@ -333,6 +380,8 @@ def group_gemm(
     out_dtype: Any = None,
     act_fn: Any = None,
     interpret: Any = None,
+    a_blocks: jax.Array | None = None,
+    into: tuple[jax.Array, Any] | None = None,
 ) -> jax.Array:
     """``out[i*bm:(i+1)*bm] = a_sorted[i*bm:(i+1)*bm] @ b[expert_ids[i]]``.
 
@@ -358,7 +407,17 @@ def group_gemm(
     kernel skips every dead 128-row panel instead of computing the
     alignment's worst-case pad rows (the ~25% MoE padding tax, VERDICT r5
     #1); dead rows come back exact zeros. ``ragged=False`` emits the
-    legacy schedule bit for bit.
+    legacy schedule bit for bit. ``a_blocks`` (``[t_pad // block_m]``
+    int32, ragged only; :func:`dead_blocks_refetch_none` makes it) names
+    the row block of `a_sorted` each block fetches: a block of no valid
+    row reads none of it, and named after the last live block before it,
+    it costs no fetch either. The result is the same with and without.
+    ``into = (buffer [rows, N], first_block)`` (ragged only) writes the
+    result over blocks ``[first_block, first_block + t_pad // block_m)``
+    of ``buffer`` in place (the kernel's output IS the buffer, aliased;
+    ``first_block`` may be traced) and returns the buffer: a pass that
+    walks its sorted rows a run of blocks at a time builds one result
+    with no copy.
     """
     from triton_dist_tpu import resilience
 
@@ -388,12 +447,19 @@ def group_gemm(
         )
     if scale is not None:
         assert scale.shape == (n_exp, 1, b.shape[2]), (scale.shape, b.shape)
+    if (a_blocks is not None or into is not None) and not ragged:
+        raise ValueError("a_blocks and into need GroupGemmConfig.ragged "
+                         "(only a block of no valid row reads nothing of A)")
+    if into is not None:
+        assert into[0].shape[1] == b.shape[2] and into[0].dtype == out_dtype, (
+            into[0].shape, into[0].dtype, b.shape, out_dtype)
     return resilience.guarded_call(
         "group_gemm",
         functools.partial(_group_gemm_fused, cfg=cfg, interpret=interpret),
         _group_gemm_xla,
         a_sorted, b, expert_ids, valid_rows=valid_rows, scale=scale,
         ragged=ragged, bm=bm, out_dtype=out_dtype, act_fn=act_fn,
+        a_blocks=a_blocks, into=into,
     )
 
 

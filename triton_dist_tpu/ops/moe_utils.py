@@ -327,10 +327,16 @@ def scatter_add_unsorted(
     n_tokens: int,
     *,
     assume_bijective: bool = True,
+    written: jax.Array | None = None,
 ) -> jax.Array:
     """Inverse of :func:`gather_sorted_rows` with the top-k weighted
     reduction fused in (≙ the consumer topk-reduce, moe_reduce_rs.py:468):
     out[token] = Σ_k w[token,k] * y_sorted[row(token,k)].
+
+    ``written [n_tokens, topk]`` bool says which slots' rows of `y_sorted`
+    hold a result; the others' (a pass that stopped before them) are never
+    read as numbers and add zero, whatever their weight: a selection, not
+    a product with 0 (the unwritten row may hold anything).
 
     NOT a scatter by default: TPU serializes ``.at[].add()`` row scatters
     (measured 4.2 ms for the bench-shape combine — 10× its HBM traffic;
@@ -367,6 +373,8 @@ def scatter_add_unsorted(
         flat_w = jnp.where(
             valid, weights.reshape(-1)[jnp.clip(ids, 0, t - 1)], 0.0
         )
+        if written is not None:
+            valid &= written.reshape(-1)[jnp.clip(ids, 0, t - 1)]
         token_of_row = jnp.clip(ids // topk, 0, n_tokens - 1)
         contrib = y_sorted.astype(jnp.float32) * flat_w[:, None]
         return (
@@ -380,9 +388,15 @@ def scatter_add_unsorted(
         # one row-gather per k slot: the obvious single [t, k, d] gather
         # measures 2.6x slower on chip (the 3-D intermediate's layout
         # defeats the streaming fusion); topk is small and static
-        out = y_sorted[inv[:, 0]].astype(jnp.float32) * w[:, 0][:, None]
+        def term(k):
+            rows = y_sorted[inv[:, k]].astype(jnp.float32)
+            if written is not None:
+                rows = jnp.where(written[:, k][:, None], rows, 0.0)
+            return rows * w[:, k][:, None]
+
+        out = term(0)
         for k in range(1, topk):
-            out = out + y_sorted[inv[:, k]].astype(jnp.float32) * w[:, k][:, None]
+            out = out + term(k)
         return out
 
     if not assume_bijective:
