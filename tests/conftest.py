@@ -96,6 +96,9 @@ _LONG_POLES = (
     "test_spec_soak.py", "test_ranged_engine.py", "test_window_moe.py",
     "test_prerouted_moe.py",    # PR 39: ~as heavy as test_window_moe.py
     "test_sparse_mla_moe.py",   # PR 41: ~350 s alone (a toy serve of 52 rounds)
+    # PR 43: 229 s of a 920 s run (+3 compiles at the TP=4 cell's widths,
+    # and the rule's larger tiles compile in 8-10 s where the old took 2)
+    "test_chip_compile.py",
     "test_emitter.py",
     "test_mla_moe.py", "test_disagg.py", "test_ranged_batcher.py",
     "test_serving.py", "test_prefill_work.py", "test_prefix_cache_soak.py",
@@ -106,7 +109,6 @@ _LONG_POLES = (
     "test_ragged_pipeline.py", "test_ranged_contiguous.py",
     "test_flight_recorder.py", "test_recovery.py", "test_fp8.py",
     "test_ssm_hybrid.py", "test_lookahead.py", "test_gemm_rs.py",
-    "test_chip_compile.py",     # PR 39: +4 compiles at published widths
     "test_chip_smoke.py",
     "test_ranged_paged.py", "test_ring_attention.py",
     "test_gate_up_layout.py", "test_moe.py", "test_ag_gemm.py",

@@ -390,6 +390,59 @@ def test_gemm_rs_compiles_on_four(mesh4):
     assert "reduce-scatter" not in text
 
 
+@pytest.mark.parametrize(
+    "k_tot, tiles",
+    [
+        pytest.param(12288, "_1024m1536n1536k_512m768n3072k", id="wo"),
+        pytest.param(28672, "_1024m2048n1024k_512m1024n1792k", id="w_down"),
+    ],
+)
+def test_gemm_rs_scatter_compiles_under_the_rule_at_the_tp4_cells_widths(
+    mesh4, k_tot, tiles
+):
+    """``mistral-large-2407-tp4.summarize``'s two row-parallel GEMMs with
+    ``config=None`` (PR 43): the remote chunks' pipeline and the own
+    chunk's each take the largest step ``ops.common.gemm_tile`` fits into
+    its budget, and the limit the kernel then asks for has to cover what
+    Mosaic allocates for both (35.8 MiB asked here: past the compiler's
+    16 MiB default, which is why the parent's tile was small). The tiles
+    are pinned: a change of the rule or of its budget shows here first."""
+    from triton_dist_tpu.ops.gemm_reduce_scatter import gemm_rs
+
+    a = _struct(
+        (M_TOKENS, k_tot), jnp.bfloat16, NamedSharding(mesh4, P(None, "tp"))
+    )
+    b = _struct(
+        (k_tot, 12288), jnp.bfloat16, NamedSharding(mesh4, P("tp", None))
+    )
+    fn = _shard_mapped(
+        # (a 2x2 described here has no wrap-around, the auto method's
+        # choice on the chip; the CPU's device list says otherwise)
+        functools.partial(gemm_rs, axis="tp", method="scatter", interpret=False),
+        mesh4, (P(None, "tp"), P("tp", None)), P("tp", None),
+    )
+    text = _compiled_text(fn, a, b)
+    assert f"gemm_rs_scatter{tiles}" in text
+
+
+def test_ag_gemm_compiles_under_the_rule_at_the_tp4_cells_qkv(mesh4):
+    """N = 3584 a shard (7 x 512): the rule's column block is 1792, where
+    halving from 2048 fell to 512 and a 268-MFLOP grid step."""
+    from triton_dist_tpu.ops.allgather_gemm import ag_gemm
+
+    a = _struct(
+        (M_TOKENS, 12288), jnp.bfloat16, NamedSharding(mesh4, P("tp", None))
+    )
+    b = _struct(
+        (12288, 4 * 3584), jnp.bfloat16, NamedSharding(mesh4, P(None, "tp"))
+    )
+    fn = _shard_mapped(
+        functools.partial(ag_gemm, axis="tp", interpret=False), mesh4,
+        (P("tp", None), P(None, "tp")), P(None, "tp"),
+    )
+    assert "ag_gemm_1024m1792n1024k" in _compiled_text(fn, a, b)
+
+
 def test_paged_flash_decode_distributed_compiles_on_four(mesh4):
     """The TP=4 decode step's attention: paged flash-decode over each
     PE's sequence shard, then the ``full_mesh_push`` all-gather of the

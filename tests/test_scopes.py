@@ -5,6 +5,8 @@ compiled or run (``scope_helpers``); the layer-plan families' cases live
 with their toy models (``test_mla_moe`` / ``test_window_moe`` /
 ``test_ssm_hybrid``)."""
 
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -64,8 +66,11 @@ def test_every_part_of_a_dense_pass_says_which_part_it_is(which):
         return
     names = admission_names(cfg, params, spec, mesh, s_max, BUCKET)
     check_scopes(names, SCOPES)
-    # (one device: ``gemm_rs``; four: ``gemm_rs_scatter``)
-    assert {k.removesuffix("_scatter") for k in _kernels_under(names)} == {
+    # (one device: ``gemm_rs``; four: ``gemm_rs_scatter``; each with the
+    # tile it ran, ``_8m16n16k``: ops.common.GemmTile.tag)
+    found = _kernels_under(names)
+    assert all(re.search(r"_\d+m\d+n\d+k$", k) for k in found), found
+    assert {re.sub(r"(_scatter)?(_\d+m\d+n\d+k)+$", "", k) for k in found} == {
         "attn/qkv/ag_gemm", "attn/out/gemm_rs", "ffn/gate_up/ag_gemm",
         "ffn/down/gemm_rs", "head/ag_gemm"}
 

@@ -35,10 +35,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from triton_dist_tpu.autotuner import contextual_autotune
 from triton_dist_tpu.ops.common import (
-    chunk_schedule,
+    GemmTile,
     dist_pallas_call,
     gemm_add_pipeline,
+    gemm_chunk_spans,
     gemm_only,
+    gemm_tile,
     jit_shard_map,
 )
 from triton_dist_tpu.shmem import device as shmem
@@ -50,11 +52,18 @@ from triton_dist_tpu.utils import axis_size as _axis_size
 class AGGemmConfig:
     """Tunables (≙ ``AllGatherGEMMTensorParallelContext``,
     reference allgather_gemm.py:407-489 — minus the stream/workspace
-    plumbing, which the fused kernel does not need)."""
+    plumbing, which the fused kernel does not need).
 
-    block_m: int = 512
-    block_n: int = 2048
-    block_k: int = 512
+    The block fields carry no default tile: left unset (``config=None``,
+    which is what every served call passes, or a config that sets
+    ``chunks_per_shard`` alone) the tile is ``ops.common.gemm_tile``'s,
+    a function of the GEMM's shape and a VMEM budget. Set (all three: the
+    autotuner's candidates, a test's tiny tile) they are honoured, each
+    shrunk to a divisor of its dimension."""
+
+    block_m: int | None = None
+    block_n: int | None = None
+    block_k: int | None = None
     # block_m=0: world-1 XLA-native sentinel — dispatch the degenerate
     # no-comm case to jnp.dot (XLA's matmul), a first-class autotune
     # candidate. Non-viable (raises) at n>1, where the fused ring kernel
@@ -68,14 +77,12 @@ class AGGemmConfig:
 
 def _ag_gemm_kernel(
     a_ref, b_ref, out_ref, ag_ref, acc_ref, copy_sem, send_sems, recv_sems,
-    *, axis: str, n: int, cfg: AGGemmConfig, out_dtype,
+    *, axis: str, n: int, tile: GemmTile, out_dtype,
 ):
     me = shmem.my_pe(axis)
     m_loc, k_dim = a_ref.shape
     n_loc = b_ref.shape[1]
-    bm = _pick_block(m_loc, cfg.block_m)
-    bn = _pick_block(n_loc, cfg.block_n)
-    bk = _pick_block(k_dim, cfg.block_k)
+    bm, bn, bk = tile[:3]
 
     local = pltpu.make_async_copy(a_ref, ag_ref.at[pl.ds(me * m_loc, m_loc)], copy_sem)
     local.start()
@@ -108,7 +115,7 @@ def _ag_gemm_kernel(
 
 def _ag_gemm_chunked_kernel(
     a_ref, b_ref, out_ref, ag_ref, acc_ref, copy_sem, send_sems, recv_sems,
-    sig_sems, *, axis: str, n: int, cfg: AGGemmConfig, out_dtype, spans,
+    sig_sems, *, axis: str, n: int, tile: GemmTile, out_dtype, spans,
 ):
     """Chunk-granular fused AG-GEMM (ISSUE 3 tentpole): step ``s`` waits,
     forwards, and COMPUTES shard ``me-s`` chunk by chunk — the MXU runs on
@@ -119,12 +126,11 @@ def _ag_gemm_chunked_kernel(
     me = shmem.my_pe(axis)
     m_loc, k_dim = a_ref.shape
     n_loc = b_ref.shape[1]
-    bn = _pick_block(n_loc, cfg.block_n)
-    bk = _pick_block(k_dim, cfg.block_k)
+    bn, bk = tile.bn, tile.bk
     # one pipeline per distinct chunk row-count (non-divisor spans differ by
     # one row); the f32 accumulator scratch is sized for the largest chunk
     # tile and sliced only for the smaller ones
-    bms = [_pick_block(rows, cfg.block_m) for _, rows in spans]
+    bms = [_pick_block(rows, tile.bm) for _, rows in spans]
     bm_max = max(bms)
     pipes = []
     for (_, rows), bm_j in zip(spans, bms):
@@ -179,7 +185,7 @@ def _ag_gemm_chunked_kernel(
 def _ag_gemm_2d_kernel(
     a_ref, b_ref, out_ref, ag_ref, acc_ref, copy_sem, in_send, in_recv,
     out_send, out_recv, *, outer: str, inner: str, n_o: int, n_i: int,
-    cfg: AGGemmConfig, out_dtype,
+    tile: GemmTile, out_dtype,
 ):
     """Fused hierarchical AG-GEMM over two mesh axes: the 2-D ring allgather
     (see ops/allgather._ring_2d_kernel) with an MXU pipeline consuming every
@@ -190,10 +196,9 @@ def _ag_gemm_2d_kernel(
     me_o = shmem.my_pe(outer)
     m_loc, k_dim = a_ref.shape
     n_loc = b_ref.shape[1]
-    bm = _pick_block(m_loc, cfg.block_m)
-    bn = _pick_block(n_loc, cfg.block_n)
-    bk = _pick_block(k_dim, cfg.block_k)
-    pipeline = gemm_add_pipeline(bm, bn, bk, m_loc, n_loc, k_dim, acc_ref, out_dtype)
+    pipeline = gemm_add_pipeline(
+        *tile[:3], m_loc, n_loc, k_dim, acc_ref, out_dtype
+    )
 
     def slot(o, i):
         return pl.ds((o * n_i + i) * m_loc, m_loc)
@@ -251,14 +256,17 @@ def _ag_gemm_2d(a, b, *, axes, cfg, gather_output, out_dtype, interpret):
     n = n_o * n_i
     m_loc, k_dim = a.shape
     n_loc = b.shape[1]
-    bm = _pick_block(m_loc, cfg.block_m)
-    bn = _pick_block(n_loc, cfg.block_n)
+    tile = gemm_tile(
+        cfg, m_loc, n_loc, k_dim, in_dtype=a.dtype, out_dtype=out_dtype
+    )
     out, ag = dist_pallas_call(
         functools.partial(
             _ag_gemm_2d_kernel, outer=outer, inner=inner, n_o=n_o, n_i=n_i,
-            cfg=cfg, out_dtype=out_dtype,
+            tile=tile, out_dtype=out_dtype,
         ),
         name="ag_gemm_2d",
+        trace_tag=tile.tag,
+        vmem_limit_bytes=tile.vmem_limit_bytes,
         out_shape=(
             jax.ShapeDtypeStruct((n * m_loc, n_loc), out_dtype),
             jax.ShapeDtypeStruct((n * m_loc, k_dim), a.dtype),
@@ -272,7 +280,7 @@ def _ag_gemm_2d(a, b, *, axes, cfg, gather_output, out_dtype, interpret):
             pl.BlockSpec(memory_space=pl.ANY),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.float32),
+            pltpu.VMEM((tile.bm, tile.bn), jnp.float32),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((max(n_i - 1, 1),)),
             pltpu.SemaphoreType.DMA((max(n_i - 1, 1),)),
@@ -427,16 +435,23 @@ def _ag_gemm_fused(
         ag = jax.lax.all_gather(a, axis, tiled=True)
         out = jnp.dot(ag, b, preferred_element_type=out_dtype)
         return (out, ag) if gather_output else out
-    bm = _pick_block(m_loc, cfg.block_m)
-    bn = _pick_block(n_loc, cfg.block_n)
-    if cfg.block_n % 128 == 0 and bn != n_loc and bn % 128 and n_loc % 512:
-        # the config asked for lane-aligned column blocks but the divisor
-        # search fell below a lane: Mosaic refuses a proper slice that is
+    tile = gemm_tile(
+        cfg, m_loc, n_loc, k_dim, in_dtype=a.dtype, out_dtype=out_dtype
+    )
+    # lane-aligned column blocks are what the rule always wants of a column
+    # count past one lane, and what an explicit config names
+    lanes = n_loc > 128 if cfg.block_n is None else (
+        cfg.block_n % 128 == 0 and tile.bn != n_loc
+    )
+    if lanes and tile.bn % 128 and n_loc % 512:
+        # ... but no divisor of the column count is a multiple of 128
+        # (the rule then holds the whole dimension; an explicit block fell
+        # below a lane by halving): Mosaic refuses a proper slice that is
         # not a multiple of 128 ("Slice shape along dimension 1 must be
         # aligned to tiling (128)" — Llama-3's vocab shard at TP=4,
-        # 32064 = 64 x 501, picks bn=64). Pad B's columns to the next
-        # multiple of 512 — one weight-shard copy per call, <2% extra MXU
-        # work — and slice the product back.
+        # 32064 = 64 x 501). Pad B's columns to the next multiple of 512
+        # — one weight-shard copy per call, <2% extra MXU work — and slice
+        # the product back; the padded call's tile is the rule's again.
         pad = -n_loc % 512
         res = _ag_gemm_fused(
             a, jnp.pad(b, ((0, 0), (0, pad))), axis=axis, config=config,
@@ -454,21 +469,14 @@ def _ag_gemm_fused(
             a, b, cfg=cfg, out_dtype=out_dtype, name="ag_gemm", interpret=interpret
         )
         return (out, a) if gather_output else out
-    chunks = max(1, int(cfg.chunks_per_shard))
-    # span boundaries quantize to the MXU row tile a chunk of this size
-    # would pick, so chunking shrinks tiles predictably (m_loc/chunks)
-    # instead of collapsing them on odd row counts (see chunk_schedule)
-    spans = chunk_schedule(
-        m_loc, chunks,
-        quantum=_pick_block(m_loc, min(cfg.block_m, max(1, m_loc // chunks))),
-    )
+    spans, chunk_tile = gemm_chunk_spans(cfg, tile, m_loc)
     n_steps = max(n - 1, 1)
     if len(spans) > 1:
         kernel = functools.partial(
-            _ag_gemm_chunked_kernel, axis=axis, n=n, cfg=cfg,
+            _ag_gemm_chunked_kernel, axis=axis, n=n, tile=chunk_tile,
             out_dtype=out_dtype, spans=spans,
         )
-        bm_acc = max(_pick_block(rows, cfg.block_m) for _, rows in spans)
+        bm_acc = max(_pick_block(rows, chunk_tile.bm) for _, rows in spans)
         sem_shapes = [
             pltpu.SemaphoreType.DMA((n_steps, len(spans))),
             pltpu.SemaphoreType.DMA((n_steps, len(spans))),
@@ -476,9 +484,9 @@ def _ag_gemm_fused(
         ]
     else:
         kernel = functools.partial(
-            _ag_gemm_kernel, axis=axis, n=n, cfg=cfg, out_dtype=out_dtype
+            _ag_gemm_kernel, axis=axis, n=n, tile=tile, out_dtype=out_dtype
         )
-        bm_acc = bm
+        bm_acc = tile.bm
         sem_shapes = [
             pltpu.SemaphoreType.DMA((n_steps,)),
             pltpu.SemaphoreType.DMA((n_steps,)),
@@ -486,6 +494,8 @@ def _ag_gemm_fused(
     out, ag = dist_pallas_call(
         kernel,
         name="ag_gemm",
+        trace_tag=tile.tag,
+        vmem_limit_bytes=tile.vmem_limit_bytes,
         out_shape=(
             jax.ShapeDtypeStruct((n * m_loc, n_loc), out_dtype),
             jax.ShapeDtypeStruct((n * m_loc, k_dim), a.dtype),
@@ -499,7 +509,7 @@ def _ag_gemm_fused(
             pl.BlockSpec(memory_space=pl.ANY),
         ),
         scratch_shapes=[
-            pltpu.VMEM((bm_acc, bn), jnp.float32),
+            pltpu.VMEM((bm_acc, tile.bn), jnp.float32),
             pltpu.SemaphoreType.DMA(()),
             *sem_shapes,
         ],
@@ -541,7 +551,10 @@ def ag_gemm_op(
 # triton.Config spaces, allgather_gemm.py:386-404). Swept per input
 # signature the first time `ag_gemm_op` is called without an explicit
 # config; `pick_block` shrinks oversized tiles, so large-tile candidates
-# degrade gracefully on small shards. Candidate ORDER is preference order
+# degrade gracefully on small shards. (The served path calls `ag_gemm`
+# with config=None and never reads this list: its tile is
+# `ops.common.gemm_tile`'s rule. Each candidate here is an explicit config
+# and runs as written.) Candidate ORDER is preference order
 # (the sweep's order-margin walk and the first-viable policy both honor
 # it): the world-1 XLA-dot sentinel leads — honest paired timing on v5e
 # showed XLA's matmul at parity-or-better with the best Pallas chunking

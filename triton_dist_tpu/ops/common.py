@@ -9,8 +9,9 @@ and the ``dist_pallas_call`` wrapper that all distributed kernels use.
 
 from __future__ import annotations
 
+import functools
 import itertools
-from typing import Any, Sequence
+from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -68,10 +69,16 @@ def dist_pallas_call(
     dimension_semantics: tuple[str, ...] | None = None,
     input_output_aliases: dict[int, int] | None = None,
     uses_barrier: bool = True,
+    trace_tag: str = "",
 ):
     """pallas_call with the invariants every distributed kernel needs:
     side effects on (remote DMAs must not be DCE'd), a collective_id for the
     barrier semaphore, and config-resolved interpret mode.
+
+    `trace_tag` is appended to the kernel's name in the lowered program and
+    so in a device trace (the GEMM families put their tile there,
+    :class:`GemmTile`); `name` alone stays the family every registry keys
+    on (collective ids, watchdog sites, telemetry).
 
     `uses_barrier` must be False for degenerate single-PE calls: Mosaic
     rejects a collective_id on kernels that never touch the barrier
@@ -230,7 +237,7 @@ def dist_pallas_call(
         compiler_params=pltpu.CompilerParams(**params),
         cost_estimate=cost_estimate,
         interpret=tdt_config.interpret_params() if interpret is None else interpret,
-        name=name,
+        name=name + trace_tag,
         **kwargs,
     )
     if not arm_diag:
@@ -483,18 +490,140 @@ def gemm_add_pipeline(
     )
 
 
+# VMEM one inner GEMM pipeline may ask of Mosaic (v5e holds 128 MiB; the
+# compiler's own scoped default is 16 MiB). The sweep that chose it is in
+# CHANGES.md (PR 43) and docs/autotuner.md "Where a served tile comes from".
+GEMM_VMEM_BUDGET = 32 * 2**20
+_MOSAIC_SCOPED_DEFAULT = 16 * 2**20
+_MXU = 128
+
+
+class GemmTile(NamedTuple):
+    """One ``gemm_add_pipeline``'s blocks and the scoped VMEM they need."""
+
+    bm: int
+    bn: int
+    bk: int
+    vmem_limit_bytes: int
+
+    @property
+    def tag(self) -> str:
+        """Suffix of the kernel's name in a trace (docs/observability.md);
+        it ends in a letter so that a reader which strips an HLO
+        instruction's trailing ``.<n>`` leaves it whole."""
+        return f"_{self.bm}m{self.bn}n{self.bk}k"
+
+
+def _tile_vmem_bytes(bm, bn, bk, n_adds, in_size, out_size) -> int:
+    """What Mosaic allocates for one pipeline at this tile: the A, B and
+    out tiles double-buffered, each add tile double-buffered and once more
+    for the epilogue that sums it, the f32 accumulator, the copy of the A
+    tile its dot makes (read off the compiler's own refusals at a described
+    v5e: the scoped allocation is this sum + under 1 MiB), 2 MiB to spare."""
+    return (
+        2 * (bm * bk + bk * bn) * in_size
+        + (2 + 3 * n_adds) * bm * bn * out_size
+        + 4 * bm * bn
+        + bm * bk * in_size
+        + 2 * 2**20
+    )
+
+
+def _mxu_blocks(dim: int) -> tuple[int, ...]:
+    """Block sizes the rule may give a dimension: its divisors that are
+    multiples of the MXU's 128; the whole dimension where it has none (a
+    whole-dimension block is legal at any size: test shapes, a row count
+    of 8 or 64)."""
+    return tuple(d for d in range(_MXU, dim + 1, _MXU) if dim % d == 0) or (dim,)
+
+
+@functools.lru_cache(maxsize=1024)
+def _largest_step(m, n, k, n_adds, in_size, out_size, budget) -> GemmTile:
+    best = None
+    for bm, bn, bk in itertools.product(*map(_mxu_blocks, (m, n, k))):
+        need = _tile_vmem_bytes(bm, bn, bk, n_adds, in_size, out_size)
+        fits = need <= budget
+        # a tile that fits (else the smallest there is); the largest grid
+        # step; among equals the fewest bytes streamed (A once a column
+        # block, B once a row block)
+        rank = (
+            fits, 0 if fits else -need, bm * bn * bk,
+            -(m * k * (n // bn) + k * n * (m // bm)),
+        )
+        if best is None or rank > best[0]:
+            best = (rank, GemmTile(bm, bn, bk, need))
+    return best[1]
+
+
+def gemm_tile(
+    cfg, m: int, n: int, k: int, *, n_adds: int = 0, in_dtype, out_dtype
+) -> GemmTile:
+    """THE place a fused GEMM's tile comes from: ``(bm, bn, bk)`` of the
+    inner ``gemm_add_pipeline`` over ``[m, k] @ [k, n]`` with `n_adds` fused
+    add operands, and the ``vmem_limit_bytes`` the kernel then asks for.
+
+    A config whose block fields are unset (``config=None`` upstream: every
+    served call) gets the rule: the largest grid step ``bm * bn * bk`` whose
+    footprint (:func:`_tile_vmem_bytes`) fits :data:`GEMM_VMEM_BUDGET`,
+    every block a divisor of its dimension and a multiple of the MXU's 128
+    where the dimension has such a divisor (N = 3584 gives 1792 or 896,
+    never 512 by halving), never more rows than there are. It follows the
+    shape: the own chunk of ``gemm_rs``'s scatter kernel, whose three adds
+    cost VMEM the remote chunks' pipeline does not pay, gets a smaller
+    tile from the same call; a 128-row chunk gets a wide one.
+
+    An explicit config (the autotuner's candidates, tests' tiny tiles) is
+    honoured as before, each block shrunk to a divisor by ``pick_block``.
+    Either way the limit is the footprint's, and never under Mosaic's own
+    16 MiB default."""
+    from triton_dist_tpu.utils import pick_block
+
+    in_size = jnp.dtype(in_dtype).itemsize
+    out_size = jnp.dtype(out_dtype).itemsize
+    if cfg is not None and cfg.block_m is not None:
+        if cfg.block_n is None or cfg.block_k is None:
+            raise ValueError(f"{cfg}: set all three block fields or none")
+        bm, bn, bk = (
+            pick_block(m, cfg.block_m), pick_block(n, cfg.block_n),
+            pick_block(k, cfg.block_k),
+        )
+        need = _tile_vmem_bytes(bm, bn, bk, n_adds, in_size, out_size)
+    else:
+        bm, bn, bk, need = _largest_step(
+            m, n, k, n_adds, in_size, out_size, GEMM_VMEM_BUDGET
+        )
+    return GemmTile(bm, bn, bk, max(need, _MOSAIC_SCOPED_DEFAULT))
+
+
+def gemm_chunk_spans(cfg, tile: GemmTile, m_loc: int):
+    """``chunks_per_shard`` applied to a shard's rows: the chunk spans, and
+    `tile` with the row block a chunk's rows shrink FROM (the cap an
+    explicit config names, as before; else the rule's block). Span
+    boundaries quantize to the MXU row tile a chunk of that size would
+    pick, so chunking shrinks tiles predictably (m_loc/chunks) instead of
+    collapsing them on odd row counts (see :func:`chunk_schedule`)."""
+    from triton_dist_tpu.utils import pick_block
+
+    chunks = max(1, int(cfg.chunks_per_shard))
+    cap_m = tile.bm if cfg.block_m is None else cfg.block_m
+    spans = chunk_schedule(
+        m_loc, chunks,
+        quantum=pick_block(m_loc, min(cap_m, max(1, m_loc // chunks))),
+    )
+    return spans, tile._replace(bm=cap_m)
+
+
 def gemm_only(a, b, *, cfg, out_dtype, name: str, interpret=None):
     """Pure-MXU pipelined matmul — the world-1 degenerate path shared by the
     fused ops (same inner ``gemm_add_pipeline``, minus workspace and ring).
-    `cfg` is any config with block_m/block_n/block_k (AGGemmConfig,
-    GemmRSConfig, …); `name` keeps traces/profiles attributed to the real op."""
-    from triton_dist_tpu.utils import pick_block
-
+    `cfg` is the op's config or None (:func:`gemm_tile`); `name` keeps
+    traces/profiles attributed to the real op."""
     m_loc, k_dim = a.shape
     n_loc = b.shape[1]
-    bm = pick_block(m_loc, cfg.block_m)
-    bn = pick_block(n_loc, cfg.block_n)
-    bk = pick_block(k_dim, cfg.block_k)
+    tile = gemm_tile(
+        cfg, m_loc, n_loc, k_dim, in_dtype=a.dtype, out_dtype=out_dtype
+    )
+    bm, bn, bk, vmem = tile
 
     def _kernel(a_ref, b_ref, out_ref, acc_ref):
         pipeline = gemm_add_pipeline(bm, bn, bk, m_loc, n_loc, k_dim, acc_ref, out_dtype)
@@ -503,6 +632,7 @@ def gemm_only(a, b, *, cfg, out_dtype, name: str, interpret=None):
     return dist_pallas_call(
         _kernel,
         name=name,
+        trace_tag=tile.tag,
         out_shape=jax.ShapeDtypeStruct((m_loc, n_loc), out_dtype),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
@@ -515,11 +645,7 @@ def gemm_only(a, b, *, cfg, out_dtype, name: str, interpret=None):
             bytes_accessed=(m_loc * k_dim + k_dim * n_loc + m_loc * n_loc) * a.dtype.itemsize,
             transcendentals=0,
         ),
-        # the emit_pipeline double-buffers a/b/out tiles; the default 16 MiB
-        # budget rejects the large-tile configs the autotuner wants to try
-        vmem_limit_bytes=2 * 2 * (bm * bk + bk * bn + bm * bn) * a.dtype.itemsize
-        + 4 * bm * bn
-        + 2 * 2**20,
+        vmem_limit_bytes=vmem,
         uses_barrier=False,
         interpret=interpret,
     )(a, b)

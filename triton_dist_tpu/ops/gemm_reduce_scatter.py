@@ -46,10 +46,12 @@ from jax.sharding import Mesh, PartitionSpec as P
 from triton_dist_tpu import resilience
 from triton_dist_tpu.autotuner import contextual_autotune
 from triton_dist_tpu.ops.common import (
-    chunk_schedule,
+    GemmTile,
     dist_pallas_call,
     gemm_add_pipeline,
+    gemm_chunk_spans,
     gemm_only,
+    gemm_tile,
     jit_shard_map,
 )
 from triton_dist_tpu.ops.reduce_scatter import get_auto_reduce_scatter_method
@@ -74,11 +76,18 @@ def _gemm_rs_xla(
 class GemmRSConfig:
     """Tunables (≙ the GEMM tile knobs of the reference contexts,
     gemm_reduce_scatter.py:42-86; stream/buffer plumbing is subsumed by the
-    fused kernel)."""
+    fused kernel).
 
-    block_m: int = 256
-    block_n: int = 1024
-    block_k: int = 512
+    The block fields carry no default tile: left unset (``config=None``,
+    which is what every served call passes, or a config that sets
+    ``chunks_per_shard`` alone) the tile is ``ops.common.gemm_tile``'s,
+    a function of the GEMM's shape and a VMEM budget. Set (all three: the
+    autotuner's candidates, a test's tiny tile) they are honoured, each
+    shrunk to a divisor of its dimension."""
+
+    block_m: int | None = None
+    block_n: int | None = None
+    block_k: int | None = None
     # block_m=0: world-1 XLA-native sentinel (see AGGemmConfig) — the
     # no-comm degenerate case goes to jnp.dot; raises at n>1.
     # Ring-step payload granularity (ISSUE 3): > 1 splits each ring hop's
@@ -88,26 +97,22 @@ class GemmRSConfig:
     chunks_per_shard: int = 1
 
 
-def _blocks(cfg: GemmRSConfig, m_loc: int, n_dim: int, k_loc: int):
-    return (
-        pick_block(m_loc, cfg.block_m),
-        pick_block(n_dim, cfg.block_n),
-        pick_block(k_loc, cfg.block_k),
-    )
-
-
 def _gemm_rs_scatter_kernel(
-    a_ref, b_ref, out_ref, send_buf, recv_buf, acc_ref, send_sems, recv_sems,
-    *, axis: str, n: int, cfg: GemmRSConfig, out_dtype,
+    a_ref, b_ref, out_ref, send_buf, recv_buf, acc_ref, acc_own_ref,
+    send_sems, recv_sems,
+    *, axis: str, n: int, tile: GemmTile, tile_own: GemmTile, out_dtype,
 ):
     me = shmem.my_pe(axis)
     m_tot, k_loc = a_ref.shape
     n_dim = b_ref.shape[1]
     m_loc = m_tot // n
-    bm, bn, bk = _blocks(cfg, m_loc, n_dim, k_loc)
-    gemm = gemm_add_pipeline(bm, bn, bk, m_loc, n_dim, k_loc, acc_ref, out_dtype, 0)
+    gemm = gemm_add_pipeline(
+        *tile[:3], m_loc, n_dim, k_loc, acc_ref, out_dtype, 0
+    )
+    # the own chunk's adds cost VMEM the remote chunks' pipeline does not
+    # pay: its tile is its own, from the same rule
     gemm_reduce = gemm_add_pipeline(
-        bm, bn, bk, m_loc, n_dim, k_loc, acc_ref, out_dtype, n - 1
+        *tile_own[:3], m_loc, n_dim, k_loc, acc_own_ref, out_dtype, n - 1
     )
 
     # race shaking (no-op unless config.debug_comm_delay)
@@ -146,13 +151,13 @@ def _gemm_rs_scatter_kernel(
 
 def _gemm_rs_ring_kernel(
     a_ref, b_ref, out_ref, comp_buf, recv_buf, acc_ref, send_sems, recv_sems,
-    *, axis: str, n: int, cfg: GemmRSConfig, out_dtype,
+    *, axis: str, n: int, tile: GemmTile, out_dtype,
 ):
     me = shmem.my_pe(axis)
     m_tot, k_loc = a_ref.shape
     n_dim = b_ref.shape[1]
     m_loc = m_tot // n
-    bm, bn, bk = _blocks(cfg, m_loc, n_dim, k_loc)
+    bm, bn, bk = tile[:3]
     gemm = gemm_add_pipeline(bm, bn, bk, m_loc, n_dim, k_loc, acc_ref, out_dtype, 0)
     gemm_add = gemm_add_pipeline(bm, bn, bk, m_loc, n_dim, k_loc, acc_ref, out_dtype, 1)
 
@@ -186,7 +191,7 @@ def _gemm_rs_ring_kernel(
 
 def _gemm_rs_ring_chunked_kernel(
     a_ref, b_ref, out_ref, comp_buf, recv_buf, acc_ref, send_sems, recv_sems,
-    sig_sems, *, axis: str, n: int, cfg: GemmRSConfig, out_dtype, spans,
+    sig_sems, *, axis: str, n: int, tile: GemmTile, out_dtype, spans,
 ):
     """Chunk-granular fused ring GEMM-RS (ISSUE 3 tentpole): step ``s``
     produces, fused-adds, and forwards its partial chunk in ``len(spans)``
@@ -198,9 +203,8 @@ def _gemm_rs_ring_chunked_kernel(
     m_tot, k_loc = a_ref.shape
     n_dim = b_ref.shape[1]
     m_loc = m_tot // n
-    bn = pick_block(n_dim, cfg.block_n)
-    bk = pick_block(k_loc, cfg.block_k)
-    bms = [pick_block(rows, cfg.block_m) for _, rows in spans]
+    bn, bk = tile.bn, tile.bk
+    bms = [pick_block(rows, tile.bm) for _, rows in spans]
     bm_max = max(bms)
     gemms, gemm_adds = [], []
     for (_, rows), bm_j in zip(spans, bms):
@@ -420,40 +424,60 @@ def _gemm_rs_fused(
         )
     # accept the standalone reduce-scatter's method name as an alias
     method = {"scatter_reduce": "scatter"}.get(method, method)
-    bm, bn, _ = _blocks(cfg, m_loc, n_dim, k_loc)
-    kernels = {"scatter": _gemm_rs_scatter_kernel, "ring": _gemm_rs_ring_kernel}
-    if method not in kernels:
+    if method not in ("scatter", "ring"):
         raise ValueError(f"unknown gemm_rs method: {method!r} (want scatter|ring)")
-    kernel = kernels[method]
     n_steps = n - 1
-    chunks = max(1, int(cfg.chunks_per_shard))
-    # quantize spans to the MXU row tile (see chunk_schedule / ag_gemm)
-    spans = chunk_schedule(
-        m_loc, chunks,
-        quantum=pick_block(m_loc, min(cfg.block_m, max(1, m_loc // chunks))),
-    )
     sem_shapes = [
         pltpu.SemaphoreType.DMA((n_steps,)),
         pltpu.SemaphoreType.DMA((n_steps,)),
     ]
-    kern = functools.partial(kernel, axis=axis, n=n, cfg=cfg, out_dtype=out_dtype)
-    acc_bm = bm
-    if method == "ring" and len(spans) > 1:
-        # chunk-granular ring (the scatter method's puts are single-hop —
-        # chunking buys no cross-hop pipelining there)
+    blocks = functools.partial(
+        gemm_tile, cfg, m_loc, n_dim, k_loc, in_dtype=a.dtype,
+        out_dtype=out_dtype,
+    )
+    if method == "scatter":
+        # (the scatter method's puts are single-hop — chunking buys no
+        # cross-hop pipelining there)
+        tile, tile_own = blocks(n_adds=0), blocks(n_adds=n_steps)
         kern = functools.partial(
-            _gemm_rs_ring_chunked_kernel, axis=axis, n=n, cfg=cfg,
-            out_dtype=out_dtype, spans=spans,
+            _gemm_rs_scatter_kernel, axis=axis, n=n, tile=tile,
+            tile_own=tile_own, out_dtype=out_dtype,
         )
-        acc_bm = max(pick_block(rows, cfg.block_m) for _, rows in spans)
-        sem_shapes = [
-            pltpu.SemaphoreType.DMA((n_steps, len(spans))),
-            pltpu.SemaphoreType.DMA((n_steps, len(spans))),
-            pltpu.SemaphoreType.REGULAR((n_steps, len(spans))),
-        ]
+        accs = [(tile.bm, tile.bn), (tile_own.bm, tile_own.bn)]
+        # both accumulators live for the whole kernel, one pipeline at a time
+        vmem = max(
+            tile.vmem_limit_bytes + 4 * tile_own.bm * tile_own.bn,
+            tile_own.vmem_limit_bytes + 4 * tile.bm * tile.bn,
+        )
+        tag = tile.tag + (tile_own.tag if tile_own[:3] != tile[:3] else "")
+    else:
+        # one tile for the ring's steps: all but the first fuse one add
+        tile = blocks(n_adds=1)
+        spans, chunk_tile = gemm_chunk_spans(cfg, tile, m_loc)
+        kern = functools.partial(
+            _gemm_rs_ring_kernel, axis=axis, n=n, tile=tile, out_dtype=out_dtype
+        )
+        acc_bm = tile.bm
+        if len(spans) > 1:
+            # chunk-granular ring
+            kern = functools.partial(
+                _gemm_rs_ring_chunked_kernel, axis=axis, n=n, tile=chunk_tile,
+                out_dtype=out_dtype, spans=spans,
+            )
+            acc_bm = max(pick_block(rows, chunk_tile.bm) for _, rows in spans)
+            sem_shapes = [
+                pltpu.SemaphoreType.DMA((n_steps, len(spans))),
+                pltpu.SemaphoreType.DMA((n_steps, len(spans))),
+                pltpu.SemaphoreType.REGULAR((n_steps, len(spans))),
+            ]
+        accs = [(acc_bm, tile.bn)]
+        vmem = tile.vmem_limit_bytes
+        tag = tile.tag
     outs = dist_pallas_call(
         kern,
         name=f"gemm_rs_{method}",
+        trace_tag=tag,
+        vmem_limit_bytes=vmem,
         out_shape=(
             jax.ShapeDtypeStruct((m_loc, n_dim), out_dtype),
             # Workspace as outputs: how a kernel gets private HBM, and the
@@ -467,7 +491,7 @@ def _gemm_rs_fused(
         ],
         out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY) for _ in range(3)),
         scratch_shapes=[
-            pltpu.VMEM((acc_bm, bn), jnp.float32),
+            *(pltpu.VMEM(acc, jnp.float32) for acc in accs),
             *sem_shapes,
         ],
         cost_estimate=pl.CostEstimate(
@@ -530,6 +554,11 @@ def gemm_rs_op(
 # ≙ the reference's tune space for gemm_rs (gemm_reduce_scatter.py contexts);
 # block_m tiles the per-destination M-chunk, which is M/n — smaller than the
 # AG-GEMM tiles for the same problem.
+# Only `gemm_rs_op` called WITHOUT a config reads this list (the autotuner's
+# sweep of the whole op, docs/autotuner.md). The served path calls `gemm_rs`
+# with config=None and never comes here: its tile is `ops.common.gemm_tile`'s
+# rule, from the call's shape. Each candidate below is an explicit config
+# and runs as written.
 # FIRST entry = best-known config (applied sweep-free under
 # TDT_AUTOTUNE_POLICY=cached_or_first): the swept winner at the bench
 # shape M=8192 K=14336 N=4096.
