@@ -147,7 +147,8 @@
 # stream byte-identical to a non-speculative run, and the quick
 # speculative soak campaign — self-draft speculation × scheduled draft
 # corruption × a straggler shrink + prefix replay mid-speculation —
-# must come up green with a bit-identical seeded replay
+# must come up green (the quick cell, on a world of two since PR 44) with
+# a bit-identical seeded replay (the slow cell, on four)
 # (resilience/soak.py SoakSpec.speculative; the full set rides
 # scripts/chaos_soak.py).
 #

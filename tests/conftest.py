@@ -10,14 +10,28 @@ need more, and real-TPU runs are unaffected.
 
 Runtime budget: the driver runs tier-1 (`-m 'not slow'`) with six xdist
 workers and `--dist loadfile` inside 1470 s, so the wall time is at least
-the slowest FILE, and a run cut by the limit counts only as far as it got.
-What a cell costs is interpreted STEPS: every interpreted pallas_call pays
-~44 ms of host machinery (io_callbacks plus per-call shared-memory setup
-across virtual devices), so a decode step of a two-layer model on the
-4-device mesh is seconds, while a program's trace and compile are about a
-tenth of the test that builds it (callback-bearing executables are not
-cacheable on disk; the keyed `jit_shard_map` cache shares them inside a
-process). The rules (PR 30 brought the suite to them):
+the slowest FILE, and a run cut by the limit counts only as far as it got
+(the driver's run of PR 43's tree was: 918 of 980 counted, which fails any
+PR by the floor). What a cell costs is interpreted STEPS where devices
+talk: every interpreted pallas_call pays ~44 ms of host machinery
+(io_callbacks plus per-call shared-memory setup across virtual devices),
+so a decode step of a two-layer model on the 4-device mesh is seconds (a
+third of that on two devices), while a program's trace and compile are
+about a tenth of the test that builds it. On ONE device (the plan
+families' toys) it is the reverse: a round is 0.3-1 s and every distinct
+program (the step, a prefill bucket, a forward at one length, a
+configuration changed in one field) is 5-10 s of tracing, lowering and
+compiling, so such a file costs the programs it builds
+(callback-bearing executables are not cacheable on disk; the keyed
+`jit_shard_map` cache shares them inside a process). The record, each the
+driver's command on its builder's machine: PR 30 brought the suite from
+1257 s to 763-871 s (4115-4522 test-seconds summed); PR 43's tree took
+1221 s (6787 summed; three family files at 388-466 s each); PR 44 brought
+it to 784 s (4371 summed; 992 passed; heaviest tests/test_chip_compile.py
+at 287 s, then test_flash_decode.py 212, test_sparse_mla_moe.py 161,
+test_emitter.py 159: the three that stand over a fifth of the NEW wall
+time and are the next to split). One run reads 15-20% off another on a
+shared machine: compare two trees in one hour. The rules:
 
 - one geometry a file: the tests of a file share one `cfg` / mesh /
   `s_max` through module-scoped fixtures, so each program is built once,
@@ -27,16 +41,28 @@ process). The rules (PR 30 brought the suite to them):
 - the smallest layer count and the shortest answers that still cover the
   property: two layers where a cache bit has to come out of attention, one
   where tokens are compared; answers just long enough to cross a decode
-  round (and, on the 4-device mesh, onto the second PE's rows);
+  round onto the second PE's rows; and the smallest MESH that has the
+  property: a cell that compares tokens between admission forms, or a
+  campaign's streams with a clean run's, needs a second PE, not four
+  (`mesh2` below, `SoakSpec(world=2)`); four stay where the
+  property is theirs (the ranged model tier, a shrink that must skip the
+  mesh of three, a chain that spans PEs);
 - the smallest campaign that still fires every fault it asserts on
   (`resilience/soak.py` fails a campaign whose scheduled fault never
   fired, so a campaign cut too far fails loudly, not vacuously); a replay
   cell reruns the green cell's spec, not a third campaign; the long sets
   live under `-m soak`;
+- a plan family's tests are a `Family` descriptor for
+  tests/family_tier.py plus what only that family has (its kernels against
+  their twins, its plan and parameter counts): the tier holds the fixtures,
+  the cases every family shows and their SIZE (tests/test_docs_refs.py
+  fails a file that loads a program of perfbench/programs beside it). A new
+  family file may cost 120 s as one of six processes: count its programs;
 - no file over a fifth of the run's wall time: split it along its tiers
   (or, where the tier is one parametrised test, a file a cell:
   ranged_model_tier.py), and keep `_LONG_POLES` in the order of the last
-  run's record."""
+  run's record. tests/test_chip_compile.py is the one file that cannot be
+  split (its docstring says why) and stands first there."""
 
 import os
 import signal
@@ -81,38 +107,40 @@ def pytest_configure(config):
     )
 
 
-# Heaviest files first: every file over 1/80 of the summed test-seconds
-# (twice as fine as the 1/40 a long pole starts at: a 100 s file that
-# sorts late in the alphabet starts late too) in PR 30's run of the
-# driver's command on its own tree before its last (six workers, 4522 s
-# summed, 871 s wall; the last read 4115 s and 763 s in the same order
-# but for neighbours), longest first. Under `--dist loadfile` xdist hands whole files
-# to workers in collection order, i.e. alphabetically: a multi-minute
-# file that sorts late starts late and becomes the wall time. Everything
-# not named keeps its order. Re-read the order from a run's `--junitxml`
-# when a file grows (tests/test_docs_refs.py holds the names to files
-# that exist).
+# Heaviest files first: every file over 1/160 of the summed test-seconds,
+# longest first, from the `--junitxml` of PR 44's run of the driver's
+# command on its own tree (six workers; the docstring above has its sums).
+# Under `--dist loadfile` xdist hands whole files to workers in collection
+# order, i.e. alphabetically: a multi-minute file that sorts late starts
+# late and becomes the wall time. And a worker holds its NEXT file while it
+# runs one, which no idle worker can take from it, so the run ends a file
+# after its sum says: what runs last has to be small (hence 1/160, ~27 s,
+# where 1/80 left files of a minute to the alphabet's end and a tail of
+# ~100 s behind an evenly spread sum). Everything not named keeps its
+# order. Re-read the order from a run's `--junitxml` when a file grows
+# (tests/test_docs_refs.py holds the names to files that exist).
 _LONG_POLES = (
-    "test_spec_soak.py", "test_ranged_engine.py", "test_window_moe.py",
-    "test_prerouted_moe.py",    # PR 39: ~as heavy as test_window_moe.py
-    "test_sparse_mla_moe.py",   # PR 41: ~350 s alone (a toy serve of 52 rounds)
-    # PR 43: 229 s of a 920 s run (+3 compiles at the TP=4 cell's widths,
-    # and the rule's larger tiles compile in 8-10 s where the old took 2)
+    # one file by its own docstring (one process loads libtpu), +3-4
+    # compiles a PR: first while it is the heaviest
     "test_chip_compile.py",
-    "test_emitter.py",
-    "test_mla_moe.py", "test_disagg.py", "test_ranged_batcher.py",
-    "test_serving.py", "test_prefill_work.py", "test_prefix_cache_soak.py",
-    "test_prefix_cache.py", "test_disagg_soak.py", "test_ranged_prefill.py",
-    "test_chunked_prefill.py", "test_flash_decode.py",
-    "test_ranged_kernel.py", "test_overload.py", "test_moe_pipeline.py",
-    "test_prefix_cache_chaos.py", "test_spec_serving.py",
-    "test_ragged_pipeline.py", "test_ranged_contiguous.py",
-    "test_flight_recorder.py", "test_recovery.py", "test_fp8.py",
-    "test_ssm_hybrid.py", "test_lookahead.py", "test_gemm_rs.py",
-    "test_chip_smoke.py",
-    "test_ranged_paged.py", "test_ring_attention.py",
-    "test_gate_up_layout.py", "test_moe.py", "test_ag_gemm.py",
-    "test_fleet.py", "test_ragged.py",
+    "test_flash_decode.py", "test_sparse_mla_moe.py", "test_emitter.py",
+    "test_window_moe.py", "test_prerouted_moe.py", "test_disagg.py",
+    "test_ranged_prefill.py", "test_disagg_soak.py", "test_serving.py",
+    "test_ranged_contiguous.py", "test_ranged_kernel.py",
+    "test_ragged_pipeline.py", "test_lookahead.py", "test_integrity.py",
+    "test_overload.py", "test_moe_pipeline.py", "test_fp8.py",
+    "test_ranged_paged.py", "test_recovery.py", "test_ssm_hybrid.py",
+    "test_spec_serving.py", "test_mla_moe.py", "test_gate_up_layout.py",
+    "test_ring_attention.py", "test_spec_soak.py", "test_gemm_rs.py",
+    "test_chip_smoke.py", "test_recovery_soak.py", "test_ragged.py",
+    "test_chunked.py", "test_chunked_prefill.py", "test_fleet.py",
+    "test_ranged_batcher.py", "test_prefill_work.py",
+    "test_prefix_cache.py", "test_flight_recorder.py",
+    "test_chunked_a2a.py", "test_moe.py", "test_overlap_structure.py",
+    "test_comm_jitter.py", "test_prefix_cache_chaos.py", "test_ag_gemm.py",
+    "test_gemm_tiles.py", "test_races.py", "test_reduce_scatter.py",
+    "test_prefix_cache_soak.py", "test_dcn.py", "test_live_prefix.py",
+    "test_allgather.py",
 )
 
 
@@ -230,6 +258,11 @@ def mesh2x4() -> Mesh:
 @pytest.fixture(scope="session")
 def mesh4() -> Mesh:
     return Mesh(np.array(jax.devices()[:4]), ("tp",))
+
+
+@pytest.fixture(scope="session")
+def mesh2() -> Mesh:
+    return Mesh(np.array(jax.devices()[:2]), ("tp",))
 
 
 @pytest.fixture(scope="session")

@@ -7,7 +7,9 @@ What these files cost is interpreted steps, not compiles (a step on the
 4-device mesh is seconds, a program's trace and compile a tenth of its
 test): the model tier keeps two layers, because a one-layer cache holds
 no bit that attention produced; the batcher and serving tiers compare
-TOKENS, which one layer's attention already decides, and serve answers
+TOKENS, which one layer's attention already decides, on the smallest mesh
+that has a second PE (conftest's ``mesh2``: a step there is a third of the
+4-device step; the model tier keeps the mesh of four), and serve answers
 just long enough to cross a decode round on the second PE."""
 
 import jax
@@ -70,7 +72,8 @@ def _put(mesh, tree, specs):
     )
 
 
-BT_SMAX = 32
+# the cache is sharded by rows: 8 a PE on the batcher tiers' mesh of two
+BT_SMAX = 16
 
 
 @pytest.fixture(scope="module")
@@ -92,18 +95,18 @@ def _bt_run(model, mesh, reqs, **kw):
 
 
 def _mk(uid, prompt, new=4, **kw):
-    # 8-token prompts fill the first PE's rows of the 4-device mesh: the
-    # answer's rows land on the second
+    # 8-token prompts fill the first PE's rows: the answer's rows land on
+    # the second
     return Request(list(prompt), max_new_tokens=new, uid=uid, **kw)
 
 
 @pytest.fixture(scope="module")
-def tok_fed(model1, mesh4, bt_prompts):
+def tok_fed(model1, mesh2, bt_prompts):
     """The token-fed contiguous batcher's answers to p1 ("a") and p2 ("c"):
     the reference of the batcher tier's byte-identity class, served once a
     file."""
     p1, p2 = bt_prompts
-    return _bt_run(model1, mesh4, [_mk("a", p1), _mk("c", p2)])[0]
+    return _bt_run(model1, mesh2, [_mk("a", p1), _mk("c", p2)])[0]
 
 
 def _serve(model, mesh, reqs, serving=None, **kw):

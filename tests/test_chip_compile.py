@@ -9,7 +9,13 @@ time. A compile that passes is not a run: ``chip_smoke.py`` is the run.
 The topology is described inside a module-scoped fixture, never at import
 (one process at a time may load libtpu; under xdist only the worker that
 is handed this file may do so), and everything built from it is built in
-fixtures or tests. All cases live in this one file for the same reason.
+fixtures or tests. All cases live in this one file for the same reason:
+tried in PR 44 with two processes that describe the topology at once, a
+second one compiles beside the first only under
+``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` (the driver's command sets it,
+scripts/run_tier1.sh does not); without it the second fails on
+``/tmp/libtpu_lockfile``. So the file is not split, and is the first of
+tests/conftest.py ``_LONG_POLES`` while it is the heaviest.
 """
 
 import functools

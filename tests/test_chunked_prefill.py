@@ -17,28 +17,28 @@ from ranged_helpers import (
 
 
 def test_chunked_composes_with_paged_and_px(
-        mesh4, model1, bt_prompts, tok_fed):
+        mesh2, model1, bt_prompts, tok_fed):
     """Chunked admission over the paged cache, and chunked × prefix-cache
     together, stay in the byte-identity class."""
     p1, p2 = bt_prompts
     cp_on, _ = _bt_run(
-        model1, mesh4, [_mk("a", p1)], prefill=True, prefill_chunk_tokens=3,
+        model1, mesh2, [_mk("a", p1)], prefill=True, prefill_chunk_tokens=3,
         page_size=4,
     )
     assert cp_on["a"] == tok_fed["a"]
     reqs = lambda: [_mk("a", p1), _mk("b", p1), _mk("c", p2)]
     o_pxt, _ = _bt_run(
-        model1, mesh4, reqs(), page_size=4, prefix_cache=PrefixCacheConfig()
+        model1, mesh2, reqs(), page_size=4, prefix_cache=PrefixCacheConfig()
     )
     cpx_on, _ = _bt_run(
-        model1, mesh4, reqs(), page_size=4,
+        model1, mesh2, reqs(), page_size=4,
         prefix_cache=PrefixCacheConfig(), prefill=True,
         prefill_chunk_tokens=2,
     )
     assert cpx_on == o_pxt
 
 
-def test_chunked_interleaves_decode(mesh4, model1, bt_prompts, tok_fed):
+def test_chunked_interleaves_decode(mesh2, model1, bt_prompts, tok_fed):
     """A long prompt chunking at ct=2 while a neighbor slot decodes:
     the neighbor makes progress during the chunk steps (the scheduling
     point of the whole feature) and the long request's tokens still
@@ -46,7 +46,7 @@ def test_chunked_interleaves_decode(mesh4, model1, bt_prompts, tok_fed):
     cfg, params = model1
     p1, p2 = bt_prompts
     bt = ContinuousBatcher(
-        cfg, params, mesh4, s_max=BT_SMAX, prefill=True,
+        cfg, params, mesh2, s_max=BT_SMAX, prefill=True,
         prefill_chunk_tokens=2,
     )
     # an answer long enough to still be decoding while "long" chunks

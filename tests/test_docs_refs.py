@@ -9,11 +9,13 @@ the document runs (``python X.py``). The documents also cite the upstream
 reference's module files by bare name, which is why a bare ``*.py`` in
 prose is not held to this tree. A bare name is looked for at the root and
 beside the document. Skipped by rule, never by list: glob and placeholder
-patterns, absolute paths, and paths under a directory that ``.gitignore``
-lists. Where a document names a test (``tests/test_x.py::test_y``) the
+patterns, absolute paths, and paths that ``.gitignore`` lists, by
+directory or by pattern (a build's output, e.g. ``csrc/*.so``: there only
+once some test has built it). Where a document names a test (``tests/test_x.py::test_y``) the
 file has to define it. A deleted script that a README still tells its
 reader to run fails here (PR 30 removed ~3.7 k lines of such)."""
 
+import fnmatch
 import pathlib
 import re
 
@@ -38,9 +40,11 @@ _RUN = re.compile(r"[\w-]+\.py")
 _PATTERN_CHARS = set("*?<>{}$")
 
 
-def _ignored_dirs() -> set[str]:
+def _ignored() -> tuple[set[str], list[str]]:
+    """``.gitignore``'s directories, and its other lines as patterns."""
     lines = (ROOT / ".gitignore").read_text().split()
-    return {ln.strip("/") for ln in lines if ln.endswith("/")}
+    return ({ln.strip("/") for ln in lines if ln.endswith("/")},
+            [ln for ln in lines if not ln.endswith("/")])
 
 
 def _named_paths(text: str):
@@ -64,11 +68,12 @@ def _named_paths(text: str):
 
 
 def _missing(document: str) -> list[str]:
-    ignored = _ignored_dirs()
+    ignored, built = _ignored()
     here = (ROOT / document).parent
     missing = set()
     for token, bare, test in _named_paths((ROOT / document).read_text()):
-        if ignored & set(pathlib.PurePosixPath(token).parts):
+        if ignored & set(pathlib.PurePosixPath(token).parts) or any(
+                fnmatch.fnmatch(token, pattern) for pattern in built):
             continue
         homes = (ROOT, here) if bare else (ROOT,)
         if not any((home / token).exists() for home in homes):
@@ -113,6 +118,33 @@ def test_long_poles_are_test_files():
     assert len(set(conftest._LONG_POLES)) == len(conftest._LONG_POLES)
     for name in conftest._LONG_POLES:
         assert (ROOT / "tests" / name).is_file(), name
+
+
+def test_a_plan_familys_tests_are_a_descriptor_for_the_tier():
+    """The rule that keeps a family's tests from being a copy of the last
+    family's (tests/conftest.py, runtime budget): a test file that loads a
+    module of ``perfbench/programs`` takes its cases from
+    tests/family_tier.py and describes its family there (``FAMILY``), or
+    is named in the tier's short list with its reason."""
+    import family_tier
+
+    tests = ROOT / "tests"
+    assert all((tests / name).is_file() and why
+               for name, why in family_tier.NOT_A_FAMILY.items())
+    loads = re.compile(r"load_module\(\s*\"programs\"")
+    families = []
+    for path in sorted(tests.glob("test_*.py")):
+        text = path.read_text()
+        if path.name in family_tier.NOT_A_FAMILY:
+            continue
+        takes = re.search(r"^from family_tier import ", text, re.M)
+        if loads.search(text):
+            assert takes, f"{path.name} loads a program beside the tier"
+        if takes:
+            assert re.search(r"^FAMILY = Family\(", text, re.M), path.name
+            assert not re.search(r"^def\s+_ref_logits\(", text, re.M), path.name
+            families.append(path.name)
+    assert len(families) >= 5, families
 
 
 def test_known_failures_name_tests_that_exist():

@@ -10,16 +10,16 @@ import pytest
 from ranged_helpers import _bt_run, _mk, _serve, bt_prompts, model1, tok_fed
 
 
-def test_chunked_prefill_byte_identity(mesh4, model1, bt_prompts, tok_fed):
+def test_chunked_prefill_byte_identity(mesh2, model1, bt_prompts, tok_fed):
     """Chunked admission (prefill_chunk_tokens) vs token-fed vs bulk
     prefill: one byte-identity class — and the swept-work counter prices
     the chunk strips strictly below the bulk bucket rectangle."""
     p1, p2 = bt_prompts
     reqs = lambda: [_mk("a", p1), _mk("c", p2)]
     c_on, bt_on = _bt_run(
-        model1, mesh4, reqs(), prefill=True, prefill_chunk_tokens=3
+        model1, mesh2, reqs(), prefill=True, prefill_chunk_tokens=3
     )
-    c_off, bt_off = _bt_run(model1, mesh4, reqs(), prefill=True)
+    c_off, bt_off = _bt_run(model1, mesh2, reqs(), prefill=True)
     assert c_on == tok_fed == c_off
     # 8-token prompt: bulk = 8×8 rectangle; chunks (0,3)(3,6)(6,8) sweep
     # 4·3 + 4·6 + 2·8 = 52 pairs — chunking does strictly less work
@@ -28,7 +28,7 @@ def test_chunked_prefill_byte_identity(mesh4, model1, bt_prompts, tok_fed):
     assert bt_on.prefill_tokens_total == bt_off.prefill_tokens_total == 16
 
 
-def test_engine_prefill_work_charge(mesh4, model1, bt_prompts):
+def test_engine_prefill_work_charge(mesh2, model1, bt_prompts):
     """virtual_prefill_work_s prices the swept rectangle on the engine
     clock: the bulk arm charges bucket² pairs where the chunked arm
     charges its strips — strictly less virtual time for the same tokens
@@ -38,7 +38,7 @@ def test_engine_prefill_work_charge(mesh4, model1, bt_prompts):
     p1, _ = bt_prompts
 
     def elapsed(serving, **kw):
-        eng = _serve(model1, mesh4, [_mk("a", p1)], serving=serving, **kw)
+        eng = _serve(model1, mesh2, [_mk("a", p1)], serving=serving, **kw)
         return eng.clock.monotonic(), eng.results["a"].tokens
 
     t_bulk, tok_bulk = elapsed(

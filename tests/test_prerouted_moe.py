@@ -2,106 +2,104 @@
 (models/window_moe.py with SmallThinker's published form: pre-norm, no q/k
 norm, a router that reads the layer's input before attention, softmax over
 the chosen logits, ReLU-gated experts, no shared expert, no dense layer)
-at toy widths on the CPU (window 8, page 4, so a ring of 3 pages; a group
-of 7 query heads a kv head; 8 experts, top-2), each piece against the
-plain reference's equations
+at toy widths on the CPU (a full and a window layer; window 8, page 4, so a
+ring of 3 pages; a group of 7 query heads a kv head; 8 experts, top-2),
+each piece against the plain reference's equations
 (perfbench/references/smallthinker_prerouted_moe.py, imported as it
 stands: it shares no code with the program), and the tiled prefill kernel
-(ops/flash_prefill.py) against plain attention. Weights are float32 here,
-so the tolerances below are those of float32 arithmetic reordered (tiled
-vs masked attention, online vs whole softmax, grouped vs dense expert
-sums), not of bf16: the lower-precision control, a router on the wrong
-rows and a SiLU gate are all far outside them."""
+(ops/flash_prefill.py) against plain attention. The family's contract and
+its size are tests/family_tier.py's; this file names the family and keeps
+what only it has. ``MATERIALIZED_UP_TO`` is 0 for the file (``tiled``):
+the toy buckets run through the tiled kernel, as the cell's 8192-row
+bucket runs (one test below holds the two forms to one result). Weights
+are float32 here, so the tolerances are those of float32 arithmetic
+reordered (tiled vs masked attention, online vs whole softmax, grouped vs
+dense expert sums), not of bf16: the lower-precision control, a router on
+the wrong rows and a SiLU gate are all far outside them."""
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
-from triton_dist_tpu.models import ContinuousBatcher, Request
-from triton_dist_tpu.models import gated_experts, window_moe
+from triton_dist_tpu.models import Request, gated_experts, window_moe
 from triton_dist_tpu.models.decode import WindowPagedKVCacheSpec
 from triton_dist_tpu.ops.flash_prefill import (
     blocks_walked, default_blocks, flash_prefill, xla_flash_prefill,
 )
 
-PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
-if PERFBENCH not in sys.path:
-    sys.path.insert(0, PERFBENCH)
-from harness import cells, correct  # noqa: E402
+from family_tier import (  # noqa: F401
+    TOL, Family, _ref_logits, adapter, family, forward_logits, make_batcher,
+    one_device, pytest_generate_tests, recorded_spans, ref, served, sized,
+    tiled_kernels_at_toy_buckets, toy,
+    test_an_admission_runs_and_writes_the_admitted_slot_only,
+    test_batcher_prefill_then_decode_matches_reference,
+    test_every_part_of_a_pass_says_which_part_it_is,
+    test_full_forward_matches_reference,
+    test_the_lower_precision_control_is_far_outside_the_tolerances,
+)
+from family_tier import (  # noqa: F401
+    test_shares_of_the_bank_add_up_to_the_layer
+    as test_the_shares_of_the_bank_add_up_to_the_whole_layer,
+)
 
-from admission_helpers import check_admission  # noqa: E402
-from scope_helpers import check_pass  # noqa: E402
-
-# float32 everywhere: what is left is the order of the sums
-TOL = dict(rtol=2e-4, atol=2e-4)
-WINDOW, PAGE, S_MAX = 8, 4, 48
-LAYOUT = [0, 1, 1, 1]
-TOY = dict(
-    hidden=64, ffn=32, n_layers=4, n_q_heads=14, n_kv_heads=2, head_dim=8,
+WINDOW, PAGE, S_MAX = 8, 4, 32
+LAYOUT = [0, 1]
+TOY = sized(dict(
+    hidden=64, ffn=32, n_layers=2, n_q_heads=14, n_kv_heads=2, head_dim=8,
     vocab=128, rope_theta=10000.0, norm_eps=1e-6, dtype="float32",
     sliding_window_layout=LAYOUT, rope_layout=LAYOUT,
     sliding_window_size=WINDOW, moe_num_primary_experts=8,
     moe_num_active_primary_experts=2, moe_ffn_hidden_size=32,
     moe_primary_router_apply_softmax=True, norm_topk_prob=True,
     engine=dict(slots=2, s_max=S_MAX, page=PAGE, max_queue=64),
-)
-TOY["sizes"] = {k: TOY[k] for k in cells.SIZE_KEYS}
+))
 SIZES = TOY["sizes"]
-POOLS = {"k_full": "block_table", "v_full": "block_table",
-         "k_win": "block_table_win", "v_win": "block_table_win"}
 
 
-@pytest.fixture(scope="module", autouse=True)
-def tiled_prefill_at_toy_size():
-    """The toy buckets are far under ``MATERIALIZED_UP_TO``: this file
-    runs them through the tiled kernel, as the cell's 8192-row bucket
-    runs (one test below holds the two forms to one result)."""
-    was, window_moe.MATERIALIZED_UP_TO = window_moe.MATERIALIZED_UP_TO, 0
-    yield
-    window_moe.MATERIALIZED_UP_TO = was
+def _admitted(counters, bucket):
+    assert counters[len(gated_experts.MOE_STATS):] == [0, 0, 0]
 
 
-@pytest.fixture(scope="module")
-def ref():
-    mod = cells.load_module("references", "smallthinker_prerouted_moe")
-    mod.configure(TOY)
-    return mod
-
-
-@pytest.fixture(scope="module")
-def adapter():
-    return cells.load_module("programs", "tdt_prerouted_moe")
-
-
-@pytest.fixture(scope="module")
-def toy(ref, adapter):
-    """``(cfg, program params, plain layers, outer)`` from one seed."""
-    cfg = adapter.model_config(TOY)
-    key = ref.seed_key(11)
-    plain = [ref.layer_weights(key, li, SIZES) for li in range(TOY["n_layers"])]
-    outer = ref.outer_weights(key, SIZES)
-    params = dict(outer, layers=[adapter.pack_layer(w, cfg) for w in plain])
-    return cfg, params, plain, outer
-
-
-def _ref_logits(ref, plain, outer, tokens, control=False, block=None):
-    """The reference's logits at every position of ``tokens [n, T]``."""
-    x = outer["embed"][tokens].astype(jnp.float32)
-    for li, w in enumerate(plain):
-        x = ref.layer(x, w, SIZES, li, control, block)
-    n, t = tokens.shape
-    return np.asarray(ref.head(x, outer, jnp.zeros(n, jnp.int32), t, SIZES,
-                               control))
-
-
-def _mesh(cfg):
-    return Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
+FAMILY = Family(
+    program="tdt_prerouted_moe", reference="smallthinker_prerouted_moe",
+    model=window_moe, toy=TOY, spec=WindowPagedKVCacheSpec, seed=11,
+    tiled=True,
+    layer=lambda ref, x, w, li, control, block: ref.layer(
+        x, w, SIZES, li, control, block),
+    # against a ring of 12 positions: a context below the window
+    # throughout; a prompt shorter than the window decoding across a ring
+    # wrap; a prompt longer than the ring (its prefill wraps once, lands
+    # the last 12 true rows of a 16-row bucket at their ring addresses),
+    # then decoding across the next wrap; a slot re-admitted onto a stale
+    # ring, which it wraps (two buckets, 8 and 16: a bucket more is a
+    # program more). The reference reads queries in blocks of 8
+    cases={"below": (5, 2), "past": (6, 8, 3 * PAGE),
+           "wrapped": (14, 11, 2 * 3 * PAGE), "readmitted": (9, 5, 3 * PAGE)},
+    block=8,
+    # tiled attention at lengths below, at and past the window; the
+    # reference's blocked attention is its whole attention
+    forward={"5": (5, None), "8": (8, None), "19": (19, None)},
+    # the last slot, a prompt shorter than its bucket and one longer than
+    # the ring
+    admissions=((-1, 5, 8), (-1, 14, 16)),
+    pools={"k_full": "block_table", "v_full": "block_table",
+           "k_win": "block_table_win", "v_win": "block_table_win"},
+    admitted=_admitted,
+    # no dense MLP, no shared expert; the admission's attention is the
+    # tiled kernel's
+    scopes=frozenset({"attn", "attn/qkv", "attn/kv_write", "attn/out", "ffn",
+                      "ffn/route", "ffn/experts", "head"}),
+    admission_scopes=frozenset({"attn/prefill"}),
+    # softmax over the chosen, ReLU, no shared expert, routing from OTHER
+    # rows than the experts multiply
+    shares=4, prerouted=True,
+    uncut=lambda ref, x, m, w: ref.experts_part(
+        m, ref.combine_weights(x, w, False), w, False),
+)
 
 
 # -- the tiled prefill kernel ----------------------------------------------------
@@ -157,9 +155,7 @@ def test_the_second_block_allocates_no_leaf_it_does_not_have(toy):
     scoring: no dense MLP, no shared expert, no q/k norm scale and no
     choice bias anywhere in the tree; rings of ``ceil(8 / 4) + 1`` pages."""
     cfg, params, _, _ = toy
-    assert window_moe.layer_plan(cfg) == (
-        ("full", "moe"), ("window", "moe"), ("window", "moe"),
-        ("window", "moe"))
+    assert window_moe.layer_plan(cfg) == (("full", "moe"), ("window", "moe"))
     assert (cfg.norm_placement, cfg.qk_norm, cfg.router_rows, cfg.scoring,
             cfg.gate_act) == ("input", False, "layer_input", "softmax", "relu")
     init = window_moe.init_window_moe_params(jax.random.PRNGKey(0), cfg)
@@ -172,8 +168,8 @@ def test_the_second_block_allocates_no_leaf_it_does_not_have(toy):
     spec = WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True)
     cache = spec.init(cfg, 1)
     assert spec.ring(cfg) == 3
-    assert cache["k_full"].shape == (1, 2 * 12, 2, PAGE, 8)
-    assert cache["k_win"].shape == (3, 2 * 3, 2, PAGE, 8)
+    assert cache["k_full"].shape == (1, 2 * 8, 2, PAGE, 8)
+    assert cache["k_win"].shape == (1, 2 * 3, 2, PAGE, 8)
     # K-EXAONE's block is the family's default
     default = window_moe.WindowMoEConfig(
         vocab=8, hidden=8, ffn=8, n_layers=1, n_q_heads=1, n_kv_heads=1,
@@ -185,19 +181,6 @@ def test_the_second_block_allocates_no_leaf_it_does_not_have(toy):
         dataclasses.replace(cfg, gate_act="gelu")
 
 
-@pytest.mark.parametrize("length", [5, 8, 19])
-def test_full_forward_matches_reference(toy, ref, length):
-    """The program's forward (tiled attention, routing issued from the
-    layer's input, grouped GEMMs) at lengths below, at and past the
-    window; the reference's blocked attention is its whole attention."""
-    cfg, params, plain, outer = toy
-    tokens = jax.random.randint(jax.random.PRNGKey(length), (2, length), 0,
-                                cfg.vocab)
-    got = window_moe.forward_logits(cfg, params, tokens)
-    want = _ref_logits(ref, plain, outer, tokens)
-    np.testing.assert_allclose(np.asarray(got), want, **TOL)
-
-
 def test_the_choice_of_prefills_form_changes_no_result(toy, monkeypatch):
     """``prefill_attention`` chooses from the bucket and the window alone:
     the tiled kernel past ``MATERIALIZED_UP_TO`` rows, under it the band
@@ -206,7 +189,7 @@ def test_the_choice_of_prefills_form_changes_no_result(toy, monkeypatch):
     cfg, params, _, _ = toy
     assert window_moe.MATERIALIZED_UP_TO == 0            # this file's fixture
     tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 19), 0, cfg.vocab)
-    tiled = np.asarray(window_moe.forward_logits(cfg, params, tokens))
+    tiled = np.asarray(forward_logits(FAMILY, cfg, params, tokens))
     assert cfg.prefill_blocks(19, 32) is not None
     monkeypatch.setattr(window_moe, "MATERIALIZED_UP_TO", 2048)  # as shipped
     assert cfg.prefill_blocks(19, 32) is None
@@ -220,8 +203,7 @@ def test_the_choice_of_prefills_form_changes_no_result(toy, monkeypatch):
             np.asarray(window_moe.prefill_attention(cfg, kind, q, k, v, lens)),
             np.asarray(xla_flash_prefill(q, k, v, lens, window)), **TOL)
     np.testing.assert_allclose(
-        np.asarray(window_moe.forward_logits(cfg, params, tokens)), tiled,
-        **TOL)
+        np.asarray(forward_logits(FAMILY, cfg, params, tokens)), tiled, **TOL)
     # a window wider than the bucket clips nothing: the causal square
     wide = dataclasses.replace(cfg, window=64)
     np.testing.assert_allclose(
@@ -241,13 +223,13 @@ def test_each_field_of_the_block_is_held_by_the_reference(toy, ref, wrong):
     cfg, params, plain, outer = toy
     assert float(jnp.abs(plain[0]["attn_norm"] - 1).max()) > 0.05
     tokens = jax.random.randint(jax.random.PRNGKey(2), (2, 19), 0, cfg.vocab)
-    want = _ref_logits(ref, plain, outer, tokens)
+    want = _ref_logits(FAMILY, ref, plain, outer, tokens)
     bad = dataclasses.replace(cfg, **wrong)
     if "scoring" in wrong:      # the sigmoid router has a bias leaf
         params = dict(params, layers=[
             dict(p, router_bias=jnp.zeros((8,), jnp.float32))
             for p in params["layers"]])
-    got = np.asarray(window_moe.forward_logits(bad, params, tokens))
+    got = np.asarray(forward_logits(FAMILY, bad, params, tokens))
     assert np.abs(got - want).max() > 50 * TOL["atol"]
 
 
@@ -262,7 +244,7 @@ def test_the_routing_is_issued_before_attention(toy):
     text = str(jax.make_jaxpr(jax.shard_map(
         lambda p, c, t, pos: cfg.decode_step(p, c, t, pos, spec=spec,
                                              interpret=True),
-        mesh=_mesh(cfg), check_vma=False,
+        mesh=one_device(cfg), check_vma=False,
         in_specs=(cfg.param_specs(), spec.specs(cfg), P(), P()),
         out_specs=(P(), spec.specs(cfg), P())))(
         shapes(params), jax.eval_shape(lambda: spec.init(cfg, 1)), i32, i32))
@@ -272,168 +254,22 @@ def test_the_routing_is_issued_before_attention(toy):
     assert order == ["route", "attend"] * cfg.n_layers
 
 
-class _Recording(Request):
-    """A request that keeps every logit row it was sampled from and then
-    takes the best token: logits are compared, not tokens."""
-
-    def sample(self, logits, rng):
-        self.__dict__.setdefault("rows", []).append(np.array(logits))
-        return int(np.argmax(logits))
-
-
-# (prompt, new) against a ring of 12 positions: a context below the window
-# throughout; a prompt shorter than the window decoding across two ring
-# wraps; a prompt longer than the ring (its prefill wraps once, lands the
-# last 12 true rows of a 16-row bucket at their ring addresses), then two
-# more wraps of decoding; a slot re-admitted onto a stale ring
-CASES = {"below": (3, 4), "past": (6, 22), "wrapped": (14, 26),
-         "readmitted": (27, 14)}
-
-
-@pytest.fixture(scope="module")
-def served(toy):
-    """Every case through ONE batcher (2 slots, so slots are re-used):
-    prefill through the tiled kernel into the two kinds of pool, then
-    decode steps through the window and the full kernel, ragged
-    positions."""
-    cfg, params, _, _ = toy
-    batcher = ContinuousBatcher(
-        cfg, params, _mesh(cfg), s_max=S_MAX, page_size=PAGE, prefill=True)
-    assert isinstance(batcher.spec, WindowPagedKVCacheSpec)
-    rng = np.random.default_rng(0)
-    reqs = {
-        name: _Recording(list(rng.integers(0, cfg.vocab, n_prompt)), n_new,
-                         temperature=1.0, uid=name)
-        for name, (n_prompt, n_new) in CASES.items()}
-    for r in reqs.values():
-        batcher.submit(r)
-    return reqs, dict(batcher.run())
-
-
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_batcher_prefill_then_decode_matches_reference(toy, ref, served, case):
-    """Every LOGIT row the batcher sampled from against the reference's
-    full forward over the same sequence (queries in blocks of 8)."""
-    _, _, plain, outer = toy
-    reqs, done = served
-    r, out = reqs[case], done[case]
-    assert len(out) == r.max_new_tokens == len(r.rows)
-    seq = list(r.prompt) + out
-    seq = np.array([seq + [0] * (-len(seq) % 8)])
-    want = _ref_logits(ref, plain, outer, seq, block=8)[0]
-    first = len(r.prompt) - 1
-    np.testing.assert_allclose(
-        np.stack(r.rows), want[first:first + len(out)], **TOL)
-    if case in ("past", "wrapped"):     # the ring wrapped at least twice
-        assert len(r.prompt) + len(out) > 2 * 3 * PAGE
-
-
-@pytest.mark.parametrize("length,bucket", [(5, 8), (14, 16)])
-def test_an_admission_runs_and_writes_the_admitted_slot_only(
-        toy, length, bucket):
-    """A one-hot mask on the last slot, a prompt shorter than its bucket
-    and one longer than the ring: the other slot's pages and rings
-    bit-identical, the admitted slot's rows and logit row the unmasked
-    whole-batch pass's, and one slot's rows counted."""
-    cfg, params, _, _ = toy
-    spec = WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True)
-    counters = check_admission(
-        cfg, params, spec, S_MAX, POOLS, cfg.batch - 1, length, bucket,
-        n_moe=4, tol=TOL, seed=bucket)
-    assert [int(v) for v in counters[len(gated_experts.MOE_STATS):]] == [0, 0, 0]
-
-
-SCOPES = {"attn", "attn/qkv", "attn/kv_write", "attn/out", "ffn",
-          "ffn/route", "ffn/experts", "head"}
-
-
-@pytest.mark.parametrize("which", ["step", "admission"])
-def test_every_part_of_a_pass_says_which_part_it_is(toy, which):
-    """The lowered step and admission carry every scope of this block's
-    row (no dense MLP, no shared expert) and no other ``tdt.`` name; the
-    admission's attention is under ``attn/prefill``."""
-    cfg, params, _, _ = toy
-    spec = WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True)
-    row = SCOPES | ({"attn/prefill"} if which == "admission" else set())
-    check_pass(which, cfg, params, spec, _mesh(cfg), S_MAX, row)
-
-
-def test_the_shares_of_the_bank_add_up_to_the_whole_layer(toy, ref):
-    """The guide's share test under the new gating (softmax over the
-    chosen, ReLU, no shared expert, routing from OTHER rows than the
-    experts multiply): the MLP run once per share of the bank (4 shares
-    of 2 experts) adds up to the whole bank's, which is the reference's."""
-    cfg, params, plain, _ = toy
-    p, w = params["layers"][1], plain[1]
-    k1, k2 = jax.random.split(jax.random.PRNGKey(4))
-    x = jax.random.normal(k1, (24, cfg.hidden), jnp.float32)   # the router's
-    m = jax.random.normal(k2, (24, cfg.hidden), jnp.float32)   # the experts'
-    want = ref.experts_part(m, ref.combine_weights(x, w, False), w, False)
-    whole, stats = gated_experts.moe_mlp(
-        cfg, m, p, 8, routing=gated_experts.route_rows(cfg, x, p, 8))
-    np.testing.assert_allclose(np.asarray(whole), np.asarray(want), **TOL)
-    total, hit = 0.0, 0
-    for first in range(0, 8, 2):
-        share = dataclasses.replace(cfg, experts_held=(first, 2))
-        bank = dict(p, we_gate_up=p["we_gate_up"][first:first + 2],
-                    we_down=p["we_down"][first:first + 2])
-        y, st = gated_experts.moe_mlp(
-            share, m, bank, 8,
-            routing=gated_experts.route_rows(share, x, bank, 8))
-        total, hit = total + y, hit + int(st[1])
-    np.testing.assert_allclose(np.asarray(total), np.asarray(want), **TOL)
-    assert hit == int(stats[1]) == 24 * 2      # every assignment, once
-    # routed on its own rows the layer is another function
-    own, _ = gated_experts.moe_mlp(cfg, m, p, 8)
-    assert np.abs(np.asarray(own) - np.asarray(want)).max() > 50 * TOL["atol"]
-
-
-LIMITS = dict(max_gap=1e-3, mean_gap=1e-4)
-
-
-def test_the_lower_precision_control_is_far_outside_the_tolerances(toy, ref):
-    """The reference as W8A8 int8: its logits differ from the reference's
-    by far more than ``TOL``, and the token it puts first breaks the toy
-    limits, so the comparisons above would catch a lower precision."""
-    cfg, _, plain, outer = toy
-    tokens = jax.random.randint(jax.random.PRNGKey(9), (2, 24), 0, cfg.vocab)
-    want = _ref_logits(ref, plain, outer, tokens)
-    low = _ref_logits(ref, plain, outer, tokens, control=True)
-    assert np.abs(low - want).max() > 50 * TOL["atol"]
-    gap, _ = ref.gaps(jnp.asarray(want), low.argmax(-1))
-    ok, _ = correct.verdict(dict(
-        max_gap=float(gap.max()), mean_gap=float(gap.mean()), failed=0,
-        health_flips=0, tokens_compared=gap.size), LIMITS)
-    assert not ok
-
-
 def test_the_admissions_span_says_what_the_band_saved(toy):
     """``prefill_blocks_live`` / ``prefill_blocks_square`` on
     ``tdt.batcher.admit_prefill``: the key blocks the prompt's band holds
     against the causal square of its bucket, every layer and kv head."""
-    from triton_dist_tpu import config as tdt_config, obs
-    from triton_dist_tpu.obs import ObsConfig
-
     cfg, params, _, _ = toy
-    assert cfg.prefill_blocks(16, 16) == (4 * 2, 4 * 2)   # one block a layer
+    assert cfg.prefill_blocks(16, 16) == (2 * 2, 2 * 2)   # one block a layer
     big = dataclasses.replace(cfg, window=4096)
     live, square = big.prefill_blocks(4100, 8192)
     full, _ = blocks_walked([4100], 8192, 7, None)
     band, sq = blocks_walked([4100], 8192, 7, 4096)
-    assert (live, square) == (2 * (full + 3 * band), 2 * 4 * sq)
-    before = tdt_config.get_config().obs
-    tdt_config.update(obs=ObsConfig(spans=True))
-    obs.reset()
-    try:
-        batcher = ContinuousBatcher(
-            cfg, params, _mesh(cfg), s_max=S_MAX, page_size=PAGE, prefill=True)
+    assert (live, square) == (2 * (full + band), 2 * 2 * sq)
+    with recorded_spans() as by_name:
+        batcher = make_batcher(FAMILY, cfg, params)
         batcher.submit(Request([1, 2, 3, 4, 5], 2, uid="a"))
         batcher.run()
-        spans = obs.spans()
-    finally:
-        tdt_config.update(obs=before)
-        obs.reset()
-    admit = [s.attrs for s in spans if s.name == "tdt.batcher.admit_prefill"]
+        admit = by_name()["tdt.batcher.admit_prefill"]
     assert len(admit) == 1
     assert (admit[0]["prefill_blocks_live"], admit[0]["prefill_blocks_square"]
             ) == cfg.prefill_blocks(5, admit[0]["bucket"])

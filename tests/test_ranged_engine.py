@@ -10,31 +10,39 @@ from triton_dist_tpu.models.prefix_cache import PrefixCacheConfig
 from ranged_helpers import _mk, _serve, bt_prompts, model1
 
 
-def test_engine_px_prefill_byte_identity(mesh4, model1, bt_prompts):
-    """Engine tier: the px+prefill arm and the chunked arm produce the
-    cold engine's exact token streams — greedy AND seeded-sampled."""
+@pytest.fixture(scope="module")
+def streams(mesh2, model1, bt_prompts):
+    """ONE serve an arm (the cold token-fed engine, px + prefill, chunked)
+    of the same four requests: each prompt greedy and seeded-sampled, so
+    that the second reader of a prompt hits the first's pages and samples;
+    ``arm -> {uid: tokens}``."""
     from triton_dist_tpu.serving.engine import ServingConfig
 
     p1, p2 = bt_prompts
+    sampled = dict(temperature=0.8, seed=5)
+    arms = dict(
+        cold={},
+        px=dict(serving=ServingConfig(prefix_cache=PrefixCacheConfig()),
+                page_size=4, prefill=True),
+        chunked=dict(serving=ServingConfig(prefill_chunk_tokens=3),
+                     prefill=True),
+    )
+    out = {}
+    for arm, kw in arms.items():
+        eng = _serve(model1, mesh2, [
+            _mk("a", p1), _mk("b", p1, **sampled), _mk("c", p2),
+            _mk("d", p2, **sampled)], **kw)
+        out[arm] = {u: eng.results[u].tokens for u in "abcd"}
+    return out
 
-    def reqs(sample):
-        kw = dict(temperature=0.8, seed=5) if sample else {}
-        return [_mk("a", p1, **kw), _mk("b", p1, **kw), _mk("c", p2, **kw)]
 
-    for sample in (False, True):
-        cold = _serve(model1, mesh4, reqs(sample))
-        px = _serve(
-            model1, mesh4, reqs(sample),
-            serving=ServingConfig(prefix_cache=PrefixCacheConfig()),
-            page_size=4, prefill=True,
-        )
-        chunked = _serve(
-            model1, mesh4, reqs(sample),
-            serving=ServingConfig(prefill_chunk_tokens=3), prefill=True,
-        )
-        want = {u: cold.results[u].tokens for u in ("a", "b", "c")}
-        assert {u: px.results[u].tokens for u in want} == want, sample
-        assert {u: chunked.results[u].tokens for u in want} == want, sample
+@pytest.mark.parametrize("arm", ["px", "chunked"])
+def test_engine_px_prefill_byte_identity(streams, arm):
+    """Engine tier: the px+prefill arm and the chunked arm produce the
+    cold engine's exact token streams — greedy AND seeded-sampled (the
+    sampled stream of a prompt is not its greedy one, so both are held)."""
+    assert streams[arm] == streams["cold"]
+    assert streams["cold"]["a"] != streams["cold"]["b"]
 
 
 def test_traffic_long_prompt_stream():

@@ -3,34 +3,35 @@ plan of two attention kinds: full layers behind a learned indexer, window
 layers with latent widths of their own on rings, headwise gates, the
 latent rescale) at toy widths on the CPU, each piece against the plain
 reference's equations (perfbench/references/dots_sparse_mla_moe.py,
-imported as it stands: it shares no code with the program). Weights are
-float32 here, so the tolerances are those of float32 arithmetic reordered
-(absorbed vs expanded attention, grouped vs dense expert sums), not of
-bf16, and the selection must then be the reference's to the row: a row of
-``S(t)`` that differs moves an output by about 1 / index_topk of a value
-vector, far over ``TOL`` at top-6, so the logit tests would see ONE; the
-selection tests below count them outright.
+imported as it stands: it shares no code with the program). The family's
+contract and its size are tests/family_tier.py's; this file names the
+family and keeps what only it has. Weights are float32 here, so the
+tolerances are those of float32 arithmetic reordered (absorbed vs expanded
+attention, grouped vs dense expert sums), not of bf16, and the selection
+must then be the reference's to the row: a row of ``S(t)`` that differs
+moves an output by about 1 / index_topk of a value vector, far over
+``TOL`` at top-6, so the logit tests would see ONE; the selection tests
+below count them outright.
 
-``MATERIALIZED_UP_TO`` is 0 for the file: the toy buckets run the tiled
-kernels and the selection's kernels, which a real run uses past 2048 rows
-only (one parametrized case keeps the materialized forms honest).
+``MATERIALIZED_UP_TO`` is 0 for the file (``tiled``): the toy buckets run
+the tiled kernels and the selection's kernels, which a real run uses past
+2048 rows only (one case of the full forward keeps the materialized forms
+honest).
 
-Toy geometry: page 4, window 9 (no multiple of the page: a ring of 4 pages
-= 16 rows), index top-6, s_max 64; contexts run to 53, so a ring wraps
-three times and every full layer selects 6 of up to 53 rows."""
+Toy geometry: two indexed full layers (a dense MLP, then experts: two, so
+that one layer's index keys are not the other's) and a window layer over
+experts; page 4, window 9 (no multiple of the page: a ring of 4 pages = 16
+rows), index top-6, s_max 64; contexts run to 34, so a ring wraps twice
+and a full layer selects 6 of up to 34 rows."""
 
 import dataclasses
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
 
-from triton_dist_tpu.models import ContinuousBatcher, Request, gated_experts
-from triton_dist_tpu.models import mla_moe
+from triton_dist_tpu.models import gated_experts, mla_moe
 from triton_dist_tpu.models.decode import (
     LatentPagedCacheSpec, WindowPagedKVCacheSpec, ring_pages,
 )
@@ -40,20 +41,26 @@ from triton_dist_tpu.ops.mla_decode import (
     _xla_mla_decode, mla_paged_decode, sparse_mla_decode,
 )
 
-PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
-if PERFBENCH not in sys.path:
-    sys.path.insert(0, PERFBENCH)
-from harness import cells  # noqa: E402
+from family_tier import (  # noqa: F401
+    TOL, Family, adapter, family, forward_logits, pytest_generate_tests,
+    random_cache, ref,
+    served, sized, tiled_kernels_at_toy_buckets, toy,
+    test_an_admission_runs_and_writes_the_admitted_slot_only,
+    test_batcher_prefill_then_decode_matches_reference,
+    test_engine_serves_it_and_the_spans_carry_the_counters,
+    test_every_part_of_a_pass_says_which_part_it_is,
+    test_full_forward_matches_reference,
+    test_what_the_kind_cannot_serve_is_refused_by_name,
+)
+from family_tier import (  # noqa: F401
+    test_shares_of_the_bank_add_up_to_the_layer
+    as test_the_eight_shares_of_the_bank_add_up_to_the_uncut_layer,
+)
 
-from scope_helpers import check_pass  # noqa: E402
-
-# float32 everywhere: what is left is the order of the sums
-TOL = dict(rtol=2e-4, atol=2e-4)
 WINDOW, PAGE, S_MAX, TOPK = 9, 4, 64, 6
-KINDS = ["full_attention", "full_attention", "sliding_attention",
-         "sliding_attention", "sliding_attention"]
-TOY = dict(
-    hidden=64, ffn=128, n_layers=5, n_q_heads=4, n_kv_heads=4, head_dim=8,
+KINDS = ["full_attention", "full_attention", "sliding_attention"]
+TOY = sized(dict(
+    hidden=64, ffn=128, n_layers=3, n_q_heads=4, n_kv_heads=4, head_dim=8,
     vocab=128, rope_theta=10000.0, norm_eps=1e-5, dtype="float32",
     num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
     qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
@@ -68,62 +75,73 @@ TOY = dict(
     n_shared_experts=1, first_k_dense_replace=1, routed_scaling_factor=1,
     scoring_func="sigmoid",
     engine=dict(slots=2, s_max=S_MAX, page=PAGE, max_queue=64),
-)
-TOY["sizes"] = {k: TOY[k] for k in cells.SIZE_KEYS}
+))
 SIZES = TOY["sizes"]
 
 
-@pytest.fixture(scope="module", autouse=True)
-def tiled_kernels_at_toy_buckets():
-    before = mla_moe.MATERIALIZED_UP_TO
-    mla_moe.MATERIALIZED_UP_TO = 0
-    yield
-    mla_moe.MATERIALIZED_UP_TO = before
+def _engine_spans(cfg, params, by_name, requests, eng):
+    """``index_rows``, ``selected_rows`` and ``window_rows`` beside the
+    routing counters, counted from the lengths: two indexed full layers, a
+    window layer, two expert layers."""
+    admits = by_name["tdt.batcher.admit_prefill"]
+    rounds = by_name["tdt.batcher.decode_round"]
+    assert admits and rounds and eng._batcher.rounds_ahead > 0
+    tri = lambda n, cap: sum(min(t + 1, cap) for t in range(n))
+    lengths = [n for n, _ in requests]
+    assert sorted(a["selected_rows"] for a in admits) == sorted(
+        2 * tri(n, TOPK) for n in lengths)
+    assert sorted(a["window_rows"] for a in admits) == sorted(
+        tri(n, WINDOW) for n in lengths)
+    assert sorted(a["index_rows"] for a in admits) == sorted(
+        2 * tri(n, 10 ** 6) for n in lengths)
+    for a in rounds:
+        assert a["selected_rows"] <= 2 * 2 * TOPK
+        assert a["window_rows"] <= 2 * WINDOW
+        assert a["index_rows"] >= a["selected_rows"]
+        assert (a["assignments"] + a["assignments_elsewhere"]
+                == 2 * cfg.topk * 2)
 
 
-@pytest.fixture(scope="module")
-def ref():
-    mod = cells.load_module("references", "dots_sparse_mla_moe")
-    mod.configure(TOY)
-    yield mod
-    mod.configure(TOY)
-
-
-@pytest.fixture(scope="module")
-def adapter():
-    return cells.load_module("programs", "tdt_sparse_mla_moe")
-
-
-@pytest.fixture(scope="module")
-def toy(ref, adapter):
-    """``(cfg, program params, plain layers, outer)`` from one seed."""
-    cfg = adapter.model_config(TOY)
-    key = ref.seed_key(7)
-    plain = [ref.layer_weights(key, li, SIZES) for li in range(TOY["n_layers"])]
-    outer = ref.outer_weights(key, SIZES)
-    params = dict(outer, layers=[
-        adapter.pack_layer(w, cfg, kind)
-        for w, kind in zip(plain, cfg.attention_kinds)])
-    return cfg, params, plain, outer
-
-
-def _ref_logits(ref, plain, outer, tokens):
-    """The reference's logits at every position of ``tokens [n, T]``."""
-    x = outer["embed"][tokens].astype(jnp.float32)
-    for li, w in enumerate(plain):
-        x = ref.layer(x, w, li, SIZES)
-    n, t = tokens.shape
-    return np.asarray(ref.head(x, outer, jnp.zeros(n, jnp.int32), t, SIZES,
-                               False))
+FAMILY = Family(
+    program="tdt_sparse_mla_moe", reference="dots_sparse_mla_moe",
+    model=mla_moe, toy=TOY, spec=LatentPagedCacheSpec, tiled=True,
+    layer=lambda ref, x, w, li, control, block: ref.layer(x, w, li, SIZES),
+    pack=lambda adapter, w, cfg, li: adapter.pack_layer(
+        w, cfg, cfg.attention_kinds[li]),
+    # absorbed decode steps through 16-row rings, ragged positions:
+    # "first" decodes across the ring's first wrap; "readmitted" lands on
+    # the slot whose ring and index keys hold the stale rows of "short",
+    # its own prefill wraps the ring and its steps wrap it again (contexts
+    # to 34: selections of 6 among up to 34 rows). Two buckets, 16 and 32:
+    # a bucket more is a program more
+    cases={"first": (13, 12, 16), "short": (9, 5), "readmitted": (27, 7, 32)},
+    forward={"tiled": (16, 0), "materialized": (16, 2048)},
+    admissions=((-1, 21, 32),),
+    pools={"lat": "block_table", "idx": "block_table",
+           "lat_win": "block_table_win"},
+    # the configuration kind's row of the table of scopes: JoyAI's row and
+    # the indexer, the gate and the tiled prefill
+    scopes=frozenset({
+        "attn", "attn/qkv", "attn/kv_write", "attn/out", "attn/index",
+        "attn/gate", "ffn", "ffn/gate_up", "ffn/act", "ffn/down", "ffn/route",
+        "ffn/experts", "ffn/shared", "head"}),
+    admission_scopes=frozenset({"attn/prefill"}),
+    shares=8,
+    uncut=lambda ref, x, m, w: (
+        ref.experts_part(m, ref.combine_weights(x, w, False), w, False)
+        + ref.shared_part(m, w, False)),
+    refused=("ranged prefill",), refusal_says=("latent cache kind",),
+    engine=dict(requests=[(12, 6), (9, 5)], kw=dict(lookahead=True),
+                check=_engine_spans),
+)
 
 
 def test_plan_geometries_specs_and_pools(toy):
     cfg, params, _, _ = toy
     assert mla_moe.layer_kinds(cfg) == (
-        ("full", "dense"), ("full", "moe"), ("window", "moe"),
-        ("window", "moe"), ("window", "moe"))
+        ("full", "dense"), ("full", "moe"), ("window", "moe"))
     # the half of the plan the accepted adapter reads stays what it was
-    assert mla_moe.layer_plan(cfg) == ("dense", "moe", "moe", "moe", "moe")
+    assert mla_moe.layer_plan(cfg) == ("dense", "moe", "moe")
     full, win = cfg.geometry("full"), cfg.geometry("window")
     assert (full.n_heads, full.head_dim, full.row, full.indexed,
             full.window) == (4, 16, 128, True, None)
@@ -140,7 +158,7 @@ def test_plan_geometries_specs_and_pools(toy):
     assert ring == 4 == ring_pages(WINDOW, PAGE, S_MAX)
     assert {k: v.shape for k, v in cache.items()} == {
         "lat": (2, 2 * 16, PAGE, 128), "idx": (2, 2 * 16, PAGE, 16),
-        "lat_win": (3, 2 * ring, PAGE, 128), "block_table": (1, 2, 16),
+        "lat_win": (1, 2 * ring, PAGE, 128), "block_table": (1, 2, 16),
         "block_table_win": (1, 2, ring), "n_alloc": (1,)}
     # the ring arithmetic lives once: the k/v window kind rings alike
     assert WindowPagedKVCacheSpec(S_MAX, PAGE, static_table=True).ring(
@@ -152,59 +170,6 @@ def test_plan_geometries_specs_and_pools(toy):
     assert set(jax.eval_shape(lambda: spec.init(plain, 1))) == {
         "lat", "block_table", "n_alloc"}
     assert plain.pass_counters == gated_experts.MOE_STATS
-
-
-@pytest.mark.parametrize("up_to", [0, 2048], ids=["tiled", "materialized"])
-def test_full_forward_matches_reference(toy, ref, up_to, monkeypatch):
-    """The program's expanded forward (window band, indexer's selection,
-    gates, rescale, grouped GEMMs) in both of prefill's forms."""
-    cfg, params, plain, outer = toy
-    monkeypatch.setattr(mla_moe, "MATERIALIZED_UP_TO", up_to)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab)
-    got = mla_moe.forward_logits(cfg, params, tokens)
-    np.testing.assert_allclose(
-        np.asarray(got), _ref_logits(ref, plain, outer, tokens), **TOL)
-
-
-class _Recording(Request):
-    """A request that keeps every logit row it was sampled from and then
-    takes the best token: logits are compared, not tokens."""
-
-    def sample(self, logits, rng):
-        self.__dict__.setdefault("rows", []).append(np.array(logits))
-        return int(np.argmax(logits))
-
-
-def test_batcher_prefill_then_decode_matches_reference(toy, ref):
-    """Prefill into the three pools, then absorbed decode steps through
-    rings that wrap more than twice (contexts to 43 over 16-row rings) and
-    selections of 6 among up to 43 rows, ragged positions; the third
-    request is admitted onto a slot whose ring and index keys hold the
-    second's stale rows: every logit row the batcher sampled from against
-    the reference's full forward over the same sequence."""
-    cfg, params, plain, outer = toy
-    mesh = Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
-    batcher = ContinuousBatcher(
-        cfg, params, mesh, s_max=S_MAX, page_size=PAGE, prefill=True)
-    assert isinstance(batcher.spec, LatentPagedCacheSpec)
-    rng = np.random.default_rng(0)
-    reqs = [
-        _Recording(list(rng.integers(0, cfg.vocab, n_prompt)), n_new,
-                   temperature=1.0, uid=f"r{i}")
-        for i, (n_prompt, n_new) in enumerate([(13, 30), (5, 10), (21, 12)])
-    ]
-    for r in reqs:          # 3 requests over 2 slots: r2 re-uses r1's slot
-        batcher.submit(r)
-    done = dict(batcher.run())
-    assert sorted(done) == ["r0", "r1", "r2"]
-    for r in reqs:
-        out = done[r.uid]
-        assert len(out) == r.max_new_tokens == len(r.rows)
-        seq = np.array([list(r.prompt) + out])
-        want = _ref_logits(ref, plain, outer, seq)[0]
-        first = len(r.prompt) - 1
-        np.testing.assert_allclose(
-            np.stack(r.rows), want[first:first + len(out)], **TOL)
 
 
 def test_the_selection_is_the_references_to_the_row(toy, ref):
@@ -269,18 +234,15 @@ def test_a_top_k_no_smaller_than_the_context_is_the_dense_result(toy):
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 16), 0, cfg.vocab)
     wide = dataclasses.replace(cfg, index_topk=S_MAX)
     none = dataclasses.replace(cfg, index_topk=0, index_n_heads=0)
-    got = mla_moe.forward_logits(wide, params, tokens)
-    want = mla_moe.forward_logits(none, params, tokens)
+    got = forward_logits(FAMILY, wide, params, tokens)
+    want = forward_logits(FAMILY, none, params, tokens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
-    narrow = mla_moe.forward_logits(cfg, params, tokens)
+    narrow = forward_logits(FAMILY, cfg, params, tokens)
     assert np.abs(np.asarray(narrow) - np.asarray(want)).max() > 1e-2
     # a step over one layer's pools
     spec = LatentPagedCacheSpec(S_MAX, PAGE, static_table=True)
     rng = np.random.default_rng(4)
-    cache = jax.tree.map(
-        lambda x: x if x.dtype == jnp.int32
-        else jnp.asarray(rng.standard_normal(x.shape), x.dtype),
-        spec.init(cfg, 1))
+    cache = random_cache(cfg, spec, rng)
     b, geo = cfg.batch, cfg.geometry("full")
     f = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
     row, q = f(b, geo.row), f(b, geo.n_heads, geo.row)
@@ -378,95 +340,3 @@ def test_sparse_decode_and_flash_prefill_forms_against_plain_attention():
     assert "name=mla_flash_prefill_w5" in text
     with pytest.raises(ValueError, match="selection"):
         flash_prefill(q, k, v, lens, window=5, keep=keep.astype(jnp.int8))
-
-
-def test_the_eight_shares_of_the_bank_add_up_to_the_uncut_layer(toy, ref):
-    """The guide's share test: one expert layer's MLP run once per share
-    of the bank (8 shares of 1 expert), what every chip computes alike
-    (the shared expert) counted once, equals the uncut reference."""
-    cfg, params, plain, _ = toy
-    p, w = params["layers"][1], plain[1]
-    h = jax.random.normal(jax.random.PRNGKey(4), (24, cfg.hidden), jnp.float32)
-    want = (ref.experts_part(h, ref.combine_weights(h, w, False), w, False)
-            + ref.shared_part(h, w, False))
-    whole, stats = gated_experts.moe_mlp(cfg, h, p, 8)
-    np.testing.assert_allclose(np.asarray(whole), np.asarray(want), **TOL)
-    total, hit = 0.0, 0
-    for first in range(8):
-        share = dataclasses.replace(cfg, experts_held=(first, 1))
-        bank = dict(p, we_gate_up=p["we_gate_up"][first:first + 1],
-                    we_down=p["we_down"][first:first + 1])
-        y, st = gated_experts.moe_mlp(share, h, bank, 8)
-        total, hit = total + y, hit + int(st[1])
-    np.testing.assert_allclose(np.asarray(total), np.asarray(want), **TOL)
-    assert hit == int(stats[1]) == 24 * 2      # every assignment, once
-
-
-# the configuration kind's row of the table of scopes
-# (docs/observability.md): JoyAI's row and the indexer, the gate and the
-# tiled prefill
-SCOPES = {"attn", "attn/qkv", "attn/kv_write", "attn/out", "attn/index",
-          "attn/gate", "ffn", "ffn/gate_up", "ffn/act", "ffn/down",
-          "ffn/route", "ffn/experts", "ffn/shared", "head"}
-
-
-@pytest.mark.parametrize("which", ["step", "admission"])
-def test_every_part_of_a_pass_says_which_part_it_is(toy, which):
-    """The lowered step and admission carry every scope of the row and no
-    other ``tdt.`` name, and every matrix product and kernel call lies
-    under a part; the new kernels carry their own names."""
-    cfg, params, _, _ = toy
-    spec = LatentPagedCacheSpec(S_MAX, PAGE, static_table=True)
-    mesh = Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
-    row = SCOPES if which == "step" else SCOPES | {"attn/prefill"}
-    check_pass(which, cfg, params, spec, mesh, S_MAX, row, bucket=16)
-
-
-def test_engine_serves_it_and_the_spans_carry_the_counters(toy):
-    """Through ``ServingEngine`` with lookahead: the step's and the
-    admission's spans carry ``index_rows``, ``selected_rows`` and
-    ``window_rows`` beside the routing counters, counted from the lengths;
-    the latent kind still refuses what reads k/v pools."""
-    from triton_dist_tpu import config as tdt_config, obs
-    from triton_dist_tpu.obs import ObsConfig
-    from triton_dist_tpu.serving import ServingConfig, ServingEngine
-    from triton_dist_tpu.serving.traffic import Arrival
-
-    cfg, params, _, _ = toy
-    mesh = Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
-    before = tdt_config.get_config().obs
-    tdt_config.update(obs=ObsConfig(spans=True))
-    try:
-        obs.reset()
-        engine = ServingEngine(
-            cfg, params, mesh, s_max=S_MAX, page_size=PAGE, prefill=True,
-            lookahead=True, serving=ServingConfig(max_queue=8))
-        rng = np.random.default_rng(3)
-        t0 = engine.clock.monotonic()
-        out = engine.serve([
-            Arrival(t0, Request(list(rng.integers(0, cfg.vocab, n)), m,
-                                uid=f"r{i}"))
-            for i, (n, m) in enumerate([(12, 6), (7, 5)])])
-        assert sorted(out) == ["r0", "r1"]
-        spans = obs.spans()
-    finally:
-        tdt_config.update(obs=before)
-    admits = [sp.attrs for sp in spans if sp.name == "tdt.batcher.admit_prefill"]
-    rounds = [sp.attrs for sp in spans if sp.name == "tdt.batcher.decode_round"]
-    assert admits and rounds
-    tri = lambda n, cap: sum(min(t + 1, cap) for t in range(n))
-    assert sorted(a["selected_rows"] for a in admits) == sorted(
-        2 * tri(n, TOPK) for n in (12, 7))
-    assert sorted(a["window_rows"] for a in admits) == sorted(
-        3 * tri(n, WINDOW) for n in (12, 7))
-    assert sorted(a["index_rows"] for a in admits) == sorted(
-        2 * tri(n, 10 ** 6) for n in (12, 7))
-    for a in rounds:
-        assert a["selected_rows"] <= 2 * 2 * TOPK
-        assert a["window_rows"] <= 3 * 2 * WINDOW
-        assert a["index_rows"] >= a["selected_rows"]
-        assert (a["assignments"] + a["assignments_elsewhere"]
-                == 2 * cfg.topk * 4)
-    with pytest.raises(NotImplementedError, match="latent cache kind"):
-        ContinuousBatcher(cfg, params, mesh, s_max=S_MAX, page_size=PAGE,
-                          prefill=True, prefill_chunk_tokens=8)

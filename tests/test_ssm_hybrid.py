@@ -1,78 +1,94 @@
 """The state-space / attention family (models/ssm_hybrid.py) at toy widths
-on the CPU (4 layers: Mamba at 0 and 2, attention at 1 and 3; page 4),
-each piece against the plain reference's equations
-(perfbench/references/jamba_ssm_hybrid.py, imported as it stands: it
-shares no code with the program). Weights are float32 here, so the
-tolerances below are those of float32 arithmetic reordered (a state held
-``[N, d]`` for ``[d, N]``, online for whole softmax, a packed gate/up),
-not of bf16."""
+on the CPU (3 layers: Mamba at 0 and 2, so that one layer's state is not
+the other's, attention at 1; page 4), each piece against the plain
+reference's equations (perfbench/references/jamba_ssm_hybrid.py, imported
+as it stands: it shares no code with the program). The family's contract
+and its size are tests/family_tier.py's; this file names the family and
+keeps what only it has. Weights are float32 here, so the tolerances are
+those of float32 arithmetic reordered (a state held ``[N, d]`` for
+``[d, N]``, online for whole softmax, a packed gate/up), not of bf16."""
 
-import dataclasses
 import importlib
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
 
-from triton_dist_tpu.models import ContinuousBatcher, Request
-from triton_dist_tpu.models import ssm_hybrid
+from triton_dist_tpu.models import Request, ssm_hybrid
 from triton_dist_tpu.models.decode import (
     PAGED_CACHE_KINDS, StatePagedKVCacheSpec,
 )
 
-PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
-if PERFBENCH not in sys.path:
-    sys.path.insert(0, PERFBENCH)
-from harness import cells  # noqa: E402
-
-from scope_helpers import check_pass  # noqa: E402
+from family_tier import (  # noqa: F401
+    PERFBENCH, TOL, Family, Recording, _ref_logits, adapter, admit, cells,
+    family, make_batcher, prompt_of, pytest_generate_tests, random_cache, ref,
+    sampled_rows_match, served, sized, tiled_kernels_at_toy_buckets, toy,
+    test_batcher_prefill_then_decode_matches_reference,
+    test_every_part_of_a_pass_says_which_part_it_is,
+)
+from family_tier import (  # noqa: F401
+    test_engine_serves_it_and_the_spans_carry_the_counters
+    as test_engine_serves_it_rebuilds_and_the_spans_carry_the_counters,
+    test_what_the_kind_cannot_serve_is_refused_by_name
+    as test_what_a_slots_state_cannot_serve_is_refused_by_name,
+)
 
 # (the package exports a function under the module's name)
 fd = importlib.import_module("triton_dist_tpu.ops.flash_decode")
 ss = importlib.import_module("triton_dist_tpu.ops.selective_scan")
 
-# float32 everywhere: what is left is the order of the sums
-TOL = dict(rtol=2e-4, atol=2e-4)
 PAGE, S_MAX = 4, 32
-TOY = dict(
-    hidden=32, ffn=64, n_layers=4, n_q_heads=4, n_kv_heads=1, head_dim=8,
+TOY = sized(dict(
+    hidden=32, ffn=64, n_layers=3, n_q_heads=4, n_kv_heads=1, head_dim=8,
     vocab=64, rope_theta=None, norm_eps=1e-6, dtype="float32",
     attn_layer_period=2, attn_layer_offset=1, mamba_expand=2,
     mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=4, mamba_conv_bias=True,
     mamba_proj_bias=False, num_experts=1, tie_word_embeddings=True,
     engine=dict(slots=3, s_max=S_MAX, page=PAGE, max_queue=64),
-)
-TOY["sizes"] = {k: TOY[k] for k in cells.SIZE_KEYS}
+))
 SIZES = TOY["sizes"]
 PUBLISHED = os.path.join(PERFBENCH, "configs", "ai21-jamba2-3b.json")
 
 
-@pytest.fixture(scope="module")
-def ref():
-    mod = cells.load_module("references", "jamba_ssm_hybrid")
-    mod.configure(TOY)
-    yield mod
-    mod.configure(TOY)
+def _engine_spans(cfg, params, by_name, requests, eng):
+    """The batcher's default of lookahead stayed on; the round's span
+    carries ``state_slots`` and ``kv_rows``, the intake's ``state_bytes``."""
+    assert eng._batcher.lookahead
+    assert [a["state_bytes"] for a in by_name["tdt.batcher.take_params"]] \
+        == [cfg.state_bytes()] * 2                  # built, and rebuilt
+    rounds = by_name["tdt.batcher.decode_round"]
+    assert rounds and all(a["state_slots"] == cfg.batch for a in rounds)
+    # 1 attention layer x the lengths the step was given, growing
+    assert all(a["kv_rows"] > 0 for a in rounds)
+    assert max(a["kv_rows"] for a in rounds) > 3 * 6
+    admits = by_name["tdt.batcher.admit_prefill"]
+    assert len(admits) >= len(requests) + 1         # and the replayed ones
+    assert all((a["state_slots"], a["kv_rows"]) == (1, 0) for a in admits)
 
 
-@pytest.fixture(scope="module")
-def adapter():
-    return cells.load_module("programs", "tdt_ssm_hybrid")
-
-
-@pytest.fixture(scope="module")
-def toy(ref, adapter):
-    """``(cfg, program params, plain layers, outer)`` from one seed."""
-    cfg = adapter.model_config(TOY)
-    key = ref.seed_key(7)
-    plain = [ref.layer_weights(key, li, SIZES) for li in range(TOY["n_layers"])]
-    outer = ref.outer_weights(key, SIZES)
-    params = dict(outer, layers=[adapter.pack_layer(w, cfg) for w in plain])
-    return cfg, params, plain, outer
+FAMILY = Family(
+    program="tdt_ssm_hybrid", reference="jamba_ssm_hybrid", model=ssm_hybrid,
+    toy=TOY, spec=StatePagedKVCacheSpec,
+    layer=lambda ref, x, w, li, control, block: ref.layer(x, w, SIZES),
+    # a prompt below its bucket's edge (3 of 4), at it (4 of 4), across it
+    # (5 -> 8) and over pages and buckets (13 -> 16, four pages); with 3
+    # slots the last two are admitted into slots that served before
+    cases={"below": (3, 3), "at": (4, 3), "across": (5, 3), "long": (13, 3),
+           "readmitted": (6, 3)},
+    # a mixer is ``ssm`` or ``attn`` by the plan, every MLP the dense ``ffn``
+    scopes=frozenset({
+        "attn", "attn/qkv", "attn/kv_write", "attn/out", "ssm", "ssm/proj",
+        "ssm/conv", "ssm/scan", "ffn", "ffn/gate_up", "ffn/act", "ffn/down",
+        "head"}),
+    refused=("prefix cache", "ranged prefill", "contiguous cache",
+             "wider mesh", "wider mesh, the spec", "verify", "the dense step",
+             "speculative decoding", "handoff", "scratch page"),
+    refusal_says=("kv_state", "one-device"),
+    engine=dict(requests=[(6, 5), (9, 4), (3, 5), (5, 3)], rebuild_after=3,
+                check=_engine_spans),
+)
 
 
 @pytest.fixture(scope="module")
@@ -80,29 +96,6 @@ def published(adapter):
     config = cells.load_json(PUBLISHED)
     config["sizes"] = {k: config[k] for k in cells.SIZE_KEYS}
     return config, adapter.model_config(config)
-
-
-def _ref_logits(ref, plain, outer, tokens):
-    """The reference's logits at every position of ``tokens [n, T]``."""
-    x = outer["embed"][tokens].astype(jnp.float32)
-    for w in plain:
-        x = ref.layer(x, w, SIZES)
-    n, t = tokens.shape
-    return np.asarray(ref.head(x, outer, jnp.zeros(n, jnp.int32), t, SIZES,
-                               False))
-
-
-def _mesh(cfg):
-    return Mesh(np.array(jax.devices()[:1]), (cfg.axis,))
-
-
-def _batcher(cfg, params, **kw):
-    return ContinuousBatcher(cfg, params, _mesh(cfg), s_max=S_MAX,
-                             page_size=PAGE, prefill=True, **kw)
-
-
-def _prompt(rng, cfg, n):
-    return [int(t) for t in rng.integers(0, cfg.vocab, n)]
 
 
 # -- (a) the two kernels ---------------------------------------------------------
@@ -176,97 +169,24 @@ def test_selective_state_update_against_its_twin_and_the_recurrence(ref, stored)
                                   np.asarray(pool[1, 0, 0]))
 
 
-# -- (b) prefill, then decode, through the cache -------------------------------
-
-class _Recording(Request):
-    """A request that keeps every logit row it was sampled from and then
-    takes the best token: logits are compared, not tokens."""
-
-    def sample(self, logits, rng):
-        self.__dict__.setdefault("rows", []).append(np.array(logits))
-        return int(np.argmax(logits))
-
-
-# (prompt, new): a prompt below its bucket's edge (3 of 4), at it (4 of 4),
-# across it (5 -> 8) and over pages and buckets (13 -> 16, four pages);
-# with 3 slots the last two are admitted into slots that served before
-CASES = {"below": (3, 3), "at": (4, 3), "across": (5, 3), "long": (13, 3),
-         "readmitted": (6, 3)}
-
-
-@pytest.fixture(scope="module")
-def served(toy):
-    """Every case through ONE batcher (3 slots, so slots are re-used,
-    lookahead off: ``temperature`` > 0 keeps every round plain)."""
-    cfg, params, _, _ = toy
-    batcher = _batcher(cfg, params)
-    assert isinstance(batcher.spec, StatePagedKVCacheSpec)
-    rng = np.random.default_rng(0)
-    reqs = {name: _Recording(_prompt(rng, cfg, n_prompt), n_new,
-                             temperature=1.0, uid=name)
-            for name, (n_prompt, n_new) in CASES.items()}
-    for r in reqs.values():
-        batcher.submit(r)
-    return reqs, dict(batcher.run())
-
-
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_batcher_prefill_then_decode_matches_reference(toy, ref, served, case):
-    """Every logit row the batcher sampled from against the reference's
-    full forward over the same sequence."""
-    _, _, plain, outer = toy
-    reqs, done = served
-    r, out = reqs[case], done[case]
-    assert len(out) == r.max_new_tokens == len(r.rows)
-    seq = np.array([list(r.prompt) + out])
-    want = _ref_logits(ref, plain, outer, seq)[0]
-    first = len(r.prompt) - 1
-    np.testing.assert_allclose(
-        np.stack(r.rows), want[first:first + len(out)], **TOL)
-
+# -- (b) the prompt a token a step ------------------------------------------------
 
 def test_token_fed_admission_matches_reference(toy, ref):
     """``prefill=False``: the prompt goes in a token a step from position
     0, where the step reads zeros for the state whatever the slot holds."""
-    cfg, params, plain, outer = toy
-    batcher = ContinuousBatcher(cfg, params, _mesh(cfg), s_max=S_MAX,
-                                page_size=PAGE)
+    cfg, params, _, _ = toy
+    batcher = make_batcher(FAMILY, cfg, params, prefill=False)
     rng = np.random.default_rng(5)
-    reqs = [_Recording(_prompt(rng, cfg, n), 2, temperature=1.0, uid=i)
+    reqs = [Recording(prompt_of(rng, cfg, n), 2, temperature=1.0, uid=i)
             for i, n in enumerate((3, 2, 4, 2))]      # the 4th re-uses a slot
     for r in reqs:
         batcher.submit(r)
     done = dict(batcher.run())
     for r in reqs:
-        seq = np.array([list(r.prompt) + done[r.uid]])
-        want = _ref_logits(ref, plain, outer, seq)[0]
-        first = len(r.prompt) - 1
-        np.testing.assert_allclose(np.stack(r.rows), want[first:first + 2],
-                                   **TOL)
+        sampled_rows_match(FAMILY, ref, toy, r, done[r.uid])
 
 
 # -- (c), (f) what an admission writes -------------------------------------------
-
-def _prefill(cfg, params, spec, cache, slot, prompt, bucket):
-    """The family's prefill of ``prompt`` into ``slot`` as an admission
-    does it; ``slot=None``: every slot gets the prompt, no mask
-    (``generate``'s form)."""
-    pcfg = dataclasses.replace(cfg, seq=bucket)
-    tokens = np.zeros((cfg.batch, bucket), np.int32)
-    pick = np.zeros(cfg.batch, np.int32)
-    tokens[slot, :len(prompt)] = prompt      # (None indexes every row)
-    pick[slot] = len(prompt) - 1
-    mask = None if slot is None else jnp.arange(cfg.batch) == slot
-    fn = jax.shard_map(
-        lambda p, c, t, m, k: ssm_hybrid.prefill_cache(
-            pcfg, p, c, t.reshape(-1), spec, S_MAX, slot_mask=m, pick=k,
-            interpret=True),
-        mesh=_mesh(cfg), in_specs=(cfg.param_specs(), spec.specs(cfg), P(),
-                                   None if slot is None else P(), P()),
-        out_specs=(spec.specs(cfg), P(), P()), check_vma=False)
-    return jax.jit(fn)(params, cache, jnp.asarray(tokens), mask,
-                       jnp.asarray(pick))
-
 
 def test_a_prompt_shorter_than_its_bucket_leaves_the_state_of_its_length(
         toy, ref):
@@ -277,9 +197,9 @@ def test_a_prompt_shorter_than_its_bucket_leaves_the_state_of_its_length(
     cfg, params, plain, outer = toy
     spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
     rng = np.random.default_rng(2)
-    prompt = _prompt(rng, cfg, 5)
-    cache, last, counters = _prefill(cfg, params, spec, spec.init(cfg, 1), 1,
-                                     prompt, 8)
+    prompt = prompt_of(rng, cfg, 5)
+    cache, last, counters = admit(FAMILY, cfg, params, spec.init(cfg, 1), 1,
+                                  prompt, 8)
     x = outer["embed"][jnp.asarray(prompt)].astype(jnp.float32)
     w = plain[0]
     _, h, u = ref.mamba_parts(ref._norm(x, w["norm_in"], 1e-6), w, SIZES, False)
@@ -288,13 +208,13 @@ def test_a_prompt_shorter_than_its_bucket_leaves_the_state_of_its_length(
     for p in (1, 2, 3, 4):
         np.testing.assert_allclose(np.asarray(cache["conv"][0, p % 4, 1]),
                                    np.asarray(u[p]), **TOL)
-    want = _ref_logits(ref, plain, outer, np.array([prompt]))[0, -1]
+    want = _ref_logits(FAMILY, ref, plain, outer, np.array([prompt]))[0, -1]
     np.testing.assert_allclose(np.asarray(last[1]), want, **TOL)
     assert [int(v) for v in counters] == [1, 0]
     # the same prompt in a bucket of its own length, in EVERY slot and with
     # no mask (``generate``'s form): the same state in each
-    exact, last, counters = _prefill(cfg, params, spec, spec.init(cfg, 1),
-                                     None, prompt, 5)
+    exact, last, counters = admit(FAMILY, cfg, params, spec.init(cfg, 1),
+                                  None, prompt, 5)
     assert [int(v) for v in counters] == [cfg.batch, 0]
     for slot in range(cfg.batch):
         np.testing.assert_allclose(np.asarray(last[slot]), want, **TOL)
@@ -309,11 +229,9 @@ def test_an_admission_changes_no_other_slots_state_or_pages(toy):
     cfg, params, _, _ = toy
     spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
     rng = np.random.default_rng(3)
-    before = jax.tree.map(
-        lambda x: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
-        if x.dtype != jnp.int32 else x, spec.init(cfg, 1))
-    after, _, _ = _prefill(cfg, params, spec, before, 1,
-                           _prompt(rng, cfg, 7), 8)
+    before = random_cache(cfg, spec, rng)
+    after, _, _ = admit(FAMILY, cfg, params, before, 1,
+                        prompt_of(rng, cfg, 7), 8)
     others = np.array([0, 2])
     for name in ("ssm", "conv"):
         np.testing.assert_array_equal(
@@ -329,33 +247,16 @@ def test_an_admission_changes_no_other_slots_state_or_pages(toy):
 
 # -- (d) a step sent in vain ------------------------------------------------------
 
-# the family's row of the table of scopes (docs/observability.md): a
-# mixer is ``ssm`` or ``attn`` by the plan, every MLP the dense ``ffn``
-SCOPES = {"attn", "attn/qkv", "attn/kv_write", "attn/out",
-          "ssm", "ssm/proj", "ssm/conv", "ssm/scan",
-          "ffn", "ffn/gate_up", "ffn/act", "ffn/down", "head"}
-
-
-@pytest.mark.parametrize("which", ["step", "admission"])
-def test_every_part_of_a_pass_says_which_part_it_is(toy, which):
-    """The lowered step and admission carry every scope of the family's
-    row and no other ``tdt.`` name, and every matrix product and kernel
-    call lies under a part; only the step calls the decode kernel."""
-    cfg, params, _, _ = toy
-    spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
-    check_pass(which, cfg, params, spec, _mesh(cfg), S_MAX, SCOPES)
-
-
 def _run_with_a_late_arrival(cfg, params, **kw):
     """Two requests decode on three slots; a third arrives after the third
     step, while a step may be out ahead."""
     rng = np.random.default_rng(4)
-    b = _batcher(cfg, params, **kw)
+    b = make_batcher(FAMILY, cfg, params, **kw)
     for i, (n, new) in enumerate([(5, 7), (3, 6)]):
-        b.submit(Request(_prompt(rng, cfg, n), new, uid=i))
+        b.submit(Request(prompt_of(rng, cfg, n), new, uid=i))
     for _ in range(3):
         b.step()
-    b.submit(Request(_prompt(rng, cfg, 6), 3, uid="late"))
+    b.submit(Request(prompt_of(rng, cfg, 6), 3, uid="late"))
     return dict(b.run(max_steps=200)), b
 
 
@@ -377,10 +278,10 @@ def test_decode_step_twice_on_the_same_inputs_is_decode_step_once(toy):
     cache: the second run read the state the first read, not the state it
     wrote."""
     cfg, params, _, _ = toy
-    b = _batcher(cfg, params, lookahead=False)
+    b = make_batcher(FAMILY, cfg, params, lookahead=False)
     rng = np.random.default_rng(6)
     for i, n in enumerate((5, 9, 2)):
-        b.submit(Request(_prompt(rng, cfg, n), 12, uid=i))
+        b.submit(Request(prompt_of(rng, cfg, n), 12, uid=i))
     for _ in range(3):
         b.step()
     tok, pos = jnp.asarray(b.tok), jnp.asarray(b.pos)
@@ -405,17 +306,17 @@ def test_a_readmitted_slot_serves_what_a_fresh_batcher_serves(toy):
     behind there, and serves the tokens it serves in a fresh batcher."""
     cfg, params, _, _ = toy
     rng = np.random.default_rng(8)
-    first = Request(_prompt(rng, cfg, 9), 3, uid="first")
-    second = _prompt(rng, cfg, 6)
-    busy = _batcher(cfg, params)
+    first = Request(prompt_of(rng, cfg, 9), 3, uid="first")
+    second = prompt_of(rng, cfg, 6)
+    busy = make_batcher(FAMILY, cfg, params)
     busy.submit(first)
     busy.run()
     stale = np.asarray(busy.cache["ssm"][:, :, 0])
     assert stale.any()
-    busy.submit(Request(second, 5, uid="second"))
+    busy.submit(Request(second, 3, uid="second"))
     got = dict(busy.run())["second"]
-    fresh = _batcher(cfg, params)
-    fresh.submit(Request(second, 5, uid="second"))
+    fresh = make_batcher(FAMILY, cfg, params)
+    fresh.submit(Request(second, 3, uid="second"))
     assert got == dict(fresh.run())["second"]
     assert busy.spec.kind == "kv_state"
 
@@ -474,55 +375,6 @@ def test_the_kv_pools_hold_the_two_attention_layers_and_the_state_its_slots(
     assert state == cfg.state_bytes() == 64 * 26 * (2 * 16 + 4) * 5120 * 4
 
 
-# -- (i) what the kind refuses ----------------------------------------------------
-
-def _refusals(cfg, params):
-    from triton_dist_tpu.models.prefix_cache import PrefixCacheConfig
-    from triton_dist_tpu.serving.disagg import DisaggServingEngine
-    from triton_dist_tpu.serving.speculative import (
-        SpecDecodeConfig, SpeculativeBatcher,
-    )
-
-    one = _mesh(cfg)
-    two = Mesh(np.array(jax.devices()[:2]), (cfg.axis,))
-    spec = StatePagedKVCacheSpec(S_MAX, PAGE, static_table=True)
-    kw = dict(s_max=S_MAX, page_size=PAGE)
-    return {
-        "prefix cache": ("prefix_cache", lambda: ContinuousBatcher(
-            cfg, params, one, prefill=True,
-            prefix_cache=PrefixCacheConfig(), **kw)),
-        "ranged prefill": ("ranged prefill", lambda: ContinuousBatcher(
-            cfg, params, one, prefill=True, prefill_chunk_tokens=8, **kw)),
-        "contiguous cache": ("contiguous cache", lambda: ContinuousBatcher(
-            cfg, params, one, s_max=S_MAX)),
-        "wider mesh": ("wider than one device", lambda: ContinuousBatcher(
-            cfg, params, two, **kw)),
-        "wider mesh, the spec": ("one-device shard", lambda: spec.init(cfg, 2)),
-        "verify": ("speculative verify", lambda: spec.update_multi_and_attend()),
-        "the dense step": ("walks its own plan",
-                           lambda: spec.update_and_attend()),
-        "speculative decoding": (
-            "speculative decoding", lambda: SpeculativeBatcher(
-                cfg, params, one, spec_decode=SpecDecodeConfig(), **kw)),
-        "handoff": ("disaggregated handoff", lambda: DisaggServingEngine(
-            cfg, params, two, **kw)),
-        "scratch page": ("prefix cache", lambda: StatePagedKVCacheSpec(
-            S_MAX, PAGE, static_table=True, extra_pages=1).init(cfg, 1)),
-    }
-
-
-@pytest.mark.parametrize("what", [
-    "prefix cache", "ranged prefill", "contiguous cache", "wider mesh",
-    "wider mesh, the spec", "verify", "the dense step", "speculative decoding",
-    "handoff", "scratch page"])
-def test_what_a_slots_state_cannot_serve_is_refused_by_name(toy, what):
-    cfg, params, _, _ = toy
-    match, build = _refusals(cfg, params)[what]
-    with pytest.raises(NotImplementedError, match=match) as err:
-        build()
-    assert "kv_state" in str(err.value) or "one-device" in str(err.value)
-
-
 # -- (k) a group of 20 on one kv head ----------------------------------------------
 
 def test_a_group_of_20_on_one_kv_head_through_the_paged_kernel(ref):
@@ -555,68 +407,3 @@ def test_a_group_of_20_on_one_kv_head_through_the_paged_kernel(ref):
         *(jnp.asarray(a) for a in (q, kp, vp, lens, table)), interpret=True)
     np.testing.assert_allclose(np.asarray(got).reshape(b, -1), want,
                                rtol=1e-4, atol=1e-4)
-
-
-# -- (l) the engine, its spans and its rebuild path --------------------------------
-
-def test_engine_serves_it_rebuilds_and_the_spans_carry_the_counters(toy):
-    """Through ``ServingEngine`` with the batcher's default of lookahead:
-    a rebuild mid-flight re-admits the in-flight requests by prefill
-    (prompt + tokens so far), and every request's tokens are the plain
-    batcher's; the round's span carries ``state_slots`` and ``kv_rows``,
-    the intake's ``state_bytes``."""
-    from triton_dist_tpu import config as tdt_config, obs
-    from triton_dist_tpu.obs import ObsConfig
-    from triton_dist_tpu.resilience import retry
-    from triton_dist_tpu.serving import ServingConfig, ServingEngine
-    from triton_dist_tpu.serving.engine import Finished
-
-    cfg, params, _, _ = toy
-    rng = np.random.default_rng(10)
-    shapes = [(6, 5), (9, 4), (3, 5), (5, 3)]
-    prompts = [_prompt(rng, cfg, n) for n, _ in shapes]
-    reqs = lambda: [Request(list(p), new, uid=f"u{i}")
-                    for i, (p, (_, new)) in enumerate(zip(prompts, shapes))]
-    plain = _batcher(cfg, params, lookahead=False)
-    for r in reqs():
-        plain.submit(r)
-    want = dict(plain.run())
-
-    before = tdt_config.get_config().obs
-    tdt_config.update(obs=ObsConfig(spans=True))
-    obs.reset()
-    try:
-        clock = retry.FakeClock()
-        with retry.clock_scope(clock):
-            eng = ServingEngine(
-                cfg, params, _mesh(cfg), s_max=S_MAX, page_size=PAGE,
-                prefill=True, clock=clock,
-                serving=ServingConfig(virtual_step_s=0.01))
-            assert eng._batcher.lookahead
-            for r in reqs():
-                eng.submit(r)
-            for _ in range(3):
-                eng._step_once()
-            assert eng._batcher.rounds_ahead > 0
-            eng._rebuild("test")
-            done = eng.run_until_idle()
-        assert eng.rebuilds == 1
-        spans = obs.spans()
-    finally:
-        tdt_config.update(obs=before)
-        obs.reset()
-    assert all(isinstance(done[u], Finished) for u in want)
-    assert {u: list(done[u].tokens) for u in want} == want
-    by_name = {}
-    for sp in spans:
-        by_name.setdefault(sp.name, []).append(sp.attrs)
-    assert [a["state_bytes"] for a in by_name["tdt.batcher.take_params"]] \
-        == [cfg.state_bytes()] * 2                  # built, and rebuilt
-    rounds = by_name["tdt.batcher.decode_round"]
-    assert rounds and all(a["state_slots"] == cfg.batch for a in rounds)
-    # 2 attention layers x the lengths the step was given, growing
-    assert all(a["kv_rows"] % 2 == 0 and a["kv_rows"] > 0 for a in rounds)
-    assert max(a["kv_rows"] for a in rounds) > 2 * 3 * 6
-    admits = by_name["tdt.batcher.admit_prefill"]
-    assert len(admits) >= 4 + 1                     # and the replayed ones
-    assert all((a["state_slots"], a["kv_rows"]) == (1, 0) for a in admits)
