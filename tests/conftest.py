@@ -123,6 +123,9 @@ _LONG_POLES = (
     # one file by its own docstring (one process loads libtpu), +3-4
     # compiles a PR: first while it is the heaviest
     "test_chip_compile.py",
+    # PR 45's own reading, alone on this machine: 196 s (five planted
+    # faults of two fresh programs each are 65 s of it)
+    "test_retention.py",
     "test_flash_decode.py", "test_sparse_mla_moe.py", "test_emitter.py",
     "test_window_moe.py", "test_prerouted_moe.py", "test_disagg.py",
     "test_ranged_prefill.py", "test_disagg_soak.py", "test_serving.py",

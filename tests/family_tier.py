@@ -4,7 +4,7 @@ ssm_hybrid.py and their second blocks) has to show at toy widths on the
 CPU against its plain reference, and AT WHAT SIZE. A family is a
 ``Family`` descriptor in a file of its own (test_mla_moe.py,
 test_sparse_mla_moe.py, test_window_moe.py, test_prerouted_moe.py,
-test_ssm_hybrid.py), which imports the fixtures and the cases it takes
+test_ssm_hybrid.py, test_retention.py), which imports the fixtures and the cases it takes
 from here and keeps beside the descriptor what only that family has (its
 kernels against their twins, its plan and parameter counts). So a family
 stays ONE file and one xdist worker's job (``--dist loadfile``), its
@@ -96,6 +96,11 @@ class Family:
     prerouted: bool = False      # the router reads other rows than the experts
     refused: tuple = ()          # names of REFUSALS
     refusal_says: tuple = ()     # one of these is in every refusal
+    # a STATE kind's (a slot holds a state after its last position, not
+    # rows): the pool a step must move, and the tolerance of a context of
+    # a few tokens where it is not the family's
+    state_pool: str | None = None
+    token_fed_tol: dict | None = None
     # the engine run: requests (prompt, new), ServingEngine keywords,
     # `rebuild_after` steps (then the tokens are held to a plain batcher's),
     # `share(cfg, params)` where a share of the model is served, and
@@ -616,6 +621,76 @@ def test_what_the_kind_cannot_serve_is_refused_by_name(family, toy, what):
     with pytest.raises(NotImplementedError, match=match) as err:
         build()
     assert any(word in str(err.value) for word in family.refusal_says)
+
+
+# -- the cases of a family whose slots hold a STATE (``state_pool``) ---------------
+
+def test_token_fed_admission_matches_reference(family, toy, ref):
+    """``prefill=False``: the prompt goes in a token a step from position
+    0, where the step reads zeros for the state whatever the slot holds."""
+    cfg, params, _, _ = toy
+    batcher = make_batcher(family, cfg, params, prefill=False)
+    rng = np.random.default_rng(5)
+    reqs = [Recording(prompt_of(rng, cfg, n), 2, temperature=1.0, uid=i)
+            for i, n in enumerate((3, 2, 4, 2))]      # the 4th re-uses a slot
+    for r in reqs:
+        batcher.submit(r)
+    done = dict(batcher.run())
+    held_to = dataclasses.replace(family, tol=family.token_fed_tol or family.tol)
+    for r in reqs:
+        sampled_rows_match(held_to, ref, toy, r, done[r.uid])
+
+
+def _run_with_a_late_arrival(family, cfg, params, **kw):
+    """Two requests decode on three slots; a third arrives after the third
+    step, while a step may be out ahead."""
+    rng = np.random.default_rng(4)
+    b = make_batcher(family, cfg, params, **kw)
+    for i, (n, new) in enumerate([(5, 7), (3, 6)]):
+        b.submit(Request(prompt_of(rng, cfg, n), new, uid=i))
+    for _ in range(3):
+        b.step()
+    b.submit(Request(prompt_of(rng, cfg, 6), 3, uid="late"))
+    return dict(b.run(max_steps=200)), b
+
+
+def test_a_step_sent_in_vain_serves_the_plain_rounds_tokens(family, toy):
+    """Lookahead on: the late admission moves the cache under a step that
+    has ALREADY advanced every live slot's state in the donated cache; the
+    step runs again and every slot's tokens are the plain batcher's."""
+    cfg, params, _, _ = toy
+    want, plain = _run_with_a_late_arrival(family, cfg, params, lookahead=False)
+    got, b = _run_with_a_late_arrival(family, cfg, params)
+    assert b.ahead_discarded >= 1 and b.rounds_ahead > 0
+    assert plain.rounds_ahead == 0 and b.rounds == plain.rounds
+    assert got == want
+
+
+def test_decode_step_twice_on_the_same_inputs_is_decode_step_once(family, toy):
+    """The planted form: the same ``(tok, pos)`` through the batcher's own
+    step program twice gives the same logits and, bit for bit, the same
+    cache: the second run read the state the first read, not the state it
+    wrote."""
+    cfg, params, _, _ = toy
+    b = make_batcher(family, cfg, params, lookahead=False)
+    rng = np.random.default_rng(6)
+    for i, n in enumerate((5, 9, 2)):
+        b.submit(Request(prompt_of(rng, cfg, n), 12, uid=i))
+    for _ in range(3):
+        b.step()
+    tok, pos = jnp.asarray(b.tok), jnp.asarray(b.pos)
+    copy = lambda tree: jax.tree.map(jnp.copy, tree)
+    before = copy(b.cache)
+    logits1, once = b._step(b.params, copy(before), tok, pos)
+    kept = copy(once)                       # the step donates its cache
+    logits2, twice = b._step(b.params, once, tok, pos)
+    np.testing.assert_array_equal(np.asarray(logits1), np.asarray(logits2))
+    for name, leaf in twice.items():
+        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(kept[name]),
+                                      err_msg=name)
+    # and the step did move the state: the test would see a double advance
+    assert not np.array_equal(np.asarray(before[family.state_pool]),
+                              np.asarray(twice[family.state_pool]))
 
 
 def test_engine_serves_it_and_the_spans_carry_the_counters(family, toy):

@@ -582,8 +582,9 @@ def _compiled_admission(cfg, spec, mesh, params, cache, L: int):
             fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
             out_specs=(cs, P(), P()), check_vma=False),
             donate_argnums=(1,)).lower(
-            params, cache, _struct((32, L), jnp.int32, rep),
-            _struct((32,), jnp.bool_, rep), _struct((32,), jnp.int32, rep)
+            params, cache, _struct((cfg.batch, L), jnp.int32, rep),
+            _struct((cfg.batch,), jnp.bool_, rep),
+            _struct((cfg.batch,), jnp.int32, rep)
         ).compile()
     finally:
         tdt_config.update(interpret=posture)
@@ -797,3 +798,71 @@ def test_dots_admission_compiles_with_no_square_of_float_scores(dots):
     # what is held to is the block of scores and the temporaries' size)
     assert "s8[1,8192,8192]" in text and "f32[1024,8192]" in text
     assert mem.temp_size_in_bytes < 2.5e9
+
+
+@pytest.fixture(scope="module")
+def brumby(topo):
+    """``(cfg, spec, mesh, params' and cache's shapes)`` of the
+    benchmark's Brumby configuration on one described chip."""
+    import sys
+
+    perfbench = os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "perfbench")
+    if perfbench not in sys.path:
+        sys.path.insert(0, perfbench)
+    from harness import cells
+
+    from triton_dist_tpu.models.decode import RetentionStateCacheSpec
+    from triton_dist_tpu.models.retention import init_retention_params
+
+    cell = cells.Cell(cells.benchmark(), "brumby-14b-base.doc-reason-b16")
+    adapter = cells.load_module("programs", cell.config["program"])
+    cfg = adapter.model_config(cell.config, interpret=False)
+    eng = cell.config["engine"]
+    spec = RetentionStateCacheSpec(eng["s_max"], eng["page"], static_table=True)
+    mesh = Mesh(np.array(topo.devices[:1]), (cfg.axis,))
+    place = lambda shapes, specs: jax.tree.map(
+        lambda x, s: _struct(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, specs)
+    params = place(
+        jax.eval_shape(functools.partial(init_retention_params, cfg=cfg),
+                       jax.random.PRNGKey(0)), cfg.param_specs())
+    cache = place(jax.eval_shape(lambda: spec.init(cfg, 1)), spec.specs(cfg))
+    return cfg, spec, mesh, params, cache
+
+
+def test_brumby_step_compiles_with_the_state_in_place(brumby):
+    """The power-retention step at the published widths: 16 slots, 6
+    layers, a kv head's state of 8704 x 128 float32 walked in row tiles;
+    both pools (6.9 GB) aliased in and out, so that the step holds the
+    state once, and the temporaries are a token's."""
+    cfg, spec, mesh, params, cache = brumby
+    assert cache["s"].shape == (6, 2, 16, 8, 8704, 128)
+    compiled = _compiled_step(cfg, spec, mesh, params, cache)
+    text = compiled.as_text()
+    assert text.count("retention_update") >= cfg.n_layers
+    mem = compiled.memory_analysis()
+    pools = sum(int(np.prod(x.shape)) * 4 for x in cache.values())
+    assert pools == cfg.state_bytes() and mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 0.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+def test_brumby_admission_compiles_at_bucket_8192_beside_the_state(brumby):
+    """One slot's admission at bucket 8192 through the chunked kernel (16
+    chunks of 512 a kv head, the state resident): no array holds ``L x L``
+    weights, and the temporaries fit beside the weights and the state."""
+    L = 8192
+    compiled = _compiled_admission(*brumby, L)
+    text = compiled.as_text()
+    assert "retention_prefill" in text
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:bf16|f32|s32)\[([\d,]+)\]", text)}
+    # (the MLP's [L, 17408] and [L, 34816] are wider than L and are rows)
+    assert not [s for s in shapes if sum(d == L for d in s) >= 2]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= brumby[0].state_bytes()
+    print("brumby admission: temporaries", mem.temp_size_in_bytes,
+          "arguments", mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 1.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9

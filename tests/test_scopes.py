@@ -76,8 +76,8 @@ def test_every_part_of_a_dense_pass_says_which_part_it_is(which):
 
 
 def test_the_helper_opens_names_of_the_table_and_refuses_any_other():
-    assert set(scopes.PARTS) == {"attn", "ffn", "ssm", "head"}
-    assert len(scopes.NAMES) == 4 + sum(map(len, scopes.PARTS.values()))
+    assert set(scopes.PARTS) == {"attn", "ffn", "ssm", "retn", "head"}
+    assert len(scopes.NAMES) == 5 + sum(map(len, scopes.PARTS.values()))
 
     @jax.jit
     def f(x):

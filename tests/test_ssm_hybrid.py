@@ -25,8 +25,11 @@ from family_tier import (  # noqa: F401
     PERFBENCH, TOL, Family, Recording, _ref_logits, adapter, admit, cells,
     family, make_batcher, prompt_of, pytest_generate_tests, random_cache, ref,
     sampled_rows_match, served, sized, tiled_kernels_at_toy_buckets, toy,
+    test_a_step_sent_in_vain_serves_the_plain_rounds_tokens,
     test_batcher_prefill_then_decode_matches_reference,
+    test_decode_step_twice_on_the_same_inputs_is_decode_step_once,
     test_every_part_of_a_pass_says_which_part_it_is,
+    test_token_fed_admission_matches_reference,
 )
 from family_tier import (  # noqa: F401
     test_engine_serves_it_and_the_spans_carry_the_counters
@@ -85,7 +88,7 @@ FAMILY = Family(
     refused=("prefix cache", "ranged prefill", "contiguous cache",
              "wider mesh", "wider mesh, the spec", "verify", "the dense step",
              "speculative decoding", "handoff", "scratch page"),
-    refusal_says=("kv_state", "one-device"),
+    refusal_says=("kv_state", "one-device"), state_pool="ssm",
     engine=dict(requests=[(6, 5), (9, 4), (3, 5), (5, 3)], rebuild_after=3,
                 check=_engine_spans),
 )
@@ -169,23 +172,6 @@ def test_selective_state_update_against_its_twin_and_the_recurrence(ref, stored)
                                   np.asarray(pool[1, 0, 0]))
 
 
-# -- (b) the prompt a token a step ------------------------------------------------
-
-def test_token_fed_admission_matches_reference(toy, ref):
-    """``prefill=False``: the prompt goes in a token a step from position
-    0, where the step reads zeros for the state whatever the slot holds."""
-    cfg, params, _, _ = toy
-    batcher = make_batcher(FAMILY, cfg, params, prefill=False)
-    rng = np.random.default_rng(5)
-    reqs = [Recording(prompt_of(rng, cfg, n), 2, temperature=1.0, uid=i)
-            for i, n in enumerate((3, 2, 4, 2))]      # the 4th re-uses a slot
-    for r in reqs:
-        batcher.submit(r)
-    done = dict(batcher.run())
-    for r in reqs:
-        sampled_rows_match(FAMILY, ref, toy, r, done[r.uid])
-
-
 # -- (c), (f) what an admission writes -------------------------------------------
 
 def test_a_prompt_shorter_than_its_bucket_leaves_the_state_of_its_length(
@@ -243,60 +229,6 @@ def test_an_admission_changes_no_other_slots_state_or_pages(toy):
     for name in ("k", "v"):
         np.testing.assert_array_equal(np.asarray(after[name][:, pages]),
                                       np.asarray(before[name][:, pages]))
-
-
-# -- (d) a step sent in vain ------------------------------------------------------
-
-def _run_with_a_late_arrival(cfg, params, **kw):
-    """Two requests decode on three slots; a third arrives after the third
-    step, while a step may be out ahead."""
-    rng = np.random.default_rng(4)
-    b = make_batcher(FAMILY, cfg, params, **kw)
-    for i, (n, new) in enumerate([(5, 7), (3, 6)]):
-        b.submit(Request(prompt_of(rng, cfg, n), new, uid=i))
-    for _ in range(3):
-        b.step()
-    b.submit(Request(prompt_of(rng, cfg, 6), 3, uid="late"))
-    return dict(b.run(max_steps=200)), b
-
-
-def test_a_step_sent_in_vain_serves_the_plain_rounds_tokens(toy):
-    """Lookahead on: the late admission moves the cache under a step that
-    has ALREADY advanced every live slot's state in the donated cache; the
-    step runs again and every slot's tokens are the plain batcher's."""
-    cfg, params, _, _ = toy
-    want, plain = _run_with_a_late_arrival(cfg, params, lookahead=False)
-    got, b = _run_with_a_late_arrival(cfg, params)
-    assert b.ahead_discarded >= 1 and b.rounds_ahead > 0
-    assert plain.rounds_ahead == 0 and b.rounds == plain.rounds
-    assert got == want
-
-
-def test_decode_step_twice_on_the_same_inputs_is_decode_step_once(toy):
-    """The planted form: the same ``(tok, pos)`` through the batcher's own
-    step program twice gives the same logits and, bit for bit, the same
-    cache: the second run read the state the first read, not the state it
-    wrote."""
-    cfg, params, _, _ = toy
-    b = make_batcher(FAMILY, cfg, params, lookahead=False)
-    rng = np.random.default_rng(6)
-    for i, n in enumerate((5, 9, 2)):
-        b.submit(Request(prompt_of(rng, cfg, n), 12, uid=i))
-    for _ in range(3):
-        b.step()
-    tok, pos = jnp.asarray(b.tok), jnp.asarray(b.pos)
-    copy = lambda tree: jax.tree.map(jnp.copy, tree)
-    before = copy(b.cache)
-    logits1, once = b._step(b.params, copy(before), tok, pos)
-    kept = copy(once)                       # the step donates its cache
-    logits2, twice = b._step(b.params, once, tok, pos)
-    np.testing.assert_array_equal(np.asarray(logits1), np.asarray(logits2))
-    for name, leaf in twice.items():
-        np.testing.assert_array_equal(np.asarray(leaf), np.asarray(kept[name]),
-                                      err_msg=name)
-    # and the step did move the state: the test would see a double advance
-    assert not np.array_equal(np.asarray(before["ssm"]),
-                              np.asarray(twice["ssm"]))
 
 
 # -- (e) a slot that served before ----------------------------------------------

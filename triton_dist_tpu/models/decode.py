@@ -884,16 +884,7 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
     kind: ClassVar[str] = "kv_state"
 
     def init(self, cfg, n: int, n_o: int = 1) -> dict:
-        if not self.static_table:
-            raise NotImplementedError(
-                "the kv_state cache kind needs static_table=True")
-        if n != 1 or n_o != 1:
-            raise NotImplementedError(
-                f"the kv_state cache kind lives on a one-device shard "
-                f"(got {n_o} x {n} devices): a slot's state is not sharded "
-                f"over channels yet")
-        if self.extra_pages:
-            refuse_state("the prefix cache (extra_pages: its scratch page)")
+        _state_kind_serves(self, n, n_o)
         n_pool, bt = self._table(cfg, n, n_o)
         kinds, b = cfg.layer_kinds, cfg.batch
         n_attn, n_mamba = kinds.count("attention"), kinds.count("mamba")
@@ -1029,13 +1020,104 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
         refuse_state("speculative verify / ranged prefill")
 
 
-def refuse_state(what: str):
+@dataclasses.dataclass(frozen=True)
+class RetentionStateCacheSpec(PagedKVCacheSpec):
+    """The paged cache's fifth KIND, which has NO PAGES: per-slot MATRIX
+    STATE alone, for a model whose every layer is a linear attention
+    (``models/retention.py``). It is a kind of its own rather than
+    ``StatePagedKVCacheSpec`` with empty k/v pools: that class is the two
+    pools' side-by-side bookkeeping (tables, page writes, a convolution's
+    ring), none of which a layer here has, and what the two share is the
+    contract below and :func:`refuse_state`. ``page_size`` is taken (the
+    batcher gives every paged kind one) and unused.
+
+    Float32, a fixed size a slot whatever the context: ``s [n_layers, 2,
+    slots, h_kv, D, d]`` (``D = cfg.state_rows``: ``phi(k) v^T`` summed) and
+    ``z [n_layers, 2, slots, h_kv, d, d]`` (``k k^T`` summed, the
+    normaliser). Both are KEYED BY POSITION as ``kv_state``'s recurrence
+    is: the state after position ``p`` lives at ``[:, p % 2]``; a step at
+    ``pos`` reads ``(pos - 1) % 2`` and writes ``pos % 2``, never the row it
+    reads, which makes the step REPEATABLE (``PAGED_CACHE_KINDS``) at the
+    price of the state twice. A step at position 0 reads zeros; an
+    admission by prefill OVERWRITES the slot's ``s`` and ``z`` of its last
+    true position WHOLE (``write_state``; the other parity is written by
+    the slot's next step before any step reads it); nothing is cleared when
+    a request leaves, and a vacated slot's stale state is never read.
+
+    What needs a sequence's state at MORE than its last position refuses
+    this kind by name (:func:`refuse_state`), as ``kv_state`` does."""
+
+    kind: ClassVar[str] = "state"
+
+    def init(self, cfg, n: int, n_o: int = 1) -> dict:
+        _state_kind_serves(self, n, n_o)
+        lead = (cfg.n_layers, 2, cfg.batch, cfg.n_kv_heads)
+        d = cfg.head_dim
+        return dict(s=jnp.zeros((*lead, cfg.state_rows, d), jnp.float32),
+                    z=jnp.zeros((*lead, d, d), jnp.float32))
+
+    def specs(self, cfg) -> dict:
+        slots = P(None, None, cfg.axis, None, None, None)
+        return dict(s=slots, z=slots)
+
+    def attention_layers(self, cfg) -> int:
+        return 0
+
+    def state_step(self, cache, li: int, q, k, v, log_g, pos_b, interpret):
+        """One step of layer ``li`` for every slot
+        (``ops/retention.retention_update``): reads the state after ``pos -
+        1`` (zeros at position 0), writes the state after ``pos``. ``(y [b,
+        h_q, d] f32, cache)``."""
+        from triton_dist_tpu.ops.retention import retention_update
+
+        with _scope("retn/update"):
+            y, s, z = retention_update(cache["s"], cache["z"], li, pos_b, q,
+                                       k, v, log_g, interpret=interpret)
+        return y, dict(cache, s=s, z=z)
+
+    def write_state(self, cache, li: int, slots, lens, s, z):
+        """An admission's state of layer ``li`` for ``slots [n]`` whose
+        prompts hold ``lens [n]`` true tokens: ``s [n, h_kv, D, d]``, ``z
+        [n, h_kv, d, d]`` after the LAST TRUE token. No other slot's rows
+        are touched."""
+        at = (li, (lens - 1) % 2, slots)
+        return dict(cache, s=cache["s"].at[at].set(s),
+                    z=cache["z"].at[at].set(z))
+
+    def update_and_attend(self, *a, **kw):
+        raise NotImplementedError(
+            "the dense family's decode step reads k/v pools of every layer: "
+            "a state model walks its own plan (cfg.decode_step)")
+
+    def update_multi_and_attend(self, *a, **kw):
+        refuse_state("speculative verify / ranged prefill", self.kind)
+
+
+# the kinds whose slots hold a state after their last position only
+STATE_CACHE_KINDS = (StatePagedKVCacheSpec.kind, RetentionStateCacheSpec.kind)
+
+
+def _state_kind_serves(spec, n: int, n_o: int) -> None:
+    """What ``init`` of a state kind checks before it builds its pools."""
+    if not spec.static_table:
+        raise NotImplementedError(
+            f"the {spec.kind} cache kind needs static_table=True")
+    if n != 1 or n_o != 1:
+        raise NotImplementedError(
+            f"the {spec.kind} cache kind lives on a one-device shard "
+            f"(got {n_o} x {n} devices): a slot's state is not sharded yet")
+    if spec.extra_pages:
+        refuse_state("the prefix cache (extra_pages: its scratch page)",
+                     spec.kind)
+
+
+def refuse_state(what: str, kind: str = "kv_state"):
     raise NotImplementedError(
-        f"{what} is not built for the kv_state cache kind "
-        f"(StatePagedKVCacheSpec): a slot's recurrent state is the state "
-        f"after its LAST position only, and it needs the state at a "
-        f"position inside the sequence (to share, to resume from, to roll "
-        f"back to or to hand over)")
+        f"{what} is not built for the {kind} cache kind "
+        f"({PAGED_CACHE_KINDS[kind].__name__}): a slot's recurrent state is "
+        f"the state after its LAST position only, and it needs the state "
+        f"at a position inside the sequence (to share, to resume from, to "
+        f"roll back to or to hand over)")
 
 
 # a config's ``cache_kind`` -> the paged cache its family's passes use.
@@ -1051,11 +1133,13 @@ def refuse_state(what: str):
 # the same k/v rows again); ``kv_state`` keeps it by keying its state the
 # same way: a parity axis of 2 on the recurrence's state and a ring of
 # ``d_conv`` rows on the convolution's inputs, read at ``pos - 1`` and
-# before, written at ``pos`` (``StatePagedKVCacheSpec``).
+# before, written at ``pos`` (``StatePagedKVCacheSpec``); ``state`` by the
+# same parity axis on its matrix state, which is all it holds
+# (``RetentionStateCacheSpec``).
 PAGED_CACHE_KINDS = {
     spec.kind: spec for spec in (
         PagedKVCacheSpec, LatentPagedCacheSpec, WindowPagedKVCacheSpec,
-        StatePagedKVCacheSpec)}
+        StatePagedKVCacheSpec, RetentionStateCacheSpec)}
 
 
 def _decode_mlp(c, x, p, me, n, n_o, interpret):

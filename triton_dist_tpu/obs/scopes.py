@@ -10,7 +10,7 @@ instruction's name. The names are API like the ``tdt.*`` span names
 (docs/observability.md, "Scopes in the device trace"): a reader outside
 the package groups device time by them.
 
-Four parts, a closed set; under each, sub-parts for the pieces of work a
+Five parts, a closed set; under each, sub-parts for the pieces of work a
 metric or a ROADMAP item names. A part is a kind of work, not a layer:
 sixteen layers' ops group under one name.
 """
@@ -36,6 +36,12 @@ PARTS: dict[str, tuple[str, ...]] = {
     # its ring, the dt / B / C projections, the recurrence kernel, the
     # gate and out-projection
     "ssm": ("proj", "conv", "scan"),
+    # a power-retention mixer whole: norm, the q/k/v projection with the
+    # head norms and the rotation (``qkv``), the gate's projection and
+    # log-sigmoid, the step's in-place state kernel (``update``) or an
+    # admission's chunked kernel and the state's write (``prefill``), the
+    # out-projection
+    "retn": ("qkv", "gate", "update", "prefill", "out"),
     # the vocabulary's two ends: the embedding lookup; the final norm, the
     # head's GEMV and the logits' gather
     "head": (),

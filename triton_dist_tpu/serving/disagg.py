@@ -62,7 +62,7 @@ from jax.sharding import Mesh
 from triton_dist_tpu import obs as _obs
 from triton_dist_tpu.obs import metrics as _mx
 from triton_dist_tpu.models.decode import (
-    Request, refuse_ring, refuse_state,
+    Request, STATE_CACHE_KINDS, refuse_ring, refuse_state,
 )
 from triton_dist_tpu.resilience import elastic, faults, health
 from triton_dist_tpu.resilience import retry as _retry
@@ -360,9 +360,10 @@ class DisaggServingEngine:
         if cfg.cache_kind == "kv_window":
             refuse_ring("the disaggregated handoff (whole page chains "
                         "streamed between pools)")
-        if cfg.cache_kind == "kv_state":
+        if cfg.cache_kind in STATE_CACHE_KINDS:
             refuse_state("the disaggregated handoff (page chains streamed "
-                         "between pools; a slot's state rides no page)")
+                         "between pools; a slot's state rides no page)",
+                         cfg.cache_kind)
         self.serving = (serving or DisaggServingConfig()).validate()
         # the elastic namespace BOTH pools share (pool-offset PE
         # attribution keys it by topology-global index); None = the

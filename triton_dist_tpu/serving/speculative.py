@@ -90,6 +90,7 @@ from triton_dist_tpu.models.decode import (
     prefill_cache_ranged,
     refuse_ring,
     refuse_state,
+    STATE_CACHE_KINDS,
     specs_for,
 )
 from triton_dist_tpu.models.speculative import accept_lengths
@@ -183,9 +184,9 @@ class SpeculativeBatcher(ContinuousBatcher):
                 "kind (LatentPagedCacheSpec): its verify step reads k/v pools")
         if cfg.cache_kind == "kv_window":
             refuse_ring("speculative decoding (its verify step)")
-        if cfg.cache_kind == "kv_state":
+        if cfg.cache_kind in STATE_CACHE_KINDS:
             refuse_state("speculative decoding (its verify step and the "
-                         "roll-back of rejected drafts)")
+                         "roll-back of rejected drafts)", cfg.cache_kind)
         if kw.pop("lookahead", False):
             raise NotImplementedError(
                 "lookahead sends the plain step ahead of its round; a "
