@@ -531,6 +531,41 @@ def test_an_admission_runs_and_writes_the_admitted_slot_only(
     family.admitted([int(v) for v in counters], bucket)
 
 
+def test_a_backlog_is_admitted_a_slot_a_pass(family, toy):
+    """The batcher fills a whole-batch pass's rows with the queued
+    requests of its bucket (docs/serving.md "The admission's
+    discipline"); a plan family's pass runs the ONE slot its mask names
+    (``gated_experts.admitted_rows``: ``argmax`` of a mask that must be
+    one-hot), so a backlog of one bucket on free slots is still a pass a
+    request, each handed a one-hot mask, in slot order. The bucket's
+    program is a stand-in that keeps what it was given: nothing is
+    traced."""
+    cfg, params, _, _ = toy
+    batcher = make_batcher(family, cfg, params)
+    assert cfg.own_passes and not batcher._fills_rows
+    given = []
+
+    def program(bucket):
+        def run(params, cache, prompt, mask, pick):
+            given.append((bucket, np.asarray(mask), np.asarray(pick)))
+            return cache, jnp.zeros((cfg.batch, cfg.vocab))
+        return run
+
+    batcher._prefill_prog = program
+    batcher._pass_stats = np.zeros(len(batcher._counters), np.int32)
+    rng = np.random.default_rng(0)
+    lens = [5 + i % 3 for i in range(cfg.batch + 1)]     # bucket 8, all
+    for i, n in enumerate(lens):
+        batcher.submit(Request(prompt_of(rng, cfg, n), 3, uid=i))
+    batcher._admit()
+    assert batcher.prefill_passes_total == len(given) == cfg.batch
+    assert [r.uid for r in batcher.queue] == [cfg.batch]
+    for slot, (bucket, mask, pick) in enumerate(given):
+        assert bucket == 8 and mask.tolist() == [
+            i == slot for i in range(cfg.batch)]
+        assert pick[slot] == lens[slot] - 1 and pick.sum() == pick[slot]
+
+
 def test_the_lowered_admission_does_not_grow_with_the_batch(family, toy):
     """The grouped GEMMs of an admission read the same operands at 2 slots
     and at 4: one slot's ``bucket x topk`` assignments, each expert padded

@@ -618,6 +618,42 @@ def test_serving_poison_quarantine_survivors_byte_identical(tiny1, _mesh1):
 
 
 @pytest.mark.chaos
+def test_a_members_non_finite_prefill_logits_poison_its_slot_only(
+        tiny1, _mesh1):
+    """A bucket pass that admits two requests (docs/serving.md "The
+    admission's discipline") keeps the finite check a member's own: the
+    one whose row came back NaN is evicted before a token exists, the
+    member beside it in the pass is served what it gets alone."""
+    from triton_dist_tpu.models.decode import ContinuousBatcher
+
+    cfg, params = tiny1
+    shapes = [(3, 4), (4, 5)]
+
+    def batcher(reqs):
+        b = ContinuousBatcher(cfg, params, _mesh1, s_max=16, prefill=True)
+        for r in reqs:
+            b.submit(r)
+        return b
+
+    want = dict(batcher(_requests(cfg, shapes)[1:]).run(max_steps=50))
+    b = batcher(_requests(cfg, shapes))
+    prog = b._prefill_prog(4)
+
+    def nan_in_row_0(*args):
+        cache, last = prog(*args)
+        return cache, last.at[0].set(jnp.nan)
+
+    b._prefill_progs[4] = nan_in_row_0
+    tdt_config.update(integrity=IntegrityConfig())
+    b._admit()
+    assert b.prefill_passes_total == 1
+    assert b.drain_poisoned() == [(0, [], "non-finite prefill logits")]
+    assert [r and r.uid for r in b.slot_req] == [None, 1]
+    assert dict(b.run(max_steps=50)) == want
+    assert health.counters()[("continuous_batcher", health.POISONED)] == 1
+
+
+@pytest.mark.chaos
 @pytest.mark.parametrize("pools", ["v", "kv"])
 def test_serving_nan_kv_stays_in_its_slot(_mesh1, pools):
     """The containment ``_poison_slot`` argues from, held at the KERNEL

@@ -285,3 +285,136 @@ def test_speculative_batcher_runs_plain_rounds_and_refuses_lookahead(
     assert b.lookahead is False
     b.submit(_reqs(cfg, [(3, 5)])[0])
     assert len(dict(b.run(max_steps=50))[0]) == 5 and b.rounds_ahead == 0
+
+
+# -- the admission's discipline: a whole-batch pass fills its rows ---------------
+# (docs/serving.md "The admission's discipline"). The dense family's bucket
+# prefill runs every slot's rows, so ``_admit`` hands the queued requests of
+# one bucket to ONE pass; what a request is served must not depend on who
+# shares its pass.
+
+def _mixed(cfg, shapes, prompts=11):
+    """Requests of ``shapes``; every odd one samples on its own seed."""
+    reqs = _reqs(cfg, shapes, prompts)
+    for r in reqs[1::2]:
+        r.temperature, r.seed = 0.8, 3 + r.uid
+    return reqs
+
+
+def _admitted(cfg, params, mesh, reqs, a_pass_each=False, **kw):
+    """A batcher that has admitted ``reqs`` and run no round: as a backlog
+    (the sweep's requests of a bucket share a pass) or, the old order, an
+    ``_admit`` a request."""
+    b = ContinuousBatcher(cfg, params, mesh, s_max=32, prefill=True, **kw)
+    for r in reqs:
+        b.submit(r)
+        if a_pass_each:
+            b._admit()
+    b._admit()
+    return b
+
+
+def _served(cfg, params, mesh, reqs, a_pass_each=False, **kw):
+    """Tokens by uid and the batcher: ``reqs`` queued at once or, the old
+    order, one submitted a step (each then finds its slot alone)."""
+    b = ContinuousBatcher(cfg, params, mesh, s_max=32, prefill=True, **kw)
+    for r in reqs:
+        b.submit(r)
+        if a_pass_each:
+            b.step()
+    return dict(b.run(max_steps=400)), b
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(page_size=8)],
+                         ids=["contiguous", "paged"])
+def test_a_backlog_of_mixed_buckets_is_served_a_request_a_passes_tokens(
+        tiny, mesh1, kw):
+    """Buckets 4 and 8 queued at once on two slots: the first sweep's two
+    share a pass, and so do the two of bucket 8 whose slots free in one
+    round; the last two differ in bucket. Request by request the tokens
+    are those of a pass each, greedy and sampled, on a fresh slot and on
+    one that served before."""
+    cfg, params = tiny
+    shapes = [(3, 3), (4, 3), (5, 3), (6, 3), (3, 2), (7, 3)]
+    want, alone = _served(cfg, params, mesh1, _mixed(cfg, shapes),
+                          a_pass_each=True, **kw)
+    got, b = _served(cfg, params, mesh1, _mixed(cfg, shapes), **kw)
+    assert got == want and set(got) == set(range(len(shapes)))
+    assert (alone.prefill_passes_total, b.prefill_passes_total) == (6, 4)
+    assert b.prefill_tokens_total == alone.prefill_tokens_total == sum(
+        n for n, _ in shapes)
+
+
+def test_a_shared_pass_on_a_mesh_of_four_leaves_what_a_pass_each_leaves(
+        mesh4):
+    """Under ``jit_shard_map`` over four devices the pass's rows are dealt
+    to the devices by position and the mask gates each device's page
+    writes: two requests in one pass leave, bit for bit, the cache, the
+    first tokens and the positions that a pass each leaves. No round: an
+    interpreted admission on four devices is ~14 s, and a round after it
+    reads nothing else."""
+    cfg, params = _tiny(4)
+    want, got = (_admitted(cfg, params, mesh4, _mixed(cfg, [(6, 3), (5, 3)]),
+                           a_pass_each=each, page_size=8)
+                 for each in (True, False))
+    assert (want.prefill_passes_total, got.prefill_passes_total) == (2, 1)
+    assert got.slot_out == want.slot_out and len(got.slot_out[1]) == 1
+    assert got.tok.tolist() == want.tok.tolist()
+    assert got.pos.tolist() == want.pos.tolist() == [6, 5]
+    for pool in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got.cache[pool]),
+                                      np.asarray(want.cache[pool]))
+
+
+def test_a_member_that_finishes_in_its_pass_frees_its_slot_for_the_queue(
+        tiny, mesh1):
+    """``max_new_tokens=1`` ends a member inside its group: its slot goes
+    to the next queued request in the same ``_admit``, in a second pass."""
+    cfg, params = tiny
+    shapes = [(3, 1), (4, 3), (3, 4)]
+    want = {r.uid: _served(cfg, params, mesh1, [r])[0][r.uid]
+            for r in _reqs(cfg, shapes)}
+    b = ContinuousBatcher(cfg, params, mesh1, s_max=32, prefill=True)
+    for r in _reqs(cfg, shapes):
+        b.submit(r)
+    b._admit()
+    assert b.finished == [(0, want[0])] and len(want[0]) == 1
+    assert [r.uid for r in b.slot_req] == [2, 1] and not b.queue
+    assert b.prefill_passes_total == 2
+    assert dict(b.run(max_steps=50)) == want
+
+
+@pytest.mark.parametrize("model, kw, shares", [
+    ("MoETransformerConfig", dict(), True),
+    ("EPMoETransformerConfig", dict(), True),
+    ("EPMoETransformerConfig", dict(ep_max_m=10), False),
+], ids=["tp_moe", "ep_moe", "ep_moe_capacity"])
+def test_a_routed_stand_in_shares_a_pass_unless_a_capacity_is_set(
+        mesh1, model, kw, shares):
+    """The routed stand-ins' forward runs every row too. Their grouped
+    GEMM and their exchange at its worst-case slab keep a row's sums its
+    own, so they share a pass bit for bit; an exchange with a set
+    capacity drops the assignments past it in row order, so what a
+    request keeps depends on who lies before it in the pass (two layers
+    on two devices, capacity 10: another first token and cache), and it
+    admits a request a pass."""
+    from triton_dist_tpu import models
+    from triton_dist_tpu.ops.group_gemm import GroupGemmConfig
+
+    cfg = getattr(models, model)(
+        vocab=32, hidden=32, ffn=64, n_layers=1, n_q_heads=4, n_kv_heads=2,
+        head_dim=8, batch=2, seq=8, n_experts=4, topk=2,
+        ag_config=AGGemmConfig(8, 16, 16), rs_config=GemmRSConfig(8, 16, 16),
+        gg_config=GroupGemmConfig(4, 32, 32), **kw)
+    params = models.init_moe_params(jax.random.PRNGKey(0), cfg)
+
+    reqs = _reqs(cfg, [(3, 3), (4, 3)])
+    got = _admitted(cfg, params, mesh1, reqs)
+    assert got._fills_rows is shares
+    assert got.prefill_passes_total == (1 if shares else 2)
+    if shares:
+        want = _admitted(cfg, params, mesh1, reqs, a_pass_each=True)
+        assert got.slot_out == want.slot_out
+        for pool in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(got.cache[pool]),
+                                          np.asarray(want.cache[pool]))

@@ -21,6 +21,7 @@ from family_tier import (  # noqa: F401
     recorded_spans, ref, serve_through_the_engine, served, sized,
     tiled_kernels_at_toy_buckets, toy,
     test_an_admission_runs_and_writes_the_admitted_slot_only,
+    test_a_backlog_is_admitted_a_slot_a_pass,
     test_batcher_prefill_then_decode_matches_reference,
     test_every_part_of_a_pass_says_which_part_it_is,
     test_full_forward_matches_reference,

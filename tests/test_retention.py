@@ -28,6 +28,7 @@ from family_tier import (  # noqa: F401
     sampled_rows_match, served, sized, tiled_kernels_at_toy_buckets, toy,
     verdict,
     test_a_step_sent_in_vain_serves_the_plain_rounds_tokens,
+    test_a_backlog_is_admitted_a_slot_a_pass,
     test_batcher_prefill_then_decode_matches_reference,
     test_decode_step_twice_on_the_same_inputs_is_decode_step_once,
     test_every_part_of_a_pass_says_which_part_it_is,

@@ -45,7 +45,8 @@ TABLE = {
         "relaid", "bytes", "relaid_wqkv", "bytes_wqkv"}),
     "tdt.batcher.admit": ("tdt.engine.step", {"queued", "admitted"}),
     "tdt.batcher.admit_prefill": (
-        "tdt.batcher.admit", {"uid", "slot", "prompt_len", "bucket"}),
+        "tdt.batcher.admit",
+        {"uid", "slot", "prompt_len", "bucket", "admitted"}),
     "tdt.batcher.admit_prefill.build": ("tdt.batcher.admit_prefill", set()),
     "tdt.batcher.admit_prefill.dispatch": ("tdt.batcher.admit_prefill", set()),
     "tdt.batcher.admit_prefill.pull": ("tdt.batcher.admit_prefill", set()),
@@ -167,8 +168,11 @@ def test_xplane_holds_every_span_of_the_table_with_its_attributes(traced):
             TABLE[s["name"]][1]), s
     (serve,) = prof.named("tdt.engine.serve")
     assert serve["stats"] == {"offered": 4}
-    assert sorted(s["stats"]["uid"] for s in
-                  prof.named("tdt.batcher.admit_prefill")) == list("abcd")
+    # "a" and "b" are due together and differ in bucket: a pass each; "c"
+    # and "d" share theirs
+    assert [(s["stats"]["uid"], s["stats"]["admitted"], s["stats"]["slot"])
+            for s in prof.named("tdt.batcher.admit_prefill")] == [
+                ("a", 1, 0), ("b", 1, 1), ("c|d", 2, 0)]
     rounds = [s["stats"]["round"] for s in
               prof.named("tdt.batcher.decode_round")]
     assert rounds == list(range(rounds[0], rounds[0] + len(rounds)))
@@ -197,7 +201,8 @@ def test_tokens_are_counted_where_they_are_made(traced):
     prof, results = traced
     made = sum(s["stats"]["tokens"] for s in
                prof.named("tdt.batcher.decode_round"))
-    made += len(prof.named("tdt.batcher.admit_prefill"))
+    made += sum(s["stats"]["admitted"] for s in
+                prof.named("tdt.batcher.admit_prefill"))
     assert made == sum(len(r.tokens) for r in results.values()) == 13
     ended = sum(s["stats"]["finished"] for s in
                 prof.named("tdt.batcher.decode_round"))
@@ -329,6 +334,39 @@ def test_ranged_admission_carries_its_span(tiny1, mesh1, tmp_path):
     assert 1 + sum(s["stats"]["tokens"] for s in rounds) == len(
         results["long"].tokens) == 3
     assert rounds[0]["stats"]["feeding"] == rounds[0]["stats"]["live"] == 1
+
+
+def test_a_pass_a_bucket_and_its_span_counts_its_members(tiny1, mesh1):
+    """Eight queued requests of two buckets on eight free slots are two
+    passes, in the order of the buckets' first members; the spans'
+    ``admitted`` sum to the admissions, ``prompt_len`` is the longest
+    member's, ``slot`` the first one's, and the work counter sweeps a
+    rectangle a PASS."""
+    import dataclasses
+
+    from triton_dist_tpu.models.decode import ContinuousBatcher
+
+    cfg, params = tiny1
+    b = ContinuousBatcher(dataclasses.replace(cfg, batch=8), params, mesh1,
+                          s_max=16, prefill=True, page_size=8)
+    lens = [5, 3, 4, 7, 6, 3, 8, 4]
+    rng = np.random.default_rng(5)
+    for i, n in enumerate(lens):
+        b.submit(Request([int(t) for t in rng.integers(0, cfg.vocab, n)], 3,
+                         uid=i))
+    tdt_config.update(obs=obs.ObsConfig(spans=True))
+    b._admit()
+    ring = obs.spans()
+    passes = [s.attrs for s in ring if s.name == "tdt.batcher.admit_prefill"]
+    (admit,) = [s.attrs for s in ring if s.name == "tdt.batcher.admit"]
+    assert [(p["bucket"], p["admitted"], p["prompt_len"], p["slot"], p["uid"])
+            for p in passes] == [(8, 4, 8, 0, "0|3|4|6"), (4, 4, 4, 1, "1|2|5|7")]
+    assert admit["admitted"] == sum(p["admitted"] for p in passes) == 8
+    assert b.prefill_passes_total == 2 and not b.queue
+    assert b.prefill_tokens_total == sum(lens)
+    assert b.prefill_work_total == 8 * 8 + 4 * 4
+    assert b.pos.tolist() == lens and all(len(o) == 1 for o in b.slot_out)
+    assert len(dict(b.run(max_steps=50))) == 8
 
 
 # -- Finished.t_tokens -------------------------------------------------------

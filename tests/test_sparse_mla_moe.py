@@ -46,6 +46,7 @@ from family_tier import (  # noqa: F401
     random_cache, ref,
     served, sized, tiled_kernels_at_toy_buckets, toy,
     test_an_admission_runs_and_writes_the_admitted_slot_only,
+    test_a_backlog_is_admitted_a_slot_a_pass,
     test_batcher_prefill_then_decode_matches_reference,
     test_engine_serves_it_and_the_spans_carry_the_counters,
     test_every_part_of_a_pass_says_which_part_it_is,

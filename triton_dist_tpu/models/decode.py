@@ -1682,6 +1682,19 @@ class ContinuousBatcher:
         # cumulative REAL prompt tokens run through the MXU prefill paths
         # (bucket prefill + ranged chunks; pad positions excluded)
         self.prefill_tokens_total = 0
+        # cumulative bucket-prefill PASSES: requests admitted through them
+        # over this = requests a pass (``_admit`` fills a pass's rows)
+        self.prefill_passes_total = 0
+        # The admission's discipline (docs/serving.md). The dense family's
+        # bucket pass runs EVERY slot's rows, so the queued requests of one
+        # bucket share it: rows never mix across the batch dim, a request's
+        # rows go through the program they would have run alone. A
+        # layer-plan family's pass runs the ONE slot its one-hot mask names
+        # (``gated_experts.admitted_rows``), and an expert exchange with a
+        # set capacity (``ep_max_m``) drops rows by who else is in the
+        # pass: both admit one request a pass.
+        self._fills_rows = not cfg.own_passes and (
+            getattr(cfg, "ep_max_m", None) is None)
         # cumulative prefill WORK in swept query×key token-pairs: a bulk
         # bucket pass computes the dense padded bucket×bucket rectangle
         # (every query row against every key slot, mask applied after),
@@ -1853,12 +1866,17 @@ class ContinuousBatcher:
                     jax.device_put(self.pos.copy(), rep), None)
         return jnp.asarray(self.tok), jnp.asarray(self.pos), None
 
-    def _pull_last(self, sp, last, i: int) -> np.ndarray:
-        """An admission's pull: slot ``i``'s logit row (and the pass's
-        counters, where it declares any, onto the admission's span)."""
+    def _pull_last(self, sp, last, slots: list) -> np.ndarray:
+        """An admission's pull: the logit rows of ``slots`` (and the
+        pass's counters, where it declares any, onto the admission's
+        span). A pass that fills its rows pulls ``last`` whole, a
+        transfer: a program that took the members' rows on the device
+        would be one a group size, compiled when a group first has it."""
         if self._counters:
             self._set_counters(sp, np.asarray(self._pass_stats))
-        return np.asarray(last[i], np.float32)
+        if self._fills_rows:
+            return np.asarray(last, np.float32)[slots]
+        return np.asarray(last[slots[0]], np.float32)[None]
 
     @property
     def params(self) -> dict:
@@ -2071,26 +2089,30 @@ class ContinuousBatcher:
                         self._px_dirty = True
             if hi < L:
                 return  # mid-prompt chunk: no token to sample yet
-            from triton_dist_tpu.resilience import integrity as _integrity
+            self._first_token(i, req, np.asarray(logits[i, S - 1], np.float32))
 
-            last_i = np.asarray(logits[i, S - 1], np.float32)
-            if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
-                # poisoned at admission: quarantine before a token exists
-                self._poison_slot(i, "non-finite prefill logits")
-                return
-            t0 = req.sample(last_i, self.slot_rng[i])
-            self.slot_fed[i] = L
-            self.slot_out[i] = [t0]
-            self.tok[i] = t0
-            self.pos[i] = L
-            if len(self.slot_out[i]) >= req.max_new_tokens or (
-                req.eos_id is not None and t0 == req.eos_id
-            ):
-                self.finished.append((req.uid, self.slot_out[i]))
-                self._vacate(i)
-                if self._px is not None:
-                    self._px.release(i)
-                    self._px_dirty = True
+    def _first_token(self, i: int, req: Request, last_i: np.ndarray) -> None:
+        """What an MXU-rate admission ends with: slot ``i``'s first
+        generated token, sampled from the logits of its prompt's last
+        position, or the slot quarantined before a token exists."""
+        from triton_dist_tpu.resilience import integrity as _integrity
+
+        if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
+            self._poison_slot(i, "non-finite prefill logits")
+            return
+        t0 = req.sample(last_i, self.slot_rng[i])
+        self.slot_fed[i] = len(req.prompt)
+        self.slot_out[i] = [t0]
+        self.tok[i] = t0
+        self.pos[i] = len(req.prompt)
+        if len(self.slot_out[i]) >= req.max_new_tokens or (
+            req.eos_id is not None and t0 == req.eos_id
+        ):
+            self.finished.append((req.uid, self.slot_out[i]))
+            self._vacate(i)
+            if self._px is not None:
+                self._px.release(i)
+                self._px_dirty = True
 
     def _admit_ranged(self, i: int, req: Request, lo: int) -> None:
         """Ranged admission: feed prompt positions ``[lo, L)`` — the
@@ -2108,127 +2130,140 @@ class ContinuousBatcher:
             return
         self._ranged_pass(i, req, lo, len(req.prompt))
 
-    def _prefill_inputs(self, i: int, req: Request, bucket: int) -> tuple:
-        """Slot ``i``'s prompt padded to its bucket, the slot mask and the
-        pick row, uploaded."""
-        L = len(req.prompt)
+    def _prefill_inputs(self, slots: list, reqs: list, bucket: int) -> tuple:
+        """The prompts of ``reqs`` padded to their bucket, each in its
+        slot's row, the mask of ``slots`` and the pick row, uploaded."""
         with _span("tdt.batcher.admit_prefill.build"):
             prompt = np.zeros((self.cfg.batch, bucket), np.int32)
-            prompt[i, :L] = req.prompt
+            mask = np.zeros(self.cfg.batch, bool)
             # pad positions write junk KV beyond L-1, but decode overwrites
             # each position before kv_lens ever exposes it; the first
             # generated token comes from position L-1's logits (pick)
             pick = np.zeros(self.cfg.batch, np.int32)
-            pick[i] = L - 1
-            return (
-                jnp.asarray(prompt),
-                jnp.asarray(np.arange(self.cfg.batch) == i),
-                jnp.asarray(pick),
-            )
+            for i, req in zip(slots, reqs):
+                prompt[i, :len(req.prompt)] = req.prompt
+                mask[i] = True
+                pick[i] = len(req.prompt) - 1
+            return jnp.asarray(prompt), jnp.asarray(mask), jnp.asarray(pick)
 
-    def _admit_prefill(self, i: int, req: Request) -> None:
+    def _admit_prefill(self, i, req) -> None:
         """MXU-rate admission: one masked full-forward pass writes the
-        whole prompt's KV and yields the first generated token."""
-        L = len(req.prompt)
+        whole prompt's KV and yields the first generated token, for slot
+        ``i``'s request ``req`` or, where both are lists (``_admit``: a
+        family whose pass fills its rows), for every member of a group of
+        one bucket, a member a row. The pass costs what it costs with one
+        live row."""
+        slots, reqs = ([i], [req]) if isinstance(req, Request) else (i, req)
+        L = max(len(r.prompt) for r in reqs)
         bucket = self._bucket(L)
-        with _span("tdt.batcher.admit_prefill", uid=str(req.uid), slot=i,
-                   prompt_len=L, bucket=bucket) as sp:
+        with self._pass_span(slots, reqs, L, bucket) as sp:
             self._set_prefill_blocks(sp, L, bucket)
-            args = self._prefill_inputs(i, req, bucket)
+            args = self._prefill_inputs(slots, reqs, bucket)
             with _span("tdt.batcher.admit_prefill.dispatch"):
                 self.cache, last = self._prefill_prog(bucket)(
                     self.params, self.cache, *args)
-            self.prefill_tokens_total += L
+            self.prefill_passes_total += 1
             self.prefill_work_total += bucket * bucket
-            from triton_dist_tpu.resilience import integrity as _integrity
-
             with _span("tdt.batcher.admit_prefill.pull"):
-                last_i = self._pull_last(sp, last, i)
-            if _integrity.output_checks_enabled() and not np.isfinite(last_i).all():
-                # poisoned at admission: quarantine before a token exists
-                self._poison_slot(i, "non-finite prefill logits")
-                return
-            t0 = req.sample(last_i, self.slot_rng[i])
-            self.slot_fed[i] = L
-            self.slot_out[i] = [t0]
-            self.tok[i] = t0
-            self.pos[i] = L
-            if len(self.slot_out[i]) >= req.max_new_tokens or (
-                req.eos_id is not None and t0 == req.eos_id
-            ):
-                self.finished.append((req.uid, self.slot_out[i]))
-                self._vacate(i)
+                rows = self._pull_last(sp, last, slots)
+            # a member at a time in slot order: its own finite check, its
+            # slot's RNG, its instant finish
+            for i, req, last_i in zip(slots, reqs, rows):
+                self.prefill_tokens_total += len(req.prompt)
+                self._first_token(i, req, last_i)
+
+    def _pass_span(self, slots: list, reqs: list, L: int, bucket: int):
+        """The span of one bucket-prefill pass: ``admitted`` members, their
+        uids (``|`` between them: a profiler annotation ends a value at a
+        comma), the first one's slot, the longest prompt."""
+        return _span("tdt.batcher.admit_prefill",
+                     uid="|".join(str(r.uid) for r in reqs), slot=slots[0],
+                     prompt_len=L, bucket=bucket, admitted=len(reqs))
+
+    def _seat(self, i: int, req: Request) -> bool:
+        """Hand free slot ``i`` to ``req`` and admit it, unless it takes
+        the whole-batch bucket prefill (True: ``_admit`` runs that pass)."""
+        self.slot_req[i] = req
+        self.slot_out[i] = []
+        # a live generator (prefix replay) continues sampling mid-stream;
+        # otherwise each admission re-derives the slot RNG from the request
+        # seed (the documented neighbor-independent sampling guarantee)
+        self.slot_rng[i] = (
+            req.rng if req.rng is not None
+            else np.random.default_rng(req.seed)
+        )
+        if self.prefill and len(req.prompt) > 1:
+            if self._px is not None:
+                # px × fast prefill (ISSUE 18): the trie hit's pages are
+                # the ranged pass's already-landed prior — only the
+                # divergent suffix runs. The MISS path rides the same
+                # ranged entry from lo=0, so hit ≡ miss bit for bit (range
+                # composition), and both ≡ the token-fed px engine
+                # (decode-chain equivalence).
+                n_hit = self._px.acquire(i, req.prompt, req.max_new_tokens)
+                self._px_dirty = True
+                self._admit_ranged(i, req, n_hit)
+            elif (self.prefill_chunk_tokens is not None
+                  and len(req.prompt) > self.prefill_chunk_tokens):
+                # chunked-prefill scheduling: park the slot; bounded ranged
+                # chunks land between decode steps. Shorter prompts keep
+                # the legacy bucket prefill byte for byte (the
+                # armed-but-untriggered pin).
+                self._admit_ranged(i, req, 0)
+            else:
+                return True
+        elif self._px is not None:
+            # longest-prefix match (ISSUE 12): every fully shared page is
+            # skipped — the slot starts its feed at the first token whose
+            # KV the trie does not already hold; the divergent page onward
+            # is freshly claimed (CoW), so shared pages are never written
+            n_hit = self._px.acquire(i, req.prompt, req.max_new_tokens)
+            self._px_dirty = True
+            self.pos[i] = n_hit
+            self.tok[i] = req.prompt[n_hit]
+            self.slot_fed[i] = n_hit + 1
+        else:
+            self.pos[i] = 0
+            self.tok[i] = req.prompt[0]
+            self.slot_fed[i] = 1
+        return False
+
+    def _by_bucket(self, waiting: list):
+        """``(slot, request)`` pairs as ``(slots, requests)`` a prompt
+        bucket, the buckets in the order of their first members."""
+        groups: dict[int, tuple[list, list]] = {}
+        for i, req in waiting:
+            slots, reqs = groups.setdefault(
+                self._bucket(len(req.prompt)), ([], []))
+            slots.append(i)
+            reqs.append(req)
+        return groups.values()
 
     def _admit(self) -> None:
         if not self.queue:
             return
         with _span("tdt.batcher.admit", queued=len(self.queue)) as sp:
-            # _admit_prefill can free the slot it just filled (max_new_tokens=1
-            # or instant EOS), so one linear pass would leave that slot empty
-            # until the next step even with queued work — re-pass until a full
-            # sweep admits nothing
+            # a prefill pass can free a slot it just filled (max_new_tokens=1
+            # or instant EOS), so one sweep would leave that slot empty until
+            # the next step even with queued work — sweep again while a slot
+            # is free and a request is queued
             n_admitted = 0
-            admitted = True
-            while admitted and self.queue:
-                admitted = False
+            while self.queue and None in self.slot_req:
+                waiting = []
                 for i, r in enumerate(self.slot_req):
                     if r is None and self.queue:
                         req = self.queue.pop(0)
-                        admitted = True
                         n_admitted += 1
-                        self.slot_req[i] = req
-                        self.slot_out[i] = []
-                        # a live generator (prefix replay) continues sampling
-                        # mid-stream; otherwise each admission re-derives the
-                        # slot RNG from the request seed (the documented
-                        # neighbor-independent sampling guarantee)
-                        self.slot_rng[i] = (
-                            req.rng if req.rng is not None
-                            else np.random.default_rng(req.seed)
-                        )
-                        if self.prefill and len(req.prompt) > 1:
-                            if self._px is not None:
-                                # px × fast prefill (ISSUE 18): the trie hit's
-                                # pages are the ranged pass's already-landed
-                                # prior — only the divergent suffix runs. The
-                                # MISS path rides the same ranged entry from
-                                # lo=0, so hit ≡ miss bit for bit (range
-                                # composition), and both ≡ the token-fed px
-                                # engine (decode-chain equivalence).
-                                n_hit = self._px.acquire(
-                                    i, req.prompt, req.max_new_tokens
-                                )
-                                self._px_dirty = True
-                                self._admit_ranged(i, req, n_hit)
-                            elif (self.prefill_chunk_tokens is not None
-                                  and len(req.prompt)
-                                  > self.prefill_chunk_tokens):
-                                # chunked-prefill scheduling: park the slot;
-                                # bounded ranged chunks land between decode
-                                # steps. Shorter prompts keep the legacy
-                                # bucket prefill byte for byte (the
-                                # armed-but-untriggered pin).
-                                self._admit_ranged(i, req, 0)
-                            else:
-                                self._admit_prefill(i, req)
-                        elif self._px is not None:
-                            # longest-prefix match (ISSUE 12): every fully
-                            # shared page is skipped — the slot starts its
-                            # feed at the first token whose KV the trie does
-                            # not already hold; the divergent page onward is
-                            # freshly claimed (CoW), so shared pages are
-                            # never written
-                            n_hit = self._px.acquire(
-                                i, req.prompt, req.max_new_tokens
-                            )
-                            self._px_dirty = True
-                            self.pos[i] = n_hit
-                            self.tok[i] = req.prompt[n_hit]
-                            self.slot_fed[i] = n_hit + 1
+                        if not self._seat(i, req):
+                            continue
+                        if self._fills_rows:
+                            # the pass runs every slot's rows: it waits
+                            # for the sweep's other requests of its bucket
+                            waiting.append((i, req))
                         else:
-                            self.pos[i] = 0
-                            self.tok[i] = req.prompt[0]
-                            self.slot_fed[i] = 1
+                            self._admit_prefill(i, req)
+                for group in self._by_bucket(waiting):
+                    self._admit_prefill(*group)
             sp.set("admitted", n_admitted)
 
     @property
