@@ -210,11 +210,14 @@ def lowered_programs(batcher) -> dict:
             params, cache, i32(b), i32(b)
         ).as_text()
     }
+    # the group as the program takes it: slots, pick and the members'
+    # count where it walks them (one chip), a mask and pick where it runs
+    # the whole batch masked
+    group = ((i32(b), i32(b), i32()) if batcher._walks_members
+             else (jax.ShapeDtypeStruct((b,), jnp.bool_), i32(b)))
     for bucket, prog in sorted(batcher._prefill_progs.items()):
         out[f"prefill{bucket}"] = prog.jitted.lower(
-            params, cache, i32(b, bucket),
-            jax.ShapeDtypeStruct((b,), jnp.bool_), i32(b),
-        ).as_text()
+            params, cache, i32(b, bucket), *group).as_text()
     return out
 
 

@@ -123,13 +123,15 @@ _LONG_POLES = (
     # one file by its own docstring (one process loads libtpu), +3-4
     # compiles a PR: first while it is the heaviest
     "test_chip_compile.py",
+    # PR 47's run of the driver's command: 343 s with the walked-pass
+    # cases (second of the record; its three mesh-of-four cases are 148 s of it)
+    "test_lookahead.py",
     # PR 45's own reading, alone on this machine: 196 s (five planted
-    # faults of two fresh programs each are 65 s of it)
+    # faults of two fresh programs each are 65 s of it); 341 s in PR 47's
     "test_retention.py",
-    # PR 46's run: 242 s with the shared-pass cases (fourth of the record)
-    "test_flash_decode.py", "test_lookahead.py", "test_sparse_mla_moe.py",
-    "test_emitter.py",
-    "test_window_moe.py", "test_prerouted_moe.py", "test_disagg.py",
+    "test_flash_decode.py", "test_emitter.py", "test_disagg.py",
+    "test_sparse_mla_moe.py",
+    "test_window_moe.py", "test_prerouted_moe.py",
     "test_ranged_prefill.py", "test_disagg_soak.py", "test_serving.py",
     "test_ranged_contiguous.py", "test_ranged_kernel.py",
     "test_ragged_pipeline.py", "test_integrity.py",

@@ -18,6 +18,7 @@ scripts/run_tier1.sh does not); without it the second fails on
 tests/conftest.py ``_LONG_POLES`` while it is the heaviest.
 """
 
+import contextlib
 import functools
 import os
 import re
@@ -76,6 +77,17 @@ def _compiled_text(fn, *args) -> str:
 
 def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@contextlib.contextmanager
+def _chip_posture():
+    """Kernels compiled, not interpreted, while a whole pass is traced."""
+    posture = tdt_config.get_config().interpret
+    tdt_config.update(interpret=False)
+    try:
+        yield
+    finally:
+        tdt_config.update(interpret=posture)
 
 
 def test_flash_decode_compiles(one_chip):
@@ -534,6 +546,57 @@ def test_kv_pool_is_written_and_read_in_place(topo, rows):
     assert mem.temp_size_in_bytes < pool_bytes // n_layers
 
 
+def test_the_member_walk_carries_the_pools_in_place(topo):
+    """The dense family's one-chip admission at serving widths (PR 47): a
+    ``while`` over the pass's members whose carry is the donated cache. The
+    chip's compiler has to keep the pools in place through the loop (no
+    ``copy`` of a pool's shape, the pools aliased in and out): beside 7.5 GB
+    of weights a second copy of them does not fit. And the trip runs ONE
+    member's rows: no temporary as wide as the whole batch's."""
+    from triton_dist_tpu.models import decode, tp_transformer
+    from triton_dist_tpu.models.tp_transformer import TransformerConfig
+
+    bucket, n_layers = 512, 2
+    cfg = TransformerConfig(
+        vocab=VOCAB, hidden=HIDDEN, ffn=FFN, n_layers=n_layers, n_q_heads=N_Q,
+        n_kv_heads=N_KV, head_dim=HEAD, batch=SLOTS, dtype=jnp.bfloat16,
+        interpret=False)
+    spec = decode.PagedKVCacheSpec(S_MAX, PAGE, static_table=True)
+    mesh = Mesh(np.array(topo.devices[:1]), ("tp",))
+    rep = NamedSharding(mesh, P())
+    place = lambda shapes, specs: jax.tree.map(
+        lambda x, s: _struct(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, specs)
+    shapes = jax.eval_shape(
+        functools.partial(tp_transformer._init_tree, cfg=cfg),
+        jax.random.PRNGKey(0))
+    ps, cs = decode.specs_for(cfg, shapes), spec.specs(cfg)
+    pools = jax.eval_shape(lambda: spec.init(cfg, 1, 1))
+    with _chip_posture():
+        compiled = jax.jit(
+            jax.shard_map(
+                decode._member_walk(cfg, spec, S_MAX, bucket), mesh=mesh,
+                in_specs=(ps, cs, P(), P(), P(), P()), out_specs=(cs, P()),
+                check_vma=False),
+            donate_argnums=(1,),
+        ).lower(
+            place(shapes, ps), place(pools, cs),
+            _struct((SLOTS, bucket), jnp.int32, rep),
+            _struct((SLOTS,), jnp.int32, rep),
+            _struct((SLOTS,), jnp.int32, rep), _struct((), jnp.int32, rep),
+        ).compile()
+    text = compiled.as_text()
+    pool = "bf16[%d,%d,%d,%d,%d]" % pools["k"].shape
+    pool_bytes = 2 * np.prod(pools["k"].shape)
+    assert len(re.findall(r" while\(", text)) == 1 and "tpu_custom_call" in text
+    assert not re.findall(re.escape(pool) + r"\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    # the widest temporaries are one member's, gate|up [bucket, 2F] and the
+    # head over its rows [bucket, V] (134 MB read), not the batch's (8 x)
+    assert mem.temp_size_in_bytes < 2 * bucket * (2 * FFN + VOCAB) * 2
+
+
 # -- SmallThinker-21BA3B at its published widths (perfbench's configuration) ------
 
 @pytest.mark.parametrize("window", [4096, None], ids=["w4096", "full"])
@@ -575,9 +638,7 @@ def _compiled_admission(cfg, spec, mesh, params, cache, L: int):
             pcfg, p, c, prompt.reshape(-1), spec, spec.s_max, slot_mask=mask,
             pick=pick)
 
-    posture = tdt_config.get_config().interpret
-    tdt_config.update(interpret=False)
-    try:
+    with _chip_posture():
         return jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(cfg.param_specs(), cs, P(), P(), P()),
             out_specs=(cs, P(), P()), check_vma=False),
@@ -586,8 +647,6 @@ def _compiled_admission(cfg, spec, mesh, params, cache, L: int):
             _struct((cfg.batch,), jnp.bool_, rep),
             _struct((cfg.batch,), jnp.int32, rep)
         ).compile()
-    finally:
-        tdt_config.update(interpret=posture)
 
 
 @pytest.fixture(scope="module")

@@ -99,6 +99,26 @@ def test_the_rule_follows_the_shape():
         GEMM_VMEM_BUDGET, _footprint(8, 4_000_037, 8, 0))
 
 
+@pytest.mark.parametrize("rows, gate_up, down", [
+    (128, (128, 4096, 1024), (128, 4096, 1024)),
+    (256, (256, 4096, 1024), (256, 4096, 1024)),
+    (512, (512, 2048, 2048), (512, 2048, 2048)),
+    (1024, (1024, 2048, 1024), (1024, 2048, 1024)),
+])
+def test_a_walked_members_gemms_keep_their_tiles(rows, gate_up, down):
+    """``mistral-7b-v0.3`` on one chip since PR 47: an admission runs ONE
+    member's ``[bucket, .]`` rows a trip (``ContinuousBatcher._prefill_prog``),
+    so these are the shapes its two large GEMMs really have. Pinned: a change
+    of the rule or of its budget shows here as this path's."""
+    tile = functools.partial(gemm_tile, None, in_dtype=BF16, out_dtype=BF16)
+    assert tile(rows, 28672, 4096)[:3] == gate_up
+    assert tile(rows, 4096, 14336)[:3] == down
+    # the step does not collapse with the rows (the whole-batch pass's 4096
+    # rows ran (1024, 2048, 1024))
+    for t in (gate_up, down):
+        assert 2 * np.prod(t, dtype=np.int64) >= 2 * 512 * 2048 * 512
+
+
 @pytest.mark.parametrize("cls", [GemmRSConfig, AGGemmConfig])
 def test_an_explicit_config_comes_back_untouched(cls):
     tile = functools.partial(gemm_tile, in_dtype=BF16, out_dtype=BF16)

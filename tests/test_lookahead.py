@@ -6,6 +6,7 @@ step sent in vain thrown away, and no step sent where the round's tokens
 decide what runs next."""
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -161,11 +162,20 @@ def test_a_rounds_inputs_are_the_devices_own(n_dev):
     assert np.asarray(pos_d).tolist() == [3, 3]
 
 
+def _compilations() -> list:
+    """A list that grows by one entry a backend compilation from now on."""
+    from jax import monitoring
+
+    compiled = []
+    monitoring.register_event_duration_secs_listener(
+        lambda event, *a, **kw: compiled.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    return compiled
+
+
 def test_nothing_compiles_after_the_first_step_sent_ahead(tiny, mesh1):
     """A warm-up of three tokens a slot sends one step ahead; a longer run
     after it (steps sent ahead of steps sent ahead) compiles nothing."""
-    from jax import monitoring
-
     cfg, params = tiny
     b = ContinuousBatcher(cfg, params, mesh1, s_max=32, prefill=True,
                           page_size=8)
@@ -173,10 +183,7 @@ def test_nothing_compiles_after_the_first_step_sent_ahead(tiny, mesh1):
         b.submit(r)
     b.run(max_steps=50)
     assert b.rounds_ahead == 1
-    compiled = []
-    monitoring.register_event_duration_secs_listener(
-        lambda event, *a, **kw: compiled.append(event)
-        if event == "/jax/core/compile/backend_compile_duration" else None)
+    compiled = _compilations()
     for r in _reqs(cfg, [(3, 9), (5, 7)], prompts=8):
         b.submit(r)
     b.run(max_steps=50)
@@ -301,11 +308,13 @@ def _mixed(cfg, shapes, prompts=11):
     return reqs
 
 
-def _admitted(cfg, params, mesh, reqs, a_pass_each=False, **kw):
+def _admitted(cfg, params, mesh, reqs, a_pass_each=False, before=None, **kw):
     """A batcher that has admitted ``reqs`` and run no round: as a backlog
     (the sweep's requests of a bucket share a pass) or, the old order, an
-    ``_admit`` a request."""
+    ``_admit`` a request. ``before(batcher)`` runs on the new batcher."""
     b = ContinuousBatcher(cfg, params, mesh, s_max=32, prefill=True, **kw)
+    if before is not None:
+        before(b)
     for r in reqs:
         b.submit(r)
         if a_pass_each:
@@ -418,3 +427,129 @@ def test_a_routed_stand_in_shares_a_pass_unless_a_capacity_is_set(
         for pool in ("k", "v"):
             np.testing.assert_array_equal(np.asarray(got.cache[pool]),
                                           np.asarray(want.cache[pool]))
+
+
+# -- on one device the pass walks its members (PR 47) -------------------------
+# One prefill program a bucket: a ``fori_loop`` over the pass's members, a trip
+# count that is data, each trip one member's ``[1, bucket]`` rows into its own
+# slot. A mesh of several devices keeps the masked whole-batch pass.
+
+def _four_slots(n_kv_heads=2):
+    cfg, params = _tiny(n_kv_heads)
+    return dataclasses.replace(cfg, batch=4), params
+
+
+def _slot_kv(b, i: int) -> list:
+    """Slot ``i``'s k and v as the cache holds them: its own pages of the
+    pool, or its rows of the contiguous layers."""
+    at = (np.asarray(b.cache["block_table"])[0, i]
+          if "block_table" in b.cache else i)
+    return [np.asarray(b.cache[pool])[:, at] for pool in ("k", "v")]
+
+
+def _admitted_over_noise(cfg, params, mesh, reqs, **kw):
+    """``_admitted`` on a cache that held noise in every slot: the batcher,
+    the logit row each request's first token was sampled from, and what
+    each slot held before."""
+    rows, held = {}, []
+
+    def dirty(b):
+        rng = np.random.default_rng(7)
+        b.cache = dict(b.cache, **{
+            pool: jax.device_put(
+                rng.standard_normal(b.cache[pool].shape).astype(
+                    b.cache[pool].dtype), b.cache[pool].sharding)
+            for pool in ("k", "v")})
+        held.extend(_slot_kv(b, i) for i in range(cfg.batch))
+        first = b._first_token
+
+        def keep(i, req, last_i):
+            rows[req.uid] = np.array(last_i)
+            first(i, req, last_i)
+
+        b._first_token = keep
+
+    return _admitted(cfg, params, mesh, reqs, before=dirty, **kw), rows, held
+
+
+@pytest.mark.parametrize("kw", [dict(), dict(page_size=8)],
+                         ids=["contiguous", "paged"])
+@pytest.mark.parametrize("m", [1, 2, 4])
+def test_a_walked_pass_gives_each_member_what_a_pass_alone_gives(
+        mesh1, m, kw):
+    """A group of ``m`` of the four slots in ONE pass: each member's first
+    token, logit row and k/v are, bit for bit, those of a pass of its own,
+    and a slot the pass does not admit keeps what it held."""
+    cfg, params = _four_slots()
+    shapes = [(6, 3), (5, 3), (7, 3), (8, 3)][:m]
+    (want, want_rows, _), (got, rows, held) = (
+        _admitted_over_noise(cfg, params, mesh1, _mixed(cfg, shapes),
+                             a_pass_each=each, **kw)
+        for each in (True, False))
+    assert (want.prefill_passes_total, got.prefill_passes_total) == (m, 1)
+    assert got.slot_out == want.slot_out
+    assert all(len(o) == 1 for o in got.slot_out[:m])
+    assert got.pos.tolist() == want.pos.tolist()
+    assert set(rows) == set(range(m))
+    for i in range(cfg.batch):
+        if i < m:
+            np.testing.assert_array_equal(rows[i], want_rows[i])
+        for ours, theirs, before in zip(
+                _slot_kv(got, i), _slot_kv(want, i), held[i]):
+            np.testing.assert_array_equal(ours, theirs)
+            assert (i >= m) == np.array_equal(ours, before)
+
+
+@pytest.mark.parametrize("groups", [(1, 3, 4), (4, 1), (3, 2)],
+                         ids=["1_3_4", "4_1", "3_2"])
+def test_one_prefill_program_a_bucket_whatever_the_group(mesh1, groups):
+    """Groups of every size of one bucket run the ONE program the first
+    group compiled: the members are data, the trip count too."""
+    cfg, params = _four_slots()
+    b = ContinuousBatcher(cfg, params, mesh1, s_max=32, prefill=True,
+                          page_size=8)
+    compiled = _compilations()
+    uid = 0
+    for n, m in enumerate(groups):
+        for r in _reqs(cfg, [(5 + (uid + j) % 4, 1) for j in range(m)],
+                       prompts=uid):
+            r.uid, uid = uid, uid + 1
+            b.submit(r)
+        if n == 1:
+            compiled.clear()      # the first group's pass compiled it
+        b._admit()
+        assert b.prefill_passes_total == n + 1 and not b.queue
+    assert b.prefill_bucket_count == 1 and len(b.finished) == sum(groups)
+    assert b.prefill_rows_total == sum(groups) * 8 and not compiled
+
+
+@pytest.mark.parametrize("mesh, whole", [("mesh1", False), ("mesh4", True)])
+def test_a_pass_counts_the_rows_it_ran(request, mesh, whole):
+    """``rows`` on the pass's span and ``prefill_rows_total``: the
+    members' rows where the pass walks them (one device), every slot's
+    where it runs masked (four); the pass of four is PR 46's, to the tokens
+    a pass each serves there."""
+    from triton_dist_tpu import config as tdt_config, obs
+
+    mesh = request.getfixturevalue(mesh)
+    cfg, params = _four_slots(4)
+    shapes = [(6, 3), (5, 3)]
+    before = tdt_config.get_config().obs
+    obs.reset()
+    tdt_config.update(obs=obs.ObsConfig(spans=True))
+    try:
+        got = _admitted(cfg, params, mesh, _mixed(cfg, shapes), page_size=8)
+        (span,) = [s.attrs for s in obs.spans()
+                   if s.name == "tdt.batcher.admit_prefill"]
+    finally:
+        tdt_config.update(obs=before)
+        obs.reset()
+    assert got._walks_members is not whole
+    rows = (cfg.batch if whole else len(shapes)) * 8
+    assert (span["admitted"], span["bucket"], span["rows"]) == (2, 8, rows)
+    assert (got.prefill_passes_total, got.prefill_rows_total) == (1, rows)
+    want = _admitted(cfg, params, mesh, _mixed(cfg, shapes),
+                     a_pass_each=True, page_size=8)
+    assert want.prefill_rows_total == 2 * (rows if whole else 8)
+    assert got.slot_out == want.slot_out and got.tok.tolist() == want.tok.tolist()
+    assert got.pos.tolist() == want.pos.tolist() == [6, 5, 0, 0]

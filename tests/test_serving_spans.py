@@ -46,7 +46,7 @@ TABLE = {
     "tdt.batcher.admit": ("tdt.engine.step", {"queued", "admitted"}),
     "tdt.batcher.admit_prefill": (
         "tdt.batcher.admit",
-        {"uid", "slot", "prompt_len", "bucket", "admitted"}),
+        {"uid", "slot", "prompt_len", "bucket", "admitted", "rows"}),
     "tdt.batcher.admit_prefill.build": ("tdt.batcher.admit_prefill", set()),
     "tdt.batcher.admit_prefill.dispatch": ("tdt.batcher.admit_prefill", set()),
     "tdt.batcher.admit_prefill.pull": ("tdt.batcher.admit_prefill", set()),

@@ -1685,14 +1685,22 @@ class ContinuousBatcher:
         # cumulative bucket-prefill PASSES: requests admitted through them
         # over this = requests a pass (``_admit`` fills a pass's rows)
         self.prefill_passes_total = 0
+        # cumulative ROWS those passes ran through the model: over passes x
+        # ``batch x bucket`` it is the share of a whole-batch pass's work
+        # still done (the ``rows`` of the pass's span)
+        self.prefill_rows_total = 0
         # The admission's discipline (docs/serving.md). The dense family's
-        # bucket pass runs EVERY slot's rows, so the queued requests of one
-        # bucket share it: rows never mix across the batch dim, a request's
-        # rows go through the program they would have run alone. A
-        # layer-plan family's pass runs the ONE slot its one-hot mask names
+        # bucket pass takes the queued requests of one bucket together:
+        # rows never mix across the batch dim, a request's rows go through
+        # the program they would have run alone. On ONE device that program
+        # walks the pass's members, a trip a member (``_prefill_prog``: it
+        # costs its members' rows); across devices it runs EVERY slot's
+        # rows under a mask, whatever the group. A layer-plan family's pass
+        # runs the ONE slot its one-hot mask names
         # (``gated_experts.admitted_rows``), and an expert exchange with a
         # set capacity (``ep_max_m``) drops rows by who else is in the
         # pass: both admit one request a pass.
+        self._walks_members = not cfg.own_passes and n * n_o == 1
         self._fills_rows = not cfg.own_passes and (
             getattr(cfg, "ep_max_m", None) is None)
         # cumulative prefill WORK in swept query×key token-pairs: a bulk
@@ -1869,9 +1877,11 @@ class ContinuousBatcher:
     def _pull_last(self, sp, last, slots: list) -> np.ndarray:
         """An admission's pull: the logit rows of ``slots`` (and the
         pass's counters, where it declares any, onto the admission's
-        span). A pass that fills its rows pulls ``last`` whole, a
-        transfer: a program that took the members' rows on the device
-        would be one a group size, compiled when a group first has it."""
+        span). A pass of a group pulls ``last`` whole (``[batch, vocab]``,
+        a member's row at its slot, whether the program walked the members
+        or ran every slot's rows), a transfer: rows taken on the device
+        would be a program a group size, compiled when a group first has
+        it."""
         if self._counters:
             self._set_counters(sp, np.asarray(self._pass_stats))
         if self._fills_rows:
@@ -1943,30 +1953,37 @@ class ContinuousBatcher:
         self.queue.append(req)
 
     def _prefill_prog(self, bucket: int):
-        """Jitted masked-prefill program for one padded prompt length
-        (compiled once per bucket; buckets are powers of two so a serving
-        mix of lengths stays at a handful of compilations)."""
+        """Jitted prefill program for one padded prompt length (compiled
+        once per bucket, whatever the group: buckets are powers of two so
+        a serving mix of lengths stays at a handful of compilations).
+        Across devices, ``fn(params, cache, prompt, mask, pick)``: every
+        slot's rows in one masked pass. On one device,
+        :func:`_member_walk`'s ``fn(params, cache, prompt, slots, pick,
+        m)``: the pass's ``m`` members, a trip each."""
         if bucket in self._prefill_progs:
             return self._prefill_progs[bucket]
         cfg, mesh, spec, s_max = self.cfg, self.mesh, self.spec, self.s_max
         b = cfg.batch
-        pcfg = dataclasses.replace(cfg, seq=bucket, batch=b // self._n_o)
 
-        def fn(params, cache, prompt, mask, pick):
-            prompt_loc = _prompt_shard(prompt, b, bucket, cfg)
-            return prefill_cache(
-                pcfg, params, cache, prompt_loc, spec, s_max,
-                slot_mask=mask, pick=pick,
-            )
+        if self._walks_members:
+            fn = _member_walk(cfg, spec, s_max, bucket)
+            inputs = (P(None, None), P(None), P(None), P())
+        else:
+            pcfg = dataclasses.replace(cfg, seq=bucket, batch=b // self._n_o)
+            inputs = (P(None, None), P(None), P(None))
+
+            def fn(params, cache, prompt, mask, pick):
+                prompt_loc = _prompt_shard(prompt, b, bucket, cfg)
+                return prefill_cache(
+                    pcfg, params, cache, prompt_loc, spec, s_max,
+                    slot_mask=mask, pick=pick,
+                )
 
         from triton_dist_tpu.ops.common import jit_shard_map
 
         prog = self._keep_stats(jit_shard_map(
             fn, mesh,
-            (
-                specs_for(cfg, self.params), spec.specs(cfg), P(None, None),
-                P(None), P(None),
-            ),
+            (specs_for(cfg, self.params), spec.specs(cfg)) + inputs,
             (spec.specs(cfg), P(None, None)) + self._stats_specs(),
             key=("batcher_prefill", cfg, spec, s_max, bucket),
             donate_argnums=(1,),  # see self._step: the old cache is dead
@@ -2131,28 +2148,40 @@ class ContinuousBatcher:
         self._ranged_pass(i, req, lo, len(req.prompt))
 
     def _prefill_inputs(self, slots: list, reqs: list, bucket: int) -> tuple:
-        """The prompts of ``reqs`` padded to their bucket, each in its
-        slot's row, the mask of ``slots`` and the pick row, uploaded."""
+        """The pass's inputs after params and cache, uploaded: the prompts
+        of ``reqs`` padded to their bucket and the pick rows, with the
+        group said the way ``_prefill_prog``'s program takes it. Across
+        devices: each prompt in its slot's row, and the mask of ``slots``.
+        On one device the group is DATA: the prompts packed in the first
+        rows, ``slots`` and ``pick`` by member, and the members' count."""
         with _span("tdt.batcher.admit_prefill.build"):
-            prompt = np.zeros((self.cfg.batch, bucket), np.int32)
-            mask = np.zeros(self.cfg.batch, bool)
+            b = self.cfg.batch
+            prompt = np.zeros((b, bucket), np.int32)
             # pad positions write junk KV beyond L-1, but decode overwrites
             # each position before kv_lens ever exposes it; the first
             # generated token comes from position L-1's logits (pick)
-            pick = np.zeros(self.cfg.batch, np.int32)
-            for i, req in zip(slots, reqs):
-                prompt[i, :len(req.prompt)] = req.prompt
-                mask[i] = True
-                pick[i] = len(req.prompt) - 1
+            pick = np.zeros(b, np.int32)
+            rows = range(len(slots)) if self._walks_members else slots
+            for r, req in zip(rows, reqs):
+                prompt[r, :len(req.prompt)] = req.prompt
+                pick[r] = len(req.prompt) - 1
+            if self._walks_members:
+                by_member = np.zeros(b, np.int32)
+                by_member[:len(slots)] = slots
+                return (jnp.asarray(prompt), jnp.asarray(by_member),
+                        jnp.asarray(pick), jnp.asarray(np.int32(len(slots))))
+            mask = np.zeros(b, bool)
+            mask[slots] = True
             return jnp.asarray(prompt), jnp.asarray(mask), jnp.asarray(pick)
 
     def _admit_prefill(self, i, req) -> None:
-        """MXU-rate admission: one masked full-forward pass writes the
+        """MXU-rate admission: one full-forward pass writes the
         whole prompt's KV and yields the first generated token, for slot
         ``i``'s request ``req`` or, where both are lists (``_admit``: a
         family whose pass fills its rows), for every member of a group of
-        one bucket, a member a row. The pass costs what it costs with one
-        live row."""
+        one bucket. On one device the pass costs its members' rows (a trip
+        a member); across devices it costs ``batch x bucket`` rows with one
+        member or ``batch`` of them (``rows`` on the span)."""
         slots, reqs = ([i], [req]) if isinstance(req, Request) else (i, req)
         L = max(len(r.prompt) for r in reqs)
         bucket = self._bucket(L)
@@ -2162,8 +2191,7 @@ class ContinuousBatcher:
             with _span("tdt.batcher.admit_prefill.dispatch"):
                 self.cache, last = self._prefill_prog(bucket)(
                     self.params, self.cache, *args)
-            self.prefill_passes_total += 1
-            self.prefill_work_total += bucket * bucket
+            self._count_pass(sp, len(reqs), bucket)
             with _span("tdt.batcher.admit_prefill.pull"):
                 rows = self._pull_last(sp, last, slots)
             # a member at a time in slot order: its own finite check, its
@@ -2171,6 +2199,18 @@ class ContinuousBatcher:
             for i, req, last_i in zip(slots, reqs, rows):
                 self.prefill_tokens_total += len(req.prompt)
                 self._first_token(i, req, last_i)
+
+    def _count_pass(self, sp, admitted: int, bucket: int) -> None:
+        """One bucket-prefill pass more on the cumulative counters, and on
+        its span the ``rows`` it ran through the model: its members' on
+        one device and for a layer-plan family, every slot's where the
+        dense family's pass runs masked (across devices)."""
+        self.prefill_passes_total += 1
+        self.prefill_work_total += bucket * bucket
+        whole = not (self._walks_members or self.cfg.own_passes)
+        rows = (self.cfg.batch if whole else admitted) * bucket
+        self.prefill_rows_total += rows
+        sp.set("rows", rows)
 
     def _pass_span(self, slots: list, reqs: list, L: int, bucket: int):
         """The span of one bucket-prefill pass: ``admitted`` members, their
@@ -2622,8 +2662,38 @@ def _prompt_shard(prompt, b, length, cfg):
     )
 
 
+def _member_walk(cfg, spec, s_max: int, bucket: int):
+    """The dense family's bucket prefill on ONE device (call inside
+    shard_map), one program whatever the group: ``fn(params, cache, prompt
+    [batch, bucket], slots [batch], pick [batch], m)`` is a ``fori_loop``
+    over the pass's ``m`` members, a trip count that is DATA. Trip ``j``
+    runs member ``j``'s ``[1, bucket]`` rows (``prompt[j]``: the members
+    are packed first) through :func:`prefill_cache`, which writes cache
+    slot ``slots[j]`` and no other, and puts its picked logit row into
+    ``last[slots[j]]`` (``[batch, vocab]`` float32; rows of no member stay
+    zero). The cache is the loop's carry, updated in place. Named ``fn``
+    like the masked program: a trace finds both as ``jit_fn``."""
+    pcfg = dataclasses.replace(cfg, seq=bucket, batch=1)
+
+    def fn(params, cache, prompt, slots, pick, m):
+        def one(j, carry):
+            cache, last = carry
+            cache, row = prefill_cache(
+                pcfg, params, cache, prompt[j], spec, s_max,
+                pick=pick[j][None], slot=slots[j],
+            )
+            return cache, jax.lax.dynamic_update_slice(
+                last, row.astype(last.dtype), (slots[j], 0))
+
+        last = jnp.zeros((cfg.batch, cfg.vocab), jnp.float32)
+        return jax.lax.fori_loop(0, m, one, (cache, last))
+
+    return fn
+
+
 def prefill_cache(
-    cfg, params, cache, prompt_loc, spec, s_max, slot_mask=None, pick=None
+    cfg, params, cache, prompt_loc, spec, s_max, slot_mask=None, pick=None,
+    slot=None,
 ):
     """Bulk prefill (call inside shard_map): run the full TP transformer
     forward over the flattened prompt shard and write every position's
@@ -2646,7 +2716,11 @@ def prefill_cache(
     neighbors' cache rows must stay untouched); padded prompt positions
     beyond a slot's true length are harmless — causal attention keeps
     them out of earlier positions and the decode-side ``kv_lens`` mask
-    never reads them. Returns ``(cache, last_logits [b, vocab])`` — the
+    never reads them. ``slot`` (int32 scalar) says the same by INDEX for a
+    pass of ONE sequence (``cfg.batch == 1``, the batcher's member walk on
+    one device): its ``[1, L]`` rows run alone and land in slot ``slot`` of
+    a cache that holds any number of slots, none of the others read or
+    written. Returns ``(cache, last_logits [b, vocab])`` — the
     cache holds positions ``[0, L)`` and `last_logits` are per-sequence
     position ``pick``'s (default ``L-1`` — ragged admission passes each
     slot's true ``len-1``; the row is selected BEFORE the vocab-shard
@@ -2678,6 +2752,10 @@ def prefill_cache(
     # batch slice (the caller's pcfg); slot_mask/pick arrive GLOBAL and
     # slice down to this group's slots here
     n_o, my_o = _outer_dims(c)
+    if slot is not None and (b != 1 or n_o > 1 or slot_mask is not None):
+        raise ValueError(
+            "slot names the cache slot of a ONE-sequence pass (cfg.batch == "
+            "1, flat mesh, no slot_mask): a pass of several runs masked")
     if n_o > 1:
         if slot_mask is not None:
             slot_mask = jax.lax.dynamic_slice_in_dim(slot_mask, my_o * b, b, 0)
@@ -2728,6 +2806,8 @@ def prefill_cache(
                     b * pps, c.n_kv_heads, ps, c.head_dim)
                 ids = cache["block_table"][0]            # [b, pps] static
                 n_pool = cache["k"].shape[1]
+                if slot is not None:
+                    ids = ids[slot][None]                # its pages alone
                 if slot_mask is not None:
                     ids = jnp.where(slot_mask[:, None], ids, n_pool)  # drop
                 cache = dict(
@@ -2738,6 +2818,15 @@ def prefill_cache(
                     v=cache["v"].at[li, ids.reshape(-1)].set(
                         vp.astype(kd), mode="drop"
                     ),
+                )
+                continue
+            if slot is not None:
+                # the slot's own [h_kv, s_shard, d] of the layer, in place
+                at = (li, slot, 0, 0, 0)
+                cache = dict(
+                    cache,
+                    k=jax.lax.dynamic_update_slice(cache["k"], k_new[None], at),
+                    v=jax.lax.dynamic_update_slice(cache["v"], v_new[None], at),
                 )
                 continue
             if slot_mask is not None:
