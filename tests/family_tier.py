@@ -75,6 +75,10 @@ class Family:
     toy: dict                    # the toy configuration, "sizes" and all
     spec: type                   # the cache spec class its batcher builds
     layer: Callable
+    name: str = ""               # which plan, where a file holds several
+    # (ref, outer, tokens) -> the residual stream's start, where it is not
+    # the plain lookup
+    embed: Callable | None = None
     seed: int = 7
     tol: dict = dataclasses.field(default_factory=lambda: TOL)
     pack: Callable = lambda adapter, w, cfg, li: adapter.pack_layer(w, cfg)
@@ -134,9 +138,36 @@ def sized(toy: dict) -> dict:
 
 # -- fixtures: a family's module imports them ------------------------------------
 
+# A file may hold SEVERAL plans of one family (``FAMILIES``, each with a
+# ``name``): its tests then run a plan each (``pytest_generate_tests``).
+# The fixtures stay a module's; what is costly to build is kept a plan
+# (``_once``), so the order the plans' tests come in costs nothing.
+_BUILT: dict = {}
+
+
+def _once(request, family, what: str, build):
+    key = (request.module.__name__, family.name, what)
+    if key not in _BUILT:
+        _BUILT[key] = build()
+    return _BUILT[key]
+
+
+def plans(module) -> tuple:
+    return getattr(module, "FAMILIES", None) or (module.FAMILY,)
+
+
+def only_for(*names):
+    """A test of a file with several plans that is one plan's (a file's
+    own test that names none is its FIRST plan's)."""
+    def mark(fn):
+        fn.families = names
+        return fn
+    return mark
+
+
 @pytest.fixture(scope="module")
 def family(request) -> Family:
-    return request.module.FAMILY
+    return getattr(request, "param", None) or plans(request.module)[0]
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -164,16 +195,19 @@ def adapter(family):
 
 
 @pytest.fixture(scope="module")
-def toy(family, ref, adapter):
+def toy(request, family, ref, adapter):
     """``(cfg, program params, plain layers, outer)`` from one seed."""
-    cfg = adapter.model_config(family.toy)
-    key = ref.seed_key(family.seed)
-    plain = [ref.layer_weights(key, li, family.sizes)
-             for li in range(family.toy["n_layers"])]
-    outer = ref.outer_weights(key, family.sizes)
-    params = dict(outer, layers=[family.pack(adapter, w, cfg, li)
-                                 for li, w in enumerate(plain)])
-    return cfg, params, plain, outer
+    def build():
+        cfg = adapter.model_config(family.toy)
+        key = ref.seed_key(family.seed)
+        plain = [ref.layer_weights(key, li, family.sizes)
+                 for li in range(family.toy["n_layers"])]
+        outer = ref.outer_weights(key, family.sizes)
+        params = dict(outer, layers=[family.pack(adapter, w, cfg, li)
+                                     for li, w in enumerate(plain)])
+        return cfg, params, plain, outer
+
+    return _once(request, family, "toy", build)
 
 
 class Recording(Request):
@@ -186,20 +220,23 @@ class Recording(Request):
 
 
 @pytest.fixture(scope="module")
-def served(family, toy):
+def served(request, family, toy):
     """Every case through ONE batcher (fewer slots than cases, so slots
     are re-used; ``temperature`` > 0 keeps every round plain): ``(requests
     by name, tokens by name)``."""
-    cfg, params, _, _ = toy
-    batcher = make_batcher(family, cfg, params)
-    assert isinstance(batcher.spec, family.spec)
-    rng = np.random.default_rng(0)
-    reqs = {name: Recording(prompt_of(rng, cfg, case[0]), case[1],
-                            temperature=1.0, uid=name)
-            for name, case in family.cases.items()}
-    for r in reqs.values():
-        batcher.submit(r)
-    return reqs, dict(batcher.run())
+    def build():
+        cfg, params, _, _ = toy
+        batcher = make_batcher(family, cfg, params)
+        assert isinstance(batcher.spec, family.spec)
+        rng = np.random.default_rng(0)
+        reqs = {name: Recording(prompt_of(rng, cfg, case[0]), case[1],
+                                temperature=1.0, uid=name)
+                for name, case in family.cases.items()}
+        for r in reqs.values():
+            batcher.submit(r)
+        return reqs, dict(batcher.run())
+
+    return _once(request, family, "served", build)
 
 
 # -- what the cases and a family's own tests call --------------------------------
@@ -210,7 +247,8 @@ def _ref_logits(family, ref, plain, outer, tokens, control=False, block=None):
     operations is a compile of its own: four times the seconds, and the
     logits differ by rounding, 3e-6)."""
     def logits(plain, outer, tokens):
-        x = outer["embed"][tokens].astype(jnp.float32)
+        x = (family.embed(ref, outer, tokens) if family.embed
+             else outer["embed"][tokens].astype(jnp.float32))
         for li, w in enumerate(plain):
             x = family.layer(ref, x, w, li, control, block)
         n, t = tokens.shape
@@ -479,15 +517,33 @@ def refusals(family, cfg, params) -> dict:
 
 def pytest_generate_tests(metafunc):
     """The cases of this module's tests come from the importing module's
-    ``FAMILY``."""
-    if metafunc.function.__module__ != __name__:
+    ``FAMILY``. Where it holds ``FAMILIES``, a test of this module runs
+    once a plan (those that have what the test ``needs``), a test of the
+    file's own for the plans it names (:func:`only_for`; its first plan
+    where it names none); the plans of a file give a test the same
+    cases."""
+    mine = metafunc.function.__module__ == __name__
+    fams = plans(metafunc.module)
+    if hasattr(metafunc.module, "FAMILIES") \
+            and "family" in metafunc.fixturenames:
+        names = getattr(metafunc.function, "families",
+                        None if mine else (fams[0].name,))
+        needs = getattr(metafunc.function, "needs", None)
+        fams = [f for f in fams if (names is None or f.name in names)
+                and (needs is None or getattr(f, needs))]
+        metafunc.parametrize("family", fams, indirect=True, scope="module",
+                             ids=[f.name for f in fams])
+    if not mine:
         return
-    fam = metafunc.module.FAMILY
-    for arg, values in (
-            ("case", sorted(fam.cases)), ("length", list(fam.forward)),
-            ("admission", fam.admissions), ("what", fam.refused),
-            ("which", ("step", "admission"))):
+    for arg, of in (
+            ("case", lambda f: sorted(f.cases)),
+            ("length", lambda f: list(f.forward)),
+            ("admission", lambda f: f.admissions),
+            ("what", lambda f: f.refused),
+            ("which", lambda f: ("step", "admission"))):
         if arg in metafunc.fixturenames:
+            values = of(fams[0])
+            assert all(of(f) == values for f in fams), (arg, fams)
             metafunc.parametrize(arg, values, ids=lambda v: (
                 "-".join(map(str, v)) if isinstance(v, tuple) else str(v)))
 
@@ -633,6 +689,9 @@ def test_shares_of_the_bank_add_up_to_the_layer(family, toy, ref):
         own, _ = jax.jit(lambda p: gated_experts.moe_mlp(cfg, m, p, 8))(p)
         assert np.abs(np.asarray(own) - np.asarray(want)).max() \
             > 50 * family.tol["atol"]
+
+
+test_shares_of_the_bank_add_up_to_the_layer.needs = "shares"
 
 
 def test_the_lower_precision_control_is_far_outside_the_tolerances(

@@ -925,3 +925,85 @@ def test_brumby_admission_compiles_at_bucket_8192_beside_the_state(brumby):
           "arguments", mem.argument_size_in_bytes)
     assert mem.temp_size_in_bytes < 1.5e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+@pytest.fixture(scope="module")
+def granite(topo):
+    """``(cfg, spec, mesh, params' and cache's shapes)`` of the
+    benchmark's Granite-4.0-H configuration on one described chip."""
+    import sys
+
+    perfbench = os.path.join(
+        os.path.dirname(os.path.dirname(__file__)), "perfbench")
+    if perfbench not in sys.path:
+        sys.path.insert(0, perfbench)
+    from harness import cells
+
+    from triton_dist_tpu.models.decode import StatePagedKVCacheSpec
+    from triton_dist_tpu.models.ssm_hybrid import init_ssm_hybrid_params
+
+    cell = cells.Cell(cells.benchmark(), "granite-4.0-h-small-ep4.doc-reason")
+    adapter = cells.load_module("programs", cell.config["program"])
+    cfg = adapter.model_config(cell.config, interpret=False)
+    eng = cell.config["engine"]
+    spec = StatePagedKVCacheSpec(eng["s_max"], eng["page"], static_table=True)
+    mesh = Mesh(np.array(topo.devices[:1]), (cfg.axis,))
+    place = lambda shapes, specs: jax.tree.map(
+        lambda x, s: _struct(x.shape, x.dtype, NamedSharding(mesh, s)),
+        shapes, specs)
+    params = place(
+        jax.eval_shape(functools.partial(init_ssm_hybrid_params, cfg=cfg),
+                       jax.random.PRNGKey(0)), cfg.param_specs())
+    cache = place(jax.eval_shape(lambda: spec.init(cfg, 1)), spec.specs(cfg))
+    return cfg, spec, mesh, params, cache
+
+
+def test_granite_step_compiles_with_the_state_and_the_ring_in_place(granite):
+    """The Mamba-2 / expert step at the published widths: 32 slots, nine
+    state-space layers whose state ``[128, 8192]`` float32 is walked in
+    lane blocks and whose convolution ring is 8448 channels wide (no
+    multiple of the ring kernel's 1024-channel unit: one block holds a
+    layer's ring whole, cut out of the pool for the call), one attention
+    layer on pages, 18 held experts of 768; every pool aliased in and out;
+    no ``[N, d]`` decay of a slot is built."""
+    cfg, spec, mesh, params, cache = granite
+    assert cache["ssm"].shape == (9, 2, 32, 128, 8192)
+    assert cache["conv"].shape == (9, 4, 32, 8448)
+    assert cache["k"].shape == (1, 32 * 128, 8, 128, 128)
+    compiled = _compiled_step(cfg, spec, mesh, params, cache)
+    text = compiled.as_text()
+    for kernel, calls in (("ssd_state_update", 9), ("conv_ring_step", 9),
+                          ("paged_flash_decode", 1), ("group_gemm", 20)):
+        assert text.count(kernel) >= calls, kernel
+    assert "selective_state_update" not in text
+    assert "f32[32,128,8192]" not in text
+    mem = compiled.memory_analysis()
+    pools = sum(int(np.prod(cache[k].shape)) * cache[k].dtype.itemsize
+                for k in ("ssm", "conv", "k", "v"))
+    assert mem.alias_size_in_bytes >= pools
+    print("granite step: temporaries", mem.temp_size_in_bytes,
+          "arguments", mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15e9
+
+
+def test_granite_admission_compiles_in_the_chunked_form(granite):
+    """One slot's admission at bucket 8192: the recurrence through the
+    chunked kernel (32 chunks of 256, the state resident), attention
+    through the tiled kernel; no token-by-token scan, no ``L x L`` array,
+    and the temporaries fit beside the weights, the state and the pages."""
+    L = 8192
+    compiled = _compiled_admission(*granite, L)
+    text = compiled.as_text()
+    assert text.count("ssd_chunk_scan") >= 9 and "flash_prefill" in text
+    assert "selective_scan" not in text
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"\b(?:bf16|f32|s32)\[([\d,]+)\]", text)}
+    # ([L, 8192] is a prompt's rows at the inner width, not L x L)
+    assert not [s for s in shapes if sum(d == L for d in s) >= 3]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= granite[0].state_bytes()
+    print("granite admission: temporaries", mem.temp_size_in_bytes,
+          "arguments", mem.argument_size_in_bytes)
+    assert mem.temp_size_in_bytes < 4.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9

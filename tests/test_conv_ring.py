@@ -135,6 +135,18 @@ def test_the_published_shape_is_blocks_of_1024_channels_with_every_slot():
     assert cr.channel_block(5120 + 128, 64, 4) == 5120 + 128
 
 
+def test_a_ring_wider_than_the_inner_width_is_one_block():
+    """Mamba-2 convolves ``x | B | C``: the ring is ``d_inner + 2 d_state``
+    channels wide (8448 at the published sizes, 8192 + 2 x 128), which the
+    unit of 1024 channels does not divide: one block holds the layer's ring
+    whole, every slot in it. At a small size of the same form (1024 + 2 x
+    128 channels) the step is the twin's and the whole sequence's."""
+    assert cr.channel_block(8192 + 2 * 128, 32, 4) == 8448
+    d = 1024 + 2 * 128
+    assert cr.channel_block(d, 6, K) == d
+    _check(*_case(POSITIONS["mixed"], d=d, stored="bfloat16"))
+
+
 def test_the_kernel_is_named_apart_from_the_recurrences_kernels():
     """perfbench matches ``^selective_scan`` and
     ``^selective_state_update`` in a device trace."""

@@ -855,11 +855,14 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
 
     - attention layers: ``k, v [n_attention_layers, n_pool, h_kv, page,
       d]`` and ``block_table``, as the k/v kind's, over those layers only.
-    - state-space layers, float32, a fixed size a slot whatever the
-      context: ``ssm [n_mamba_layers, 2, slots, d_state, d_inner]`` (the
-      recurrence's state, channels on the lanes) and ``conv
-      [n_mamba_layers, d_conv, slots, d_inner]`` (the convolution's last
-      inputs).
+    - state-space layers (``mamba`` or ``mamba2``, one kind a model),
+      float32, a fixed size a slot whatever the context: ``ssm
+      [n_state_layers, 2, slots, d_state, d_inner]`` (the recurrence's
+      state, channels on the lanes: Mamba-2's heads lie side by side there)
+      and ``conv [n_state_layers, d_conv, slots, cfg.conv_channels]`` (the
+      convolution's last inputs: THE CONFIG says how wide the convolution
+      is, ``d_inner`` for Mamba-1 and ``d_inner + 2 d_state`` for Mamba-2,
+      whose ``B`` and ``C`` are convolved with ``x``).
 
     State is not paged, has no table and is not masked by a length: a
     stale row is not hidden, it is wrong. So both pools are RINGS KEYED BY
@@ -887,7 +890,8 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
         _state_kind_serves(self, n, n_o)
         n_pool, bt = self._table(cfg, n, n_o)
         kinds, b = cfg.layer_kinds, cfg.batch
-        n_attn, n_mamba = kinds.count("attention"), kinds.count("mamba")
+        n_attn = kinds.count("attention")
+        n_mamba = len(kinds) - n_attn
         pool = lambda: jnp.zeros(
             (n_attn, n_pool, cfg.n_kv_heads, self.page_size, cfg.head_dim),
             cfg.dtype)
@@ -895,7 +899,7 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
             k=pool(), v=pool(),
             ssm=jnp.zeros((n_mamba, 2, b, cfg.d_state, cfg.d_inner),
                           jnp.float32),
-            conv=jnp.zeros((n_mamba, cfg.d_conv, b, cfg.d_inner),
+            conv=jnp.zeros((n_mamba, cfg.d_conv, b, cfg.conv_channels),
                            jnp.float32),
             block_table=bt,
             n_alloc=jnp.zeros((bt.shape[0],), jnp.int32),
@@ -992,24 +996,53 @@ class StatePagedKVCacheSpec(PagedKVCacheSpec):
                 d_skip, interpret=interpret)
         return y, dict(cache, ssm=ssm)
 
-    def write_state(self, cache, ki: int, slots, lens, u, h):
+    def head_state_step(self, cache, ki: int, x, dt_in, dt_bias, a, b_in,
+                        c_out, d_skip, pos_b, interpret):
+        """:meth:`state_step` for a layer whose decay is ONE scalar a head
+        (Mamba-2; ``ops/ssd.ssd_state_update``, handed ``dt_bias`` and
+        ``d_skip [heads]`` as stored): the same pool, the same parity."""
+        from triton_dist_tpu.ops.ssd import ssd_state_update
+
+        with _scope("ssm/scan"):
+            y, ssm = ssd_state_update(
+                cache["ssm"], ki, pos_b, x, dt_in, dt_bias, a, b_in, c_out,
+                d_skip, interpret=interpret)
+        return y, dict(cache, ssm=ssm)
+
+    def write_state(self, cache, ki: int, slots, lens, u, h, first=None):
         """An admission's state of the ``ki``-th state-space layer for
         ``slots [n]`` whose prompts hold ``lens [n]`` true tokens: ``h [n,
         d_state, d]`` the recurrence's state after the LAST TRUE token,
-        ``u [n, L, d]`` the convolution's inputs, whose last ``d_conv``
+        ``u [n, L, channels]`` the convolution's inputs, whose last ``d_conv``
         true rows go to their ring rows. No other slot's rows are
-        touched."""
+        touched. ``first [n]``: ``u`` holds the rows of positions ``first``
+        on only (:meth:`tail`: a layer that keeps a whole prompt's inputs
+        until the pass ends holds ``layers x L x channels`` floats)."""
         K = cache["conv"].shape[1]
         last = lens[:, None] - 1
         # ring row r <- the last position p < len with p % K == r (none yet:
         # any row; the step masks it by position)
         src = last - (last - jnp.arange(K, dtype=jnp.int32)) % K   # [n, K]
+        if first is not None:
+            src = src - first[:, None]
         rows = jnp.take_along_axis(
             u, jnp.clip(src, 0, u.shape[1] - 1)[:, :, None], axis=1)
         conv = cache["conv"].at[
             ki, jnp.arange(K)[None, :], slots[:, None]].set(rows)
         ssm = cache["ssm"].at[ki, (lens - 1) % 2, slots].set(h)
         return dict(cache, conv=conv, ssm=ssm)
+
+    @staticmethod
+    def tail(u, lens, taps: int):
+        """The last ``taps`` true rows of ``u [n, L, channels]`` (from
+        position 0 where a prompt is shorter) and the position of the first
+        of them: what :meth:`write_state` reads of ``u``, as ``(rows [n,
+        taps, channels], first [n])``."""
+        first = jnp.maximum(lens - taps, 0)
+        idx = first[:, None] + jnp.arange(taps, dtype=jnp.int32)
+        rows = jnp.take_along_axis(
+            u, jnp.minimum(idx, u.shape[1] - 1)[:, :, None], axis=1)
+        return rows, first
 
     def update_and_attend(self, *a, **kw):
         raise NotImplementedError(

@@ -34,8 +34,9 @@ PARTS: dict[str, tuple[str, ...]] = {
     "ffn": ("gate_up", "act", "down", "route", "experts", "shared"),
     # a state-space mixer whole: norm, in-projection, the convolution and
     # its ring, the dt / B / C projections, the recurrence kernel, the
-    # gate and out-projection
-    "ssm": ("proj", "conv", "scan"),
+    # gate and out-projection; the norm over the gated output where the
+    # block has one (Mamba-2: a reduction over the whole inner width)
+    "ssm": ("proj", "conv", "scan", "norm"),
     # a power-retention mixer whole: norm, the q/k/v projection with the
     # head norms and the rotation (``qkv``), the gate's projection and
     # log-sigmoid, the step's in-place state kernel (``update``) or an
