@@ -230,6 +230,19 @@ def _interpret_mode():
     yield
 
 
+@pytest.fixture
+def chip_posture():
+    """The program a chip runs, traced here: ``config.interpreting()``
+    false for one test (no validating ``lax.cond``, and so no sort of its
+    own, around the routed combine)."""
+    from triton_dist_tpu import config
+
+    posture = config.get_config().interpret
+    config.update(interpret=False)
+    yield
+    config.update(interpret=posture)
+
+
 @pytest.fixture(autouse=True)
 def _resilience_isolation():
     """The resilience health registry is process-global: a watchdog

@@ -783,7 +783,15 @@ def test_joyai_step_compiles_to_the_parents_program(topo):
     walked, a constant of the step), so the vector's own entries read
     ``s32[4]`` for ``s32[3]`` (its four pads, three adds and one fusion
     of the sum over layers) beside one more ``s32[1]`` constant; every
-    other instruction is the parent's."""
+    other instruction is the parent's. PR 49 did again, in that vector
+    alone: it holds a fifth int32 (the rows the combine gathered, a
+    constant of the step), and the compiler builds the five as ONE
+    ``concatenate s32[5]`` where it padded and added the four: ``add
+    s32[4]`` x3, ``pad s32[4]`` x4 and ``fusion s32[4]`` x1 are gone,
+    ``constant s32[1]`` 2 -> 3, ``parameter s32[1]`` 13 -> 9 and
+    ``constant s32[]`` 369 -> 368; every other instruction is the
+    parent's (the combine of a step is the ``topk``-gather form it was:
+    the landed walk is a share's chunked admission's)."""
     import collections
     import json
 
@@ -847,8 +855,15 @@ def test_dots_admission_compiles_with_no_square_of_float_scores(dots):
     # at a chunk's rows and never at the alignment's, and each chunk's
     # down GEMM writes its rows of the one result (8 chunks' rows) in place
     assert "bf16[9216,3072]" in text and "bf16[69760,3072]" not in text
-    assert sum(" while(" in line and "bf16[73728,5120]" in line
-               for line in text.splitlines()) == 4
+    # three loops a layer hold that result: the pass that writes it and,
+    # since PR 49, the combine's walk of the landed slots that reads it:
+    # a loop over the 16 chunks of 512 tokens around a loop over a chunk's
+    # trips, whose carry is the chunk's float32 sum
+    loops = [line for line in text.splitlines()
+             if " while(" in line and "bf16[73728,5120]" in line]
+    assert len(loops) == 12
+    assert sum("f32[16,512,5120]" in line for line in loops) == 4
+    assert sum("f32[512,5120]" in line for line in loops) == 4
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 15e9
     # the selection leaves as int8; in float32 the scores exist a block of

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from triton_dist_tpu.models import gated_experts as ge
+from triton_dist_tpu.ops import moe_utils
 
 PERFBENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
 if PERFBENCH not in sys.path:
@@ -65,6 +66,8 @@ def _layer(c: Toy, favoured=()):
 REGIMES = {
     # 2 of 16 held, the router left alone: about 7/8 of the rows away
     "a_share_with_most_away": ((0, 2), (), None),
+    # 4 of 16 held: a quarter of the bank, about 3/4 of the rows away
+    "a_quarter_share": ((4, 4), (), None),
     # every expert held: only the worst-case tail is dead
     "the_full_bank": ((0, 16), (), None),
     # every row chooses two experts held elsewhere: zero chunks, the
@@ -75,16 +78,39 @@ REGIMES = {
 }
 
 
+def _weights_of_one_bit(monkeypatch):
+    """Round every routing weight down to a power of two, in both passes
+    alike. A share's chunked pass combines by the landed walk and the one
+    straight-line call by ``topk`` gathers: the same float32 products
+    added in the same order, but XLA's CPU backend contracts a multiply
+    and an add into one rounding, and not the same pair in the two forms
+    (1 ulp apart with free weights). With one-bit weights every product is
+    exact, so the contraction rounds what the MATERIALISED products would,
+    and ``array_equal`` tests the order of the additions."""
+    route = ge.route
+
+    def one_bit(c, h, p):
+        w, ids = route(c, h, p)
+        return 2.0 ** jnp.floor(jnp.log2(w)), ids
+
+    monkeypatch.setattr(ge, "route", one_bit)
+
+
 @pytest.mark.parametrize("regime", sorted(REGIMES))
 def test_the_live_prefix_pass_is_the_whole_pass_bit_for_bit(regime, monkeypatch):
-    """Chunks of 6 blocks over an alignment of 35 (a share) or 46 (the
+    """Chunks of 6 blocks over an alignment of 35 / 37 (a share) or 46 (the
     bank): the same output and counters as the one straight-line call
     over every block, with the rows never walked holding NaN (so one read
-    as a number would show), and ``sorted_rows_walked`` the live prefix
-    rounded up to whole chunks."""
+    as a number would show, by the pass or by the combine after it),
+    ``sorted_rows_walked`` the live prefix rounded up to whole chunks, and
+    ``combine_rows_gathered`` the landed slots + the rows + the trips'
+    rounding on a share, ``topk x rows`` where every slot holds a result."""
     held, favoured, live_want = REGIMES[regime]
     c = Toy(held=held)
     p, h = _layer(c, favoured)
+    _weights_of_one_bit(monkeypatch)
+    walk_rows = 16
+    monkeypatch.setattr(moe_utils, "COMBINE_WALK_ROWS", walk_rows)
     chunk = ge._chunk_blocks(c, BLOCK_M)
     al = ge.route_rows(c, h, p, BLOCK_M)[3]
     n_blocks = al.expert_ids.shape[0]
@@ -118,6 +144,27 @@ def test_the_live_prefix_pass_is_the_whole_pass_bit_for_bit(regime, monkeypatch)
     assert whole_stats[3] == n_blocks * BLOCK_M
     if live == 0:       # no assignment here: the routed part adds nothing
         assert stats[1] == 0 and walked == 0
+    gathered = dict(zip(ge.MOE_STATS, stats))["combine_rows_gathered"]
+    assert whole_stats[4] == c.topk * ROWS
+    if held[1] == c.n_experts:      # every slot landed: the whole form
+        assert gathered == c.topk * ROWS
+    else:       # the walk: what landed, a trip's rounding for each j, m
+        landed = stats[1]
+        assert landed + ROWS <= gathered <= landed + ROWS + c.topk * walk_rows
+        assert (gathered - ROWS) % walk_rows == 0
+        assert (landed == 0) == (gathered == ROWS)
+
+
+def test_a_partly_dead_last_chunk_is_among_the_regimes():
+    """The two free-routed shares end inside a chunk (their live prefix is
+    no whole number of chunks), so the combine's rows come from a last
+    chunk whose trailing blocks are dead: written as zeros, never NaN."""
+    for regime in ("a_share_with_most_away", "a_quarter_share"):
+        c = Toy(held=REGIMES[regime][0])
+        p, h = _layer(c)
+        al = ge.route_rows(c, h, p, BLOCK_M)[3]
+        live = int(al.num_tokens_post_pad) // BLOCK_M
+        assert live % ge._chunk_blocks(c, BLOCK_M), regime
 
 
 def test_a_dead_block_names_the_last_live_block_of_a():
@@ -179,6 +226,8 @@ def _expert_layer(cell_name: str):
 PASSES = {
     "dots3-note-prev-ep8.doc-reason": [(32, ge.DECODE_BLOCK_M, 1),
                                        (8192, ge.PREFILL_BLOCK_M, 8)],
+    "granite-4.0-h-small-ep4.doc-reason": [(32, ge.DECODE_BLOCK_M, 1),
+                                           (8192, ge.PREFILL_BLOCK_M, 19)],
     "k-exaone-236b-a23b-ep8.reason-long": [(32, ge.DECODE_BLOCK_M, 1),
                                            (256, ge.PREFILL_BLOCK_M, 1)],
     "joyai-llm-flash.reason": [(16, ge.DECODE_BLOCK_M, 1),
@@ -205,5 +254,40 @@ def test_a_pass_of_at_most_one_chunk_traces_no_loop(cell):
         groups = n_held if n_held == cfg.n_experts else n_held + 1
         n_blocks = -(-(t + min(groups, t) * (block_m - 1)) // block_m)
         assert -(-n_blocks // ge._chunk_blocks(cfg, block_m)) == chunks, n_blocks
-        assert text.count("while[") == (chunks > 1), (rows, chunks)
+        share = n_held < cfg.n_experts
+        # the sorted-row pass's loop and, behind a share's, the combine's
+        # (a chunk of tokens' trips, inside a scan over the chunks)
+        assert text.count("while[") == (chunks > 1) * (1 + share), (rows, chunks)
         assert text.count("pallas_call[") == 2
+
+
+@pytest.mark.parametrize("cell", sorted(PASSES))
+def test_the_combine_walks_behind_a_shares_chunked_pass_only(
+        cell, chip_posture):
+    """In the chip's posture (no validating ``lax.cond``) a routed pass
+    sorts its assignments twice, as it did: the alignment's sort and the
+    combine's of the padded slot ids. A whole bank's pass and a pass of at
+    most one chunk keep the ``topk`` gathers of every token's row; a
+    share's long admission sorts its ``rows`` tokens by their landed slots
+    besides and gathers rows of the result a chunk a trip inside its walk
+    and once after it."""
+    cfg, layer = _expert_layer(cell)
+    for rows, block_m, chunks in PASSES[cell]:
+        h = jax.ShapeDtypeStruct((rows, cfg.hidden), cfg.dtype)
+        text = str(jax.make_jaxpr(
+            lambda h, p: ge.moe_mlp(cfg, h, p, block_m, True))(h, layer))
+        sorts = [line for line in text.splitlines() if " sort[" in line]
+        walk = chunks > 1 and cfg.held[1] < cfg.n_experts
+        assert len(sorts) == 2 + walk, (rows, sorts)
+        assert f"i32[{rows * cfg.topk}]" in sorts[0]
+        if walk:
+            assert f"i32[{rows}]" in sorts[2]
+        gathers = lambda shape: sum(
+            " gather[" in line and shape in line.split("=")[0]
+            for line in text.splitlines())
+        every = gathers(f"bf16[{rows},{cfg.hidden}]")
+        assert every == (0 if walk else cfg.topk), (rows, every)
+        if walk:
+            trip = min(moe_utils.COMBINE_WALK_ROWS, rows)
+            assert gathers(f"bf16[{trip},{cfg.hidden}]") == 1
+            assert gathers(f"f32[{rows},{cfg.hidden}]") == 1

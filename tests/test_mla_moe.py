@@ -181,15 +181,16 @@ def test_router_against_numpy_twin():
 
 def test_routing_counters_on_a_hand_made_routing():
     """(f) experts_hit, assignments, expert_load_max, and the sorted rows
-    the layer's pass walked as the pass hands them over."""
+    the layer's pass walked and the rows its combine gathered as the pass
+    hands them over."""
     ids = jnp.array([[0, 3], [3, 5], [3, 0], [7, 3]], jnp.int32)
-    st = mla_moe.routing_stats(ids, jnp.ones_like(ids, bool), 8, 48)
-    assert [int(x) for x in st] == [4, 8, 4, 48]      # 0,3,5,7; 8; expert 3
+    st = mla_moe.routing_stats(ids, jnp.ones_like(ids, bool), 8, 48, 8)
+    assert [int(x) for x in st] == [4, 8, 4, 48, 8]   # 0,3,5,7; 8; expert 3
     # a share holding experts 4..7: local ids, the rest is elsewhere
     local = ids - 4
     here = (local >= 0) & (local < 4)
     st = mla_moe.routing_stats(jnp.where(here, local, 0), here, 4)
-    assert [int(x) for x in st] == [2, 2, 1, 0]       # experts 5 and 7
+    assert [int(x) for x in st] == [2, 2, 1, 0, 0]    # experts 5 and 7
 
 
 def test_lookahead_same_tokens_and_counters_round_for_round(toy):
@@ -236,7 +237,10 @@ def test_an_admissions_span_says_how_far_its_sorted_row_pass_went(adapter):
     pass walked them and no more (a chunk is one block of 128 rows at
     ``expert_ffn`` 32: ``gated_experts._chunk_blocks``), never the whole
     alignment of 17; a decode round walks its one straight-line call's
-    every row, a constant."""
+    every row, a constant. ``combine_rows_gathered`` beside it: the whole
+    bank is held, every slot holds a result, and the combine gathers
+    ``topk`` rows a token (the landed walk is a share's:
+    tests/test_live_prefix.py reads the counter there)."""
     from triton_dist_tpu.models import gated_experts
 
     cfg = adapter.model_config(sized(dict(TOY, n_routed_experts=16)))
@@ -256,6 +260,8 @@ def test_an_admissions_span_says_how_far_its_sorted_row_pass_went(adapter):
         assert attrs["sorted_rows_walked"] == (
             min(-(-live // chunk) * chunk, n_blocks) * 128)
         assert attrs["sorted_rows_walked"] < n_blocks * 128
+        assert attrs["combine_rows_gathered"] == t == attrs["assignments"]
     rounds = by_name["tdt.batcher.decode_round"]
     # 2 slots x top-2 = 4 assignments on at most 4 experts: 4 + 4 x 15 rows
     assert rounds and {a["sorted_rows_walked"] for a in rounds} == {64}
+    assert {a["combine_rows_gathered"] for a in rounds} == {4}

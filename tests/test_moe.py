@@ -14,7 +14,9 @@ from jax.sharding import PartitionSpec as P
 from triton_dist_tpu.ops.allgather_group_gemm import ag_group_gemm_op
 from triton_dist_tpu.ops.group_gemm import GroupGemmConfig, group_gemm
 from triton_dist_tpu.ops.moe_reduce_rs import moe_reduce_rs_op
+from triton_dist_tpu.ops import moe_utils
 from triton_dist_tpu.ops.moe_utils import (
+    combine_rows_gathered,
     gather_sorted_rows,
     moe_align_block_size,
     scatter_add_unsorted,
@@ -109,6 +111,121 @@ def test_gather_scatter_roundtrip():
     np.testing.assert_allclose(
         np.asarray(back_guard), want, rtol=1e-5, atol=1e-5
     )
+
+
+def _share(n_exp: int, n_held: int, m: int, topk: int, bm: int, h: int):
+    """A share's routing as ``gated_experts`` aligns it (the experts held
+    elsewhere one group, sorted last), with hand-made tokens: token 0 has
+    NO landed slot, token 1 ALL ``topk``. ``y`` holds NaN in every row past
+    the live prefix (the rows no chunk wrote) and some -0.0 among token 1's
+    rows. The weights are powers of two: each product ``y x w`` is then
+    exact in float32, so a compiler that contracts a multiply and an add
+    into one rounding (XLA's CPU backend does, and not alike in the two
+    forms: 1 ulp between them with free weights) gives the sum of the
+    MATERIALISED products whatever it contracts, and ``array_equal`` tests
+    the order of the additions, which is what the two forms could differ
+    in."""
+    k = jax.random.split(jax.random.PRNGKey(n_exp + m), 3)
+    ids = jnp.argsort(jax.random.uniform(k[0], (m, n_exp)), axis=1)[:, :topk]
+    ids = ids.at[0].set(n_held + jnp.arange(topk)).at[1].set(jnp.arange(topk))
+    here = ids < n_held
+    al = moe_align_block_size(
+        jnp.where(here, ids, n_held).reshape(-1).astype(jnp.int32),
+        n_held + 1, bm, ragged=True)
+    live = int(jnp.sum((al.valid_rows > 0) & (al.expert_ids < n_held))) * bm
+    y = jax.random.normal(k[1], (al.sorted_token_ids.shape[0], h), jnp.float32)
+    inv = jnp.argsort(al.sorted_token_ids, stable=True)[:m * topk]
+    y = y.at[inv[topk:2 * topk], :3].set(-0.0).astype(jnp.bfloat16)
+    y = y.at[live:].set(jnp.nan)
+    w = 2.0 ** jax.random.randint(k[2], (m, topk), -4, 1).astype(jnp.float32)
+    return al, y, jnp.where(here, w, 0.0), here, inv.reshape(m, topk)
+
+
+@pytest.mark.parametrize("n_exp,n_held,topk", [(24, 6, 5), (32, 4, 4)],
+                         ids=["a_quarter", "an_eighth"])
+def test_the_landed_walk_is_the_whole_form_bit_for_bit(
+        n_exp, n_held, topk, monkeypatch):
+    """``scatter_add_unsorted(written=here)`` walks the landed slots; the
+    ``topk``-gather form over the same rows with zeros where nobody wrote
+    (an unlanded slot's term is then ``0.0 x 0.0``, the +0.0 its selection
+    gave before) is its reference, ``array_equal`` on float32: a token
+    with no landed
+    slot, one with all of them (-0.0 among its terms), trips whose last
+    rows reach past their prefix, and NaN in every row of ``y`` that no
+    chunk wrote (fetched and USED, it would show)."""
+    m, h = 96, 24
+    monkeypatch.setattr(moe_utils, "COMBINE_WALK_ROWS", 16)
+    al, y, w, here, inv = _share(n_exp, n_held, m, topk, 4, h)
+    landed = np.asarray(here).sum(1)
+    assert landed[0] == 0 and landed[1] == topk
+    assert any((landed > j).sum() % 16 for j in range(topk))
+    got = jax.jit(lambda y, al, w, here: scatter_add_unsorted(
+        y, al, w, m, written=here))(y, al, w, here)
+    want = jax.jit(moe_utils._slot_sum)(jnp.nan_to_num(y), inv, w)
+    assert got.dtype == jnp.float32 and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got)[0].any()
+    # the sign of a zero too: where every slot landed and every term is
+    # -0.0 the whole form gives -0.0, elsewhere +0.0
+    np.testing.assert_array_equal(np.signbit(np.asarray(got)),
+                                  np.signbit(np.asarray(want)))
+    assert np.signbit(np.asarray(want)[1, :3]).all()
+    # against plain numpy: float32 products, added in ascending k
+    inv = np.asarray(inv)
+    ref = np.zeros((m, h), np.float32)
+    for k in range(topk):
+        rows = np.asarray(y.astype(jnp.float32))[inv[:, k]]
+        ref += np.where(np.asarray(here)[:, k, None],
+                        rows * np.asarray(w)[:, k, None], np.float32(0))
+    np.testing.assert_array_equal(np.asarray(got), ref)
+
+
+@pytest.mark.parametrize("n_exp,n_held,topk", [(24, 6, 5), (32, 4, 4)],
+                         ids=["a_quarter", "an_eighth"])
+def test_the_combine_counts_the_rows_it_gathers(n_exp, n_held, topk,
+                                                monkeypatch):
+    """``combine_rows_gathered``: ``topk x m`` in the whole form; on the
+    walk the landed slots, the ``m`` rows of the last gather and at most
+    one chunk's rows of rounding for each ``j`` that any token reaches."""
+    m, rows = 96, 16
+    monkeypatch.setattr(moe_utils, "COMBINE_WALK_ROWS", rows)
+    here = _share(n_exp, n_held, m, topk, 4, 8)[3]
+    assert combine_rows_gathered(m, topk) == topk * m
+    got = int(combine_rows_gathered(m, topk, here))
+    landed = np.asarray(here).sum(1)
+    reached = sum((landed > j).any() for j in range(topk))
+    assert int(landed.sum()) + m <= got <= int(landed.sum()) + m + rows * reached
+    assert got == m + sum(-(-int((landed > j).sum()) // rows) * rows
+                          for j in range(topk))
+    assert got < topk * m
+    assert int(combine_rows_gathered(m, topk, jnp.zeros_like(here))) == m
+
+
+@pytest.mark.parametrize("form", ["whole", "landed_walk"])
+def test_the_combine_sorts_no_assignment_twice(form, chip_posture):
+    """The sorts in the combine's program: the one over the padded slot
+    ids (each slot's row) in both forms, and on the landed walk one more
+    of ``m`` keys (the tokens by their landed slots), never a second one
+    over the assignments. The whole form is ``topk`` gathers of every
+    token's row and no loop; the walk gathers inside its loops, a chunk's
+    rows a trip, and once after them."""
+    m, topk, h = 96, 4, 8
+    al, y, w, here, _ = _share(32, 4, m, topk, 4, h)
+    written = here if form == "landed_walk" else None
+    text = str(jax.make_jaxpr(lambda y, al, w: scatter_add_unsorted(
+        y, al, w, m, written=written))(y, al, w))
+    sorts = [line for line in text.splitlines() if " sort[" in line]
+    assert f"i32[{al.sorted_token_ids.shape[0]}]" in sorts[0]
+    gathers = lambda shape: sum(" gather[" in line and shape in line.split("=")[0]
+                                for line in text.splitlines())
+    if form == "whole":
+        assert len(sorts) == 1 and "while[" not in text and "scan[" not in text
+        assert gathers(f"bf16[{m},{h}]") == topk
+    else:
+        assert len(sorts) == 2 and f"i32[{m}]" in sorts[1]
+        # a scan over the chunks of tokens, a loop over a chunk's trips
+        assert text.count("scan[") == 1 and text.count("while[") == 1
+        assert gathers(f"bf16[{m},{h}]") == 1 and gathers(f"f32[{m},{h}]") == 1
 
 
 def _moe_golden(a, b, topk_ids):

@@ -122,7 +122,7 @@ TOY2 = sized(dict(
 SIZES2 = TOY2["sizes"]
 PUBLISHED2 = os.path.join(PERFBENCH, "configs", "granite-4.0-h-small-ep4.json")
 MOE_COUNTERS = ("experts_hit", "assignments", "expert_load_max",
-                "sorted_rows_walked")
+                "sorted_rows_walked", "combine_rows_gathered")
 
 
 def _engine_spans2(cfg, params, by_name, requests, eng):

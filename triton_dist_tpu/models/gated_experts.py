@@ -47,14 +47,14 @@ from triton_dist_tpu.ops.group_gemm import (
     GroupGemmConfig, dead_blocks_refetch_none, group_gemm,
 )
 from triton_dist_tpu.ops.moe_utils import (
-    gather_sorted_rows, moe_align_block_size, scatter_add_unsorted,
-    select_experts,
+    combine_rows_gathered, gather_sorted_rows, moe_align_block_size,
+    scatter_add_unsorted, select_experts,
 )
 from triton_dist_tpu.utils import axis_size as _axis_size
 
 # counters a pass returns, summed over its expert layers (docs/observability.md)
 MOE_STATS = ("experts_hit", "assignments", "expert_load_max",
-             "sorted_rows_walked")
+             "sorted_rows_walked", "combine_rows_gathered")
 # rows per grouped-GEMM block: small at decode, where a step's assignments
 # spread over more experts than there are rows (chip, PR 28: 16-row blocks
 # over the min(E, T) alignment 1.376 ms a layer, 32-row 1.410, 8-row 1.363)
@@ -185,14 +185,16 @@ def _align_share(local, here, n_held: int, block_m: int):
         num_tokens_post_pad=jnp.sum(valid_rows > 0, dtype=jnp.int32) * block_m)
 
 
-def routing_stats(local_ids, here, n_held: int, rows_walked=0) -> jax.Array:
+def routing_stats(local_ids, here, n_held: int, rows_walked=0,
+                  rows_combined=0) -> jax.Array:
     """One layer's ``MOE_STATS`` int32: ``[experts hit, assignments,
     largest count on one expert]`` of its routing over the experts held
-    here, and the sorted rows its pass walked."""
+    here, the sorted rows its pass walked and the rows its combine
+    gathered."""
     counts = jnp.zeros((n_held,), jnp.int32).at[local_ids.reshape(-1)].add(
         here.reshape(-1).astype(jnp.int32))
     return jnp.stack([jnp.sum(counts > 0), jnp.sum(counts), jnp.max(counts),
-                      rows_walked]).astype(jnp.int32)
+                      rows_walked, rows_combined]).astype(jnp.int32)
 
 
 def no_stats() -> jax.Array:
@@ -202,9 +204,11 @@ def no_stats() -> jax.Array:
 
 def add_stats(stats, st):
     """A pass's counters with one more layer's: hit, assignments and the
-    sorted rows walked add over layers; the load is the largest seen."""
+    rows walked and gathered add over layers; the load is the largest
+    seen."""
     return jnp.stack([stats[0] + st[0], stats[1] + st[1],
-                      jnp.maximum(stats[2], st[2]), stats[3] + st[3]])
+                      jnp.maximum(stats[2], st[2]), stats[3] + st[3],
+                      stats[4] + st[4]])
 
 
 def route_rows(c, rows, p, block_m: int):
@@ -270,7 +274,7 @@ def _blocks(al, start, n: int):
 
 def moe_mlp(c, h, p, block_m: int, interpret=None, routing=None):
     """Routed experts (the share held here) + the shared expert on rows
-    ``h [m, H]``: ``(y [m, H], stats int32[4])``, the stats ``MOE_STATS``.
+    ``h [m, H]``: ``(y [m, H], stats int32[5])``, the stats ``MOE_STATS``.
     ``routing`` is :func:`route_rows` of the rows the model's router
     reads, where those are not ``h`` (issued earlier in the pass); None
     routes on ``h``.
@@ -283,13 +287,23 @@ def moe_mlp(c, h, p, block_m: int, interpret=None, routing=None):
     to its last live block and no further: the trip count is data, the
     program is one. Each live block goes through the same kernels with the
     same tiles as in one whole call, so the result is that call's bit for
-    bit; the rows never walked are never written, and the combine selects
-    them away (``written=here``). ``sorted_rows_walked`` says how far a
-    pass went. An alignment of at most one chunk (every decode step) is
-    walked whole, in straight-line calls. Each chunk's down GEMM writes
-    its rows of the one result in place (``group_gemm(into=)``), and the
-    alignment is padded with dead blocks to whole chunks, so no live block
-    is walked twice.
+    bit; the rows never walked are never written. ``sorted_rows_walked``
+    says how far a pass went. An alignment of at most one chunk (every
+    decode step) is walked whole, in straight-line calls. Each chunk's
+    down GEMM writes its rows of the one result in place
+    (``group_gemm(into=)``), and the alignment is padded with dead blocks
+    to whole chunks, so no live block is walked twice.
+
+    The weighted combine after it (``scatter_add_unsorted``) runs in one of
+    two forms that follow from the same two shapes the pass was picked by.
+    Behind a chunked pass over a SHARE (``written=here``) most slots name
+    rows nobody wrote, and the combine walks the landed ones: a trip count
+    that is data again, no unwritten row fetched, the float32 sum of each
+    token's landed terms in ascending ``k`` as before, bit for bit. Behind
+    a whole bank's pass or one of at most one chunk every slot holds a
+    result, and the combine is ``topk`` gathers of every token's row, the
+    right form when all landed. ``combine_rows_gathered`` says what either
+    fetched.
 
     Inside ``scope("ffn")``: ``ffn/route`` is what a routed layer runs
     around its GEMMs (scores and top-k, the alignment, the gather of
@@ -351,5 +365,6 @@ def moe_mlp(c, h, p, block_m: int, interpret=None, routing=None):
             out = out + gated_mlp(
                 c, h, p["ws_gate_up"], p["ws_down"]).astype(jnp.float32)
     with scope("ffn/route"):
-        stats = routing_stats(local, here, n_held, walked * block_m)
+        stats = routing_stats(local, here, n_held, walked * block_m,
+                              combine_rows_gathered(m, c.topk, written))
     return out.astype(h.dtype), stats

@@ -320,6 +320,99 @@ def gather_sorted_rows(
     return x[token_of_row]
 
 
+# Tokens in one chunk of the landed walk (:func:`_landed_sum`): a trip
+# gathers this many rows of `y_sorted` (chip, PR 49, a layer's combine at
+# granite's / dots3's admission, 256 / 512 / 1024: 2.74 / 2.76 / 3.16 and
+# 2.44 / 2.43 / 2.68 ms, against 6.86 / 6.88 for the whole form)
+COMBINE_WALK_ROWS = 512
+
+
+def _slot_sum(y_sorted, inv, w):
+    """The combine's WHOLE form: one gather of all ``m`` token rows for
+    each of the ``topk`` slots, weighted and added in ascending ``k`` in
+    float32. ``inv [m, topk]`` is each slot's row of `y_sorted`."""
+    # one row-gather per k slot: the obvious single [t, k, d] gather
+    # measures 2.6x slower on chip (the 3-D intermediate's layout
+    # defeats the streaming fusion); topk is small and static
+    def term(k):
+        return y_sorted[inv[:, k]].astype(jnp.float32) * w[:, k][:, None]
+
+    out = term(0)
+    for k in range(1, w.shape[1]):
+        out = out + term(k)
+    return out
+
+
+def combine_rows_gathered(n_tokens: int, topk: int, written=None):
+    """The rows :func:`scatter_add_unsorted` gathers for these arguments:
+    ``topk x n_tokens`` in the whole form; on the landed walk its trips'
+    rows of `y_sorted` (the landed slots, and for each ``j`` the rounding
+    of the tokens that have a ``j``-th one up to whole chunks) and the
+    ``n_tokens`` rows of the last gather."""
+    if written is None:
+        return topk * n_tokens
+    rows = min(COMBINE_WALK_ROWS, n_tokens)
+    landed = jnp.sum(written, axis=1, dtype=jnp.int32)
+    reach = jnp.sum(landed[:, None] > jnp.arange(topk, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    return jnp.sum((reach + rows - 1) // rows) * rows + n_tokens
+
+
+def _landed_sum(y_sorted, inv, w, written):
+    """The combine's LANDED walk, :func:`_slot_sum` bit for bit where few
+    of a token's slots are ``written``. Each token's landed slots move to
+    the front in ascending ``k`` (a count along ``topk``, no sort over the
+    assignments) and the tokens are ordered by how many they have, most
+    first (one sort of ``m`` keys). A chunk of ``COMBINE_WALK_ROWS`` tokens
+    of that order then needs as many trips as its FIRST token has landed
+    slots, a number on the device: trip ``j`` gathers the chunk's ``j``-th
+    landed rows of `y_sorted`, times their weight, into the chunk's float32
+    sum (a trip count that is data: one program whatever landed). One last
+    gather puts the sums back in token order. A slot that did not land
+    names no row to fetch; it adds exactly 0.0 in the whole form and is
+    left out here, which changes no bit (a sum starts at -0.0, the
+    identity, where every slot landed, as the whole form starts at its
+    first term; at +0.0, an unlanded slot's term, elsewhere)."""
+    m, topk = w.shape
+    rows = min(COMBINE_WALK_ROWS, m)
+    m_pad = round_up(m, rows)
+    landed = jnp.sum(written, axis=1, dtype=jnp.int32)
+    # slot k of a token is its `place`-th landed one
+    place = jnp.cumsum(written, axis=1, dtype=jnp.int32) - 1
+    front = written[:, None, :] & (
+        place[:, None, :] == jnp.arange(topk, dtype=jnp.int32)[None, :, None])
+    order = jnp.argsort(-landed, stable=True)
+
+    def chunked(x):     # [m, ...] by token -> [chunks, rows, ...] in `order`
+        x = jnp.pad(x[order], [(0, m_pad - m)] + [(0, 0)] * (x.ndim - 1))
+        return x.reshape(m_pad // rows, rows, *x.shape[1:])
+
+    def fronted(x):     # [m, topk] by slot -> [chunks, topk, rows] by place
+        x = jnp.sum(jnp.where(front, x[:, None, :], 0), axis=2)
+        return chunked(x).transpose(0, 2, 1)
+
+    def chunk_sum(chunk):
+        inv_c, w_c, landed_c = chunk      # [topk, rows] x2, [rows]
+
+        def add(j, acc):
+            take = lambda x: jax.lax.dynamic_index_in_dim(x, j, keepdims=False)
+            term = y_sorted[take(inv_c)].astype(jnp.float32)
+            # a token with no j-th landed slot keeps its sum (a selection:
+            # the row fetched for it is row 0, written whenever a trip
+            # runs, and is never added)
+            return jnp.where((landed_c > j)[:, None],
+                             acc + term * take(w_c)[:, None], acc)
+
+        start = jnp.where(landed_c == topk, -0.0, 0.0).astype(jnp.float32)
+        return jax.lax.fori_loop(0, landed_c[0], add, jnp.broadcast_to(
+            start[:, None], (rows, y_sorted.shape[1])))
+
+    acc = jax.lax.map(chunk_sum, (fronted(inv), fronted(w), chunked(landed)))
+    back = jnp.zeros((m,), jnp.int32).at[order].set(
+        jnp.arange(m, dtype=jnp.int32), unique_indices=True)
+    return acc.reshape(m_pad, -1)[back]
+
+
 def scatter_add_unsorted(
     y_sorted: jax.Array,
     alignment: MoEAlignment,
@@ -347,6 +440,14 @@ def scatter_add_unsorted(
     a stable argsort of the slot ids IS the inverse permutation, and the
     combine becomes gather + weighted sum, both streaming ops (0.89 ms
     on chip).
+
+    WHICH FORM RUNS, from the arguments alone: without ``written`` every
+    slot holds a result and the combine is ``topk`` gathers of all
+    ``n_tokens`` rows (:func:`_slot_sum`); with it the caller's pass
+    stopped short, most slots name rows nobody wrote, and the combine
+    walks the landed ones (:func:`_landed_sum`: the same float32 sum in
+    the same order, bit for bit, and no unwritten row fetched).
+    :func:`combine_rows_gathered` counts either's rows.
 
     ``assume_bijective`` is that CONTRACT, not a PRODUCTION runtime check
     (a traced guard + ``lax.cond`` costs ~1.1 ms — re-measured r5): pass
@@ -385,19 +486,9 @@ def scatter_add_unsorted(
     def bijective_gather(ids):
         inv = jnp.argsort(ids, stable=True)[:t].reshape(n_tokens, topk)
         w = weights.astype(jnp.float32)
-        # one row-gather per k slot: the obvious single [t, k, d] gather
-        # measures 2.6x slower on chip (the 3-D intermediate's layout
-        # defeats the streaming fusion); topk is small and static
-        def term(k):
-            rows = y_sorted[inv[:, k]].astype(jnp.float32)
-            if written is not None:
-                rows = jnp.where(written[:, k][:, None], rows, 0.0)
-            return rows * w[:, k][:, None]
-
-        out = term(0)
-        for k in range(1, topk):
-            out = out + term(k)
-        return out
+        if written is None:
+            return _slot_sum(y_sorted, inv, w)
+        return _landed_sum(y_sorted, inv, w, written)
 
     if not assume_bijective:
         return masked_scatter(ids)
